@@ -59,7 +59,7 @@ from .findings import (
 )
 
 CHECK_DIRS = ("detectmateservice_tpu", "scripts", "tests")
-TOP_FILES = ("bench.py", "__graft_entry__.py")
+TOP_FILES = ("bench.py", "chip_smoke.py", "__graft_entry__.py")
 SKIP_PARTS = {"__pycache__", "_native"}
 SKIP_FILES = {"schemas_pb2.py"}  # generated by protoc
 

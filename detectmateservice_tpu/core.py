@@ -154,26 +154,6 @@ class Service:
         from .utils.backend import request_platform
 
         request_platform(settings.backend)
-        # shared persistent compile cache (dmwarm): armed BEFORE the
-        # component loads so the very first jit — warm-up included — is
-        # cache-backed. Replicas and dmroll candidates pointed at the same
-        # compile_cache_dir reuse each other's compiles; the settings
-        # validator already proved the dir writable. Gated on the setting so
-        # non-jax stages never pay the jax import.
-        self.compile_cache_dir: Optional[str] = None
-        if settings.compile_cache_enabled:
-            from .utils.profiling import enable_compilation_cache
-
-            self.compile_cache_dir = enable_compilation_cache(
-                settings.compile_cache_dir or "")
-            if self.compile_cache_dir:
-                self.logger.info("persistent compile cache armed at %s",
-                                 self.compile_cache_dir)
-            else:
-                self.logger.warning(
-                    "compile_cache_enabled but the persistent cache did not "
-                    "arm (no usable directory — set compile_cache_dir, or "
-                    "DETECTMATE_JAX_CACHE for the env path)")
         # multi-host chip plane: when a coordinator is configured, join this
         # process's devices into the global mesh BEFORE any component can
         # initialize a jax backend. The import stays behind the check — the
@@ -185,6 +165,30 @@ class Service:
             from .parallel.distributed import initialize_from_settings
 
             initialize_from_settings(settings, self.logger)
+        # persistent compile cache (utils/profiling.py): armed BEFORE the
+        # component loads so the very first jit — warm-up included — is
+        # cache-backed. JAX_COMPILATION_CACHE_DIR places it; without that
+        # variable compile_cache_dir does (the settings validator already
+        # proved it writable), else the fixed in-checkout default. Gated on
+        # the setting so non-jax stages never pay the jax import; the
+        # backend is resolved first because the cache's off-on-CPU default
+        # asks jax which backend it runs on.
+        self.compile_cache_dir: Optional[str] = None
+        if settings.compile_cache_enabled:
+            from .utils.backend import apply_platform_pin
+            from .utils.profiling import enable_compilation_cache
+
+            apply_platform_pin()
+            self.compile_cache_dir = enable_compilation_cache(
+                settings.compile_cache_dir or "")
+            if self.compile_cache_dir:
+                self.logger.info("persistent compile cache armed at %s",
+                                 self.compile_cache_dir)
+            else:
+                self.logger.warning(
+                    "compile_cache_enabled but the persistent cache stayed "
+                    "off (CPU backend with no directory named — set "
+                    "compile_cache_dir or JAX_COMPILATION_CACHE_DIR)")
         self._labels = dict(
             component_type=settings.component_type,
             component_id=settings.component_id or "unknown",

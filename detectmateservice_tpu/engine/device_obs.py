@@ -51,13 +51,16 @@ from typing import Any, Dict, Iterator, Optional, Tuple
 from . import metrics as m
 from .health import DEGRADED, PASS, UNHEALTHY
 
-# the jax.monitoring event name that marks one XLA backend compile
+# the jax.monitoring duration event around compile-or-get-cached: one per
+# program the jit path had to obtain an executable for, whether the backend
+# compiled it or the persistent cache served it
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-# the jax.monitoring event recorded when the persistent compilation cache
-# serves a compile by deserializing a stored executable (the backend compile
-# — and therefore COMPILE_EVENT — is skipped entirely on that path)
+# the jax.monitoring events that say which of the two it was: the
+# persistent cache deserialized a stored executable (hit), or the backend
+# compiled and the result was written to the cache (miss)
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 # duration event covering the deserialization itself — the ground truth for
 # the warm-up's cache_load phase split
@@ -96,10 +99,9 @@ class CompileLedger:
         self._totals = {"compiles": 0, "seconds": 0.0, "unexpected": 0}
         self._last_unexpected_mono: Optional[float] = None
         self._recent_unexpected: deque = deque(maxlen=64)  # monotonic stamps
-        # persistent compile-cache classifier (dmwarm): None = cache not
-        # armed, counters stay silent; a threshold = a recorded "compile"
-        # faster than it is a deserialized cache entry, not a real compile
-        self._cache_threshold_s: Optional[float] = None
+        # persistent compile-cache counters: silent until the cache is
+        # armed; then jax's own cache_hits / cache_misses events drive them
+        self._cache_armed = False
         self._cache_totals = {"hits": 0, "misses": 0}
         self._cache_children: Optional[tuple] = None
         self._cache_load_seconds = 0.0
@@ -111,6 +113,9 @@ class CompileLedger:
         # GET /admin/xla report the LIVE warm/retired compile-bucket sets
         # next to the compile history they explain
         self._bucket_state_fn = None
+        # device-info provider (the scorer's resolved placement): platform,
+        # device kind/count, host twin, native featurize, compile-cache dir
+        self._device_info_fn = None
 
     # -- wiring ----------------------------------------------------------
     def bind(self, labels: Optional[Dict[str, str]] = None, monitor=None,
@@ -146,6 +151,14 @@ class CompileLedger:
         with self._lock:
             self._bucket_state_fn = fn
 
+    def set_device_info_provider(self, fn) -> None:
+        """Attach a callable returning where the scorer runs (resolved
+        platform, device kind and count, host twin, native featurize,
+        compile-cache directory); surfaced under ``device`` in
+        :meth:`snapshot`. Last registration wins, like the bucket state."""
+        with self._lock:
+            self._device_info_fn = fn
+
     # -- attribution contexts -------------------------------------------
     @contextlib.contextmanager
     def context(self, bucket: Optional[int] = None,
@@ -178,19 +191,18 @@ class CompileLedger:
                     eff[key] = value
         return eff
 
-    # -- persistent compile-cache classification (dmwarm) ----------------
-    def arm_cache_classifier(self, threshold_s: float) -> None:
-        """Arm hit/miss counting: the persistent compilation cache is on,
-        and a recorded compile returning in under ``threshold_s`` is a
-        deserialized cache entry (utils/profiling.enable_compilation_cache
-        calls this after configuring jax)."""
+    # -- persistent compile-cache counters (dmwarm) ----------------------
+    def arm_cache_counters(self) -> None:
+        """The persistent compilation cache is on
+        (utils/profiling.enable_compilation_cache calls this after
+        configuring jax): hit/miss counting starts."""
         with self._lock:
-            self._cache_threshold_s = float(threshold_s)
+            self._cache_armed = True
 
     @property
     def cache_armed(self) -> bool:
         with self._lock:
-            return self._cache_threshold_s is not None
+            return self._cache_armed
 
     def _cache_counters(self) -> tuple:
         pair = self._cache_children
@@ -200,14 +212,18 @@ class CompileLedger:
             self._cache_children = pair
         return pair
 
-    def record_cache_hit(self) -> None:
-        """One persistent-cache hit observed DIRECTLY (the jax
-        ``cache_hits`` monitoring event — on that path the backend compile
-        is skipped entirely, so :meth:`record_compile` never sees it)."""
+    def record_cache_lookup(self, hit: bool) -> None:
+        """One persistent-cache lookup, as jax itself reports it: a
+        ``cache_hits`` event (the stored executable was deserialized, no
+        backend compile ran) or a ``cache_misses`` event (a real compile
+        whose result was written to the cache). The compile-duration event
+        fires around both, so durations cannot tell them apart."""
         with self._lock:
-            self._cache_totals["hits"] += 1
-            hits_c, _ = self._cache_counters()
-        hits_c.inc()
+            if not self._cache_armed:
+                return
+            self._cache_totals["hits" if hit else "misses"] += 1
+            hits_c, misses_c = self._cache_counters()
+        (hits_c if hit else misses_c).inc()
 
     def record_cache_retrieval(self, duration_s: float) -> None:
         """Accumulate persistent-cache deserialization wall time (the jax
@@ -263,6 +279,7 @@ class CompileLedger:
             self._last_unexpected_mono = None
             self._recent_unexpected.clear()
             self._bucket_state_fn = None  # bound to a dead scorer otherwise
+            self._device_info_fn = None
 
     # -- recording -------------------------------------------------------
     def _compile_counters(self, bucket: str, backend: str) -> tuple:
@@ -317,18 +334,6 @@ class CompileLedger:
                 "phase": phase,
                 "unexpected": unexpected,
             }
-            cache_c = None
-            if self._cache_threshold_s is not None:
-                # cache armed: a sub-threshold "compile" is a deserialized
-                # cache entry (the ISSUE's hit heuristic — most hits skip
-                # backend compile entirely and arrive via record_cache_hit
-                # instead); anything slower is a real compile that now
-                # populates the shared dir
-                hit = float(duration_s) < self._cache_threshold_s
-                event["cache"] = "hit" if hit else "miss"
-                self._cache_totals["hits" if hit else "misses"] += 1
-                hits_c, misses_c = self._cache_counters()
-                cache_c = hits_c if hit else misses_c
             unexpected_c = None
             if unexpected:
                 self._totals["unexpected"] += 1
@@ -344,8 +349,6 @@ class CompileLedger:
             self._events.append(event)
         compiles_c.inc()
         seconds_c.inc(float(duration_s))
-        if cache_c is not None:
-            cache_c.inc()
         if unexpected_c is not None:
             unexpected_c.inc()
         if emit:
@@ -395,7 +398,8 @@ class CompileLedger:
             totals["seconds"] = round(totals["seconds"], 6)
             warmed = self._warmed
             bucket_fn = self._bucket_state_fn
-            cache_armed = self._cache_threshold_s is not None
+            device_fn = self._device_info_fn
+            cache_armed = self._cache_armed
             cache_totals = dict(self._cache_totals)
             warmup_phases = dict(self._warmup_phases)
         if limit is not None and limit >= 0:
@@ -409,11 +413,12 @@ class CompileLedger:
             "compile_cache": {"armed": cache_armed, **cache_totals},
             "warmup_phases": warmup_phases,
         }
-        if bucket_fn is not None:
-            try:
-                doc["buckets"] = bucket_fn()
-            except Exception:  # noqa: BLE001 — a racing scorer must not kill the read
-                pass
+        for key, fn in (("buckets", bucket_fn), ("device", device_fn)):
+            if fn is not None:
+                try:
+                    doc[key] = fn()
+                except Exception as exc:  # noqa: BLE001 — a racing scorer must not kill the read
+                    doc[key] = {"error": f"{type(exc).__name__}: {exc}"}
         return doc
 
 
@@ -525,20 +530,18 @@ _CACHE_LISTENER_INSTALLED = False
 
 
 def _on_cache_event(event: str, **kwargs) -> None:
-    if event != CACHE_HIT_EVENT:
+    if event not in (CACHE_HIT_EVENT, CACHE_MISS_EVENT):
         return
     try:
-        _ACTIVE.record_cache_hit()
-    # dmlint: ignore[DM-R001] hit counting is telemetry riding a compile —
+        _ACTIVE.record_cache_lookup(event == CACHE_HIT_EVENT)
+    # dmlint: ignore[DM-R001] cache counting is telemetry riding a compile —
     except Exception:  # noqa: BLE001 — it must never break the compile
         pass
 
 
 def install_cache_listener() -> bool:
-    """Register the persistent-cache hit listener (idempotent; once per
-    process). A cache hit deserializes the stored executable and skips the
-    backend compile — so COMPILE_EVENT never fires and only this event
-    carries the hit. Called by ``enable_compilation_cache`` when the cache
+    """Register the persistent-cache hit/miss listener (idempotent; once
+    per process). Called by ``enable_compilation_cache`` when the cache
     arms; returns False when jax is unavailable."""
     global _CACHE_LISTENER_INSTALLED
     with _INSTALL_LOCK:

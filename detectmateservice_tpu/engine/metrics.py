@@ -233,12 +233,11 @@ XLA_RECOMPILES_UNEXPECTED = _series(
 # (params landing in HBM / mesh shards) — set once per boot, so a replica
 # whose aot phase blows past the fleet norm is visible per-phase
 # (ops/alerts.yml ReplicaColdStartSlow). The cache pair only moves while
-# the persistent compile cache is armed (compile_cache_enabled /
-# DETECTMATE_JAX_CACHE): hits are deserialized cache entries (direct
-# /jax/compilation_cache/cache_hits events, plus sub-threshold ledger
-# compiles), misses are real backend compiles that had to run — a fleet
-# whose replicas share a compile_cache_dir should see hits dominate from
-# the second boot on.
+# the persistent compile cache is armed (utils/profiling.py): hits are
+# deserialized cache entries (jax's /jax/compilation_cache/cache_hits
+# events), misses are real backend compiles whose result was written to
+# the cache (cache_misses events) — a fleet whose replicas share a cache
+# directory should see hits dominate from the second boot on.
 WARMUP_PHASE_LABELS = ("component_type", "component_id", "phase")
 SCORER_WARMUP_SECONDS = _series(
     Gauge,

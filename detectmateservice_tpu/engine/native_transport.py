@@ -40,22 +40,17 @@ _SRC_PATH = _PKG_DIR.parent / "native" / "transport" / "dmtransport.cpp"
 _OK, _ETIMEOUT, _EAGAIN, _ECLOSED, _EERR, _ETOOBIG = 0, -1, -2, -3, -4, -5
 
 # Feature version this binding expects the library to report
-# (dmt_feature_version; stamped by native/build.sh, defaulted in the .cpp).
-# A mismatch raises ImportError so "auto" backend selection falls back to
-# the Python transport LOUDLY instead of serving an older wire surface.
-# Bump in lockstep with the default in native/transport/dmtransport.cpp.
+# (dmt_feature_version). Built from native/, never shipped: the loader and
+# native/build.sh both stamp THIS number, and a library that is missing or
+# reports another number is rebuilt (the same rule as utils/matchkern.py).
+# A library that cannot be built or loaded raises ImportError so "auto"
+# backend selection falls back to the Python transport LOUDLY. Bump it with
+# the wire surface (and the default in native/transport/dmtransport.cpp).
 DMT_FEATURE_VERSION = 3
 
 _INITIAL_BUF = 16 * 1024 * 1024  # starting recv buffer; grows on demand —
                                  # oversized frames are stashed native-side
                                  # (dmt_pending_size) and retried, never lost
-
-
-def _stale() -> bool:
-    if not _LIB_PATH.exists():
-        return True
-    return (_SRC_PATH.exists()
-            and _SRC_PATH.stat().st_mtime > _LIB_PATH.stat().st_mtime)
 
 
 def _rebuild() -> None:
@@ -70,7 +65,8 @@ def _rebuild() -> None:
     os.close(fd)
     try:
         subprocess.run(
-            ["c++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp,
+            ["c++", "-O2", "-std=c++17", "-shared", "-fPIC",
+             f"-DDMT_FEATURE_VERSION={DMT_FEATURE_VERSION}", "-o", tmp,
              str(_SRC_PATH), "-l:libzmq.so.5", "-lpthread"],
             check=True, capture_output=True, timeout=120,
         )
@@ -91,38 +87,35 @@ def _lib_feature_version(lib: ctypes.CDLL) -> int:
     return int(fn())
 
 
-def _load() -> ctypes.CDLL:
-    if _stale():
-        if not _SRC_PATH.exists() and not _LIB_PATH.exists():
-            raise ImportError(f"native transport source not found at {_SRC_PATH}")
-        if _SRC_PATH.exists():
-            try:
-                _rebuild()
-            except (subprocess.SubprocessError, OSError) as exc:
-                if not _LIB_PATH.exists():
-                    raise ImportError(f"cannot build native transport: {exc}")
+def _dlopen() -> ctypes.CDLL:
     try:
-        lib = ctypes.CDLL(str(_LIB_PATH))
+        return ctypes.CDLL(str(_LIB_PATH))
     except OSError as exc:
-        # e.g. no libzmq.so.5 on this host, or a wrong-arch committed .so —
-        # surface as ImportError so "auto" backend selection falls back to
-        # the pure-Python transport
+        # e.g. no libzmq.so.5 on this host — surface as ImportError so
+        # "auto" backend selection falls back to the pure-Python transport
         raise ImportError(f"cannot load native transport: {exc}")
-    if _lib_feature_version(lib) != DMT_FEATURE_VERSION:
-        # stale binary: rebuild when the source is present (os.replace swaps
-        # the inode, so re-dlopen maps the new object), else fail loudly
-        if _SRC_PATH.exists():
-            try:
-                _rebuild()
-                lib = ctypes.CDLL(str(_LIB_PATH))
-            except (subprocess.SubprocessError, OSError):
-                pass
-        got = _lib_feature_version(lib)
-        if got != DMT_FEATURE_VERSION:
+
+
+def _load() -> ctypes.CDLL:
+    lib = _dlopen() if _LIB_PATH.exists() else None
+    if lib is None or _lib_feature_version(lib) != DMT_FEATURE_VERSION:
+        # missing or stale: build from source. dlopen returns the object it
+        # already holds for a path, so the stale mapping is dropped first
+        if lib is not None:
+            import _ctypes
+
+            _ctypes.dlclose(lib._handle)
+        try:
+            _rebuild()
+        except (subprocess.SubprocessError, OSError) as exc:
+            raise ImportError(
+                f"cannot build native transport from {_SRC_PATH}: {exc}")
+        lib = _dlopen()
+        if _lib_feature_version(lib) != DMT_FEATURE_VERSION:
             raise ImportError(
                 f"stale native transport library {_LIB_PATH}: reports "
-                f"feature version {got}, bindings expect "
-                f"{DMT_FEATURE_VERSION} — rebuild with native/build.sh")
+                f"feature version {_lib_feature_version(lib)} after a "
+                f"rebuild, bindings expect {DMT_FEATURE_VERSION}")
     u8p = ctypes.POINTER(ctypes.c_uint8)
     lib.dmt_listen.argtypes = [ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int]
     lib.dmt_listen.restype = ctypes.c_void_p

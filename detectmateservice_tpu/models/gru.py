@@ -54,6 +54,9 @@ class GRUScorerConfig:
     score_vocab: int = 0
     # candidate scoring-head implementation (same knob as LogBERTConfig)
     head_impl: str = "auto"
+    # platform of the device the scorer is placed on ("tpu" | "cpu"); set by
+    # the executor, "" = the process default backend (models/base.py)
+    platform: str = ""
 
 
 class GRULM(nn.Module):

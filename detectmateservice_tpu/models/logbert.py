@@ -55,6 +55,9 @@ class LogBERTConfig:
     # online-logsumexp kernel that never materializes the [N, C] logits
     # (ops/scorehead.py — route here once measured faster on real chips)
     head_impl: str = "auto"
+    # platform of the device the scorer is placed on ("tpu" | "cpu"); set by
+    # the executor, "" = the process default backend (models/base.py)
+    platform: str = ""
 
 
 class Block(nn.Module):
@@ -70,7 +73,8 @@ class Block(nn.Module):
         b, s, _ = q.shape
         reshape = lambda t: t.reshape(b, s, cfg.heads, head_dim).transpose(0, 2, 1, 3)
         out = attention(reshape(q), reshape(k), reshape(v),
-                        key_mask=pad_mask, impl=cfg.attn_impl)
+                        key_mask=pad_mask, impl=cfg.attn_impl,
+                        platform=cfg.platform or None)
         out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.dim)
         x = x + nn.Dense(cfg.dim, dtype=cfg.dtype, name="proj")(out)
         y = nn.LayerNorm(dtype=cfg.dtype)(x)

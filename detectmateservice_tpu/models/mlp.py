@@ -35,6 +35,9 @@ class MLPScorerConfig:
     # ([B, V] logits materialize); "pallas" = fused online-logsumexp kernel
     # (ops/scorehead.py) + direct target dots — no [B, V] tensor in HBM
     head_impl: str = "auto"
+    # platform of the device the scorer is placed on ("tpu" | "cpu"); set by
+    # the executor, "" = the process default backend (models/base.py)
+    platform: str = ""
 
 
 class EmbedMLPModel(nn.Module):

@@ -48,6 +48,7 @@ def attention(
     v: jax.Array,  # [B, H, T, D]
     key_mask: Optional[jax.Array] = None,  # [B, T] bool; True = attend
     impl: str = "auto",
+    platform: Optional[str] = None,
 ) -> jax.Array:
     """Route to the right attention implementation.
 
@@ -55,11 +56,18 @@ def attention(
     "einsum", "flash", "blockwise", or "ring" (sequence-parallel exact
     attention over the mesh provided via ``ring_context``). The mask here is
     the scorer's PAD-key form ([B, T]); einsum/blockwise broadcast it, ring
-    uses it as per-shard key validity."""
+    uses it as per-shard key validity.
+
+    ``platform`` is the platform of the device the computation is placed on
+    (the scorers pass the one their executor resolved); None = the process
+    default backend. The flash kernel compiles for ``tpu`` and runs in
+    interpret mode on ``cpu`` — and only there."""
     t = k.shape[2]
+    if platform is None:
+        platform = jax.default_backend()
     if impl == "auto":
-        on_tpu = any(d.platform == "tpu" for d in jax.devices())
-        impl = "flash" if (on_tpu and t >= FLASH_MIN_SEQ) else "einsum"
+        impl = ("flash" if platform == "tpu" and t >= FLASH_MIN_SEQ
+                else "einsum")
     if impl == "ring":
         ctx = _RING_CTX.get()
         if ctx is None:
@@ -76,9 +84,9 @@ def attention(
         from .flash import flash_attention
 
         # interpret mode keeps a forced flash config runnable (and its
-        # numerics testable) on CPU hosts — slow, but not a crash
-        on_tpu = any(d.platform == "tpu" for d in jax.devices())
-        return flash_attention(q, k, v, key_mask, interpret=not on_tpu)
+        # numerics testable) when placed on the CPU — slow, but not a crash
+        return flash_attention(q, k, v, key_mask,
+                               interpret=platform == "cpu")
     mask = None if key_mask is None else key_mask[:, None, None, :]
     if impl == "blockwise":
         return blockwise_attention(q, k, v, mask=mask)

@@ -43,22 +43,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_Q = 256  # best of the swept (bq, bk) grids on v5e at S>=4096
+DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 _NEG_BIG = -1e30
-
-try:  # pallas import kept lazy-tolerant: CPU-only deployments skip the kernel
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # jax renamed TPUCompilerParams -> CompilerParams (~0.5); support both so
-    # the kernels run on this image's 0.4.x AND current jax
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    _PALLAS_OK = _COMPILER_PARAMS is not None
-except Exception:  # pragma: no cover - environment without pallas
-    _PALLAS_OK = False
 
 
 def _flash_kernel(bias_ref, q_ref, k_ref, v_ref, o_ref, *rest,
@@ -172,8 +162,6 @@ def _flash_forward(q, k, v, key_mask, block_q, block_k, interpret,
     """Run the fused forward; returns (out [B,H,S,D], lse [BH,Sp,128] or
     None). The lse output exists only when a backward is pending — the
     scoring path skips its HBM write."""
-    if not _PALLAS_OK:
-        raise RuntimeError("pallas is unavailable in this jax install")
     b, h, s, d = q.shape
     q, k, v, bias, block_q, block_k, s_pad, t_pad = _pad_inputs(
         q, k, v, key_mask, block_q, block_k)
@@ -207,7 +195,7 @@ def _flash_forward(q, k, v, key_mask, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -277,9 +265,8 @@ def _dkv_kernel(bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _reference_attention(q, k, v, key_mask):
     """The einsum formulation the kernel matches — the fwd/grad parity
-    oracle in tests. (No pallas ⇒ flash_attention raises up front; there
-    is deliberately no silent einsum fallback inside this module — the
-    route decision lives in ops/attention.py.)"""
+    oracle in tests. There is deliberately no einsum fallback inside this
+    module — the route decision lives in ops/attention.py."""
     d = q.shape[-1]
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * (d ** -0.5)
@@ -328,7 +315,7 @@ def _flash_bwd(block_q, block_k, interpret, residuals, g):
         out_specs=pl.BlockSpec((1, bq, d), lambda bh_, i, j: (bh_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(bias, qr, kr, vr, dor, lse, deltar)
@@ -353,7 +340,7 @@ def _flash_bwd(block_q, block_k, interpret, residuals, g):
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(bias, qr, kr, vr, dor, lse, deltar)

@@ -31,25 +31,14 @@ high-water of the exact path) at parity speed (within ~10%).
 """
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_C = 512
 _NEG_BIG = -1e30
-
-try:  # pallas import kept lazy-tolerant: CPU-only deployments skip the kernel
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # jax renamed TPUCompilerParams -> CompilerParams (~0.5); support both so
-    # the kernels run on this image's 0.4.x AND current jax
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    _PALLAS_OK = _COMPILER_PARAMS is not None
-except Exception:  # pragma: no cover - environment without pallas
-    _PALLAS_OK = False
 
 
 def _lse_kernel(bias_ref, h_ref, e_ref, o_ref, m_ref, l_ref):
@@ -98,8 +87,6 @@ def candidate_lse(hidden: jax.Array, emb_c: jax.Array,
     bias (the flash-kernel pattern), so arbitrary vocab/candidate sizes
     keep full-width blocks instead of degrading to divisor-sized ones.
     """
-    if not _PALLAS_OK:
-        raise RuntimeError("pallas is unavailable in this jax install")
     n, d = hidden.shape
     c = emb_c.shape[0]
     block_n = min(block_n, max(n, 8))
@@ -129,7 +116,7 @@ def candidate_lse(hidden: jax.Array, emb_c: jax.Array,
             pltpu.VMEM((block_n, 128), jnp.float32),  # running max
             pltpu.VMEM((block_n, 128), jnp.float32),  # running sum
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

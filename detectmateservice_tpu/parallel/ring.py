@@ -23,25 +23,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import blockwise_attention_step
 
-# jax moved shard_map out of experimental (~0.6) and added the vma/pcast
-# check (~0.8); support this image's 0.4.x AND current jax. On old jax the
-# carry-type vma annotation does not exist and is not needed — _pcast
-# degrades to identity there.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - version-dependent import path
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_pcast = getattr(jax.lax, "pcast", None)
-if _pcast is None:  # pragma: no cover - version-dependent
-    _pcast = lambda t, axes, to: t  # noqa: E731 — identity on pre-vma jax
-
 
 def _ring_attention_shard(q, k, v, kv_valid, axis_name: str,
                           vary_axes: tuple = (), n: int = 1):
     """Per-device body. q/k/v: [B, H, Sl, D] local shards; kv_valid: [B, Sl]
     bool validity (PAD masking) for the local key shard. ``n`` is the ring
-    size (the mesh axis size — static, passed by ring_attention, since
-    ``jax.lax.axis_size`` only exists on newer jax).
+    size (the mesh axis size — static, passed by ring_attention).
 
     The hop loop is ``lax.scan`` (not fori_loop) so the whole ring is
     reverse-mode differentiable — ppermute's transpose is the inverted
@@ -51,8 +38,8 @@ def _ring_attention_shard(q, k, v, kv_valid, axis_name: str,
 
     # mark the accumulators as device-varying over every manually-mapped
     # mesh axis (ring axis + optional batch axis) so the scan carry type
-    # matches (jax >= 0.8 shard_map vma check)
-    vary = lambda t: _pcast(t, vary_axes or (axis_name,), to="varying")
+    # matches (shard_map's varying-manual-axes check)
+    vary = lambda t: jax.lax.pcast(t, vary_axes or (axis_name,), to="varying")
     acc = vary(jnp.zeros((b, h, s_local, d), jnp.float32))
     row_max = vary(jnp.full((b, h, s_local), jnp.finfo(jnp.float32).min, jnp.float32))
     row_sum = vary(jnp.zeros((b, h, s_local), jnp.float32))
@@ -95,7 +82,7 @@ def ring_attention(
     spec_qkv = P(batch_axis, None, axis_name, None)
     spec_valid = P(batch_axis, axis_name)
     vary_axes = (axis_name,) + ((batch_axis,) if batch_axis else ())
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_ring_attention_shard, axis_name=axis_name,
                 vary_axes=vary_axes, n=int(mesh.shape[axis_name])),
         mesh=mesh,

@@ -448,17 +448,16 @@ class ServiceSettings(BaseModel):
     # construction — BEFORE the component's first jit — so a restarted
     # replica (or a dmroll candidate swap on the same host) reuses every
     # already-seen (kernel, bucket) compile instead of paying cold-start.
-    # Point every replica of a tier at the SAME compile_cache_dir and HPA
+    # Point every replica of a tier at the SAME cache directory and HPA
     # scale-out boots against a warm cache (docs/walkthrough.md "make
-    # scale-out honest"). Off (the default) keeps the env-only behavior
-    # (DETECTMATE_JAX_CACHE), which is OFF on CPU backends.
+    # scale-out honest"). Off (the default) leaves arming to the scorer's
+    # first jax use: on an accelerator the cache is on anyway, on the CPU
+    # backend it stays off unless a directory is named.
     compile_cache_enabled: bool = False
-    # shared cache root; entries land under a machine-fingerprint
-    # subdirectory (utils/profiling._machine_fingerprint) so heterogeneous
-    # hosts can share the directory without ever loading each other's
-    # machine-tuned artifacts. An explicit dir persists EVERY compile
-    # (min-compile-time floor drops to 0) — required for CPU-sim parity
-    # runs, harmless on TPU. None + enabled = the env/default-home path.
+    # cache directory, used exactly as given. JAX_COMPILATION_CACHE_DIR,
+    # where set, wins over it (the cache is placed from outside); with
+    # neither, the fixed in-checkout default (utils/profiling.py
+    # DEFAULT_CACHE_DIR).
     compile_cache_dir: Optional[str] = None
 
     # -- multi-tenant admission control: dmshed (shed/) -------------------
@@ -629,9 +628,8 @@ class ServiceSettings(BaseModel):
     # -- compile-cache cross-validation -----------------------------------
     @model_validator(mode="after")
     def _check_compile_cache(self) -> "ServiceSettings":
-        """A non-writable ``compile_cache_dir`` must fail at startup, not at
-        the first compile (where enable_compilation_cache swallows the
-        OSError and the operator's shared cache silently never fills)."""
+        """A non-writable ``compile_cache_dir`` must fail at settings load,
+        with the field named, not at the first compile."""
         if self.compile_cache_enabled and self.compile_cache_dir:
             probe = os.path.join(self.compile_cache_dir,
                                  f".dmwarm_probe_{os.getpid()}")

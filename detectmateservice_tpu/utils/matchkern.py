@@ -2,9 +2,11 @@
 
 Role of the reference's ``detectmateperformance`` pybind11 package
 (reference: uv.lock:278,301-310); this image has no pybind11, so the binding
-layer is ctypes over a plain C shared library. Auto-builds from source on
-first import when the library is missing and a C compiler is present;
-importers fall back to the pure-Python paths on any failure.
+layer is ctypes over a plain C shared library. The library is not shipped:
+it is built from native/matchkern/dmkern.c on first import (or by
+native/build.sh) and rebuilt whenever it reports another feature version.
+Without a C compiler the import raises ImportError; importers then take the
+pure-Python paths and say so.
 """
 from __future__ import annotations
 
@@ -21,40 +23,27 @@ _LIB_PATH = _PKG_DIR / "_native" / "libdmkern.so"
 _SRC_PATH = _PKG_DIR.parent / "native" / "matchkern" / "dmkern.c"
 
 # Feature version this binding layer expects the library to report
-# (dm_feature_version). native/build.sh stamps the same number into the .so;
-# a mismatch at load time means a stale binary (e.g. an old committed .so on
-# a host without a compiler) and raises ImportError — every importer already
-# falls back to the pure-Python paths, so the failure is loud but safe.
-# Bump IN LOCKSTEP with the default in native/matchkern/dmkern.c whenever a
-# kernel's ABI or semantics change.
+# (dm_feature_version). The library is built from native/, never shipped:
+# the loader and native/build.sh both stamp THIS number into the .so, and a
+# library that is missing or reports another number is rebuilt — the one
+# staleness rule (file times mean nothing on a fresh checkout). Bump it
+# whenever a kernel's ABI or semantics change (and the default in
+# native/matchkern/dmkern.c with it, for bare `cc` builds).
 DM_FEATURE_VERSION = 7
-
-
-def _stale() -> bool:
-    """True when the library is missing or older than its source.
-
-    The mtime comparison is a dev convenience (rebuild after editing the C
-    source); on a fresh checkout it may fire spuriously, so a failed rebuild
-    falls back to the committed library rather than raising.
-    """
-    if not _LIB_PATH.exists():
-        return True
-    return (_SRC_PATH.exists()
-            and _SRC_PATH.stat().st_mtime > _LIB_PATH.stat().st_mtime)
 
 
 def _rebuild() -> None:
     """Compile to a temp file and atomically replace, so concurrent importers
     never dlopen a half-written library."""
-    import os
     import tempfile
 
     _LIB_PATH.parent.mkdir(parents=True, exist_ok=True)
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=str(_LIB_PATH.parent))
     os.close(fd)
     try:
-        subprocess.run(["cc", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp,
-                        str(_SRC_PATH)],
+        subprocess.run(["cc", "-O3", "-shared", "-fPIC", "-pthread",
+                        f"-DDM_FEATURE_VERSION={DM_FEATURE_VERSION}",
+                        "-o", tmp, str(_SRC_PATH)],
                        check=True, capture_output=True, timeout=120)
         os.chmod(tmp, 0o755)  # mkstemp creates 0600; other users must dlopen
         os.replace(tmp, str(_LIB_PATH))
@@ -73,35 +62,34 @@ def _lib_feature_version(lib: ctypes.CDLL) -> int:
     return int(fn())
 
 
+def _close(lib: ctypes.CDLL) -> None:
+    """Drop a mapping: dlopen returns the object it already holds for a
+    path, so a stale library must be closed before its rebuilt successor
+    at the same path can be mapped."""
+    import _ctypes
+
+    _ctypes.dlclose(lib._handle)
+
+
 def _load() -> ctypes.CDLL:
-    if _stale():
-        if not _SRC_PATH.exists() and not _LIB_PATH.exists():
-            raise ImportError(f"native kernel source not found at {_SRC_PATH}")
-        if _SRC_PATH.exists():
-            try:
-                _rebuild()
-            except (subprocess.SubprocessError, OSError) as exc:
-                if not _LIB_PATH.exists():
-                    raise ImportError(f"cannot build native kernel: {exc}")
-                # no compiler / read-only tree: use the committed library
-    lib = ctypes.CDLL(str(_LIB_PATH))
-    if _lib_feature_version(lib) != DM_FEATURE_VERSION:
-        # stale binary (mtimes lie on fresh checkouts): rebuild if possible —
-        # os.replace swaps the inode, so re-dlopen maps the NEW object —
-        # else fail LOUDLY rather than silently running without the newer
-        # kernels (importers fall back to the pure-Python paths)
-        if _SRC_PATH.exists():
-            try:
-                _rebuild()
-                lib = ctypes.CDLL(str(_LIB_PATH))
-            except (subprocess.SubprocessError, OSError):
-                pass
-        got = _lib_feature_version(lib)
-        if got != DM_FEATURE_VERSION:
+    lib = ctypes.CDLL(str(_LIB_PATH)) if _LIB_PATH.exists() else None
+    if lib is None or _lib_feature_version(lib) != DM_FEATURE_VERSION:
+        # missing or stale: build from source
+        if lib is not None:
+            _close(lib)
+        try:
+            _rebuild()
+        except (subprocess.SubprocessError, OSError) as exc:
+            detail = getattr(exc, "stderr", b"") or b""
+            raise ImportError(
+                f"cannot build native kernel library from {_SRC_PATH}: {exc} "
+                f"{detail.decode('utf-8', 'replace')[-500:]}".rstrip())
+        lib = ctypes.CDLL(str(_LIB_PATH))
+        if _lib_feature_version(lib) != DM_FEATURE_VERSION:
             raise ImportError(
                 f"stale native kernel library {_LIB_PATH}: reports feature "
-                f"version {got}, bindings expect {DM_FEATURE_VERSION} — "
-                f"rebuild with native/build.sh")
+                f"version {_lib_feature_version(lib)} after a rebuild, "
+                f"bindings expect {DM_FEATURE_VERSION}")
     lib.dm_featurize_batch.argtypes = [
         ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_int,
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_uint8),

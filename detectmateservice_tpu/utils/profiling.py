@@ -2,149 +2,90 @@
 the reference has no profiling subsystem at all)."""
 from __future__ import annotations
 
+import os
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 
 _cache_enabled = False
 _cache_dir: Optional[str] = None
 _cache_lock = threading.Lock()
 
-# persistence floor when the cache is armed from SETTINGS (an explicit
-# shared dir): 0.0 — the operator asked for a shared cache, so every
-# compile persists, including the sub-second CPU-sim compiles the
-# warm-start parity tests and smoke rely on. The env-only path keeps the
-# historical 1.0 s floor (tiny compiles are cheaper to redo than to load).
-_MIN_COMPILE_S_EXPLICIT = 0.0
-_MIN_COMPILE_S_DEFAULT = 1.0
+# where JAX_COMPILATION_CACHE_DIR names a directory, the cache lives exactly
+# there and this module never touches jax_compilation_cache_dir
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# otherwise: one fixed, git-ignored directory inside the checkout. The path
+# is part of what a later process must find again, so it is never derived
+# from a temp name, a pid or the clock.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
 
-# ledger hit-classification threshold (engine/device_obs.py): a backend
-# "compile" returning faster than this while the persistent cache is armed
-# is a deserialized cache entry, not a real compile. Only used when the
-# persistence floor is 0 (explicit dir); otherwise the floor itself is the
-# natural boundary.
-_HIT_THRESHOLD_S = 0.05
+# every compile persists, including the sub-second CPU compiles the
+# warm-start parity tests rely on (jax's own floor is 1 s)
+_MIN_COMPILE_S = 0.0
 
 
-def _machine_fingerprint() -> str:
-    """Stable id for (host µarch, jax version): XLA:CPU AOT artifacts are
-    machine-specific, and a cache shared across heterogeneous hosts loads
-    executables compiled for the wrong CPU features ("could lead to
-    execution errors such as SIGILL" — observed in CI). Keying the cache dir
-    by this fingerprint makes cross-machine reuse structurally impossible."""
-    import hashlib
-    import platform as plt
-
-    # the leading salt versions the cache *format policy*: entries written
-    # before jax_persistent_cache_enable_xla_caches="none" embed XLA:CPU AOT
-    # blobs whose loader spews machine-feature warnings on every hit; bumping
-    # the salt orphans them instead of reloading them forever
-    parts = ["v2", plt.machine(), plt.system()]
-    try:
-        import jax
-
-        parts.append(jax.__version__)
-    except Exception:
-        pass
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith("flags"):
-                    parts.append(line.strip())
-                    break
-    except OSError:
-        pass
-    return hashlib.sha256("|".join(parts).encode()).hexdigest()[:12]
+def resolve_cache_dir(path: str = "") -> Tuple[str, bool]:
+    """Where the persistent compilation cache goes → ``(directory,
+    placed_by_env)``. ``JAX_COMPILATION_CACHE_DIR`` wins over everything
+    (``path`` — the ``compile_cache_dir`` setting — included); without it
+    ``path``, else :data:`DEFAULT_CACHE_DIR`."""
+    env = os.environ.get(CACHE_DIR_ENV, "").strip()
+    if env:
+        return env, True
+    return (path or DEFAULT_CACHE_DIR), False
 
 
 def enable_compilation_cache(path: str = "") -> Optional[str]:
-    """Enable JAX's persistent compilation cache (idempotent).
+    """Enable JAX's persistent compilation cache (idempotent; the first
+    decision in a process stands).
 
-    Service restarts then skip the multi-second XLA compiles for every
-    already-seen (kernel, bucket) shape — the largest component of a scorer
-    service's cold-start time. Failures are non-fatal (read-only FS etc.).
-    Returns the armed cache directory, or ``None`` when persistence stayed
-    off (also on repeat calls after an off decision).
+    Service restarts then skip the XLA compiles for every already-seen
+    (kernel, bucket) shape — the largest component of a scorer service's
+    cold-start time. Returns the armed cache directory, or ``None`` when
+    persistence stayed off.
 
-    An EXPLICIT ``path`` (the ``compile_cache_dir`` setting, wired through
-    ``core.py``) arms the cache unconditionally — including on CPU backends,
-    where the env-default path declines — and drops the persistence floor to
-    0 so every compile lands in the shared dir. ``DETECTMATE_JAX_CACHE``
-    controls the no-path behavior: unset = on under
-    ``~/.cache/detectmate/jax/<machine-fingerprint>`` (non-CPU only); a
-    path = on there (also fingerprint-suffixed); ``0``/``off``/``none``/
-    ``disabled`` = off (e.g. deterministic CI timing runs).
+    The directory is :func:`resolve_cache_dir`'s. With neither the
+    environment variable nor ``path`` naming one, persistence stays off on
+    the CPU backend: XLA:CPU compiles here are small and its serialized
+    executables are tuned to the build host.
 
-    On success the compile ledger (engine/device_obs.py) is armed with the
-    hit-classification threshold, so ``compile_cache_{hits,misses}_total``
-    start moving with the first cache-backed compile."""
+    On success the compile ledger's (engine/device_obs.py) cache counters
+    are armed, so ``compile_cache_{hits,misses}_total`` start moving with
+    the first cache-backed compile."""
     global _cache_enabled, _cache_dir
     with _cache_lock:
         if _cache_enabled:
             return _cache_dir
-        import os
-
         import jax
 
-        explicit = bool(path)
-        base = path or os.environ.get("DETECTMATE_JAX_CACHE") or ""
-        if base.strip().lower() in ("0", "off", "none", "disabled", "false"):
-            _cache_enabled = True  # explicitly off: don't retry every call
+        cache_dir, from_env = resolve_cache_dir(path)
+        _cache_enabled = True
+        if not from_env and not path and jax.default_backend() == "cpu":
             return None
-        if not base:
-            try:
-                backend = jax.default_backend()
-            # dmlint: ignore[DM-R001] backend probe on an uninitialized
-            except Exception:  # noqa: BLE001 — runtime: treat as unknown
-                backend = "unknown"
-            if backend == "cpu":
-                # XLA:CPU serializes machine-tuned AOT executables into every
-                # cache entry and its loader then distrusts them on any
-                # feature-flag drift (cpu_aot_loader "could lead to SIGILL"
-                # spew). CPU compiles here are small; persistence is off by
-                # default and opt-in via compile_cache_dir /
-                # DETECTMATE_JAX_CACHE=<path>.
-                _cache_enabled = True
-                return None
-            base = os.path.expanduser("~/.cache/detectmate/jax")
-        cache_dir = os.path.join(base, _machine_fingerprint())
-        min_compile_s = (_MIN_COMPILE_S_EXPLICIT if explicit
-                         else _MIN_COMPILE_S_DEFAULT)
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
+        os.makedirs(cache_dir, exist_ok=True)
+        if not from_env:
             jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              min_compile_s)
-            # keep the cache at the jax/StableHLO level only: XLA:CPU's AOT
-            # artifacts embed compile-machine tuning flags and the loader
-            # distrusts them on any feature drift ("could lead to SIGILL"
-            # cpu_aot_loader warnings observed in CI), so persisting them is
-            # a portability hazard with no TPU upside
-            jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-            _cache_enabled = True
-            _cache_dir = cache_dir
-        except Exception:
-            return None
-    # arm the ledger's hit/miss classifier OUTSIDE the cache lock (the
-    # ledger has its own); a sub-threshold "compile" is a deserialized
-    # cache entry, and real hits skip backend compile entirely (counted by
-    # the /jax/compilation_cache/cache_hits listener)
-    try:
-        from ..engine import device_obs
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          _MIN_COMPILE_S)
+        # keep the cache at the jax/StableHLO level only: XLA's own
+        # sub-caches embed compile-machine tuning that the loader distrusts
+        # on any feature drift
+        jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+        _cache_dir = cache_dir
+    # arm the ledger's hit/miss counters OUTSIDE the cache lock (the ledger
+    # has its own); jax's cache_hits / cache_misses events drive them
+    from ..engine import device_obs
 
-        device_obs.get_ledger().arm_cache_classifier(
-            max(min_compile_s, _HIT_THRESHOLD_S))
-        device_obs.install_cache_listener()
-    # dmlint: ignore[DM-R001] classifier arming is telemetry — it must not
-    except Exception:  # noqa: BLE001 — break cache setup
-        pass
-    # dmlint: ignore[DM-L001] written once under _cache_lock above; stable
-    return _cache_dir
+    device_obs.get_ledger().arm_cache_counters()
+    device_obs.install_cache_listener()
+    return cache_dir
 
 
 def persistent_cache_dir() -> Optional[str]:
-    """The armed cache directory (None while off) — smoke/test introspection."""
+    """The armed cache directory (None while off)."""
     with _cache_lock:
         return _cache_dir
 

@@ -46,10 +46,11 @@ esac
 [ -n "$SANITIZE" ] && echo "sanitized build: $SANITIZE"
 
 CC="${CC:-cc}"
-# Stamp the feature version the Python bindings expect: the bindings refuse
-# a library reporting a different number, so a stale committed .so fails
-# loudly at import instead of silently bypassing newer kernels. The C
-# sources default to the same numbers for bare `cc` builds.
+# Stamp the feature version the Python bindings expect — the one staleness
+# rule this script shares with the import-time builds in utils/matchkern.py
+# and engine/native_transport.py: a library reporting a different number is
+# rebuilt at import. The C sources default to the same numbers for bare
+# `cc` builds.
 KVER=$(sed -n 's/^DM_FEATURE_VERSION = \([0-9][0-9]*\).*/\1/p' \
     ../detectmateservice_tpu/utils/matchkern.py)
 $CC $KERN_OPT -shared -fPIC -pthread $SAN_CFLAGS \

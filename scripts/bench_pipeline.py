@@ -217,7 +217,6 @@ def main() -> None:
                 proc.wait(timeout=15)
             except Exception:
                 proc.terminate()
-    os._exit(0)
 
 
 if __name__ == "__main__":

@@ -267,7 +267,6 @@ def main() -> None:
             # a service wedged in a heavy device batch must not turn a
             # completed measurement into a failed bench run
             proc.kill()
-    os._exit(0)
 
 
 def _status_up() -> bool:

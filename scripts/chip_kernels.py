@@ -1,0 +1,182 @@
+"""Compile both Pallas kernels on the attached TPU and compare each with its
+``jax.numpy`` reference at the shapes the scorers use.
+
+One process, ``interpret=False`` throughout; fails without a TPU. Each check
+also asserts that the lowered program holds a Mosaic custom call, so a route
+that quietly fell back to XLA or to the interpreter cannot pass.
+
+* ``ops/scorehead.candidate_lse`` at the flagship exact-head shape
+  (N = 16384·32 rows, D = 256, V = 32768; the reference runs in the
+  16384-row chunks the einsum head uses) and at ``mlp``'s (N = 16384,
+  D = 128, V = 32768);
+* ``ops/flash.flash_attention`` forward at S = T = 2048 and 8192, D = 64,
+  bf16, with a key mask;
+* the flash backward kernels (dq; dk+dv) at the same shapes.
+
+Prints one JSON line per check and a final summary line; the full record
+goes to ``chiprun_out/chip_kernels.json``. Exit code 1 if any check failed.
+
+Usage: python scripts/chip_kernels.py
+"""
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+OUT_PATH = os.path.join("chiprun_out", "chip_kernels.json")
+
+
+def _compiled(fn, *args):
+    """Lower ``fn`` for ``args``, require a Mosaic kernel in the program,
+    compile it and return (executable, compile seconds)."""
+    import jax
+
+    lowered = jax.jit(fn).lower(*args)
+    if "tpu_custom_call" not in lowered.as_text():
+        raise AssertionError("lowered program holds no Mosaic custom call")
+    t0 = time.perf_counter()
+    exe = lowered.compile()
+    return exe, time.perf_counter() - t0
+
+
+def check_candidate_lse(n: int, d: int, v: int, ref_chunk: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from detectmateservice_tpu.ops.scorehead import candidate_lse
+
+    kh, ke = jax.random.split(jax.random.PRNGKey(n + d))
+    hidden = jax.random.normal(kh, (n, d), jnp.float32).astype(jnp.bfloat16)
+    emb = (jax.random.normal(ke, (v, d), jnp.float32) * d ** -0.5
+           ).astype(jnp.bfloat16)
+    exe, compile_s = _compiled(
+        lambda h, e: candidate_lse(h, e, interpret=False), hidden, emb)
+    got = np.asarray(exe(hidden, emb))
+
+    @jax.jit
+    def ref_chunked(h, e):
+        def one(h_c):
+            logits = jnp.einsum("nd,vd->nv", h_c, e,
+                                preferred_element_type=jnp.float32)
+            return jax.nn.logsumexp(logits, axis=-1)
+
+        return jax.lax.map(one, h.reshape(n // ref_chunk, ref_chunk, d)
+                           ).reshape(n)
+
+    want = np.asarray(ref_chunked(hidden, emb))
+    err = float(np.max(np.abs(got - want)))
+    return {"compile_s": round(compile_s, 2), "max_abs_err": err,
+            "finite": bool(np.isfinite(got).all()), "ok": err < 2e-2}
+
+
+def _flash_inputs(s: int):
+    import jax
+    import jax.numpy as jnp
+
+    b, h, d = 1, 4, 64
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(s), 4)
+    q, k, v = (jax.random.normal(r, (b, h, s, d), jnp.float32
+                                 ).astype(jnp.bfloat16) for r in (kq, kk, kv))
+    # the last eighth of the keys is padding
+    key_mask = jnp.arange(s)[None, :] < (s - s // 8)
+    w = jax.random.normal(kw, (b, h, s, d), jnp.float32)
+    return q, k, v, key_mask, w
+
+
+def check_flash_forward(s: int) -> dict:
+    import numpy as np
+
+    from detectmateservice_tpu.ops.flash import (_reference_attention,
+                                                 flash_attention)
+
+    q, k, v, key_mask, _ = _flash_inputs(s)
+    exe, compile_s = _compiled(
+        lambda q, k, v, m: flash_attention(q, k, v, m, interpret=False),
+        q, k, v, key_mask)
+    got = np.asarray(exe(q, k, v, key_mask), np.float32)
+    want = np.asarray(_reference_attention(q, k, v, key_mask), np.float32)
+    err = float(np.max(np.abs(got - want)))
+    return {"compile_s": round(compile_s, 2), "max_abs_err": err,
+            "finite": bool(np.isfinite(got).all()), "ok": err < 3e-2}
+
+
+def check_flash_backward(s: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from detectmateservice_tpu.ops.flash import (_reference_attention,
+                                                 flash_attention)
+
+    q, k, v, key_mask, w = _flash_inputs(s)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) * w)
+
+    flash_grad = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, key_mask, interpret=False)), argnums=(0, 1, 2))
+    ref_grad = jax.jit(jax.grad(loss(lambda q, k, v: _reference_attention(
+        q, k, v, key_mask)), argnums=(0, 1, 2)))
+    exe, compile_s = _compiled(flash_grad, q, k, v)
+    got = [np.asarray(g, np.float32) for g in exe(q, k, v)]
+    want = [np.asarray(g, np.float32) for g in ref_grad(q, k, v)]
+    # bf16 gradients of an O(sqrt(S))-magnitude sum: compare relative to
+    # the reference's own scale
+    errs = {name: float(np.max(np.abs(g - r)) / max(1e-6, np.max(np.abs(r))))
+            for name, g, r in zip(("dq", "dk", "dv"), got, want)}
+    return {"compile_s": round(compile_s, 2), "max_rel_err": errs,
+            "finite": all(bool(np.isfinite(g).all()) for g in got),
+            "ok": max(errs.values()) < 5e-2}
+
+
+CHECKS = [
+    ("candidate_lse logbert N=524288 D=256 V=32768",
+     lambda: check_candidate_lse(16384 * 32, 256, 32768, 16384)),
+    ("candidate_lse mlp N=16384 D=128 V=32768",
+     lambda: check_candidate_lse(16384, 128, 32768, 16384)),
+    ("flash forward S=2048", lambda: check_flash_forward(2048)),
+    ("flash forward S=8192", lambda: check_flash_forward(8192)),
+    ("flash backward S=2048", lambda: check_flash_backward(2048)),
+    ("flash backward S=8192", lambda: check_flash_backward(8192)),
+]
+
+
+def main() -> int:
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(f"chip_kernels: no TPU (jax reports {device.platform!r}); "
+              "the kernels compile for the chip only", file=sys.stderr)
+        return 1
+    results = []
+    for name, check in CHECKS:
+        entry = {"check": name}
+        try:
+            entry.update(check())
+        except Exception as exc:  # noqa: BLE001 — the compiler's message IS the result
+            entry.update(ok=False, error=f"{type(exc).__name__}: {exc}"[:4000])
+        results.append(entry)
+        print(json.dumps(entry), flush=True)
+    summary = {
+        "ok": all(r["ok"] for r in results),
+        "device": {"platform": device.platform, "kind": device.device_kind,
+                   "count": len(jax.devices())},
+        "jax": jax.__version__,
+        "checks": results,
+    }
+    os.makedirs(os.path.dirname(OUT_PATH), exist_ok=True)
+    with open(OUT_PATH, "w", encoding="utf-8") as fh:
+        json.dump(summary, fh, indent=1)
+    print(json.dumps({k: summary[k] for k in ("ok", "device", "jax")}))
+    return 0 if summary["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

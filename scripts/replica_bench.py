@@ -24,6 +24,13 @@ Scorer modes (recorded, with the core count, in ``environment``):
   throughput is device-bound and overlaps freely across processes. This
   is what makes the ROUTER's scale-out measurable on a small host — and
   it is what ``--mode auto`` picks there.
+
+Devices: this launcher starts several scorer PROCESSES at once and pins jax
+to the CPU (``JAX_PLATFORMS=cpu`` below). A chip belongs to one process at a
+time, so on an accelerator host every replica needs a device of its own —
+one process per chip, each replica's scorer config naming it
+(``device: "tpu:<id>"``). On a one-chip machine the second replica cannot
+have the chip; do not point this script at one.
 """
 from __future__ import annotations
 

@@ -43,6 +43,37 @@ class TestFeatureVersion:
         with pytest.raises(ImportError, match="stale native kernel"):
             matchkern._load()
 
+    def test_staleness_is_the_feature_version_not_file_times(
+            self, monkeypatch, tmp_path):
+        """One staleness rule: a missing library is built from native/; one
+        reporting the expected feature version loads as it is however old
+        its file looks (file times are arbitrary on a fresh checkout); one
+        reporting another version is rebuilt with the expected number
+        stamped in."""
+        import os
+
+        rebuilds = []
+        real_rebuild = matchkern._rebuild
+        monkeypatch.setattr(matchkern, "_LIB_PATH", tmp_path / "libdmkern.so")
+        monkeypatch.setattr(matchkern, "_rebuild",
+                            lambda: rebuilds.append(1) or real_rebuild())
+        first = matchkern._load()                      # missing → built
+        assert rebuilds == [1]
+        os.utime(matchkern._LIB_PATH, (1, 1))          # "older than its source"
+        second = matchkern._load()
+        assert rebuilds == [1], "a version-current library was rebuilt"
+        for lib in (first, second):
+            assert (matchkern._lib_feature_version(lib)
+                    == matchkern.DM_FEATURE_VERSION)
+            matchkern._close(lib)
+        monkeypatch.setattr(matchkern, "DM_FEATURE_VERSION",
+                            matchkern.DM_FEATURE_VERSION + 1)
+        third = matchkern._load()                      # stale → rebuilt
+        assert rebuilds == [1, 1]
+        assert (matchkern._lib_feature_version(third)
+                == matchkern.DM_FEATURE_VERSION)
+        matchkern._close(third)
+
     def test_pre_versioning_library_reports_zero(self):
         class _NoSymbol:
             def __getattr__(self, name):
