@@ -106,7 +106,7 @@ def run(n_bench: int) -> dict:
     # warmup (compile cache for the bench bucket); flush_final also joins
     # the host-bucket warm thread fit() started — its background XLA:CPU
     # compiles otherwise steal host cycles from featurize/drain inside the
-    # timed loop (measured: 149k vs 246k lines/s on the same build)
+    # timed loop
     det.process_batch(bench_msgs[:batch])
     det.flush_final()
 

@@ -2,8 +2,7 @@
 
 The per-message socket cost (zmq enqueue + GIL crossing + syscall amortization)
 caps a single Python sender at ~80k sends/s (measured via
-scripts/bench_service.py) — far below what the TPU detector sustains
-(445k+ lines/s). Packing K messages per frame amortizes that cost K-fold on
+scripts/bench_service.py) — below a batched detector's rate. Packing K messages per frame amortizes that cost K-fold on
 both ends; this is SURVEY.md §7 hard part #3 ("batch *frames* before
 crossing into Python") applied to the whole service mesh, not just ingest.
 

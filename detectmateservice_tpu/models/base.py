@@ -191,9 +191,8 @@ class SequenceScorerBase(ScorerBase):
           estimated over a fixed seeded subset C of the vocab with the
           uniform-proposal correction ``+ log(V/|C|)``, while the target
           token's logit stays EXACT (direct hidden·emb[target] dot). Head
-          FLOPs drop V/|C|-fold (the chunked full head is the sequence
-          families' device bottleneck: measured 247 ms vs 63 ms per 16k×32
-          batch at V=32k, C=2048, i.e. 66k → 262k lines/s on one v5e).
+          FLOPs drop V/|C|-fold (the effect on the rate is not measured
+          on the attached chip).
           Scores are approximate but CONSISTENTLY so — calibration (fit)
           and detection use the same subset, so the threshold stays in the
           same units; measured corr(exact, approx) ≈ 0.995.

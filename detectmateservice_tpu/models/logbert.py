@@ -45,7 +45,7 @@ class LogBERTConfig:
     score_topk: int = 0
     # 0 = exact full-vocab NLL; 0 < C < vocab_size = candidate-vocab
     # approximation (models/base.py _token_nlls_candidate): ~V/C fewer head
-    # FLOPs, the family's device bottleneck (66k → 262k lines/s at C=2048)
+    # FLOPs
     score_vocab: int = 0
     # "auto" = pallas flash kernel on TPU for long sequences, fused einsum
     # otherwise; "einsum" | "flash" | "blockwise" force a path
@@ -53,7 +53,7 @@ class LogBERTConfig:
     # candidate scoring-head implementation: "auto"/"einsum" = S-chunked
     # einsum + low-precision logsumexp (models/base.py); "pallas" = fused
     # online-logsumexp kernel that never materializes the [N, C] logits
-    # (ops/scorehead.py — route here once measured faster on real chips)
+    # (ops/scorehead.py; ROADMAP D5 decides by measurement)
     head_impl: str = "auto"
     # platform of the device the scorer is placed on ("tpu" | "cpu"); set by
     # the executor, "" = the process default backend (models/base.py)

@@ -5,7 +5,7 @@ with a numerically stable blockwise variant that is the building block for
 ring attention (parallel/ring.py), and the fused pallas kernel (ops/flash.py)
 for long sequences. ``attention()`` routes between them: below
 ``FLASH_MIN_SEQ`` the whole score matrix fits one MXU tile and XLA's fused
-einsum is already optimal (measured: the kernel only wins from ~512 tokens),
+einsum has nothing for a kernel to save,
 above it the pallas kernel avoids materializing the [S, T] logits in HBM.
 """
 from __future__ import annotations
@@ -17,10 +17,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-# measured on TPU v5e (scripts/bench_flash.py): flash ~parity with the fused
-# einsum at S=1024-4096 and 2.4-2.7x faster at S=8192 (where einsum's [S,S]
-# fp32 logits are also 1 GB/batch-head and OOM first); below this the einsum
-# path stays — one MXU tile, nothing for a kernel to save
+# from here up the kernel avoids the [S, S] fp32 logits (1 GB per batch-head
+# at S=8192); below it the einsum path stays. The crossover is not measured
+# on the attached chip (scripts/bench_flash.py, ROADMAP D10)
 FLASH_MIN_SEQ = 2048
 
 # (mesh, batch_axis, seq_axis) for impl="ring" — set by the execution layer

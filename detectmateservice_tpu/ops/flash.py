@@ -11,12 +11,10 @@ windows, stack traces, transaction sessions tokenized to thousands of
 tokens). At the flagship scorer's default seq_len=32 the whole attention fits
 in one MXU tile and XLA's fused einsum is already optimal — so
 ``attention()`` in ops/attention.py routes: seq < FLASH_MIN_SEQ stays on the
-einsum path, longer sequences take this kernel. Measured on TPU v5e
-(scripts/bench_flash.py, median-of-15 blocking calls): parity at
-S=1024-4096, **2.4-2.7x at S=8192** (einsum 180 ms vs flash 67-75 ms,
-B1 H4 D64) — and the einsum path's [B,H,S,S] fp32 logits (1 GB per
-batch-head at S=8192) OOM long before the kernel's O(S·block_k) VMEM
-working set does.
+einsum path, longer sequences take this kernel. Its speed against the
+einsum path (scripts/bench_flash.py) is not measured on the attached chip;
+the einsum path's [B,H,S,S] fp32 logits are 1 GB per batch-head at S=8192,
+against the kernel's O(S·block_k) VMEM working set.
 
 Training-grade: the backward is two more fused kernels (dq; dk+dv) that
 recompute probability tiles from (q, k, saved per-row logsumexp) — the

@@ -2,8 +2,7 @@
 
 The sequence families' detect-path bottleneck is the scoring head: for
 every token position, logits against the candidate subset ``emb_c`` and a
-logsumexp over them (models/base.py ``_token_nlls_candidate``; the r3
-roofline measured logbert-candidate at 5.6% MFU, VPU-softmax-bound). On
+logsumexp over them (models/base.py ``_token_nlls_candidate``). On
 the XLA path the ``[N, C]`` logits tensor materializes between the matmul
 and the reduce — at N = B·S = 512k, C = 2048 that is 2 GB of HBM traffic
 written and read back per batch.
@@ -19,15 +18,13 @@ output.
 
 Correctness is pinned against the jnp reference in interpret mode on CPU
 (tests/test_scorehead.py); routing lives behind the scorer's
-``head_impl`` knob. Measured on the live v5e (round 4,
-scripts/bench_scorehead.py slope protocol): at the candidate hot shape
-(N=512k, C=2048, D=256) the XLA einsum+bf16-lse route is 1.8× FASTER
-than this kernel (6.7 vs 12.1 ms/op — XLA's bf16 exp runs at twice this
-kernel's fp32 lane width and its own fusion already keeps the C=2048
-logits tile cheap), so ``head_impl: auto`` keeps einsum for the
-candidate head. The kernel earns its keep on the EXACT full-vocab head,
-where it deletes the [rows, V] chunk materialization (the HBM
-high-water of the exact path) at parity speed (within ~10%).
+``head_impl`` knob. On the attached chip the kernel compiles and matches
+the reference at the flagship and mlp head shapes
+(scripts/chip_kernels.py, CHANGES.md PR 21); its speed against the XLA
+einsum route is not measured there (scripts/bench_scorehead.py, ROADMAP
+D5), so ``head_impl: auto`` keeps einsum. On the EXACT full-vocab head the
+kernel deletes the [rows, V] chunk materialization (the HBM high-water of
+the exact path).
 """
 from __future__ import annotations
 

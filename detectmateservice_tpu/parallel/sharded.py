@@ -121,28 +121,10 @@ class ShardedScorer:
         self._qparams = None
         self._qscore = None
         self._qnormscore = None
-        # the last token batch placed for scoring (placement() reads its
-        # shard layout; one reference, no copy)
-        self._last_tokens = None
 
     @property
     def data_parallelism(self) -> int:
         return int(self.mesh.shape.get(AXIS_DATA, 1))
-
-    def placement(self) -> Dict[str, Any]:
-        """Where things actually are, from ``addressable_shards``: the
-        largest param leaf and the last scored token batch, as
-        ``[[device, shard shape], ...]`` — the evidence that a mesh run
-        uses every chip and not the first."""
-        def shards(array):
-            return [[str(s.device), list(s.data.shape)]
-                    for s in array.addressable_shards]
-
-        leaf = max(jax.tree_util.tree_leaves(self.params),
-                   key=lambda x: x.size)
-        return {"largest_param": shards(leaf),
-                "last_batch": (None if self._last_tokens is None
-                               else shards(self._last_tokens))}
 
     def install_params(self, params, opt_state) -> None:
         """Hot-swap the served param/opt trees (model rollout): the new
@@ -277,8 +259,7 @@ class ShardedScorer:
         int8 quantized path when live, then the bucket's AOT executable,
         then the jit (whose compile the ledger attributes)."""
         tokens, _ = self._pad_batch(np.asarray(tokens))
-        tokens = self._last_tokens = jax.device_put(tokens,
-                                                    self._batch_sharding)
+        tokens = jax.device_put(tokens, self._batch_sharding)
         if self._qparams is not None:
             return self._traced(self._qscore, self._qparams, tokens,
                                 bucket=tokens.shape[0])

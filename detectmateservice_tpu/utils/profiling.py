@@ -27,6 +27,21 @@ DEFAULT_CACHE_DIR = os.path.join(
 _MIN_COMPILE_S = 0.0
 
 
+class CompileCacheError(RuntimeError):
+    """The persistent compile cache's directory cannot be used. A boot
+    failure, not a quiet cold start on every restart: the message names the
+    variable that places the cache somewhere writable."""
+
+    def __init__(self, cache_dir: str, from_env: bool, cause: object) -> None:
+        origin = (CACHE_DIR_ENV if from_env
+                  else "compile_cache_dir / the in-checkout default")
+        super().__init__(
+            f"compile cache directory {cache_dir!r} (from {origin}) is "
+            f"unusable: {cause}. Set {CACHE_DIR_ENV} to a directory this "
+            "process can write.")
+        self.cache_dir = cache_dir
+
+
 def resolve_cache_dir(path: str = "") -> Tuple[str, bool]:
     """Where the persistent compilation cache goes → ``(directory,
     placed_by_env)``. ``JAX_COMPILATION_CACHE_DIR`` wins over everything
@@ -45,7 +60,9 @@ def enable_compilation_cache(path: str = "") -> Optional[str]:
     Service restarts then skip the XLA compiles for every already-seen
     (kernel, bucket) shape — the largest component of a scorer service's
     cold-start time. Returns the armed cache directory, or ``None`` when
-    persistence stayed off.
+    persistence stayed off; raises :class:`CompileCacheError` when the
+    directory cannot be created or written (an installed package's default
+    sits beside site-packages — deployments set the variable).
 
     The directory is :func:`resolve_cache_dir`'s. With neither the
     environment variable nor ``path`` naming one, persistence stays off on
@@ -62,10 +79,16 @@ def enable_compilation_cache(path: str = "") -> Optional[str]:
         import jax
 
         cache_dir, from_env = resolve_cache_dir(path)
-        _cache_enabled = True
         if not from_env and not path and jax.default_backend() == "cpu":
+            _cache_enabled = True
             return None
-        os.makedirs(cache_dir, exist_ok=True)
+        try:
+            os.makedirs(cache_dir, exist_ok=True)
+            writable = os.access(cache_dir, os.W_OK | os.X_OK)
+        except OSError as exc:
+            raise CompileCacheError(cache_dir, from_env, exc) from exc
+        if not writable:
+            raise CompileCacheError(cache_dir, from_env, "not writable")
         if not from_env:
             jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
@@ -75,6 +98,9 @@ def enable_compilation_cache(path: str = "") -> Optional[str]:
         # on any feature drift
         jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
         _cache_dir = cache_dir
+        # only now: a failed attempt above must fail again on the next call,
+        # not read as "decided: off"
+        _cache_enabled = True
     # arm the ledger's hit/miss counters OUTSIDE the cache lock (the ledger
     # has its own); jax's cache_hits / cache_misses events drive them
     from ..engine import device_obs

@@ -120,8 +120,8 @@ def main() -> None:
         # device batches ride the largest warmed compile bucket
         "engine_batch_size": 16384,
         # sender-side SNDHWM is the pipe's flow-control window; the 100
-        # default lockstepped the sender to the engine's wakeup cadence
-        # (measured 9k lines/s); 8192 lets the engine drain full bursts
+        # default lockstepped the sender to the engine's wakeup cadence;
+        # 8192 lets the engine drain full bursts
         "engine_buffer_size": 8192,
         # pack alerts going out; the senders pack their ingress frames —
         # one zmq send per 512 messages instead of per message

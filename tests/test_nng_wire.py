@@ -570,7 +570,7 @@ class TestFluentdPayloadContract:
         container/fluentout/schemas_pb.rb:8)."""
         from google.protobuf import descriptor as _d
 
-        from detectmateservice_tpu.schemas import schemas_pb2
+        from detectmateservice_tpu.schemas import _is_repeated, schemas_pb2
 
         rb_text = (REPO_ROOT / "container" / "fluentout" / "schemas_pb.rb").read_text()
         rb: dict = {}
@@ -600,7 +600,7 @@ class TestFluentdPayloadContract:
             py_msg = getattr(schemas_pb2, msg_name).DESCRIPTOR
             py_fields = {}
             for f in py_msg.fields:
-                if (f.label == _d.FieldDescriptor.LABEL_REPEATED
+                if (_is_repeated(f)
                         and f.message_type is not None
                         and f.message_type.GetOptions().map_entry):
                     entry = f.message_type.fields_by_name
@@ -608,7 +608,7 @@ class TestFluentdPayloadContract:
                         "map",
                         f"{type_names[entry['key'].type]}->{type_names[entry['value'].type]}",
                         f.number)
-                elif f.label == _d.FieldDescriptor.LABEL_REPEATED:
+                elif _is_repeated(f):
                     py_fields[f.name] = ("repeated", type_names[f.type], f.number)
                 else:
                     py_fields[f.name] = ("singular", type_names[f.type], f.number)

@@ -938,7 +938,7 @@ class TestRealTree:
 
     def test_marker_lint_sees_registered_markers(self):
         regs = markers.registered_markers(REPO / "pyproject.toml")
-        assert {"tpu", "slow"} <= regs
+        assert "slow" in regs
 
     def test_shim_is_invocable(self):
         """scripts/static_check.py keeps working and stays standalone."""
