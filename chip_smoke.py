@@ -19,10 +19,13 @@ stages stay jax-free (a process that has touched jax holds the chip).
     python chip_smoke.py --mesh data=4    # one scorer process over 4 chips
 
 The rehearsal is how the flow is debugged before chip time is spent; it is
-never reached by falling through. On success the last stdout line is one JSON
-object ``{"ok": true, "device": {...}, ...}`` — counts and set-up seconds
-only, no rates. Any failed check, child crash or timeout exits non-zero and
-prints no result line.
+never reached by falling through. On success stdout carries two JSON lines:
+first the report (``{"report": {...}}`` — the scorer configuration, counts and
+set-up seconds, no rates, ending ``"claim": null``), then, as the last line,
+the verdict with exactly these keys:
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`` — the
+device as the detector's jax reported it. Any failed check, child crash or
+timeout exits non-zero and prints neither line.
 """
 from __future__ import annotations
 
@@ -466,10 +469,9 @@ def run(profile: dict, work: str) -> dict:
     if failures:
         raise SmokeFailure("; ".join(failures))
     return {
-        "ok": True,
-        "device": {"platform": device["platform"],
-                   "kind": device["device_kind"],
-                   "count": device["device_count"]},
+        "device": {"platform": str(device["platform"]),
+                   "kind": str(device["device_kind"]),
+                   "count": int(device["device_count"])},
         "scorer": device["scorer"],
         "rows": {"trained": n_train, "scored": expect,
                  "injected_anomalies": len(anomalies),
@@ -527,7 +529,9 @@ def main() -> int:
         return 1
     finally:
         shutil.rmtree(work, ignore_errors=True)
-    print(json.dumps(result))
+    print(json.dumps({"report": result}))
+    # the last line is the verdict alone: nothing but "ok" and the device
+    print(json.dumps({"ok": True, "device": result["device"]}))
     return 0
 
 

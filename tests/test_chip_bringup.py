@@ -390,9 +390,17 @@ class TestLaunchers:
     def test_chip_smoke_cpu_rehearsal_runs_green(self):
         proc = _run(["chip_smoke.py", "--rehearse-cpu"], timeout=600)
         assert proc.returncode == 0, proc.stderr[-3000:]
-        result = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert result["ok"] is True and result["claim"] is None
-        assert result["device"]["platform"] == "cpu"
+        report_line, verdict_line = proc.stdout.strip().splitlines()[-2:]
+        # the last line is the verdict alone: exactly these keys, no more
+        verdict = json.loads(verdict_line)
+        assert set(verdict) == {"ok", "device"} and verdict["ok"] is True
+        assert set(verdict["device"]) == {"platform", "kind", "count"}
+        assert verdict["device"]["platform"] == "cpu"
+        assert isinstance(verdict["device"]["kind"], str)
+        assert type(verdict["device"]["count"]) is int
+        result = json.loads(report_line)["report"]
+        assert result["claim"] is None and list(result)[-1] == "claim"
+        assert result["device"] == verdict["device"]
         assert result["scorer"]["model"] == "logbert"
         assert result["batches"]["device_path"] > 0
         assert result["batches"]["host_path"] > 0
