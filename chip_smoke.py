@@ -353,8 +353,9 @@ def run(profile: dict, work: str) -> dict:
             return http_json(det.port, "/admin/xla?limit=1")["device"]
 
         def scored_rows() -> int:
-            # one ledger span per batch whose scores reached the host
-            # (detector_device_lines_total counts rows on ARRIVAL)
+            # one ledger span per batch whose scores reached the host, on
+            # either path (detector_device_lines_total counts the device
+            # path's rows alone; the lone frames ride the host twin)
             return sum(span["real"] for span in
                        http_json(det.port, "/admin/xla")["batches"])
 

@@ -25,7 +25,15 @@ events):
   ``xla_recompile_storm`` watchdog check.
 * **batch span log** — each drained device batch records a span (bucket,
   real rows, path, queue-wait vs device-time split, the PR-1 trace id
-  current at dispatch) into a bounded ring, also on ``GET /admin/xla``.
+  current at dispatch, and the batch's stamps as offsets from its release)
+  into a bounded ring, also on ``GET /admin/xla``.
+* :func:`span` — the one helper every layer boundary is marked with: a
+  ``jax.profiler.TraceAnnotation`` on the profiler's host plane (the clock
+  the device plane of a ``POST /admin/profile`` capture is on) and, for the
+  names a metric reads, ``detector_phase_seconds_total{phase}``.
+* :class:`DeviceIdleClock` — what the host knows of the device's idle time,
+  split by what the coalescer held meanwhile
+  (``detector_device_idle_seconds_total{cause}``).
 * :func:`export_hbm_gauges` — ``device_hbm_bytes{device,kind}`` computed at
   scrape time from ``jax.Device.memory_stats()`` (absent on CPU backends,
   which return ``None`` — then nothing is exported).
@@ -66,6 +74,15 @@ CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 # the warm-up's cache_load phase split
 CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
+# the batch span ring: at the ~40 releases a second a 25 ms deadline gives,
+# 4,096 spans cover ~100 s — more than a benchmark window
+MAX_SPANS = 4096
+
+# the order a batch's stamps come in (record_span stores each as an offset
+# in seconds from ``release``)
+SPAN_STAMPS = ("oldest_arrival", "release", "pickup", "call_issued",
+               "readable", "sent")
+
 # how long after the last unexpected recompile the watchdog check stays
 # degraded (long enough to survive a scrape/evaluation gap, short enough
 # that a one-off mis-sized batch does not page for an hour)
@@ -79,7 +96,7 @@ class CompileLedger:
     only fires on actual backend compiles, and span recording is one lock +
     deque append per *drained batch*, never per message)."""
 
-    def __init__(self, max_events: int = 256, max_spans: int = 256,
+    def __init__(self, max_events: int = 256, max_spans: int = MAX_SPANS,
                  storm_window_s: float = RECOMPILE_STORM_WINDOW_S) -> None:
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=max(1, max_events))
@@ -357,18 +374,42 @@ class CompileLedger:
             monitor.emit_event(dict(event, kind="unexpected_recompile"))
         return event
 
+    def next_batch_seq(self) -> int:
+        """The identifier of the next device batch, allotted when the batch
+        is released: its ``dm.*`` annotations carry it as ``batch`` and
+        :meth:`record_span` files the ring entry under it."""
+        with self._lock:
+            self._span_seq += 1
+            return self._span_seq
+
     def record_span(self, bucket: int, real: int, path: str,
                     queue_wait_s: float, device_s: float,
                     trace_id: Optional[str] = None,
-                    release: Optional[str] = None) -> None:
+                    release: Optional[str] = None,
+                    seq: Optional[int] = None,
+                    stamps: Optional[Dict[str, Optional[float]]] = None
+                    ) -> Dict[str, Any]:
         """One drained device batch: the span the flight recorder's trace id
         links back to (PR-1 `/admin/trace` ↔ this batch). ``release`` names
         why the coalescer let the batch go (full/deadline/flush); None for
-        uncoalesced dispatches."""
+        uncoalesced dispatches. ``seq`` is the identifier
+        :meth:`next_batch_seq` gave the batch at release (allotted here
+        when the caller has none). ``stamps`` are the batch's monotonic
+        stamps by :data:`SPAN_STAMPS` name; each is stored as an offset in
+        seconds from ``release`` (``sent`` is filled in by
+        :meth:`note_sent`). Returns the ring entry."""
+        offsets: Dict[str, Optional[float]] = {}
+        base = (stamps or {}).get("release")
+        if base is not None:
+            for name in SPAN_STAMPS:
+                at = stamps.get(name)
+                offsets[name] = None if at is None else round(at - base, 6)
         with self._lock:
-            self._span_seq += 1
-            self._spans.append({
-                "seq": self._span_seq,
+            if seq is None:
+                self._span_seq += 1
+                seq = self._span_seq
+            entry = {
+                "seq": seq,
                 "ts": round(time.time(), 6),
                 "bucket": int(bucket),
                 "real": int(real),
@@ -378,7 +419,17 @@ class CompileLedger:
                 "device_s": round(float(device_s), 6),
                 "trace_id": trace_id,
                 "release": release,
-            })
+                "offsets_s": offsets,
+            }
+            self._spans.append(entry)
+        return entry
+
+    def note_sent(self, entry: Dict[str, Any], after_release_s: float) -> None:
+        """The batch's alerts were built and handed to the engine's send
+        path ``after_release_s`` after its release: the last of the ring
+        entry's offsets."""
+        with self._lock:
+            entry["offsets_s"]["sent"] = round(after_release_s, 6)
 
     # -- reads -----------------------------------------------------------
     def unexpected_in_window(self, window_s: Optional[float] = None,
@@ -403,8 +454,11 @@ class CompileLedger:
             cache_totals = dict(self._cache_totals)
             warmup_phases = dict(self._warmup_phases)
         if limit is not None and limit >= 0:
-            events = events[-limit:]
-            spans = spans[-limit:]
+            events = events[-limit:] if limit else []
+            spans = spans[-limit:] if limit else []
+        # copies: note_sent fills an entry's last offset after it is filed
+        spans = [dict(span, offsets_s=dict(span["offsets_s"]))
+                 for span in spans]
         doc = {
             "warmup_complete": warmed,
             "totals": totals,
@@ -563,6 +617,162 @@ def _default_backend() -> str:
         return jax.default_backend()
     except Exception:  # noqa: BLE001 — jax absent or not yet initialized
         return "unknown"
+
+
+# ---------------------------------------------------------------------------
+# spans: one helper, two sinks
+# ---------------------------------------------------------------------------
+# The vocabulary (docs/telemetry.md has the table): dm.recv_wait, dm.featurize,
+# dm.release, dm.upload, dm.call, dm.readback, dm.alert_build, dm.send. Every
+# span is taken per engine burst or per device batch by the thread that does
+# the work, never per line. Batch-scoped spans carry batch=<seq>, bucket, rows
+# and release, so all spans of one device batch and its ring entry share an
+# identifier.
+
+# the spans a per-layer metric reads: these also feed
+# detector_phase_seconds_total / detector_phase_total{phase}
+PHASE_SPANS = {"dm.upload": "upload", "dm.readback": "readback",
+               "dm.alert_build": "alert_build"}
+
+# jax.profiler.TraceAnnotation once a scorer has resolved it; None in a
+# stage that never imports jax (parser, output): span() is then a no-op
+_ANNOTATION = None
+# span name -> (seconds child, count child), bound by the scorer
+_PHASE_CHILDREN: Dict[str, tuple] = {}
+
+
+class _NullSpan:
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    __slots__ = ("_annotation", "_phase", "_t0")
+
+    def __init__(self, annotation, phase) -> None:
+        self._annotation = annotation
+        self._phase = phase
+        self._t0 = 0.0
+
+    def __enter__(self) -> None:
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._phase is not None:
+            self._t0 = time.monotonic()
+
+    def __exit__(self, *exc) -> bool:
+        if self._phase is not None:
+            seconds_c, count_c = self._phase
+            seconds_c.inc(time.monotonic() - self._t0)
+            count_c.inc()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
+
+
+def span(name: str, **kv):
+    """Context manager marking one layer boundary. Enters a
+    ``TraceAnnotation(name, **kv)`` — an event on the profiler's host plane
+    while a capture runs, a flag test otherwise — and, for the names in
+    :data:`PHASE_SPANS`, adds the elapsed monotonic time to
+    ``detector_phase_seconds_total{phase}`` and one to
+    ``detector_phase_total{phase}``. Where neither sink is armed it returns
+    a shared no-op."""
+    phase = _PHASE_CHILDREN.get(name)
+    if _ANNOTATION is not None:
+        return _Span(_ANNOTATION(name, **kv), phase)
+    return NULL_SPAN if phase is None else _Span(None, phase)
+
+
+def arm_spans(labels: Dict[str, str]) -> None:
+    """Scorer set-up: resolve the annotation class (this process has jax)
+    and bind the phase counters' children, so that each series is exported
+    as 0 from boot. Last call wins, like the ledger's binding."""
+    global _ANNOTATION
+    from jax.profiler import TraceAnnotation
+
+    _ANNOTATION = TraceAnnotation
+    for name, phase in PHASE_SPANS.items():
+        _PHASE_CHILDREN[name] = (
+            m.PHASE_SECONDS().labels(phase=phase, **labels),
+            m.PHASE_COUNT().labels(phase=phase, **labels))
+
+
+class DeviceIdleClock:
+    """What the host knows of the device's idle time, split by cause.
+
+    An idle stretch runs from the moment the last unfinished device batch
+    was seen readable (:meth:`idle_from`) to the moment the next scoring
+    call has been issued. The scorer's engine thread calls :meth:`advance`
+    before every change to what the coalescer holds and at every pump, with
+    ``release_at``: ``None`` when nothing is held, else the time at which
+    the held rows met (or will meet) the release rule — ``-inf`` once the
+    release target is reached, the oldest row's due time otherwise. The
+    stretch since the last call then splits into ``no_rows`` (nothing
+    held), ``fill`` (held, rule not met) and ``host`` (rule met, the engine
+    thread had not pumped yet). :meth:`issued` adds the rest of a release
+    made onto an idle device — release to call issued — to ``host``.
+
+    Pure bookkeeping on stamps the caller passes (tests run it on a fake
+    clock); single-owner, the engine thread. It falls short of the device's
+    own idle time by the lateness of the ``is_ready()`` poll that sees a
+    batch readable."""
+
+    CAUSES = ("fill", "no_rows", "host")
+
+    def __init__(self, children: Optional[Dict[str, Any]] = None) -> None:
+        self.seconds = dict.fromkeys(self.CAUSES, 0.0)
+        self._children = children or {}
+        self._mark: Optional[float] = None
+
+    @property
+    def idle(self) -> bool:
+        return self._mark is not None
+
+    def _add(self, cause: str, seconds: float) -> None:
+        if seconds <= 0.0:
+            return
+        self.seconds[cause] += seconds
+        child = self._children.get(cause)
+        if child is not None:
+            child.inc(seconds)
+
+    def idle_from(self, now: float) -> None:
+        if self._mark is None:
+            self._mark = now
+
+    def advance(self, now: float, release_at: Optional[float]) -> None:
+        mark = self._mark
+        if mark is None or now <= mark:
+            return
+        if release_at is None:
+            self._add("no_rows", now - mark)
+        else:
+            met = min(max(release_at, mark), now)
+            self._add("fill", met - mark)
+            self._add("host", now - met)
+        self._mark = now
+
+    def busy_from(self, now: float, release_at: Optional[float]) -> bool:
+        """A batch was released to the device at ``now``. True when it
+        found the device idle: its release → call issued is then idle time
+        too (:meth:`issued`)."""
+        if self._mark is None:
+            return False
+        self.advance(now, release_at)
+        self._mark = None
+        return True
+
+    def issued(self, seconds: float) -> None:
+        self._add("host", seconds)
 
 
 # ---------------------------------------------------------------------------

@@ -54,6 +54,7 @@ from .framing import (
     wrap_tenant,
     wrap_trace,
 )
+from .device_obs import span
 from .health import Heartbeat
 from .tracing import FRAME_CONTEXT, FlightRecorder
 from .socket import (
@@ -160,6 +161,9 @@ class Engine:
         self._m_dropped_b = m.DATA_DROPPED_BYTES().labels(**self._labels)
         self._m_dropped_l = m.DATA_DROPPED_LINES().labels(**self._labels)
         self._m_send_backlog = m.OUTPUT_SEND_BACKLOG().labels(**self._labels)
+        # seconds of the stretches in which that gauge reads > 0: the time
+        # this stage's engine thread stood blocked on a full peer
+        self._m_send_blocked = m.SEND_BLOCKED_SECONDS().labels(**self._labels)
 
         # self-diagnosis heartbeats (engine/health.py): one monotonic clock
         # write per loop iteration — the beats happen unconditionally (they
@@ -896,11 +900,14 @@ class Engine:
             if remaining_ms <= 0:
                 break
             try:
-                if callable(recv_many):
-                    frames = recv_many(remaining_fn(), max(1, int(remaining_ms)))
-                else:
-                    self._pair_sock.recv_timeout = max(1, int(remaining_ms))
-                    frames = [self._pair_sock.recv()]
+                with span("dm.recv_wait"):
+                    if callable(recv_many):
+                        frames = recv_many(remaining_fn(),
+                                           max(1, int(remaining_ms)))
+                    else:
+                        self._pair_sock.recv_timeout = max(
+                            1, int(remaining_ms))
+                        frames = [self._pair_sock.recv()]
             except (TransportTimeout, TransportError):
                 break
             for nxt in frames:
@@ -1013,7 +1020,8 @@ class Engine:
                     self._pair_sock.recv_timeout = want
                     current_timeout = want
             try:
-                raw = self._pair_sock.recv()
+                with span("dm.recv_wait"):
+                    raw = self._pair_sock.recv()
             except TransportTimeout:
                 # input went idle (or a short-poll tick passed): drain
                 # pipelined results so a quiet stream still gets bounded
@@ -1538,12 +1546,20 @@ class Engine:
             except OSError as exc:
                 self.logger.error("injected sock_send fault: %s", exc)
                 return
-        frame_batch = getattr(self.settings, "engine_frame_batch", 1)
         if origins is not None and len(origins) == len(outs):
             pending = [(o, origins[i]) for i, o in enumerate(outs)
                        if o is not None]
         else:
             pending = [(o, None) for o in outs if o is not None]
+        if pending:
+            with span("dm.send", results=len(pending)):
+                self._fan_out(pending)
+
+    def _fan_out(self, pending: List) -> None:
+        """Build the wire units for ``pending`` (result, origin) pairs and
+        send them: the body of :meth:`_send_results`, under its ``dm.send``
+        span."""
+        frame_batch = getattr(self.settings, "engine_frame_batch", 1)
         attach = bool(self._trace_enabled
                       and (self._out_socks or self.router is not None)
                       and not self._trace_terminal
@@ -1635,6 +1651,7 @@ class Engine:
         idx = 0
         retries = 0
         waited = False
+        blocked_from = 0.0
         # dmlint: hot-loop
         while idx < len(wires):
             hard = False
@@ -1673,6 +1690,7 @@ class Engine:
                 backlog_g.set(1)
                 if not waited:
                     self._hb_output.wait_begin()
+                    blocked_from = time.monotonic()
                 else:
                     self._hb_output.beat()
                 waited = True
@@ -1694,6 +1712,7 @@ class Engine:
             self._drop_frame(metas[j], wires[j])
         if waited:
             backlog_g.set(0)
+            self._m_send_blocked.inc(time.monotonic() - blocked_from)
             self._hb_output.wait_end()
 
     def _send_to_outputs(self, data: bytes, lines: Optional[int] = None,
@@ -1804,6 +1823,7 @@ class Engine:
             backlog_g = self._m_send_backlog
             pending_socks = list(self._out_socks)
             waited = False
+            blocked_from = 0.0
             # dmlint: hot-loop
             while pending_socks:
                 if not self._running or self._stop_event.is_set():
@@ -1833,6 +1853,7 @@ class Engine:
                     backlog_g.set(len(still))
                     if not waited:
                         self._hb_output.wait_begin()
+                        blocked_from = time.monotonic()
                     else:
                         self._hb_output.beat()
                     waited = True
@@ -1846,10 +1867,12 @@ class Engine:
                 drop_ref()
             if waited:
                 backlog_g.set(0)
+                self._m_send_blocked.inc(time.monotonic() - blocked_from)
                 self._hb_output.wait_end()
             return any_ok
 
         waited = False
+        blocked_from = 0.0
         for sock in self._out_socks:
             sent = False
             # dmlint: hot-loop
@@ -1862,6 +1885,7 @@ class Engine:
                     if not waited:
                         # gauge only touched once a peer actually stalls
                         self._m_send_backlog.set(1)
+                        blocked_from = time.monotonic()
                         waited = True
                     # bounded retries (max retry_count × 10 ms) never trip
                     # the saturation check — drop mode surfaces through the
@@ -1883,4 +1907,5 @@ class Engine:
                 drop_ref()
         if waited:
             self._m_send_backlog.set(0)
+            self._m_send_blocked.inc(time.monotonic() - blocked_from)
         return any_ok

@@ -91,6 +91,9 @@ DATA_PROCESSED_LINES = _series(Counter, "data_processed_lines_total", "Lines han
 # /metrics endpoint to report per-chip rates; new series, new 'device' label,
 # existing series untouched)
 DEVICE_LABELS = ("component_type", "component_id", "device")
+# both tick when a device-path batch's scores are host-readable
+# (jax_scorer._observe_batch): rows that arrived but are still held, and
+# rows the host twin scored, are not in them
 DEVICE_BATCHES = _series(Counter, "detector_device_batches_total", "Scored batches per device", DEVICE_LABELS)
 DEVICE_LINES = _series(Counter, "detector_device_lines_total", "Scored lines per device", DEVICE_LABELS)
 BATCH_SIZE_HIST = _series(
@@ -172,6 +175,12 @@ OUTPUT_SEND_BACKLOG = _series(
     Gauge,
     "output_send_backlog",
     "Output sockets currently waiting on a full peer queue",
+)
+SEND_BLOCKED_SECONDS = _series(
+    Counter,
+    "engine_send_blocked_seconds_total",
+    "Seconds the engine thread spent waiting on a full peer queue inside "
+    "the send path (the stretches in which output_send_backlog reads > 0)",
 )
 
 # self-diagnosis series (engine/health.py): the watchdog rolls the
@@ -498,6 +507,56 @@ DEADLINE_RELEASES = _series(
     "Coalesced micro-batch releases by reason: full (target occupancy "
     "reached), deadline (latency budget spent), flush (idle/teardown)",
     RELEASE_LABELS,
+)
+# the hold of the MEAN row (detector_queue_wait_seconds observes the oldest
+# row's): at each coalesced release, rows x (release - the segment's arrival
+# stamp) summed over the released segments, and the rows released by reason.
+# seconds / rows = mean hold of a row in the coalescer.
+ROW_HOLD_SECONDS = _series(
+    Counter,
+    "detector_row_hold_seconds_total",
+    "Row-seconds spent in the batch coalescer: sum over released rows of "
+    "(release time - the row's arrival at the coalescer)",
+)
+ROWS_RELEASED = _series(
+    Counter,
+    "detector_rows_released_total",
+    "Rows released by the batch coalescer, by reason (full / deadline / "
+    "flush)",
+    RELEASE_LABELS,
+)
+
+# batch spans a per-layer metric reads (engine/device_obs.py span()): wall
+# seconds inside the span and the number of spans, per phase — upload
+# (narrow + device_put, dispatch worker), readback (np.asarray of the
+# scores, engine thread), alert_build (parse + alert construction for the
+# rows over the threshold, engine thread). One observation per device batch.
+PHASE_LABELS = ("component_type", "component_id", "phase")
+PHASE_SECONDS = _series(
+    Counter,
+    "detector_phase_seconds_total",
+    "Wall seconds inside a dm.<phase> batch span, by phase",
+    PHASE_LABELS,
+)
+PHASE_COUNT = _series(
+    Counter,
+    "detector_phase_total",
+    "dm.<phase> batch spans completed, by phase",
+    PHASE_LABELS,
+)
+
+# what the host knows of the device's idle time: from the moment the last
+# unfinished device batch was seen readable to the next scoring call being
+# issued, split by what the coalescer held meanwhile — fill (rows held,
+# release rule not met yet), no_rows (nothing held), host (the release rule
+# was met, or the batch was released, and the call had not been issued yet)
+IDLE_LABELS = ("component_type", "component_id", "cause")
+DEVICE_IDLE_SECONDS = _series(
+    Counter,
+    "detector_device_idle_seconds_total",
+    "Host-known device idle seconds (last batch readable to next call "
+    "issued), by cause: fill / no_rows / host",
+    IDLE_LABELS,
 )
 
 # multi-tenant admission control (shed/, dmshed): the ingress overload

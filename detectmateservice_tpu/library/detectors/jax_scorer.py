@@ -26,6 +26,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ...engine import device_obs
 from ...schemas import DetectorSchema, ParserSchema, SchemaError
 from ..common.core import LibraryError
 from ..common.detector import BufferMode, CoreDetector, CoreDetectorConfig
@@ -140,6 +141,14 @@ class JaxScorerDetectorConfig(CoreDetectorConfig):
     seed: int = 0
 
 
+def _batch_span(name: str, batch_kv: Optional[Dict[str, Any]]):
+    """``device_obs.span`` for a served device batch; nothing for a call
+    that is not one (warm-up, fit, parity: ``batch_kv`` None)."""
+    if batch_kv is None:
+        return device_obs.NULL_SPAN
+    return device_obs.span(name, **batch_kv)
+
+
 def _bucket(n: int, max_batch: int) -> int:
     """Round a ragged batch size up to a power of two (≤ max_batch)."""
     b = 1
@@ -160,20 +169,28 @@ class _InflightSlot:
     Telemetry fields (engine/device_obs.py batch spans): ``t_enqueue`` is
     dispatch-call time (for a coalesced release, the OLDEST held row's
     arrival — so queue-wait telemetry includes the coalescer hold),
-    ``t_start`` when the scoring call actually began (worker pickup),
-    ``trace_id`` the flight recorder's last completed trace at dispatch —
-    the link from a device batch back to PR-1 traces — and ``release`` why
-    the coalescer let the batch go (full/deadline/flush; None
-    uncoalesced)."""
+    ``t_release`` the dispatch call itself, ``t_start`` when the scoring
+    call actually began (worker pickup), ``t_issued`` when the upload and
+    the scoring call had been issued, ``trace_id`` the flight recorder's
+    last completed trace at dispatch — the link from a device batch back to
+    PR-1 traces — ``release`` why the coalescer let the batch go
+    (full/deadline/flush; None uncoalesced), ``seq`` the batch's identifier
+    (the ledger's, allotted at release: its ``dm.*`` annotations and its
+    ring entry share it) and ``idle_start`` whether the release found
+    nothing unfinished on the device (its release → call issued is then
+    device idle time)."""
 
     __slots__ = ("scores", "raws", "real", "error", "done",
-                 "t_enqueue", "t_start", "bucket", "path", "trace_id",
-                 "release", "tokens")
+                 "t_enqueue", "t_release", "t_start", "t_issued", "bucket",
+                 "path", "trace_id", "release", "tokens", "seq",
+                 "idle_start")
 
     def __init__(self, raws, real: int, bucket: int = 0,
                  path: str = "device", trace_id: Optional[str] = None,
                  release: Optional[str] = None,
-                 tokens: Optional[np.ndarray] = None):
+                 tokens: Optional[np.ndarray] = None, seq: int = 0,
+                 t_enqueue: Optional[float] = None,
+                 t_release: Optional[float] = None):
         import threading
 
         self.scores = None
@@ -187,12 +204,22 @@ class _InflightSlot:
         self.tokens = tokens
         self.error: Optional[Exception] = None
         self.done = threading.Event()
-        self.t_enqueue = time.monotonic()
+        now = time.monotonic()
+        self.t_enqueue = now if t_enqueue is None else t_enqueue
+        self.t_release = now if t_release is None else t_release
         self.t_start: Optional[float] = None
+        self.t_issued: Optional[float] = None
         self.bucket = bucket
         self.path = path
         self.trace_id = trace_id
         self.release = release
+        self.seq = seq
+        self.idle_start = False
+
+    def span_kv(self) -> Dict[str, Any]:
+        """What every ``dm.*`` span of this batch carries."""
+        return {"batch": self.seq, "bucket": self.bucket, "rows": self.real,
+                "release": self.release or "none"}
 
 
 class _ChainRaws:
@@ -257,7 +284,7 @@ class _BatchCoalescer:
 
     __slots__ = ("deadline_s", "target_occupancy", "releases", "rows_in",
                  "max_wait_s", "wait_sum_s", "wait_n", "retired_total",
-                 "_q", "_rr", "_deficit", "_total")
+                 "row_hold_s", "rows_out", "_q", "_rr", "_deficit", "_total")
 
     def __init__(self, deadline_s: float, target_occupancy: float) -> None:
         from collections import deque
@@ -270,6 +297,11 @@ class _BatchCoalescer:
         self.wait_sum_s = 0.0
         self.wait_n = 0
         self.retired_total = 0
+        # row-weighted hold: sum over released rows of (release - the row's
+        # arrival), and the rows it covers — their quotient is the MEAN
+        # row's hold, where wait_sum_s / wait_n is the oldest row's
+        self.row_hold_s = 0.0
+        self.rows_out = 0
         # tenant -> deque of (t_arrival, tokens [k, S], raws); queues are
         # pruned when emptied so the table tracks ACTIVE tenants only
         self._q: Any = {}
@@ -294,9 +326,19 @@ class _BatchCoalescer:
         self._total += len(tokens)
         self.rows_in += len(tokens)
 
-    def oldest_age(self, now: float) -> float:
+    def oldest_arrival(self) -> Optional[float]:
+        """Arrival stamp of the oldest held row; None when nothing is held."""
         heads = [q[0][0] for q in self._q.values() if q]
-        return 0.0 if not heads else max(0.0, now - min(heads))
+        return min(heads) if heads else None
+
+    def oldest_age(self, now: float) -> float:
+        oldest = self.oldest_arrival()
+        return 0.0 if oldest is None else max(0.0, now - oldest)
+
+    def due_at(self) -> Optional[float]:
+        """When :meth:`due` turns true for the rows held now."""
+        oldest = self.oldest_arrival()
+        return None if oldest is None else oldest + self.deadline_s * 0.75
 
     def due(self, now: float) -> bool:
         """True once the oldest row's wait APPROACHES the deadline: release
@@ -313,8 +355,10 @@ class _BatchCoalescer:
                 sum(len(seg[1]) for seg in q)
                 for t, q in self._q.items()}
 
-    def take(self, n: int):
-        """Pop ``n`` rows → (tokens [n, S], raws, t_oldest).
+    def take(self, n: int, now: Optional[float] = None):
+        """Pop ``n`` rows → (tokens [n, S], raws, t_oldest). With ``now``
+        (the release time) the popped rows' holds — rows × (now − their
+        segment's arrival stamp) — are added to ``row_hold_s``/``rows_out``.
 
         The round starts at the tenant holding the globally-oldest row, so
         a deadline release always carries the row that tripped it; each
@@ -338,6 +382,8 @@ class _BatchCoalescer:
                 if t_oldest is None or t < t_oldest:
                     t_oldest = t
                 want = take_rows - served
+                if now is not None:
+                    self.row_hold_s += min(want, len(tok)) * max(0.0, now - t)
                 if want < len(tok):
                     parts.append(tok[:want])
                     raw_segs.append(raws[:want])
@@ -359,6 +405,8 @@ class _BatchCoalescer:
                 self._deficit.pop(key, None)
                 del self._q[key]
         self._total -= n
+        if now is not None:
+            self.rows_out += n
         tokens = parts[0] if len(parts) == 1 else np.concatenate(parts)
         raws = raw_segs[0] if len(raw_segs) == 1 else _ChainRaws(raw_segs)
         return tokens, raws, t_oldest
@@ -418,7 +466,6 @@ class JaxScorerDetector(CoreDetector):
         self._host_warm: set = set()                   # compiled host buckets
         self._host_warm_thread = None
         self._ready_supported: Optional[bool] = None   # jax.Array.is_ready seen?
-        self._metrics_labels = None
         self._feat_counters = None  # (native_rows, fallback_rows) label pair
         # device observability (engine/device_obs.py): the process-wide XLA
         # compile ledger (set in _ensure_scorer) plus cached label children
@@ -447,6 +494,16 @@ class JaxScorerDetector(CoreDetector):
         self._retire_last_sweep: Optional[float] = None
         self._coalesce_gauge = None
         self._release_children: Dict[str, Any] = {}
+        # boundary counters (bound at scorer set-up, _bind_boundary_counters,
+        # so each series reads 0 from boot): rows released by reason + the
+        # row-seconds they were held, scored rows/batches per device, and
+        # the host-known device idle time by cause (engine-thread-owned,
+        # like _inflight; _dev_unfinished counts its device-path slots)
+        self._rows_released_children: Dict[str, Any] = {}
+        self._row_hold_child = None
+        self._device_children: Optional[tuple] = None
+        self._idle_clock = None
+        self._dev_unfinished = 0
         self._occ_stats = (0, 0.0)            # (dispatches, occupancy sum)
         # the native featurize module, loaded once: its absence is logged
         # here, at boot, and reported by GET /admin/xla — never discovered
@@ -564,16 +621,15 @@ class JaxScorerDetector(CoreDetector):
         t0 = _time.monotonic()
         self._ensure_scorer()
 
-        from ...engine.device_obs import WarmupPendingCheck
-
         # boot→ACTIVE gate: register BEFORE the first compile so a deep
         # health probe racing the warm-up sees UNHEALTHY (the router treats
         # "degraded" as dispatchable — only unhealthy refuses traffic)
         monitor = getattr(self._ledger, "monitor", None)
         if monitor is not None:
             try:
-                monitor.remove_check(WarmupPendingCheck.name)
-                monitor.add_check(WarmupPendingCheck(self._ledger, monitor))
+                monitor.remove_check(device_obs.WarmupPendingCheck.name)
+                monitor.add_check(device_obs.WarmupPendingCheck(
+                    self._ledger, monitor))
             # dmlint: ignore[DM-R001] a bare-bones test monitor without the
             except Exception:  # noqa: BLE001 — check API must not fail boot
                 pass
@@ -674,8 +730,6 @@ class JaxScorerDetector(CoreDetector):
         # XLA compile ledger: the jax.monitoring listener installs once per
         # process; this detector's jit call sites wrap themselves in ledger
         # contexts so every compile attributes to a (bucket, trigger) pair
-        from ...engine import device_obs
-
         self._ledger = device_obs.get_ledger()
         device_obs.install_listener()
         # GET /admin/xla reports the live warm/retired bucket sets next to
@@ -748,11 +802,13 @@ class JaxScorerDetector(CoreDetector):
             self._device = f"mesh({','.join(f'{k}={v}' for k, v in mesh.shape.items())})"
             self._obs_backend = "mesh"
             device_obs.export_hbm_gauges(self._obs_labels())
+            self._bind_boundary_counters()
             if cfg.host_score_max_batch > 0:
                 self._host_twin_state = "unsupported"  # mesh owns the params
             return
         self._obs_backend = self._platform
         device_obs.export_hbm_gauges(self._obs_labels())
+        self._bind_boundary_counters()
         params, opt_state = self._scorer.init(self._rng)
         # params pinned in device memory once (HBM residency; north-star
         # item); construction-time, before any other thread can exist:
@@ -950,7 +1006,8 @@ class JaxScorerDetector(CoreDetector):
         return jax.device_put(narrow_tokens(array, self.config.vocab_size),
                               self._device)
 
-    def _score_dev(self, tokens: np.ndarray):
+    def _score_dev(self, tokens: np.ndarray,
+                   batch_kv: Optional[Dict[str, Any]] = None):
         """Dispatch scoring for [n, S] tokens; returns the device array
         without forcing readback (single device or sharded mesh). Applies
         per-position normalization once calibrated (fit). Routing order:
@@ -958,33 +1015,54 @@ class JaxScorerDetector(CoreDetector):
         AOT executable — a bucket that has one ALWAYS runs it: an argument
         it rejects (dtype, sharding, committed device) raises instead of
         quietly retracing — then, for buckets outside the AOT set, the jit
-        (whose compile the ledger sees)."""
+        (whose compile the ledger sees).
+
+        ``batch_kv`` (a served device batch: ``_InflightSlot.span_kv``)
+        marks the upload as ``dm.upload`` and the call, with the start of
+        the asynchronous readback, as ``dm.call``; warm-up, fit and parity
+        calls pass none and leave no span. On a mesh the sharded scorer
+        places its own shards, so the whole of it is ``dm.call``."""
+        if self._sharded is None:
+            with _batch_span("dm.upload", batch_kv):
+                tokens = self._put(tokens)
+        with _batch_span("dm.call", batch_kv):
+            scores = self._call_dev(tokens)
+            if batch_kv is not None:
+                try:
+                    scores.copy_to_host_async()
+                except AttributeError:
+                    pass
+        return scores
+
+    def _call_dev(self, tokens):
+        """The scoring call itself, on tokens already placed (host rows on
+        a mesh: the sharded scorer places them)."""
         if self._norm_mu is not None:
             if self._sharded is not None:
                 return self._sharded.normscore_device(
                     tokens, self._norm_mu, self._norm_sigma)
             # dmlint: ignore[DM-L001] ref-atomic q-tree swap
             if self._qparams is not None:
-                return self._qnormscore(self._qparams, self._put(tokens),
+                return self._qnormscore(self._qparams, tokens,
                                         self._norm_mu, self._norm_sigma)
             comp = self._aot_exec.get(("normscore", len(tokens)))
             if comp is not None:
                 # dmlint: ignore[DM-L001] ref-atomic param swap
-                return comp(self._params, self._put(tokens),
+                return comp(self._params, tokens,
                             self._norm_mu, self._norm_sigma)
             return self._scorer._normscore(
-                self._params, self._put(tokens), self._norm_mu, self._norm_sigma)
+                self._params, tokens, self._norm_mu, self._norm_sigma)
         if self._sharded is not None:
             return self._sharded.score_device(tokens)
         # dmlint: ignore[DM-L001] ref-atomic q-tree swap
         if self._qparams is not None:
-            return self._qscore(self._qparams, self._put(tokens))
+            return self._qscore(self._qparams, tokens)
         comp = self._aot_exec.get(("score", len(tokens)))
         if comp is not None:
             # dmlint: ignore[DM-L001] ref-atomic param swap
-            return comp(self._params, self._put(tokens))
+            return comp(self._params, tokens)
         # dmlint: ignore[DM-L001] ref-atomic param swap; either generation
-        return self._scorer.score(self._params, self._put(tokens))
+        return self._scorer.score(self._params, tokens)
 
     def _token_nlls_dev(self, tokens: np.ndarray):
         if self._sharded is not None:
@@ -1369,7 +1447,8 @@ class JaxScorerDetector(CoreDetector):
         fit_thread = self._fit_thread  # local read: another thread may None it
         if fit_thread is not None and not fit_thread.is_alive():
             self._finish_fit()
-        tokens, ok = self._featurize_raw_batch(batch)
+        with device_obs.span("dm.featurize", rows=len(batch)):
+            tokens, ok = self._featurize_raw_batch(batch)
 
         # split the batch across the train/detect phase boundary
         detect_idx: List[int] = []
@@ -1403,18 +1482,16 @@ class JaxScorerDetector(CoreDetector):
                 detect_idx.append(i)
         ready: List[Optional[bytes]] = []  # outputs from drained older batches
         if detect_idx:
-            n = len(detect_idx)
             det_tokens = tokens[detect_idx]
             det_raws = [batch[i] for i in detect_idx]
             coalescer = self._get_coalescer()
             if coalescer is not None:
                 # continuous batching: hold the rows toward a warm bucket;
                 # _coalesce_pump below decides what (if anything) dispatches
-                coalescer.add(det_tokens, det_raws, time.monotonic(),
-                              tenant=self._ingress_tenant)
+                self._coalesce_add(det_tokens, det_raws,
+                                   tenant=self._ingress_tenant)
             else:
                 self._dispatch(det_tokens, det_raws)
-            self._count_device_lines(n)
         self._coalesce_pump()
         # event-driven drain: anything whose readback already landed goes out
         # NOW (bounded latency even under a steady stream that never lulls);
@@ -1468,8 +1545,9 @@ class JaxScorerDetector(CoreDetector):
         if fit_thread is not None and not fit_thread.is_alive():
             self._finish_fit()
 
-        fb = matchkern.featurize_frames(frames, self.config.seq_len,
-                                        self.config.vocab_size)
+        with device_obs.span("dm.featurize", frames=len(frames)):
+            fb = matchkern.featurize_frames(frames, self.config.seq_len,
+                                            self.config.vocab_size)
         if fb.n_corrupt_frames:
             self.count_processing_errors(fb.n_corrupt_frames,
                                          "corrupt batch frame(s)")
@@ -1504,11 +1582,10 @@ class JaxScorerDetector(CoreDetector):
             if coalescer is not None:
                 # SpanRaws segments stay lazy inside the coalescer — no
                 # per-message bytes objects until alert construction
-                coalescer.add(tokens, raws, time.monotonic(),
-                              tenant=self._ingress_tenant)
+                self._coalesce_add(tokens, raws,
+                                   tenant=self._ingress_tenant)
             else:
                 self._dispatch(tokens, raws)
-            self._count_device_lines(n_ok)
         self._coalesce_pump()
         while self._inflight and self._head_ready():
             ready.extend(self._drain_one())
@@ -1649,79 +1726,94 @@ class JaxScorerDetector(CoreDetector):
                     # the backlog's size is whatever the fit's duration made
                     # it — bucketing it through the coalescer (released by
                     # the caller's pump) keeps it on warm compile shapes
-                    coalescer.add(tokens, raws, time.monotonic())
+                    self._coalesce_add(tokens, raws)
                 else:
                     self._dispatch(tokens, raws)
-                self._count_device_lines(len(raws))
+
+    def _route(self, n: int, coalesced: bool) -> tuple:
+        """Where ``n`` rows score and in which compile bucket →
+        ``(path, bucket)``.
+
+        Small batches (≤ ``host_score_max_batch``) ride the CPU twin, in
+        power-of-two host buckets that keep the padding compute proportional
+        to the batch (padding everything to the cap costs ~60 ms for 128
+        rows on a small CPU — measured, it broke the p50 target); those
+        compile in a background warm thread, and a batch whose bucket is not
+        warm yet rides the device path instead of stalling the engine loop
+        on a synchronous XLA compile. A coalesced release buckets against
+        the ACTIVE warm set (``_pick_device_bucket``) instead of the raw
+        power-of-two rule, so it rides a pre-warmed compile shape."""
+        cap = self.config.host_score_max_batch
+        # dmlint: ignore[DM-L001] ref-atomic mirror swap (see _score_host)
+        if 0 < n <= cap and self._host_params is not None:
+            bucket = _bucket(n, cap)
+            if bucket in self._host_warm:
+                return "host", bucket
+        if coalesced:
+            bucket = self._pick_device_bucket(n)
+            self._bucket_usage[bucket] = self._bucket_usage.get(bucket, 0) + 1
+            return "device", bucket
+        bucket = _bucket(n, self.config.max_batch)
+        if bucket not in self._device_warm:
+            # legacy (non-coalescer) path: a bucket outside the warm set —
+            # traffic whose natural batch size the setup warm-up never saw,
+            # e.g. a replica tier halving each scorer's burst — gets the
+            # same EXPECTED on-demand pre-warm the adaptive path does,
+            # instead of paging the first dispatch as an unexpected
+            # recompile
+            self._warm_device_bucket(bucket)
+        return "device", bucket
 
     def _dispatch(self, tokens: np.ndarray, msgs: List[Any],
                   t_enqueue: Optional[float] = None,
-                  release: Optional[str] = None) -> None:
+                  release: Optional[str] = None,
+                  route: Optional[tuple] = None,
+                  seq: Optional[int] = None,
+                  t_release: Optional[float] = None) -> None:
         """Asynchronously score [n, S] tokens, padded to a compile bucket.
 
-        Small batches (≤ ``host_score_max_batch``) score synchronously on the
-        CPU twin instead, skipping the upload and readback. The host result
+        Small batches score synchronously on the CPU twin instead
+        (``_route``), skipping the upload and readback. The host result
         enters the same in-flight queue (as a ready numpy array) so ordering
         with accelerator batches is preserved.
 
         A coalesced release (``release`` set) backdates ``t_enqueue`` to the
         oldest held row's arrival — queue-wait telemetry then includes the
-        coalescer hold — and buckets against the ACTIVE warm set
-        (``_pick_device_bucket``) instead of the raw power-of-two rule, so
-        every coalesced dispatch rides a pre-warmed compile shape."""
+        coalescer hold — and brings the ``route`` and the batch identifier
+        ``seq`` its ``dm.release`` span already carries (a further chunk, or
+        an uncoalesced dispatch, is allotted its own) and ``t_release``, the
+        pump's clock reading at which the release rule was seen met."""
         self._ensure_scorer()
         n = len(tokens)
         # retain real token rows on the slot only while a rollout sampler
         # is attached: the drain path offers rows PAIRED with their scores
         # (dmdrift reads the live score distribution off the reservoir)
         keep_tokens = self._rollout_sampler is not None
-        cap = self.config.host_score_max_batch
-        # dmlint: ignore[DM-L001] ref-atomic mirror swap (see _score_host)
-        if 0 < n <= cap and self._host_params is not None:
-            # power-of-two host buckets keep the padding compute proportional
-            # to the batch (padding everything to the cap costs ~60 ms for
-            # 128 rows on a small CPU — measured, it broke the p50 target);
-            # buckets compile in a background warm thread, and a batch whose
-            # bucket is not warm yet rides the device path instead of
-            # stalling the engine loop on a synchronous XLA compile
-            bucket = _bucket(n, cap)
-            if bucket in self._host_warm:
-                chunk = tokens
-                if n < bucket:
-                    chunk = np.concatenate(
-                        [tokens, np.zeros((bucket - n, tokens.shape[1]), np.int32)])
-                slot = _InflightSlot(list(msgs), n, bucket=bucket,
-                                     path="host",
-                                     trace_id=self._current_trace_id(),
-                                     release=release,
-                                     tokens=tokens if keep_tokens else None)
-                if t_enqueue is not None:
-                    slot.t_enqueue = t_enqueue
-                slot.t_start = time.monotonic()
-                # only warmed host buckets reach here, so a compile in this
-                # context IS an unexpected recompile (a warm-set bug)
-                with self._ledger.context(bucket=bucket, backend="cpu",
-                                          where="host", expected=False):
-                    slot.scores = np.asarray(self._score_host(chunk))[:n]
-                slot.done.set()
-                # synchronous path: scores are host-readable now — record
-                # the span/occupancy here, not at drain
-                self._observe_batch(slot, time.monotonic() - slot.t_start)
-                self._inflight.append(slot)
-                return
-        if release is not None:
-            bucket = self._pick_device_bucket(n)
-            self._bucket_usage[bucket] = self._bucket_usage.get(bucket, 0) + 1
-        else:
-            bucket = _bucket(n, self.config.max_batch)
-            if bucket not in self._device_warm:
-                # legacy (non-coalescer) path: a bucket outside the warm
-                # set — traffic whose natural batch size the setup warm-up
-                # never saw, e.g. a replica tier halving each scorer's
-                # burst — gets the same EXPECTED on-demand pre-warm the
-                # adaptive path does, instead of paging the first dispatch
-                # as an unexpected recompile
-                self._warm_device_bucket(bucket)
+        path, bucket = route or self._route(n, release is not None)
+        if path == "host":
+            chunk = tokens
+            if n < bucket:
+                chunk = np.concatenate(
+                    [tokens, np.zeros((bucket - n, tokens.shape[1]), np.int32)])
+            slot = _InflightSlot(list(msgs), n, bucket=bucket,
+                                 path="host",
+                                 trace_id=self._current_trace_id(),
+                                 release=release,
+                                 tokens=tokens if keep_tokens else None,
+                                 seq=seq or self._ledger.next_batch_seq(),
+                                 t_enqueue=t_enqueue, t_release=t_release)
+            slot.t_start = time.monotonic()
+            # only warmed host buckets reach here, so a compile in this
+            # context IS an unexpected recompile (a warm-set bug)
+            with self._ledger.context(bucket=bucket, backend="cpu",
+                                      where="host", expected=False):
+                slot.scores = np.asarray(self._score_host(chunk))[:n]
+            slot.done.set()
+            # synchronous path: scores are host-readable now — record
+            # the span/occupancy here, not at drain
+            self._observe_batch(slot, time.monotonic() - slot.t_start)
+            self._inflight.append(slot)
+            return
         use_workers = self.config.upload_workers > 0
         if use_workers:
             self._ensure_upload_workers()
@@ -1737,25 +1829,38 @@ class JaxScorerDetector(CoreDetector):
                                  trace_id=self._current_trace_id(),
                                  release=release,
                                  tokens=(tokens[start:start + real]
-                                         if keep_tokens else None))
-            if t_enqueue is not None:
-                slot.t_enqueue = t_enqueue
+                                         if keep_tokens else None),
+                                 seq=seq or self._ledger.next_batch_seq(),
+                                 t_enqueue=t_enqueue, t_release=t_release)
+            seq = None                     # a further chunk takes its own
+            # nothing unfinished on the device: the idle stretch since the
+            # last batch was seen readable ends with this call being issued
+            if self._dev_unfinished == 0:
+                slot.idle_start = self._idle_clock.busy_from(
+                    slot.t_release, self._release_at())
+            self._dev_unfinished += 1
             self._inflight.append(slot)
             if use_workers:
                 self._upload_queue.put((slot, chunk))
             else:
                 # inline: fill before returning; dispatch errors propagate
                 # to the caller exactly as before
-                slot.t_start = time.monotonic()
-                with self._ledger.context(bucket=bucket,
-                                          backend=self._obs_backend,
-                                          where="dispatch", expected=False):
-                    slot.scores = self._score_dev(chunk)
-                    try:
-                        slot.scores.copy_to_host_async()
-                    except AttributeError:
-                        pass
-                slot.done.set()
+                try:
+                    slot.scores = self._issue(slot, chunk)
+                finally:
+                    slot.done.set()
+
+    def _issue(self, slot: "_InflightSlot", chunk: np.ndarray):
+        """Upload, scoring call and the start of the readback for one
+        device batch (dispatch worker, or the engine thread inline)."""
+        slot.t_start = time.monotonic()  # queue wait ends here
+        try:
+            with self._ledger.context(bucket=slot.bucket,
+                                      backend=self._obs_backend,
+                                      where="dispatch", expected=False):
+                return self._score_dev(chunk, slot.span_kv())
+        finally:
+            slot.t_issued = time.monotonic()
 
     # -- adaptive continuous batching (the coalescer) --------------------
     def _get_coalescer(self) -> Optional["_BatchCoalescer"]:
@@ -1788,8 +1893,9 @@ class JaxScorerDetector(CoreDetector):
         if self.config.batch_deadline_ms <= 0:
             force = True  # disabled at runtime with rows still held
         now = time.monotonic()
+        self._idle_advance(now)
         largest = self._largest_active_bucket()
-        target = max(1, math.ceil(co.target_occupancy * largest))
+        target = self._release_target(largest)
         while len(co) >= target:
             self._release_coalesced(min(len(co), largest), "full", now)
         if force:
@@ -1803,10 +1909,52 @@ class JaxScorerDetector(CoreDetector):
         self._observe_coalesce_depth(len(co))
 
     def _release_coalesced(self, n: int, reason: str, now: float) -> None:
-        tokens, raws, t_oldest = self._coalescer.take(n)
-        self._coalescer.note_release(reason, now - t_oldest)
-        self._count_release(reason)
-        self._dispatch(tokens, raws, t_enqueue=t_oldest, release=reason)
+        """One coalesced release: the batch's identifier and route are fixed
+        first, so that ``dm.release`` — take, concatenate, pad, hand-off —
+        carries what every later span of the batch carries."""
+        self._ensure_scorer()
+        co = self._coalescer
+        seq = self._ledger.next_batch_seq()
+        route = self._route(n, coalesced=True)
+        with device_obs.span("dm.release", batch=seq, bucket=route[1],
+                             rows=n, release=reason):
+            self._idle_advance(now)      # before what is held changes
+            held_s = co.row_hold_s
+            tokens, raws, t_oldest = co.take(n, now)
+            co.note_release(reason, now - t_oldest)
+            self._count_release(reason)
+            self._row_hold_child.inc(co.row_hold_s - held_s)
+            self._rows_released_children[reason].inc(n)
+            self._dispatch(tokens, raws, t_enqueue=t_oldest, release=reason,
+                           route=route, seq=seq, t_release=now)
+
+    def _coalesce_add(self, tokens: np.ndarray, raws,
+                      tenant: Optional[str] = None) -> None:
+        now = time.monotonic()
+        self._idle_advance(now)          # before what is held changes
+        self._coalescer.add(tokens, raws, now, tenant=tenant)
+
+    def _release_at(self) -> Optional[float]:
+        """When the rows the coalescer holds met, or will meet, its release
+        rule (``DeviceIdleClock.advance``): None with nothing held, ``-inf``
+        once the target is reached or coalescing was switched off with rows
+        held, else the oldest row's due time."""
+        co = self._coalescer
+        if co is None or not len(co):
+            return None
+        if (len(co) >= self._release_target(self._largest_active_bucket())
+                or self.config.batch_deadline_ms <= 0):
+            return -math.inf
+        return co.due_at()
+
+    def _release_target(self, largest: int) -> int:
+        """Held rows at which a ``full`` release goes."""
+        return max(1, math.ceil(self._coalescer.target_occupancy * largest))
+
+    def _idle_advance(self, now: float) -> None:
+        clock = self._idle_clock
+        if clock is not None and clock.idle:
+            clock.advance(now, self._release_at())
 
     def _active_buckets(self) -> List[int]:
         """The warm set minus retirements, sorted ascending."""
@@ -1919,6 +2067,8 @@ class JaxScorerDetector(CoreDetector):
             "max_wait_s": 0.0 if co is None else round(co.max_wait_s, 6),
             "mean_wait_s": (round(co.wait_sum_s / co.wait_n, 6)
                             if co is not None and co.wait_n else 0.0),
+            "mean_row_hold_s": (round(co.row_hold_s / co.rows_out, 6)
+                                if co is not None and co.rows_out else 0.0),
             "buckets_retired_total": 0 if co is None else co.retired_total,
             "held_by_tenant": {} if co is None else co.held_by_tenant(),
             "dispatches": occ_n,
@@ -1977,17 +2127,8 @@ class JaxScorerDetector(CoreDetector):
             if self._dispatch_hb is not None:
                 self._dispatch_hb.beat()
             slot, chunk = item
-            slot.t_start = time.monotonic()  # queue wait ends here
             try:
-                with self._ledger.context(bucket=slot.bucket,
-                                          backend=self._obs_backend,
-                                          where="dispatch", expected=False):
-                    scores = self._score_dev(chunk)
-                    try:
-                        scores.copy_to_host_async()
-                    except AttributeError:
-                        pass
-                slot.scores = scores
+                slot.scores = self._issue(slot, chunk)
             except Exception as exc:  # noqa: BLE001 — containment boundary
                 slot.error = exc
             finally:
@@ -2010,39 +2151,67 @@ class JaxScorerDetector(CoreDetector):
         slot = self._inflight.popleft()
         slot.done.wait()
         self._drained_total += 1
+        on_device = slot.path != "host"
+        kv = slot.span_kv()
         if slot.error is not None:
             # worker-path dispatch failure: same containment rule as the
             # engine's per-message processing — count EVERY lost message
             # (error-rate dashboards must see the real magnitude), emit
             # nothing, live on
+            self._device_batch_done(slot, time.monotonic())
             self.count_processing_errors(
                 slot.real, f"batch dispatch failed: {slot.error}")
             return []
         raws, real = slot.raws, slot.real
-        scores = np.asarray(slot.scores)[:real]
+        if on_device:
+            with device_obs.span("dm.readback", **kv):
+                scores = np.asarray(slot.scores)[:real]
+            self._device_batch_done(slot, time.monotonic())
+        else:
+            scores = np.asarray(slot.scores)[:real]
         if self._rollout_sampler is not None and slot.tokens is not None:
             # drain-time tap (dmdrift): rows enter the reservoir PAIRED
             # with the scores this batch produced — the drift monitor's
             # live distribution is exactly what the dispatch path scored
             self._rollout_sampler.offer_rows(slot.tokens[:real], scores)
-        if slot.path != "host":
+        entry = None
+        if on_device:
             # np.asarray above forced the readback: scoring-call start →
             # now is the batch's device compute + readback time (the host
             # path recorded its synchronous span at dispatch)
             start = slot.t_start if slot.t_start is not None else slot.t_enqueue
-            self._observe_batch(slot, time.monotonic() - start)
+            entry = self._observe_batch(slot, time.monotonic() - start)
         threshold = self._threshold if self._threshold is not None else float("inf")
         out: List[Optional[bytes]] = []
-        hits = np.flatnonzero(scores > threshold)
-        if hits.size == 0:
-            return out
-        from ...schemas import schemas_pb2 as _pb
+        with device_obs.span("dm.alert_build", **kv):
+            hits = np.flatnonzero(scores > threshold)
+            if hits.size:
+                from ...schemas import schemas_pb2 as _pb
 
-        for i in hits:  # touch only the anomalous rows (~1% of the batch)
-            msg = _pb.ParserSchema()
-            msg.ParseFromString(raws[i])
-            out.append(self._make_alert_pb(msg, float(scores[i])))
+                for i in hits:  # touch only the anomalous rows (~1%)
+                    msg = _pb.ParserSchema()
+                    msg.ParseFromString(raws[i])
+                    out.append(self._make_alert_pb(msg, float(scores[i])))
+        if entry is not None:
+            # the engine sends what this call returns: the ring entry's
+            # last stamp
+            self._ledger.note_sent(entry, time.monotonic() - slot.t_release)
         return out
+
+    def _device_batch_done(self, slot: "_InflightSlot", now: float) -> None:
+        """A device batch was seen readable (or failed) at ``now``: close
+        its share of the idle account, and start the idle clock when
+        nothing else is unfinished on the device."""
+        if slot.path == "host":
+            return
+        self._dev_unfinished = max(0, self._dev_unfinished - 1)
+        clock = self._idle_clock
+        if clock is None:
+            return
+        if slot.idle_start and slot.t_issued is not None:
+            clock.issued(slot.t_issued - slot.t_release)
+        if self._dev_unfinished == 0:
+            clock.idle_from(now)
 
     def flush(self) -> List[Optional[bytes]]:
         """Idle-time drain (engine calls on every input lull): NON-blocking —
@@ -2134,27 +2303,33 @@ class JaxScorerDetector(CoreDetector):
             output_["alertsObtain"].update(
                 {f"{self.name} - score": f"anomaly score {score:.4f} > {self._threshold:.4f}"}
             )
-            self._count_device_lines(1)
             return True
-        self._count_device_lines(1)
         return False
-
-    def _count_device_lines(self, n: int) -> None:
-        from ...engine import metrics as m
-
-        if self._metrics_labels is None:
-            self._metrics_labels = dict(
-                component_type=self.config.method_type,
-                component_id=self.name,
-                device=str(self._device),
-            )
-        m.DEVICE_LINES().labels(**self._metrics_labels).inc(n)
-        m.DEVICE_BATCHES().labels(**self._metrics_labels).inc()
 
     # -- device observability (engine/device_obs.py) ---------------------
     def _obs_labels(self) -> Dict[str, str]:
         return dict(component_type=self.config.method_type,
                     component_id=self.name)
+
+    def _bind_boundary_counters(self) -> None:
+        """Scorer set-up, once the device is resolved: arm the ``dm.*``
+        spans and create every child of the counters that tick where rows
+        cross a boundary, so that each series is exported as 0 from boot (a
+        reader that finds a series absent drops its metric)."""
+        from ...engine import metrics as m
+
+        labels = self._obs_labels()
+        device_obs.arm_spans(labels)
+        self._row_hold_child = m.ROW_HOLD_SECONDS().labels(**labels)
+        for reason in ("full", "deadline", "flush"):
+            self._rows_released_children[reason] = m.ROWS_RELEASED().labels(
+                reason=reason, **labels)
+        self._device_children = (
+            m.DEVICE_LINES().labels(device=str(self._device), **labels),
+            m.DEVICE_BATCHES().labels(device=str(self._device), **labels))
+        self._idle_clock = device_obs.DeviceIdleClock({
+            cause: m.DEVICE_IDLE_SECONDS().labels(cause=cause, **labels)
+            for cause in device_obs.DeviceIdleClock.CAUSES})
 
     def _current_trace_id(self) -> Optional[str]:
         """Flight recorder's last completed trace id (the PR-1 link a
@@ -2166,17 +2341,25 @@ class JaxScorerDetector(CoreDetector):
                 if recorder is not None else None)
 
     def _observe_batch(self, slot: "_InflightSlot",
-                       device_s: float) -> None:
+                       device_s: float) -> Optional[Dict[str, Any]]:
         """Per-dispatch batch telemetry, recorded when a batch's scores
         become host-readable: occupancy (real/bucket — 1 minus padding
-        waste), bucket selection, and the queue-wait vs device-time split,
-        attributed to the host or device path; plus a span in the compile
-        ledger carrying the dispatch-time trace id."""
+        waste), bucket selection, the queue-wait vs device-time split,
+        attributed to the host or device path, and — device path only — the
+        rows and the batch the chip scored
+        (``detector_device_lines_total`` / ``_batches_total``); plus a span
+        in the compile ledger carrying the dispatch-time trace id and the
+        batch's stamps. Returns the ledger's ring entry."""
         from ...engine import metrics as m
 
         bucket, path = slot.bucket, slot.path
         if bucket <= 0:
-            return
+            return None
+        now = time.monotonic()
+        if path == "device" and self._device_children is not None:
+            lines_c, batches_c = self._device_children
+            lines_c.inc(slot.real)
+            batches_c.inc()
         t_start = slot.t_start if slot.t_start is not None else slot.t_enqueue
         queue_wait_s = max(0.0, t_start - slot.t_enqueue)
         children = self._batch_obs.get(path)
@@ -2206,15 +2389,20 @@ class JaxScorerDetector(CoreDetector):
                 bucket=str(bucket), path=path, **self._obs_labels())
             self._bucket_children[(bucket, path)] = bucket_child
         bucket_child.inc()
+        entry = None
         if self._ledger is not None:
-            self._ledger.record_span(bucket, slot.real, path, queue_wait_s,
-                                     max(0.0, device_s), slot.trace_id,
-                                     release=slot.release)
+            entry = self._ledger.record_span(
+                bucket, slot.real, path, queue_wait_s, max(0.0, device_s),
+                slot.trace_id, release=slot.release, seq=slot.seq or None,
+                stamps={"oldest_arrival": slot.t_enqueue,
+                        "release": slot.t_release, "pickup": slot.t_start,
+                        "call_issued": slot.t_issued, "readable": now})
         tap = self._capacity_tap
         if tap is not None:
             # dmdrift capacity arithmetic: real rows + the device-time this
             # batch cost, from the one site every scored batch reports to
             tap(slot.real, max(0.0, device_s))
+        return entry
 
     # -- model rollout (rollout/manager.py seams) ------------------------
     def set_rollout_sampler(self, sampler) -> None:

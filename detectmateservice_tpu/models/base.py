@@ -235,7 +235,13 @@ class SequenceScorerBase(ScorerBase):
         if n_cand >= v:
             return self._token_nlls_exact(params, tokens, dtype)
         hidden = self.model.apply(params, tokens, method="hidden").astype(dtype)
-        emb = emb.astype(dtype)
+        with jax.named_scope("head/nll"):
+            return self._candidate_head(hidden, emb.astype(dtype), tokens,
+                                        dtype, n_cand)
+
+    def _candidate_head(self, hidden: jax.Array, emb: jax.Array,
+                        tokens: jax.Array, dtype, n_cand: int) -> jax.Array:
+        v = emb.shape[0]
         emb_c = emb[self._candidate_ids(v, n_cand)]     # [C, D]
         correction = jnp.log(float(v) / n_cand)
         # exact target logit: direct dot against the gathered target rows
@@ -265,9 +271,12 @@ class SequenceScorerBase(ScorerBase):
             h = hidden.reshape(b, n_chunks, sc, d).transpose(1, 0, 2, 3)
 
             def step(carry, h_c):
-                logits_c = jnp.einsum("bsd,cd->bsc", h_c, emb_c,
-                                      preferred_element_type=dtype)
-                return carry, self._lse_low_precision(logits_c, dtype)
+                # the body's own locations start a new name stack (XLA's
+                # op_name joins it to the call site's)
+                with jax.named_scope("head/nll"):
+                    logits_c = jnp.einsum("bsd,cd->bsc", h_c, emb_c,
+                                          preferred_element_type=dtype)
+                    return carry, self._lse_low_precision(logits_c, dtype)
 
             _, lse = jax.lax.scan(step, None, h)        # [n_chunks, B, Sc]
             lse = lse.transpose(1, 0, 2).reshape(b, s) + correction
@@ -285,6 +294,11 @@ class SequenceScorerBase(ScorerBase):
         dot."""
         hidden = self.model.apply(params, tokens, method="hidden").astype(dtype)
         emb = params["params"]["tok_embed"]["embedding"].astype(dtype)
+        with jax.named_scope("head/nll"):
+            return self._exact_head(hidden, emb, tokens)
+
+    def _exact_head(self, hidden: jax.Array, emb: jax.Array,
+                    tokens: jax.Array) -> jax.Array:
         b, s, d = hidden.shape
         v = emb.shape[0]
         if getattr(self.config, "head_impl", "auto") == "pallas":
@@ -307,11 +321,15 @@ class SequenceScorerBase(ScorerBase):
 
         def step(carry, ht):
             h_c, t_c = ht
-            logits = jnp.einsum("bsd,vd->bsv", h_c, emb,
-                                preferred_element_type=jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            tgt = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
-            return carry, tgt - lse  # [B, Sc] log-probs
+            # the body's own locations start a new name stack (XLA's
+            # op_name joins it to the call site's)
+            with jax.named_scope("head/nll"):
+                logits = jnp.einsum("bsd,vd->bsv", h_c, emb,
+                                    preferred_element_type=jnp.float32)
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                tgt = jnp.take_along_axis(logits, t_c[..., None],
+                                          axis=-1)[..., 0]
+                return carry, tgt - lse  # [B, Sc] log-probs
 
         _, lp = jax.lax.scan(step, None, (h, t))
         lp = lp.transpose(1, 0, 2).reshape(b, s)

@@ -80,14 +80,16 @@ class GRULM(nn.Module):
         alignment contract positional_z_max and the calibration pass assume.
         Exposed separately for the chunked NLL path (models/base.py)."""
         cfg = self.config
-        emb = self.tok_embed(tokens)             # [B, S, D]
-        # teacher-forced shift-right: the input at step t is token t-1
-        x = jnp.concatenate(
-            [jnp.broadcast_to(self.bos_embed.astype(cfg.dtype),
-                              (tokens.shape[0], 1, cfg.dim)),
-             emb[:, :-1]], axis=1)
-        for rnn in self.rnns:
-            x = rnn(x)                           # lax.scan over time
+        with jax.named_scope("embed"):
+            emb = self.tok_embed(tokens)             # [B, S, D]
+            # teacher-forced shift-right: the input at step t is token t-1
+            x = jnp.concatenate(
+                [jnp.broadcast_to(self.bos_embed.astype(cfg.dtype),
+                                  (tokens.shape[0], 1, cfg.dim)),
+                 emb[:, :-1]], axis=1)
+        for i, rnn in enumerate(self.rnns):
+            with jax.named_scope(f"layer{i}/rnn"):
+                x = rnn(x)                           # lax.scan over time
         return self.final_ln(x).astype(jnp.float32)
 
     def __call__(self, tokens: jax.Array) -> jax.Array:

@@ -62,26 +62,32 @@ class LogBERTConfig:
 
 class Block(nn.Module):
     config: LogBERTConfig
+    # position in the stack: names the device scopes (``layer<i>/attn``,
+    # ``layer<i>/ffn``) a profiler capture groups operations by; metadata
+    # only — the parameter tree is named by the parent's attribute
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array, pad_mask: jax.Array) -> jax.Array:
         cfg = self.config
         head_dim = cfg.dim // cfg.heads
-        y = nn.LayerNorm(dtype=cfg.dtype)(x)
-        qkv = nn.Dense(3 * cfg.dim, dtype=cfg.dtype, name="qkv")(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        b, s, _ = q.shape
-        reshape = lambda t: t.reshape(b, s, cfg.heads, head_dim).transpose(0, 2, 1, 3)
-        out = attention(reshape(q), reshape(k), reshape(v),
-                        key_mask=pad_mask, impl=cfg.attn_impl,
-                        platform=cfg.platform or None)
-        out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.dim)
-        x = x + nn.Dense(cfg.dim, dtype=cfg.dtype, name="proj")(out)
-        y = nn.LayerNorm(dtype=cfg.dtype)(x)
-        y = nn.Dense(cfg.dim * cfg.mlp_ratio, dtype=cfg.dtype, name="mlp_in")(y)
-        y = nn.gelu(y)
-        y = nn.Dense(cfg.dim, dtype=cfg.dtype, name="mlp_out")(y)
-        return x + y
+        with jax.named_scope(f"layer{self.layer}/attn"):
+            y = nn.LayerNorm(dtype=cfg.dtype)(x)
+            qkv = nn.Dense(3 * cfg.dim, dtype=cfg.dtype, name="qkv")(y)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            b, s, _ = q.shape
+            reshape = lambda t: t.reshape(b, s, cfg.heads, head_dim).transpose(0, 2, 1, 3)
+            out = attention(reshape(q), reshape(k), reshape(v),
+                            key_mask=pad_mask, impl=cfg.attn_impl,
+                            platform=cfg.platform or None)
+            out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.dim)
+            x = x + nn.Dense(cfg.dim, dtype=cfg.dtype, name="proj")(out)
+        with jax.named_scope(f"layer{self.layer}/ffn"):
+            y = nn.LayerNorm(dtype=cfg.dtype)(x)
+            y = nn.Dense(cfg.dim * cfg.mlp_ratio, dtype=cfg.dtype, name="mlp_in")(y)
+            y = nn.gelu(y)
+            y = nn.Dense(cfg.dim, dtype=cfg.dtype, name="mlp_out")(y)
+            return x + y
 
 
 class LogBERT(nn.Module):
@@ -93,7 +99,7 @@ class LogBERT(nn.Module):
         self.pos_embed = self.param(
             "pos_embed", nn.initializers.normal(0.02), (cfg.seq_len, cfg.dim)
         )
-        self.blocks = [Block(cfg) for _ in range(cfg.depth)]
+        self.blocks = [Block(cfg, layer=i) for i in range(cfg.depth)]
         self.final_ln = nn.LayerNorm(dtype=cfg.dtype)
 
     def hidden(self, tokens: jax.Array) -> jax.Array:
@@ -105,8 +111,9 @@ class LogBERT(nn.Module):
         tensor alone exceeds HBM (models/base.py chunked NLL)."""
         cfg = self.config
         pad_mask = tokens != PAD_ID
-        x = self.tok_embed(tokens) + self.pos_embed[
-            None, : tokens.shape[1]].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = self.tok_embed(tokens) + self.pos_embed[
+                None, : tokens.shape[1]].astype(cfg.dtype)
         for blk in self.blocks:
             x = blk(x, pad_mask)
         return self.final_ln(x).astype(jnp.float32)

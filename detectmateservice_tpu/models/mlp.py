@@ -57,14 +57,18 @@ class EmbedMLPModel(nn.Module):
         ``apply(..., method="hidden")`` so the pallas head can compute the
         logsumexp without materializing the [B, V] logits."""
         cfg = self.config
-        emb = self.tok_embed(tokens)
-        mask = (tokens != PAD_ID).astype(cfg.dtype)[..., None]
-        pooled = (emb * mask).sum(1) / jnp.maximum(mask.sum(1), 1.0)
-        return self.fc2(nn.gelu(self.fc1(pooled)))
+        with jax.named_scope("embed"):
+            emb = self.tok_embed(tokens)
+            mask = (tokens != PAD_ID).astype(cfg.dtype)[..., None]
+            pooled = (emb * mask).sum(1) / jnp.maximum(mask.sum(1), 1.0)
+        with jax.named_scope("layer0/ffn"):
+            return self.fc2(nn.gelu(self.fc1(pooled)))
 
     def __call__(self, tokens: jax.Array) -> jax.Array:
         """[B, S] int32 → [B, V] fp32 logits (context token distribution)."""
-        return self.tok_embed.attend(self.hidden(tokens).astype(jnp.float32))
+        hidden = self.hidden(tokens).astype(jnp.float32)
+        with jax.named_scope("head/nll"):
+            return self.tok_embed.attend(hidden)
 
 
 def _masked_mean_nll(tok_lp: jax.Array, tokens: jax.Array) -> jax.Array:
@@ -78,9 +82,10 @@ def _masked_mean_nll(tok_lp: jax.Array, tokens: jax.Array) -> jax.Array:
 def bag_nll(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     """Mean NLL of each sequence's non-PAD tokens under its single context
     distribution → [B] fp32."""
-    logprobs = jax.nn.log_softmax(logits, axis=-1)           # [B, V]
-    tok_lp = jnp.take_along_axis(logprobs, tokens, axis=-1)  # [B, S]
-    return _masked_mean_nll(tok_lp, tokens)
+    with jax.named_scope("head/nll"):
+        logprobs = jax.nn.log_softmax(logits, axis=-1)           # [B, V]
+        tok_lp = jnp.take_along_axis(logprobs, tokens, axis=-1)  # [B, S]
+        return _masked_mean_nll(tok_lp, tokens)
 
 
 class MLPScorer(ScorerBase):
@@ -103,11 +108,12 @@ class MLPScorer(ScorerBase):
         the sequence heads."""
         dtype = self.config.dtype
         h = self.model.apply(params, tokens, method="hidden").astype(dtype)
-        emb = params["params"]["tok_embed"]["embedding"].astype(dtype)
-        lse = self._pallas_lse_rows(h, emb)                     # [B]
-        tgt = jnp.einsum("bsd,bd->bs", emb[tokens], h,
-                         preferred_element_type=jnp.float32)
-        return tgt - lse[:, None]
+        with jax.named_scope("head/nll"):
+            emb = params["params"]["tok_embed"]["embedding"].astype(dtype)
+            lse = self._pallas_lse_rows(h, emb)                     # [B]
+            tgt = jnp.einsum("bsd,bd->bs", emb[tokens], h,
+                             preferred_element_type=jnp.float32)
+            return tgt - lse[:, None]
 
     def _use_pallas_head(self) -> bool:
         return getattr(self.config, "head_impl", "auto") == "pallas"
@@ -127,9 +133,10 @@ class MLPScorer(ScorerBase):
         if self._use_pallas_head():
             tok_lp = self._pallas_token_logprobs(params, tokens)
         else:
-            logprobs = jax.nn.log_softmax(
-                self.model.apply(params, tokens), axis=-1)
-            tok_lp = jnp.take_along_axis(logprobs, tokens, axis=-1)  # [B, S]
+            logits = self.model.apply(params, tokens)
+            with jax.named_scope("head/nll"):
+                logprobs = jax.nn.log_softmax(logits, axis=-1)
+                tok_lp = jnp.take_along_axis(logprobs, tokens, axis=-1)  # [B, S]
         return -tok_lp * (tokens != PAD_ID).astype(jnp.float32)
 
     def _normscore_impl(self, params, tokens: jax.Array,

@@ -67,6 +67,11 @@ def attention(
     if impl == "auto":
         impl = ("flash" if platform == "tpu" and t >= FLASH_MIN_SEQ
                 else "einsum")
+    with jax.named_scope(f"attn_{impl}"):
+        return _attention(q, k, v, key_mask, impl, platform)
+
+
+def _attention(q, k, v, key_mask, impl: str, platform: str) -> jax.Array:
     if impl == "ring":
         ctx = _RING_CTX.get()
         if ctx is None:

@@ -84,6 +84,12 @@ def candidate_lse(hidden: jax.Array, emb_c: jax.Array,
     bias (the flash-kernel pattern), so arbitrary vocab/candidate sizes
     keep full-width blocks instead of degrading to divisor-sized ones.
     """
+    with jax.named_scope("lse_pallas"):
+        return _candidate_lse(hidden, emb_c, block_n, block_c, interpret)
+
+
+def _candidate_lse(hidden: jax.Array, emb_c: jax.Array, block_n: int,
+                   block_c: int, interpret: bool) -> jax.Array:
     n, d = hidden.shape
     c = emb_c.shape[0]
     block_n = min(block_n, max(n, 8))

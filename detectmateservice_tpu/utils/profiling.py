@@ -202,7 +202,17 @@ class ProfileManager:
         import jax
 
         try:
-            jax.profiler.start_trace(info["dir"])
+            # the Python call tracer (level 1 by default) hooks every call
+            # on every thread for the length of the capture and stalls the
+            # engine thread while it starts; the host plane still takes the
+            # dm.* TraceAnnotation events (host_tracer_level stays as it is)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            t0 = time.monotonic()
+            jax.profiler.start_trace(info["dir"], profiler_options=options)
+            # how long the profiler took to start: the stall a capture
+            # costs the threads that wait on the interpreter meanwhile
+            info["start_trace_s"] = round(time.monotonic() - t0, 6)
             time.sleep(info["seconds"])
             jax.profiler.stop_trace()
             info["state"] = "done"

@@ -40,8 +40,9 @@ HTTP_PORT = 18941
 
 
 def scrape_processed(port: int):
-    """Messages scored on the device path so far; None while the metrics
-    endpoint is unreachable (the readiness gate needs that distinction).
+    """Messages scored on the device path so far (counted when a batch's
+    scores are host-readable); None while the metrics endpoint is
+    unreachable (the readiness gate needs that distinction).
     Uses the per-device counter, NOT data_processed_lines_total: the latter
     counts 0x0A bytes in the raw payload (reference line-counting semantics)
     and protobuf framing contains plenty of those, so it overcounts ~4x."""
@@ -132,9 +133,12 @@ def main() -> None:
     else:
         shard_addrs = [settings["engine_addr"]]
     # the canonical headline-bench scorer config (ONE home: bench.py), plus
-    # this script's single knob
+    # this script's single knob. The host twin is off: the progress counter
+    # (scrape_processed) counts rows the DEVICE path scored, and a probe
+    # message or a burst's remainder that rode the twin would never reach it
     config = {"detectors": {"JaxScorerDetector": dict(
-        B.BENCH_SCORER_CONFIG, upload_workers=args.upload_workers)}}
+        B.BENCH_SCORER_CONFIG, upload_workers=args.upload_workers,
+        host_score_max_batch=0)}}
     import yaml
 
     with open(f"{work}/settings.yaml", "w") as f:

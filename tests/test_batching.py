@@ -145,6 +145,138 @@ class TestCoalescerMechanics:
         assert co.wait_sum_s == pytest.approx(0.09)
 
 
+class TestRowWeightedHold:
+    """``detector_row_hold_seconds_total`` / ``detector_rows_released_total``:
+    the hold of the MEAN row, where ``detector_queue_wait_seconds`` observes
+    the oldest row's."""
+
+    def _rows(self, ids):
+        tokens = np.asarray(ids, np.int32).reshape(-1, 1)
+        return tokens, [str(i).encode() for i in ids]
+
+    def test_scripted_sequence_sums_rows_times_wait(self):
+        co = _BatchCoalescer(deadline_s=10.0, target_occupancy=0.9)
+        co.add(*self._rows([1, 2, 3]), now=10.0)
+        co.add(*self._rows([4, 5]), now=11.0)
+        co.add(*self._rows([6, 7, 8, 9]), now=12.5)
+        # release at 13.0: three rows held 3 s, two 2 s, one of four 0.5 s
+        co.take(6, now=13.0)
+        assert co.row_hold_s == 3 * 3.0 + 2 * 2.0 + 1 * 0.5
+        assert co.rows_out == 6
+        # the remainder keeps ITS stamp (12.5) across the second release
+        co.add(*self._rows([10]), now=14.0)
+        co.take(4, now=15.0)
+        assert co.row_hold_s == 13.5 + 3 * 2.5 + 1 * 1.0
+        assert co.rows_out == 10 and len(co) == 0
+        # the oldest row's wait, by comparison, was 3.0 and 2.5
+
+    def test_take_without_a_clock_counts_nothing(self):
+        co = _BatchCoalescer(deadline_s=1.0, target_occupancy=0.9)
+        co.add(*self._rows([1, 2]), now=1.0)
+        co.take(2)
+        assert co.row_hold_s == 0.0 and co.rows_out == 0
+
+    def test_fair_share_release_weights_each_tenant_by_its_own_stamp(self):
+        co = _BatchCoalescer(deadline_s=1.0, target_occupancy=0.9)
+        co.add(*self._rows([1, 2, 3, 4]), now=0.0, tenant="a")
+        co.add(*self._rows([5, 6, 7, 8]), now=1.0, tenant="b")
+        co.take(4, now=2.0)              # two rows of each tenant
+        assert co.row_hold_s == 2 * 2.0 + 2 * 1.0
+
+    def test_counters_tick_at_release_by_reason(self):
+        from prometheus_client import REGISTRY
+
+        det = coalescing_detector(batch_deadline_ms=10_000.0)
+        labels = det._obs_labels()
+
+        def sample(name, **extra):
+            return REGISTRY.get_sample_value(name, dict(labels, **extra)) or 0.0
+
+        hold0 = sample("detector_row_hold_seconds_total")
+        full0 = sample("detector_rows_released_total", reason="full")
+        flush0 = sample("detector_rows_released_total", reason="flush")
+        co0 = det._get_coalescer().row_hold_s
+        det.process_batch([msg(i) for i in range(10)])
+        time.sleep(0.03)
+        det.process_batch([msg(i) for i in range(10, 30)])   # 30 >= 29: full
+        assert sample("detector_rows_released_total",
+                      reason="full") == full0 + 30
+        held = sample("detector_row_hold_seconds_total") - hold0
+        assert held == pytest.approx(det._coalescer.row_hold_s - co0)
+        assert held >= 10 * 0.03 - 1e-3          # ten rows waited >= 30 ms
+        det.process_batch([msg(99)])
+        det.flush()
+        assert sample("detector_rows_released_total",
+                      reason="flush") == flush0 + 1
+        assert det.batching_stats()["mean_row_hold_s"] > 0.0
+
+
+class TestScoredRowsCounter:
+    """``detector_device_lines_total`` / ``_batches_total`` count rows and
+    batches the DEVICE path scored, when their scores are host-readable —
+    not arrivals at the coalescer, and not the host twin's rows."""
+
+    def _sample(self, det, name):
+        from prometheus_client import REGISTRY
+
+        labels = dict(det._obs_labels(), device=str(det._device))
+        return REGISTRY.get_sample_value(name, labels) or 0.0
+
+    def test_ticks_at_drain_not_on_arrival(self):
+        det = coalescing_detector(batch_deadline_ms=10_000.0)
+        lines0 = self._sample(det, "detector_device_lines_total")
+        batches0 = self._sample(det, "detector_device_batches_total")
+        det.process_batch([msg(i) for i in range(20)])
+        # held by the coalescer: arrived, not scored
+        assert self._sample(det, "detector_device_lines_total") == lines0
+        outs = det.flush()                   # release + drain
+        assert len(alert_log_ids(outs)) == 20
+        assert self._sample(det, "detector_device_lines_total") == lines0 + 20
+        assert self._sample(
+            det, "detector_device_batches_total") == batches0 + 1
+
+    def test_host_twin_rows_do_not_count(self):
+        det = coalescing_detector(host_score_max_batch=8,
+                                  batch_deadline_ms=0.0)
+        det.flush_final()                    # the twin's buckets are warm
+        assert det._host_twin_state == "ready"
+        lines0 = self._sample(det, "detector_device_lines_total")
+        det.process_batch([msg(i) for i in range(4)])       # host twin
+        det.flush()
+        assert self._sample(det, "detector_device_lines_total") == lines0
+        det.process_batch([msg(i) for i in range(16)])      # device
+        det.flush()
+        assert self._sample(det, "detector_device_lines_total") == lines0 + 16
+        paths = [span["path"] for span in
+                 device_obs.get_ledger().snapshot()["batches"][-2:]]
+        assert paths == ["host", "device"]
+
+
+class TestIdleAccountOnTheDispatchPath:
+    def test_idle_time_between_two_batches_is_fill_then_host(self):
+        """Device idle from the first batch's drain to the second call
+        issued: the coalescer held rows short of its target (fill) until
+        the target was reached, the rest is the release itself (host)."""
+        det = coalescing_detector(batch_deadline_ms=10_000.0)
+        det.process_batch([msg(i) for i in range(32)])       # full release
+        t0 = time.monotonic()
+        det.flush()                                          # seen readable
+        clock = det._idle_clock
+        assert clock.idle
+        base = dict(clock.seconds)
+        det.process_batch([msg(i) for i in range(5)])        # held: fill
+        time.sleep(0.05)
+        det.process_batch([msg(i) for i in range(5, 32)])    # target reached
+        assert not clock.idle                                # call issued
+        det.flush()
+        wall = time.monotonic() - t0
+        grown = {c: clock.seconds[c] - base[c] for c in clock.CAUSES}
+        assert grown["fill"] >= 0.05 - 2e-3
+        assert grown["host"] > 0.0                  # release → call issued
+        # no_rows: from the drain to the first rows nothing was held
+        assert sum(grown.values()) <= wall
+
+
 # ---------------------------------------------------------------------------
 # detector-level coalescing (CPU scorer; the acceptance behaviors)
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ and a ledger entry on /admin/xla.
 """
 import io
 import json
+import time
 import urllib.error
 import urllib.request
 import zipfile
@@ -287,6 +288,311 @@ class TestBatchTelemetry:
         assert span["occupancy"] == pytest.approx(9 / 16)
         assert span["trace_id"] == "abcd" * 4
         assert span["path"] == "device"
+
+
+# ---------------------------------------------------------------------------
+# spans, the ring's stamps and the idle account (no jax needed)
+# ---------------------------------------------------------------------------
+class TestSpanRing:
+    def test_ring_keeps_4096_spans_with_offsets_in_order(self):
+        ledger = CompileLedger()
+        for i in range(device_obs.MAX_SPANS + 10):
+            t = 100.0 + i
+            seq = ledger.next_batch_seq()
+            entry = ledger.record_span(
+                8, 5, "device", 0.0, 0.01, release="full", seq=seq,
+                stamps={"oldest_arrival": t - 0.4, "release": t,
+                        "pickup": t + 0.001, "call_issued": t + 0.003,
+                        "readable": t + 0.6})
+            ledger.note_sent(entry, 0.62)
+        spans = ledger.snapshot()["batches"]
+        assert len(spans) == device_obs.MAX_SPANS == 4096
+        assert [s["seq"] for s in spans] == list(range(11, 4096 + 11))
+        for span in (spans[0], spans[-1]):
+            offsets = [span["offsets_s"][name]
+                       for name in device_obs.SPAN_STAMPS]
+            assert offsets == sorted(offsets)
+            assert offsets == pytest.approx(
+                [-0.4, 0.0, 0.001, 0.003, 0.6, 0.62])
+
+    def test_a_span_without_stamps_still_files_under_its_own_seq(self):
+        ledger = CompileLedger()
+        ledger.record_span(8, 5, "host", 0.0, 0.01)
+        held = ledger.next_batch_seq()           # allotted at release
+        ledger.record_span(8, 8, "host", 0.0, 0.01)
+        ledger.record_span(16, 9, "device", 0.0, 0.02, seq=held)
+        spans = ledger.snapshot()["batches"]
+        assert [s["seq"] for s in spans] == [1, 3, held]
+        assert spans[0]["offsets_s"] == {}
+
+    def test_limit_zero_serves_no_ring_entries(self):
+        ledger = CompileLedger()
+        ledger.record_span(8, 5, "device", 0.0, 0.01)
+        snap = ledger.snapshot(limit=0)
+        assert snap["batches"] == [] and snap["compiles"] == []
+        assert len(ledger.snapshot(limit=1)["batches"]) == 1
+
+    def test_span_is_a_shared_noop_where_nothing_is_armed(self, monkeypatch):
+        monkeypatch.setattr(device_obs, "_ANNOTATION", None)
+        monkeypatch.setattr(device_obs, "_PHASE_CHILDREN", {})
+        assert device_obs.span("dm.send", results=3) is device_obs.NULL_SPAN
+        with device_obs.span("dm.recv_wait"):
+            pass
+
+    def test_phase_spans_feed_their_counters(self, monkeypatch):
+        labels = {"component_type": "test_obs", "component_id": "phase-1"}
+        monkeypatch.setattr(device_obs, "_PHASE_CHILDREN", {})
+        device_obs.arm_spans(labels)
+
+        def sample(name, phase):
+            return REGISTRY.get_sample_value(
+                name, dict(labels, phase=phase))
+
+        for phase in device_obs.PHASE_SPANS.values():
+            assert sample("detector_phase_total", phase) is not None
+        count0 = sample("detector_phase_total", "upload")
+        seconds0 = sample("detector_phase_seconds_total", "upload")
+        with device_obs.span("dm.upload", batch=1, bucket=8, rows=5,
+                             release="full"):
+            pass
+        with device_obs.span("dm.call", batch=1, bucket=8, rows=5,
+                             release="full"):
+            pass                                 # annotation only
+        assert sample("detector_phase_total", "upload") == count0 + 1
+        assert sample("detector_phase_seconds_total", "upload") >= seconds0
+        assert set(device_obs.PHASE_SPANS.values()) == {
+            "upload", "readback", "alert_build"}
+
+
+class TestDeviceIdleClock:
+    """The three causes sum to the host-known idle time — last batch seen
+    readable to next call issued — and never exceed wall time; on a fake
+    clock."""
+
+    def test_fill_then_host_then_issue(self):
+        clock = device_obs.DeviceIdleClock()
+        clock.idle_from(100.0)                   # batch k seen readable
+        clock.advance(100.2, None)               # nothing held: no_rows
+        clock.advance(100.5, release_at=101.0)   # rows held, due at 101.0
+        clock.advance(101.3, release_at=101.0)   # the pump came 0.3 late
+        assert clock.busy_from(101.3, release_at=101.0) is True
+        clock.issued(0.004)                      # release → call issued
+        assert clock.seconds == pytest.approx(
+            {"no_rows": 0.2, "fill": 0.3 + 0.5, "host": 0.3 + 0.004})
+        assert sum(clock.seconds.values()) == pytest.approx(1.304)
+        assert not clock.idle
+
+    def test_busy_device_accrues_nothing(self):
+        clock = device_obs.DeviceIdleClock()
+        clock.advance(5.0, None)
+        assert clock.busy_from(6.0, None) is False
+        assert sum(clock.seconds.values()) == 0.0
+
+    def test_target_reached_counts_as_host_from_the_mark(self):
+        clock = device_obs.DeviceIdleClock()
+        clock.idle_from(10.0)
+        clock.advance(10.5, release_at=float("-inf"))
+        assert clock.seconds == {"fill": 0.0, "no_rows": 0.0, "host": 0.5}
+
+    def test_random_walk_sums_to_the_idle_time_and_stays_under_wall(self):
+        import random
+
+        rng = random.Random(7)
+        clock = device_obs.DeviceIdleClock()
+        now, idle_time, idle_since = 50.0, 0.0, None
+        wall0 = now
+        for _ in range(2000):
+            now += rng.random() * 0.01
+            release_at = rng.choice(
+                [None, float("-inf"), now - 0.005, now + 0.02])
+            roll = rng.random()
+            if clock.idle and roll < 0.15:
+                assert clock.busy_from(now, release_at)
+                issue = rng.random() * 0.002
+                clock.issued(issue)
+                now += issue
+                idle_time += now - idle_since
+                idle_since = None
+            elif not clock.idle and roll < 0.3:
+                clock.idle_from(now)
+                idle_since = now
+            else:
+                clock.advance(now, release_at)
+        if idle_since is not None:
+            clock.advance(now, None)
+            idle_time += now - idle_since
+        assert sum(clock.seconds.values()) == pytest.approx(idle_time)
+        assert sum(clock.seconds.values()) <= now - wall0
+        assert all(v >= 0.0 for v in clock.seconds.values())
+
+    def test_children_tick_with_the_account(self):
+        class Child:
+            def __init__(self):
+                self.total = 0.0
+
+            def inc(self, amount):
+                self.total += amount
+
+        children = {c: Child() for c in device_obs.DeviceIdleClock.CAUSES}
+        clock = device_obs.DeviceIdleClock(children)
+        clock.idle_from(1.0)
+        clock.advance(2.0, None)
+        clock.busy_from(2.5, release_at=2.25)
+        assert {c: ch.total for c, ch in children.items()} == clock.seconds
+        assert clock.seconds == {"no_rows": 1.0, "fill": 0.25, "host": 0.25}
+
+
+# ---------------------------------------------------------------------------
+# the spans on a real scorer (CPU): counters from boot, one batch's events
+# ---------------------------------------------------------------------------
+def _parser_msg(i: int) -> bytes:
+    from detectmateservice_tpu.schemas import ParserSchema
+
+    return ParserSchema(
+        EventID=1, template="user <*> logged in from <*>",
+        variables=[f"u{i % 8}", f"10.0.0.{i % 16}"], logID=str(i),
+        logFormatVariables={"Time": "1700000000"}).serialize()
+
+
+def _span_detector(name: str, **overrides):
+    from detectmateservice_tpu.library.detectors import JaxScorerDetector
+
+    base = {
+        "method_type": "jax_scorer", "auto_config": False, "model": "mlp",
+        "data_use_training": 32, "train_epochs": 1, "min_train_steps": 5,
+        "seq_len": 16, "dim": 32, "max_batch": 32, "pipeline_depth": 2,
+        "async_fit": False, "host_score_max_batch": 0,
+        "batch_deadline_ms": 10_000.0, "batch_target_occupancy": 0.9,
+        "score_threshold": -1e9, "upload_workers": 1,
+    }
+    base.update(overrides)
+    return JaxScorerDetector(name=name,
+                             config={"detectors": {name: base}})
+
+
+class TestBoundaryCountersFromBoot:
+    def test_every_child_reads_zero_before_the_first_batch(self):
+        """``benchmark/lib/layers.py`` drops a metric whose series is absent
+        from the scrape: every phase / cause / reason child must be exported
+        as 0 from scorer set-up on."""
+        from prometheus_client import generate_latest
+
+        det = _span_detector("zero-from-boot")
+        det.setup_io()
+        text = generate_latest(REGISTRY).decode()
+        ident = 'component_id="zero-from-boot",component_type="jax_scorer"'
+
+        def line(series, extra=""):
+            wanted = "{" + ",".join(sorted(
+                (ident + ("," + extra if extra else "")).split(","))) + "}"
+            return f"{series}{wanted} 0.0"
+
+        for phase in ("upload", "readback", "alert_build"):
+            assert line("detector_phase_seconds_total",
+                        f'phase="{phase}"') in text
+            assert line("detector_phase_total", f'phase="{phase}"') in text
+        for cause in ("fill", "no_rows", "host"):
+            assert line("detector_device_idle_seconds_total",
+                        f'cause="{cause}"') in text
+        for reason in ("full", "deadline", "flush"):
+            assert line("detector_rows_released_total",
+                        f'reason="{reason}"') in text
+        assert line("detector_row_hold_seconds_total") in text
+        device = f'device="{det._device}"'
+        assert line("detector_device_lines_total", device) in text
+        assert line("detector_device_batches_total", device) in text
+        det.flush_final()
+
+
+class TestBatchSpansInACapture:
+    """A ``ProfileManager`` capture (Python tracer off) over a few batches
+    holds the ``dm.*`` host events, and those of one device batch share its
+    ``batch`` id — the one its ring entry is filed under."""
+
+    def test_one_batch_carries_one_id_through_every_span(self, tmp_path):
+        import glob
+
+        import jax
+
+        from detectmateservice_tpu.utils.profiling import ProfileManager
+
+        det = _span_detector("span-capture")
+        det.setup_io()
+        assert det.process_batch([_parser_msg(i) for i in range(32)]) == []
+        det.flush_final()                        # fitted
+        manager = ProfileManager()
+        manager.start(str(tmp_path / "profiles"), 1.0)
+        outs, deadline = [], time.monotonic() + 0.7
+        k = 1000
+        while time.monotonic() < deadline:
+            outs.extend(det.process_batch(
+                [_parser_msg(k + j) for j in range(32)]))   # full release
+            k += 32
+            time.sleep(0.02)
+        outs.extend(det.flush())
+        assert manager.wait(60)
+        det.flush_final()
+        assert manager.status()["last"]["state"] == "done"
+        assert outs
+
+        (path,) = glob.glob(str(tmp_path / "profiles" / "**" / "*.xplane.pb"),
+                            recursive=True)
+        events = {}                              # name -> {batch: stats}
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for event in line.events:
+                    if event.name.startswith("dm."):
+                        stats = {k: v for k, v in event.stats}
+                        events.setdefault(event.name, {})[
+                            stats.get("batch")] = stats
+        # the python tracer is off and the annotations still land
+        for name in ("dm.featurize", "dm.release", "dm.upload", "dm.call",
+                     "dm.readback", "dm.alert_build"):
+            assert name in events, sorted(events)
+        shared = (set(events["dm.upload"]) & set(events["dm.call"])
+                  & set(events["dm.readback"])
+                  & set(events["dm.alert_build"])
+                  & set(events["dm.release"]))
+        assert shared, "no batch carries every span"
+        batch = sorted(shared)[0]
+        stats = events["dm.call"][batch]
+        assert stats["bucket"] == 32 and stats["rows"] == 32
+        assert stats["release"] == "full"
+        ring = {s["seq"]: s for s in
+                device_obs.get_ledger().snapshot()["batches"]}
+        assert ring[batch]["real"] == 32 and ring[batch]["release"] == "full"
+        offsets = ring[batch]["offsets_s"]
+        assert (offsets["oldest_arrival"] <= offsets["release"] == 0.0
+                <= offsets["pickup"] <= offsets["call_issued"]
+                <= offsets["readable"] <= offsets["sent"])
+
+
+class TestDeviceScopes:
+    def test_logbert_program_names_its_layers_and_head(self):
+        """``jax.named_scope`` is metadata: the scopes are in the lowered
+        program's debug info and nowhere in the text the persistent cache's
+        key is made from."""
+        import jax
+        import jax.numpy as jnp
+
+        from detectmateservice_tpu.models.logbert import (
+            LogBERTConfig,
+            LogBERTScorer,
+        )
+
+        # chunked head (the scan body) at a small size
+        scorer = LogBERTScorer(LogBERTConfig(vocab_size=512, dim=32, depth=2,
+                                             heads=2, seq_len=16))
+        scorer._CHUNK_ELEMENT_BUDGET = 64 * 8 * 512
+        params, _ = scorer.init(jax.random.PRNGKey(0))
+        lowered = jax.jit(scorer._score_impl).lower(
+            params, jnp.zeros((64, 16), jnp.uint16))
+        named = lowered.as_text(debug_info=True)
+        for scope in ("embed", "layer0/attn", "layer0/ffn", "layer1/attn",
+                      "head/nll", "head/nll/while"):
+            assert scope in named, scope
+        plain = lowered.as_text()
+        assert "head/nll" not in plain and "layer0" not in plain
 
 
 # ---------------------------------------------------------------------------
