@@ -51,10 +51,12 @@ class JaxScorerDetectorConfig(CoreDetectorConfig):
     # sequences, fused einsum otherwise) | "einsum" | "flash" | "blockwise"
     # | "ring" (sequence-parallel over the mesh_shape 'seq' axis)
     attn_impl: str = "auto"
-    # candidate scoring-head path (gru/logbert with score_vocab > 0):
-    # "auto"/"einsum" = S-chunked einsum + low-precision logsumexp;
-    # "pallas" = fused online-logsumexp kernel (ops/scorehead.py) that
-    # never materializes the [N, C] logits in HBM
+    # scoring-head path: "einsum" = S-chunked einsum + logsumexp over
+    # materialized logits; "pallas" = fused online-logsumexp kernel
+    # (ops/scorehead.py) that keeps the logits in VMEM; "auto" = the
+    # kernel for the exact head of gru/logbert on one TPU, einsum on the
+    # CPU, on a multi-device mesh and for the candidate and mlp heads
+    # (models/base.py head_route; GET /admin/xla buckets.head_route)
     head_impl: str = "auto"
     data_use_training: int = 256
     train_epochs: int = 3
@@ -2050,6 +2052,11 @@ class JaxScorerDetector(CoreDetector):
             "coalescing": self.config.batch_deadline_ms > 0,
             "warm": self._active_buckets(),
             "retired": sorted(self._retired_buckets),
+            # which head each traced device executable took (models/base.py
+            # head_route; decided at trace time, per bucket). The host
+            # twin's calls are not in it: it is pinned to einsum
+            "head_route": {str(rows): route for rows, route in sorted(
+                dict(getattr(self._scorer, "head_routes", {})).items())},
         }
 
     def batching_stats(self) -> Dict[str, Any]:

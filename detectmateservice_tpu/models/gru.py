@@ -52,7 +52,7 @@ class GRUScorerConfig:
     score_topk: int = 0
     # candidate-vocab approximate NLL (same knob as LogBERTConfig.score_vocab)
     score_vocab: int = 0
-    # candidate scoring-head implementation (same knob as LogBERTConfig)
+    # scoring-head implementation (same knob as LogBERTConfig)
     head_impl: str = "auto"
     # platform of the device the scorer is placed on ("tpu" | "cpu"); set by
     # the executor, "" = the process default backend (models/base.py)

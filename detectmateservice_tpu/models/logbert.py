@@ -50,10 +50,12 @@ class LogBERTConfig:
     # "auto" = pallas flash kernel on TPU for long sequences, fused einsum
     # otherwise; "einsum" | "flash" | "blockwise" force a path
     attn_impl: str = "auto"
-    # candidate scoring-head implementation: "auto"/"einsum" = S-chunked
-    # einsum + low-precision logsumexp (models/base.py); "pallas" = fused
-    # online-logsumexp kernel that never materializes the [N, C] logits
-    # (ops/scorehead.py; ROADMAP D5 decides by measurement)
+    # scoring-head implementation: "einsum" = S-chunked einsum + logsumexp
+    # over materialized logits; "pallas" = fused online-logsumexp kernel
+    # that keeps the [N, V] / [N, C] logits in VMEM (ops/scorehead.py);
+    # "auto" = per traced call, from platform, head kind, shape and mesh
+    # size (models/base.py head_route): the kernel for the exact head on
+    # one TPU, einsum everywhere else
     head_impl: str = "auto"
     # platform of the device the scorer is placed on ("tpu" | "cpu"); set by
     # the executor, "" = the process default backend (models/base.py)

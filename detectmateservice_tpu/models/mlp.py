@@ -115,14 +115,15 @@ class MLPScorer(ScorerBase):
                              preferred_element_type=jnp.float32)
             return tgt - lse[:, None]
 
-    def _use_pallas_head(self) -> bool:
-        return getattr(self.config, "head_impl", "auto") == "pallas"
+    def _use_pallas_head(self, tokens: jax.Array) -> bool:
+        return self._head_route(False, tokens.shape[0],
+                                self.config.vocab_size) == "pallas"
 
     def _score_impl(self, params, tokens: jax.Array) -> jax.Array:
         # tokens may arrive as uint16 (the half-width wire format the
         # detector uploads to cut host→device bandwidth); compute in int32
         tokens = tokens.astype(jnp.int32)
-        if self._use_pallas_head():
+        if self._use_pallas_head(tokens):
             return _masked_mean_nll(
                 self._pallas_token_logprobs(params, tokens), tokens)
         return bag_nll(self.model.apply(params, tokens), tokens)
@@ -130,7 +131,7 @@ class MLPScorer(ScorerBase):
     def _token_nlls_impl(self, params, tokens: jax.Array) -> jax.Array:
         """[B, S] per-position NLL under the bag context distribution."""
         tokens = tokens.astype(jnp.int32)
-        if self._use_pallas_head():
+        if self._use_pallas_head(tokens):
             tok_lp = self._pallas_token_logprobs(params, tokens)
         else:
             logits = self.model.apply(params, tokens)

@@ -43,6 +43,10 @@ class ShardedScorer:
     ):
         self.scorer = scorer
         self.mesh = mesh if mesh is not None else make_mesh()
+        # placement fact for the scorer's kernel routing (models/base.py
+        # head_route): GSPMD does not partition a Pallas call, so on more
+        # than one device ``head_impl: auto`` keeps the einsum head
+        scorer.mesh_devices = int(self.mesh.devices.size)
         if rules is None:
             rules = LOGBERT_RULES if getattr(scorer, "name", "") == "logbert" else REPLICATED_RULES
         # sequence parallelism (long-context): a 'seq' mesh axis shards the

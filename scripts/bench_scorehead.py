@@ -1,11 +1,14 @@
-"""Candidate scoring-head microbench: XLA einsum+logsumexp vs the fused
-Pallas online-logsumexp kernel (ops/scorehead.py).
+"""Scoring-head microbench: XLA einsum+logsumexp vs the fused Pallas
+online-logsumexp kernel (ops/scorehead.py), and the whole scoring call per
+bucket with either head.
 
-The shapes are the logbert/gru candidate-path hot shapes: N = B·S rows of
-hidden state against C candidate embeddings. The XLA path materializes the
-[N, C] logits between matmul and reduce; the kernel keeps them in VMEM —
-on a chip the delta is HBM traffic, so run this ON TPU to decide whether
-``head_impl: pallas`` should become the auto route.
+Kernel rows: N = B·S rows of hidden state against C embeddings — the
+served exact head (32768 rows x 32 positions against V = 32768, D = 256),
+one chunk of the einsum route, and the candidate-path shapes. The XLA
+path materializes the [N, C] logits between matmul and reduce; the kernel
+keeps them in VMEM — on a chip the delta is HBM traffic, so run this ON
+TPU. ``models/base.py::head_route`` holds what was decided from it
+(PERF.md section 6, PR 25; ROADMAP D5).
 
 Measurement protocol: the harness (a) chains CHAIN data-dependent
 evaluations inside one jit (the k-th call consumes a perturbation derived
@@ -15,10 +18,14 @@ the SLOPE between a short and a long chain — per-op time with the
 per-call dispatch and fetch floor cancelled:
 ``(T(chain) - T(4)) / (chain - 4)``.
 
-Not measured on the attached chip (docs/benchmarks.md); ``head_impl: auto``
-keeps einsum until it is (ROADMAP D5).
+``--buckets`` instead times ``LogBERTScorer.score`` at the flagship shape
+(dim 256, depth 4, V = 32768, S = 32) for every power-of-two bucket from 32
+to 32768 rows, ``head_impl: einsum`` against ``pallas`` in one process,
+with the largest score difference between the two: the table the ``auto``
+rule is set from.
 
 Usage: python scripts/bench_scorehead.py [chain]
+       python scripts/bench_scorehead.py --buckets
        JAX_PLATFORMS=cpu python scripts/bench_scorehead.py  # interpret-mode smoke
 """
 from __future__ import annotations
@@ -35,7 +42,56 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SHORT_CHAIN = 4
 
 
+def bench_buckets() -> None:
+    """One JSON line per bucket: median ms of the whole scoring call with
+    the einsum head and with the fused head, and how far the scores part."""
+    import jax
+    import numpy as np
+
+    from detectmateservice_tpu.models.logbert import (LogBERTConfig,
+                                                      LogBERTScorer)
+
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        sys.exit(f"--buckets times the chip; jax reports {device.platform!r}")
+    einsum = LogBERTScorer(LogBERTConfig(head_impl="einsum"))
+    fused = LogBERTScorer(LogBERTConfig(head_impl="pallas"))
+    auto = LogBERTScorer(LogBERTConfig())
+    params = jax.device_put(einsum.init(jax.random.PRNGKey(0))[0], device)
+    cfg = einsum.config
+    rng = np.random.default_rng(0)
+
+    def median_ms(scorer, tokens, repeats):
+        jax.block_until_ready(scorer.score(params, tokens))
+        ts = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(scorer.score(params, tokens))
+            ts.append((time.perf_counter() - t0) * 1000)
+        return statistics.median(ts)
+
+    rows = 32
+    while rows <= 32768:
+        tokens = jax.device_put(rng.integers(
+            1, cfg.vocab_size, (rows, cfg.seq_len)).astype(np.uint16), device)
+        repeats = 5 if rows >= 8192 else 20
+        out = {"bucket": rows, "device": device.device_kind,
+               "einsum_ms": round(median_ms(einsum, tokens, repeats), 3),
+               "pallas_ms": round(median_ms(fused, tokens, repeats), 3)}
+        out["speedup"] = round(out["einsum_ms"] / out["pallas_ms"], 2)
+        out["max_abs_score_diff"] = float(np.max(np.abs(
+            np.asarray(einsum.score(params, tokens))
+            - np.asarray(fused.score(params, tokens)))))
+        jax.eval_shape(auto._score_impl, params, tokens)
+        out["auto"] = auto.head_routes[rows]
+        print(json.dumps(out), flush=True)
+        rows *= 2
+
+
 def main() -> None:
+    if "--buckets" in sys.argv[1:]:
+        bench_buckets()
+        return
     chain = int(sys.argv[1]) if len(sys.argv) > 1 else 36
     if chain <= _SHORT_CHAIN:
         sys.exit(f"chain must exceed {_SHORT_CHAIN} (the short-chain "
@@ -56,6 +112,10 @@ def main() -> None:
     # the auto route on numbers head_impl: auto never produces
     shapes = [
         # (label, N, C, D, baseline) — N = B*S for the shipped batch shapes
+        # the served exact head, whole: 32768 rows x 32 positions. The XLA
+        # side maps over 32768-row chunks, the einsum route's own schedule
+        ("exact-head served 32768 x 32 rows, V=32768, D=256", 32768 * 32,
+         32768, 256, "exact"),
         ("logbert-16k x 32, C=2048, D=256", 16384 * 32, 2048, 256, "candidate"),
         ("gru-16k x 32, C=2048, D=128", 16384 * 32, 2048, 128, "candidate"),
         # one S-chunk of the shipped exact path (the chunk budget caps
@@ -75,10 +135,20 @@ def main() -> None:
         return jnp.log(s) + m[..., 0].astype(jnp.float32)
 
     def xla_lse_exact(h, e):
-        logits = jax.lax.dot_general(
-            h, e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return jax.nn.logsumexp(logits, axis=-1)
+        def one_chunk(h_c):
+            logits = jax.lax.dot_general(
+                h_c, e, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return jax.nn.logsumexp(logits, axis=-1)
+
+        # the einsum route scans chunks of at most 2**28 logits
+        # (models/base.py _CHUNK_ELEMENT_BUDGET)
+        n = h.shape[0]
+        chunk = max(1, (1 << 28) // e.shape[0])
+        if n <= chunk:
+            return one_chunk(h)
+        return jax.lax.map(one_chunk, h.reshape(n // chunk, chunk, -1)
+                           ).reshape(n)
 
     def chained(single, k):
         """k data-dependent evals of ``single`` in one jitted program:
@@ -112,9 +182,12 @@ def main() -> None:
     def pal_single(h, e):
         return candidate_lse(h, e, interpret=not on_tpu)
 
+    full_chain = chain
     for label, n, c, d, baseline in shapes:
+        # 0.1-0.4 s an op at the served shape: a short long-chain is enough
+        chain = min(full_chain, 12) if n * c >= 1 << 34 else full_chain
         h = jnp.asarray(rng.normal(size=(n, d)), jnp.bfloat16)
-        e = jnp.asarray(rng.normal(size=(c, d)), jnp.bfloat16)
+        e = jnp.asarray(rng.normal(size=(c, d)) * d ** -0.5, jnp.bfloat16)
         # ONE definition per path, shared by parity check and timing — the
         # two must measure the same program
         xla_single = xla_lse_exact if baseline == "exact" else xla_lse_candidate

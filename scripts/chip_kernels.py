@@ -5,10 +5,11 @@ One process, ``interpret=False`` throughout; fails without a TPU. Each check
 also asserts that the lowered program holds a Mosaic custom call, so a route
 that quietly fell back to XLA or to the interpreter cannot pass.
 
-* ``ops/scorehead.candidate_lse`` at the flagship exact-head shape
-  (N = 16384·32 rows, D = 256, V = 32768; the reference runs in the
-  16384-row chunks the einsum head uses) and at ``mlp``'s (N = 16384,
-  D = 128, V = 32768);
+* ``ops/scorehead.candidate_lse`` at the served exact-head shape
+  (N = 32768·32 = 1,048,576 rows, D = 256, V = 32768; the reference runs in
+  the 32768-row chunks the einsum head uses), at half of it (16384·32) and
+  at ``mlp``'s (N = 16384, D = 128, V = 32768), each with the kernel's own
+  time (``kernel_ms``, median of three calls);
 * ``ops/flash.flash_attention`` forward at S = T = 2048 and 8192, D = 64,
   bf16, with a key mask;
 * the flash backward kernels (dq; dk+dv) at the same shapes.
@@ -57,6 +58,11 @@ def check_candidate_lse(n: int, d: int, v: int, ref_chunk: int) -> dict:
     exe, compile_s = _compiled(
         lambda h, e: candidate_lse(h, e, interpret=False), hidden, emb)
     got = np.asarray(exe(hidden, emb))
+    times = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(exe(hidden, emb))
+        times.append((time.perf_counter() - t0) * 1000)
 
     @jax.jit
     def ref_chunked(h, e):
@@ -71,6 +77,7 @@ def check_candidate_lse(n: int, d: int, v: int, ref_chunk: int) -> dict:
     want = np.asarray(ref_chunked(hidden, emb))
     err = float(np.max(np.abs(got - want)))
     return {"compile_s": round(compile_s, 2), "max_abs_err": err,
+            "kernel_ms": round(sorted(times)[1], 3),
             "finite": bool(np.isfinite(got).all()), "ok": err < 2e-2}
 
 
@@ -136,6 +143,8 @@ def check_flash_backward(s: int) -> dict:
 
 
 CHECKS = [
+    ("candidate_lse logbert served N=1048576 D=256 V=32768",
+     lambda: check_candidate_lse(32768 * 32, 256, 32768, 32768)),
     ("candidate_lse logbert N=524288 D=256 V=32768",
      lambda: check_candidate_lse(16384 * 32, 256, 32768, 16384)),
     ("candidate_lse mlp N=16384 D=128 V=32768",
