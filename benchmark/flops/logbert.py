@@ -37,3 +37,16 @@ def ops_and_bytes(scorer: dict, rows: int) -> tuple:
     nbytes = (4 * params_count(scorer) + rows * scorer["seq_len"] * 2
               + rows * 4)
     return ops, nbytes
+
+
+def head_ops_and_bytes(scorer: dict, rows: int) -> tuple:
+    """Least work of the exact head's logsumexp kernel (``lse_pallas``) for
+    one call: the logits' matrix multiplication, rows x S positions against
+    the V x D embedding, two operations per multiply-add. The V exponentials
+    per position are left out, so the count is a lower bound. Bytes: hidden
+    states and embedding once in bfloat16, as the kernel is given them, and
+    one float32 per position out."""
+    d, v, s = scorer["dim"], scorer["vocab_size"], scorer["seq_len"]
+    ops = 2 * rows * s * v * d
+    nbytes = 2 * rows * s * d + 2 * v * d + 4 * rows * s
+    return ops, nbytes

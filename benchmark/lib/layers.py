@@ -9,11 +9,15 @@ Source kinds:
 * ``prom-gauge`` — a gauge sampled through the window, reduced (``max``);
 * ``generator`` — the load generator's own clock (``late_ms``);
 * ``trace`` — the device trace, reduced by ``lib/xplane.py`` and read by the
-  module the metric's file names (``layer_metrics/<reducer>.py``).
+  module the metric's file names (``layer_metrics/<reducer>.py``). Two
+  readers take their parameters from the metric's file, so that a metric of
+  their kind is a data file: ``scope_share`` (the file's ``scopes`` globs)
+  and ``kernel_roofline_share`` (its ``kernel`` and ``least``).
 """
 from __future__ import annotations
 
 import importlib
+import inspect
 from typing import Optional
 
 from . import prom, quantiles
@@ -61,10 +65,14 @@ def _generator(spec: dict, ctx: dict) -> Optional[float]:
 def _trace(spec: dict, ctx: dict) -> Optional[float]:
     """The metric's own reader, ``benchmark/layer_metrics/<reducer>.py``:
     ``read(ctx)`` over the reduced trace (``lib/xplane.py``), the scorer's
-    block, the buckets dispatched during the capture and the device's
-    peaks. A later PR adds a trace metric by adding such a file."""
+    block, the buckets dispatched during the capture, the device's peaks and
+    the capture's directory — or ``read(ctx, spec)`` where the reader takes
+    the metric's file too. A later PR adds a trace metric by adding such a
+    reader, or a data file for one that is there."""
     reader = importlib.import_module(
         f"benchmark.layer_metrics.{spec['reducer']}")
+    if len(inspect.signature(reader.read).parameters) > 1:
+        return reader.read(ctx, spec)
     return reader.read(ctx)
 
 

@@ -144,9 +144,10 @@ def send_all(zmq, sock, frames, stages) -> None:
 
 
 class ScoredRows:
-    """Rows whose scores reached the host, summed over the detector's batch
-    spans (``GET /admin/xla``; ``detector_device_lines_total`` counts rows on
-    arrival). The ring holds 256 spans, so this is for set-up only."""
+    """Rows whose scores reached the host, on the device path or the host
+    twin's, summed over the detector's batch spans (``GET /admin/xla``;
+    ``detector_device_lines_total`` leaves the twin's rows out). Each poll
+    reads the newest 256 spans, so this is for set-up only."""
 
     def __init__(self, port: int):
         self.port, self.seq, self.rows = port, 0, 0
@@ -359,6 +360,9 @@ def measure(root: str, workload: str, seed: int, seconds: float,
     say(f"cell {workload}: config {config['name']}, traffic "
         f"{traffic['name']} at {rate:.0f} lines/s, seed {seed}, window "
         f"{seconds:g}s, trace {int(trace)}")
+    say(f"configuration: reduced {config['reduced'] or 'nothing'}"
+        + "".join(f"; {key} {cut['published']} -> {cut['here']}"
+                  for key, cut in config.get("cut", {}).items()))
 
     def serialize(log_id: str, line: str) -> bytes:
         return LogSchema(logID=log_id, logSource="bench", log=line).serialize()
@@ -633,6 +637,7 @@ def conclude(measured: dict) -> dict:
             "trace": trace_doc, "scorer": dict(scorer),
             "capture_buckets": obs["capture_buckets"],
             "peak": cell["peaks"].get(device["device_kind"]),
+            "capture_dir": os.path.join(work, "profile"),
         }
         ctx["scorer"].setdefault("vocab_size", 32768)
         for key, value in config.get("assumed", {}).items():
@@ -702,6 +707,29 @@ def conclude(measured: dict) -> dict:
     if trace_doc and trace_doc.get("devices"):
         result["breakdown"] = {"device_ops": trace_doc["device_ops"],
                                "idle_gaps": trace_doc["idle_gaps"]}
+        for (cause, gap_s), shares in zip(trace_doc["idle_gaps"],
+                                          trace_doc["idle_gap_cover"]):
+            if gap_s >= 1e-3:
+                by_share = sorted(shares.items(), key=lambda kv: -kv[1])[:4]
+                say(f"idle gap {gap_s:.4f}s, {cause}: " + ", ".join(
+                    f"{name} {100 * share:.1f}%" for name, share in by_share))
+        for kernel, runs in sorted(trace_doc["kernels"].items()):
+            say(f"kernel {kernel}: " + "; ".join(
+                f"{run['count']} calls, {run['seconds']:.6f}s in {module}"
+                for module, run in runs.items()))
+        by_self = sorted(trace_doc.get("scopes", {}).items(),
+                         key=lambda kv: -kv[1]["self_s"])[:12]
+        for scope, entry in by_self:
+            say(f"scope {scope}: {entry['self_s']:.6f}s self, "
+                f"{entry['events']} operations")
+    # every number compared beside its limit: the run's last lines on
+    # standard error, and the result's last key
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, value, limit in numbers}
+    for name, value, limit in numbers:
+        print(f"compared: {name} = {value:g} (limit {limit:g}) "
+              f"{'ok' if value <= limit else 'FAIL'}", file=sys.stderr,
+              flush=True)
     return result
 
 
@@ -715,7 +743,8 @@ def main(argv=None) -> int:
     try:
         result = run_cell(REPO, args.workload, args.seed, args.seconds,
                           bool(args.trace))
-    except (HarnessFailure, KeyError, OSError, urllib.error.URLError) as exc:
+    except (HarnessFailure, KeyError, ValueError, OSError,
+            urllib.error.URLError) as exc:
         if not isinstance(exc, HarnessFailure):
             traceback.print_exc()
         print(f"benchmark: no result — {exc}", file=sys.stderr)

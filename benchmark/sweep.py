@@ -56,7 +56,8 @@ def variant_root(workload: str, rate: float) -> str:
             config = manifest.read_json(os.path.join(root, file))
             listed["configs"].append({
                 "name": cell["config"], "source": config["source"],
-                "file": file, "reduced": [], "why": "not admitted yet"})
+                "file": file, "reduced": config["reduced"],
+                "why": "not admitted yet"})
         for group in ("end_to_end", "per_layer"):
             for metric in listed[group]:
                 if "workloads" in metric:

@@ -45,3 +45,17 @@ def test_mlp_full_batch():
     ops, nbytes = mlp.ops_and_bytes(MLP, 16384)
     assert ops == 16384 * 8527872
     assert nbytes == 4 * mlp.params_count(MLP) + 16384 * 68
+
+
+def test_logbert_head_kernel_by_hand():
+    # the logits' matmul alone: 2 * rows * 32 positions * 32768 ids * 256
+    ops, nbytes = logbert.head_ops_and_bytes(LOGBERT, 32768)
+    assert ops == 2 * 32768 * 32 * 32768 * 256 == 17592186044416
+    # bfloat16 hidden states and embedding in, one float32 per position out
+    assert nbytes == (2 * 32768 * 32 * 256 + 2 * 32768 * 256
+                      + 4 * 32768 * 32) == 557842432
+    assert ops / 197e12 == pytest.approx(0.08930, rel=1e-3)
+    assert ops / 197e12 > nbytes / 819e9        # compute-bound on the v5e
+    # the head is 537 of the 742 MFLOP a line that ops_and_bytes counts
+    assert ops == 32768 * 32 * 2 * 256 * 32768
+    assert ops < logbert.ops_and_bytes(LOGBERT, 32768)[0]

@@ -1,12 +1,14 @@
-"""``BENCHMARK.json`` against the contract's shape, and every file it names."""
+"""``BENCHMARK.json`` against the contract's shape, and every file it names:
+the repo's own, and a temporary copy to which a configuration cut to a chip's
+share, its cell and a data-only scope metric were added the way a later PR
+adds them (``bench_helpers.room_root``)."""
 import bench_helpers  # noqa: F401  (puts the repo root on sys.path)
-import json
 import os
 import re
 
 import pytest
 
-from bench_helpers import REPO, read_json
+from bench_helpers import REPO, read_json, room_root
 from benchmark.lib import manifest as manifest_lib
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -15,17 +17,24 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 KINDS = {"prom-delta", "prom-gauge", "generator", "trace"}
 
 
+@pytest.fixture(scope="module", params=["repo", "room"])
+def root(request, tmp_path_factory):
+    if request.param == "repo":
+        return REPO
+    return room_root(tmp_path_factory.mktemp("room"))[0]
+
+
 @pytest.fixture(scope="module")
-def manifest():
-    return read_json(os.path.join(REPO, "BENCHMARK.json"))
+def manifest(root):
+    return read_json(os.path.join(root, "BENCHMARK.json"))
 
 
-def test_top_level_keys(manifest):
+def test_top_level_keys(manifest, root):
     assert set(manifest) == {"command", "paths", "run_seconds", "configs",
                              "workloads", "end_to_end", "per_layer"}
     assert manifest["command"] == ["python3", "benchmark/run.py"]
     assert 1 <= manifest["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
+    assert os.path.getsize(os.path.join(root, "BENCHMARK.json")) < 64 * 1024
 
 
 def test_run_seconds_fits_a_full_check_of_24_cells(manifest):
@@ -70,13 +79,13 @@ def test_setup_s_is_an_end_to_end_metric_of_every_cell(manifest):
     assert "workloads" not in setup and setup["bound"] <= 0.1
 
 
-def test_every_cells_files_exist_and_agree(manifest):
+def test_every_cells_files_exist_and_agree(manifest, root):
     configs = {c["name"]: c for c in manifest["configs"]}
     assert {w["config"] for w in manifest["workloads"]} == set(configs)
     pairs = [(w["config"], w["traffic"]) for w in manifest["workloads"]]
     assert len(pairs) == len(set(pairs))
     for entry in manifest["workloads"]:
-        cell = manifest_lib.load_cell(REPO, entry["name"])
+        cell = manifest_lib.load_cell(root, entry["name"])
         assert cell["config"]["name"] == entry["config"]
         assert cell["traffic"]["name"] == entry["traffic"]
         assert cell["cell"]["config"] == entry["config"]
@@ -90,9 +99,21 @@ def test_every_cells_files_exist_and_agree(manifest):
             assert spec["moves"] in reported, (entry["name"], spec["name"])
 
 
-def test_layer_metric_files_match_the_manifest(manifest):
+def test_every_cell_reports_every_layer(manifest, root):
+    """A cell cannot be added blind: each ``layer`` the manifest names has a
+    metric that lists the cell (or lists none and follows the end-to-end
+    metric it moves)."""
+    layers = {entry["layer"] for entry in manifest["per_layer"]}
+    assert len(layers) >= 10
+    for entry in manifest["workloads"]:
+        cell = manifest_lib.load_cell(root, entry["name"])
+        assert {spec["layer"] for spec in cell["per_layer"]} == layers, \
+            entry["name"]
+
+
+def test_layer_metric_files_match_the_manifest(manifest, root):
     for entry in manifest["per_layer"]:
-        spec = read_json(os.path.join(REPO, "benchmark", "layer_metrics",
+        spec = read_json(os.path.join(root, "benchmark", "layer_metrics",
                                       entry["name"] + ".json"))
         for key in ("name", "layer", "unit", "moves"):
             assert spec[key] == entry[key], (entry["name"], key)
@@ -104,11 +125,15 @@ def test_layer_metric_files_match_the_manifest(manifest):
     assert all(len(spellings) == 1 for spellings in layers.values())
 
 
-def test_configs_state_source_changes_and_guarantees(manifest):
+def test_configs_state_source_changes_and_guarantees(manifest, root):
     for entry in manifest["configs"]:
-        config = read_json(os.path.join(REPO, entry["file"]))
+        config = read_json(os.path.join(root, entry["file"]))
         assert config["source"] == entry["source"]
-        assert config["reduced"] == entry["reduced"] == []
+        # the rule on ``reduced`` (lib/manifest.py): equal in both places,
+        # distinct keys, and each cut written down beside the published value
+        assert manifest_lib.reduced_breaches(entry, config) == []
+        assert config["reduced"] == entry["reduced"]
+        assert sorted(config.get("cut", {})) == sorted(config["reduced"])
         for key in ("assumed", "changed", "guarantees", "stated"):
             assert key in config, (entry["name"], key)
         for stage in ("parser", "detector", "output"):
@@ -118,13 +143,13 @@ def test_configs_state_source_changes_and_guarantees(manifest):
         assert config["check"]["tolerance_nats"] > 0
 
 
-def test_paths_hold_only_allowed_file_names(manifest):
+def test_paths_hold_only_allowed_file_names(manifest, root):
     allowed = re.compile(r"^[A-Za-z0-9_.\-/]+$")
     for path in manifest["paths"]:
-        for folder, dirs, files in os.walk(os.path.join(REPO, path)):
+        for folder, dirs, files in os.walk(os.path.join(root, path)):
             dirs[:] = [d for d in dirs if d != "__pycache__"]
             for name in files:
-                rel = os.path.relpath(os.path.join(folder, name), REPO)
+                rel = os.path.relpath(os.path.join(folder, name), root)
                 assert allowed.match(rel), rel
 
 

@@ -21,12 +21,26 @@ def _run(root, cell, seed=11, seconds=3.0, trace=False):
                         t_start=time.monotonic())
 
 
-def test_a_sound_run_is_correct_and_reports_the_cells_metrics(tmp_path):
+def test_a_sound_run_is_correct_and_reports_the_cells_metrics(tmp_path,
+                                                              capsys):
     root, cell = temp_root(tmp_path, config_name="mlp-compose", model="mlp",
                            traffic="steady", rate=8000)
     result = _run(root, cell)
     assert set(result) >= {"correct", "attempted", "failed", "metrics",
                            "device"}
+    # every number compared beside its limit: the result's last key, and the
+    # last lines on standard error
+    assert list(result)[-1] == "compared"
+    assert all(set(pair) == {"value", "limit"}
+               for pair in result["compared"].values())
+    assert {"score_gap_max_nats", "dropped_lines",
+            "compiles_after_warmup"} <= set(result["compared"])
+    printed = capsys.readouterr()
+    last = printed.err.strip().splitlines()[-len(result["compared"]):]
+    assert [line.split()[1] for line in last] == list(result["compared"])
+    assert all(line.startswith("compared: ") and line.endswith(" ok")
+               for line in last)
+    assert "configuration: reduced nothing" in printed.out
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
     assert set(result["metrics"]) == {"setup_s", "alert_p50_ms"}
@@ -34,9 +48,11 @@ def test_a_sound_run_is_correct_and_reports_the_cells_metrics(tmp_path):
     json.dumps(result)
 
 
-def test_a_traced_run_reports_per_layer_metrics_and_an_added_one(tmp_path):
-    """A configuration, a traffic mix, a cell and a per-layer metric of an
-    existing source kind, added by files and manifest entries only."""
+def test_a_traced_run_reports_per_layer_metrics_and_an_added_one(tmp_path,
+                                                                 capsys):
+    """A configuration cut in depth, a traffic mix, a cell and a per-layer
+    metric of an existing source kind, added by files and manifest entries
+    only."""
     added = {
         "name": "detector_rows_per_call",
         "file": {"name": "detector_rows_per_call", "layer": "detector host",
@@ -51,8 +67,10 @@ def test_a_traced_run_reports_per_layer_metrics_and_an_added_one(tmp_path):
     mix = {"name": "overload-poisson", "loop": "open", "frame_lines": 128,
            "arrival": "exponential", "anomaly_share": 0.02, "ramp_s": 1.0,
            "saturating": True}
-    root, cell = temp_root(tmp_path, model="logbert", traffic="steady",
-                           rate=4000, metric=added, new_traffic=mix)
+    root, cell = temp_root(
+        tmp_path, model="logbert", traffic="steady", rate=4000, metric=added,
+        new_traffic=mix, reduced={"depth": {
+            "published": 4, "here": 1, "why": "what the CPU holds"}})
     assert cell == "tiny-logbert.overload-poisson"
     before = {name: read_json(os.path.join(REPO, "benchmark", sub, name))
               for sub in ("configs", "traffic", "cells", "layer_metrics")
@@ -60,6 +78,9 @@ def test_a_traced_run_reports_per_layer_metrics_and_an_added_one(tmp_path):
               if name.endswith(".json")}
     result = _run(root, cell, trace=True)
     assert result["correct"] is True
+    # the report a person reads says what was cut
+    assert ("configuration: reduced ['depth']; depth 4 -> 1"
+            in capsys.readouterr().out)
     assert {"parser_busy_share", "detector_busy_share", "batch_occupancy",
             "dispatch_ready_ms.lat", "queue_wait_mean_ms", "alert_p95_ms",
             "detector_rows_per_call"} <= set(result["metrics"])
@@ -67,6 +88,8 @@ def test_a_traced_run_reports_per_layer_metrics_and_an_added_one(tmp_path):
     # no device trace on the CPU: the trace readers find nothing and the
     # harness leaves their metrics out
     assert "device_idle_share" not in result["metrics"]
+    assert "attn_share_of_call" not in result["metrics"]
+    assert "lse_pallas_roofline" not in result["metrics"]
     # the copy's pre-existing files are letter for letter the repo's
     for sub in ("configs", "traffic", "cells", "layer_metrics"):
         for name in before:

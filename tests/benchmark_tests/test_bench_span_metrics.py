@@ -127,4 +127,5 @@ def test_every_metric_is_in_the_manifest_for_the_steady_cell():
         assert entry["moves"] == spec["moves"] == "alert_p50_ms"
         assert entry["layer"] == spec["layer"]
         assert entry["unit"] == spec["unit"]
-        assert entry["workloads"] == ["logbert-256x4.steady"]
+        # a PR that adds a cell appends its name to the list
+        assert "logbert-256x4.steady" in entry["workloads"]
