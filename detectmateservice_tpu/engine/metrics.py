@@ -526,6 +526,34 @@ ROWS_RELEASED = _series(
     RELEASE_LABELS,
 )
 
+# the sparse-expert scorer's routing, counted on the device in the scoring
+# call and read back with the scores (one [3] int32 array beside them, no
+# second device->host sync): every (non-PAD token, chosen expert) pair of
+# every expert layer; those that fell on experts this chip holds; and, per
+# call and expert layer, the largest count among the held experts, summed.
+# held / assignments is the chip's share of the routing (held experts /
+# router experts under even routing); busiest x held experts / held is the
+# skew (1.0 = balanced). Exported as 0 by every detector: a scorer without
+# experts never moves them.
+MOE_ASSIGNMENTS = _series(
+    Counter,
+    "detector_moe_assignments_total",
+    "Expert assignments routed by the scorer: non-PAD tokens x experts per "
+    "token x expert layers, over all published experts",
+)
+MOE_HELD_ASSIGNMENTS = _series(
+    Counter,
+    "detector_moe_held_assignments_total",
+    "Expert assignments that fell on experts held on this chip (the ones "
+    "it computes)",
+)
+MOE_BUSIEST_ASSIGNMENTS = _series(
+    Counter,
+    "detector_moe_busiest_expert_assignments_total",
+    "Per scoring call and expert layer the largest assignment count among "
+    "the held experts, summed",
+)
+
 # batch spans a per-layer metric reads (engine/device_obs.py span()): wall
 # seconds inside the span and the number of spans, per phase — upload
 # (narrow + device_put, dispatch worker), readback (np.asarray of the

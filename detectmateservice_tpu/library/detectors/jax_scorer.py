@@ -30,16 +30,25 @@ from ...engine import device_obs
 from ...schemas import DetectorSchema, ParserSchema, SchemaError
 from ..common.core import LibraryError
 from ..common.detector import BufferMode, CoreDetector, CoreDetectorConfig
+from .scorer_families import FAMILIES
 
 
 class JaxScorerDetectorConfig(CoreDetectorConfig):
     method_type: str = "jax_scorer"
-    model: str = "mlp"                # "mlp" | "gru" | "logbert"
+    # "mlp" | "gru" | "logbert" | "moe_mla" (scorer_families.FAMILIES)
+    model: str = "mlp"
     vocab_size: int = 32768
     seq_len: int = 32
     dim: int = 128
     depth: int = 2                    # logbert/gru layers
     heads: int = 4                    # logbert only
+    # moe_mla only: the model's shape as the published config.json keys
+    # under their published names (hidden_size, num_attention_heads,
+    # kv_lora_rank, n_routed_experts ...; models/moe_mla.py MoEMLAArch),
+    # plus the chip's share of an expert-parallel group: router_experts
+    # (the published count the router scores over; n_routed_experts is
+    # then the count HELD here) and expert_offset (the first one held)
+    arch: Optional[Dict[str, Any]] = None
     score_topk: int = 0               # logbert/gru: 0=mean NLL, k>0=top-k mean
     # logbert/gru: candidate-vocab approximate scoring NLL. 0 = exact
     # full-vocab head; 0 < C < vocab_size estimates the logsumexp over a
@@ -182,7 +191,7 @@ class _InflightSlot:
     nothing unfinished on the device (its release → call issued is then
     device idle time)."""
 
-    __slots__ = ("scores", "raws", "real", "error", "done",
+    __slots__ = ("scores", "aux", "raws", "real", "error", "done",
                  "t_enqueue", "t_release", "t_start", "t_issued", "bucket",
                  "path", "trace_id", "release", "tokens", "seq",
                  "idle_start")
@@ -196,6 +205,9 @@ class _InflightSlot:
         import threading
 
         self.scores = None
+        # what a score_aux scorer's call returned beside the scores (the
+        # sparse-expert scorer's routing counts): read back with them
+        self.aux = None
         self.raws = raws
         self.real = real
         # the REAL (unpadded) token rows, retained only while a rollout
@@ -504,6 +516,7 @@ class JaxScorerDetector(CoreDetector):
         self._rows_released_children: Dict[str, Any] = {}
         self._row_hold_child = None
         self._device_children: Optional[tuple] = None
+        self._moe_children: Optional[tuple] = None
         self._idle_clock = None
         self._dev_unfinished = 0
         self._occ_stats = (0, 0.0)            # (dispatches, occupancy sum)
@@ -581,8 +594,10 @@ class JaxScorerDetector(CoreDetector):
             raise LibraryError(
                 f"unknown attn_impl {cfg.attn_impl!r}; expected 'auto', "
                 "'einsum', 'flash', 'blockwise', or 'ring'")
-        if cfg.model not in ("mlp", "gru", "logbert"):
-            raise LibraryError(f"unknown scorer model {cfg.model!r}")
+        family = FAMILIES.get(cfg.model)
+        if family is None:
+            raise LibraryError(f"unknown scorer model {cfg.model!r}; "
+                               f"expected one of {sorted(FAMILIES)}")
         if cfg.dtype not in ("auto", "bfloat16", "float32", "float16",
                              "int8w"):
             raise LibraryError(
@@ -603,6 +618,9 @@ class JaxScorerDetector(CoreDetector):
             raise LibraryError(
                 "bucket_retire_interval_s must be >= 0 "
                 f"(got {cfg.bucket_retire_interval_s})")
+        refusal = family.refuses(cfg)
+        if refusal:
+            raise LibraryError(refusal)
 
     # -- lifecycle ------------------------------------------------------
     def setup_io(self) -> None:
@@ -767,33 +785,10 @@ class JaxScorerDetector(CoreDetector):
                                  else jnp.bfloat16)
         elif cfg.dtype and cfg.dtype != "auto":
             model_kw["dtype"] = jnp.dtype(cfg.dtype).type
-        if cfg.model == "logbert":
-            from ...models.logbert import LogBERTConfig, LogBERTScorer
-
-            self._scorer = LogBERTScorer(LogBERTConfig(
-                vocab_size=cfg.vocab_size, dim=cfg.dim, depth=cfg.depth,
-                heads=cfg.heads, seq_len=cfg.seq_len, score_topk=cfg.score_topk,
-                attn_impl=cfg.attn_impl, score_vocab=cfg.score_vocab,
-                head_impl=cfg.head_impl, **model_kw,
-            ))
-        elif cfg.model == "gru":
-            from ...models.gru import GRUScorer, GRUScorerConfig
-
-            self._scorer = GRUScorer(GRUScorerConfig(
-                vocab_size=cfg.vocab_size, dim=cfg.dim, depth=cfg.depth,
-                seq_len=cfg.seq_len, score_topk=cfg.score_topk,
-                score_vocab=cfg.score_vocab, head_impl=cfg.head_impl,
-                **model_kw,
-            ))
-        elif cfg.model == "mlp":
-            from ...models.mlp import MLPScorer, MLPScorerConfig
-
-            self._scorer = MLPScorer(MLPScorerConfig(
-                vocab_size=cfg.vocab_size, dim=cfg.dim, seq_len=cfg.seq_len,
-                head_impl=cfg.head_impl, **model_kw,
-            ))
-        else:
-            raise LibraryError(f"unknown scorer model {cfg.model!r}")
+        try:
+            self._scorer = FAMILIES[cfg.model].build(cfg, model_kw)
+        except ValueError as exc:   # a shape the family refuses, by name
+            raise LibraryError(f"scorer model {cfg.model!r}: {exc}") from exc
         self._rng = jax.random.PRNGKey(cfg.seed)
         if mesh is not None:
             # multi-chip: batches shard over the mesh's data axis, params per
@@ -834,8 +829,9 @@ class JaxScorerDetector(CoreDetector):
         log = logging.getLogger(__name__)
         if not self._host_scoring_possible():
             self._host_twin_state = "unsupported"
-            log.info("host twin unsupported for attn_impl=%r: every batch "
-                     "scores on %s", cfg.attn_impl, self._device)
+            log.info("host twin unsupported for model=%r attn_impl=%r: every "
+                     "batch scores on %s", cfg.model, cfg.attn_impl,
+                     self._device)
             return
         try:
             self._cpu_device = jax.devices("cpu")[0]
@@ -904,6 +900,7 @@ class JaxScorerDetector(CoreDetector):
                 "model": cfg.model, "vocab_size": cfg.vocab_size,
                 "dim": cfg.dim, "depth": cfg.depth, "heads": cfg.heads,
                 "seq_len": cfg.seq_len, "max_batch": cfg.max_batch,
+                "arch": cfg.arch,
                 "head": "candidate" if cfg.score_vocab else "exact",
                 "dtype": (str(np.dtype(self._scorer.config.dtype))
                           if self._scorer is not None else cfg.dtype),
@@ -926,14 +923,10 @@ class JaxScorerDetector(CoreDetector):
         }
 
     def _host_scoring_possible(self) -> bool:
-        """Whether the model can run on the host CPU twin at all: a forced
-        flash kernel would run there in interpret mode and ring attention is
-        bound to the accelerator mesh, so those attention configs are
-        device-only and small batches ride the device path instead.
-        (``auto`` routes per platform: the twin takes einsum.)"""
-        cfg = self.config
-        return not (cfg.model == "logbert"
-                    and cfg.attn_impl in ("flash", "ring"))
+        """Whether the model can run on the host CPU twin at all
+        (scorer_families.py has each family's reason); where it cannot,
+        small batches ride the device path instead."""
+        return FAMILIES[self.config.model].host_twin(self.config)
 
     def _sync_host_params(self) -> None:
         """Mirror the current params onto the host CPU backend (one transfer,
@@ -1009,7 +1002,8 @@ class JaxScorerDetector(CoreDetector):
                               self._device)
 
     def _score_dev(self, tokens: np.ndarray,
-                   batch_kv: Optional[Dict[str, Any]] = None):
+                   batch_kv: Optional[Dict[str, Any]] = None,
+                   slot: Optional["_InflightSlot"] = None):
         """Dispatch scoring for [n, S] tokens; returns the device array
         without forcing readback (single device or sharded mesh). Applies
         per-position normalization once calibrated (fit). Routing order:
@@ -1023,15 +1017,25 @@ class JaxScorerDetector(CoreDetector):
         marks the upload as ``dm.upload`` and the call, with the start of
         the asynchronous readback, as ``dm.call``; warm-up, fit and parity
         calls pass none and leave no span. On a mesh the sharded scorer
-        places its own shards, so the whole of it is ``dm.call``."""
+        places its own shards, so the whole of it is ``dm.call``. Where the
+        scorer's call returns counts beside the scores (``score_aux``) they
+        go to ``slot.aux`` — read back with the scores at the drain — and
+        nowhere when no slot is given."""
         if self._sharded is None:
             with _batch_span("dm.upload", batch_kv):
                 tokens = self._put(tokens)
         with _batch_span("dm.call", batch_kv):
             scores = self._call_dev(tokens)
+            aux = None
+            if isinstance(scores, (tuple, list)):
+                scores, aux = scores
+            if slot is not None:
+                slot.aux = aux
             if batch_kv is not None:
                 try:
                     scores.copy_to_host_async()
+                    if aux is not None:
+                        aux.copy_to_host_async()
                 except AttributeError:
                     pass
         return scores
@@ -1064,7 +1068,7 @@ class JaxScorerDetector(CoreDetector):
             # dmlint: ignore[DM-L001] ref-atomic param swap
             return comp(self._params, tokens)
         # dmlint: ignore[DM-L001] ref-atomic param swap; either generation
-        return self._scorer.score(self._params, tokens)
+        return self._scorer._score(self._params, tokens)
 
     def _token_nlls_dev(self, tokens: np.ndarray):
         if self._sharded is not None:
@@ -1219,12 +1223,14 @@ class JaxScorerDetector(CoreDetector):
     def _train_step(self, step_rng, batch: np.ndarray) -> float:
         if self._sharded is not None:
             return self._sharded.train_step(step_rng, batch)
-        # the boundary fit owns these trees until _finish_fit hands off;
-        # install_candidate joins the fit before swapping:
+        # the boundary fit owns these trees until _finish_fit hands off
+        # (install_candidate joins the fit before swapping), so both are
+        # given up to the step and rebound at once: a step holds one
+        # generation of parameters and moments, not two
         # dmlint: ignore[DM-L001] single-writer fit phase
         self._params, self._opt_state, loss_arr = self._scorer.train_step(
-            self._params, self._opt_state, step_rng, self._put(batch)
-        )
+            self._params, self._opt_state, step_rng, self._put(batch),
+            donate=True)
         return float(loss_arr)
 
     # -- featurization (CPU side) ---------------------------------------
@@ -1860,7 +1866,7 @@ class JaxScorerDetector(CoreDetector):
             with self._ledger.context(bucket=slot.bucket,
                                       backend=self._obs_backend,
                                       where="dispatch", expected=False):
-                return self._score_dev(chunk, slot.span_kv())
+                return self._score_dev(chunk, slot.span_kv(), slot)
         finally:
             slot.t_issued = time.monotonic()
 
@@ -2057,6 +2063,10 @@ class JaxScorerDetector(CoreDetector):
             # twin's calls are not in it: it is pinned to einsum
             "head_route": {str(rows): route for rows, route in sorted(
                 dict(getattr(self._scorer, "head_routes", {})).items())},
+            # which expert path each traced executable took (the
+            # sparse-expert scorer's; empty for a scorer without experts)
+            "expert_route": {str(rows): route for rows, route in sorted(
+                dict(getattr(self._scorer, "expert_routes", {})).items())},
         }
 
     def batching_stats(self) -> Dict[str, Any]:
@@ -2173,7 +2183,11 @@ class JaxScorerDetector(CoreDetector):
         if on_device:
             with device_obs.span("dm.readback", **kv):
                 scores = np.asarray(slot.scores)[:real]
+                aux = None if slot.aux is None else np.asarray(slot.aux)
             self._device_batch_done(slot, time.monotonic())
+            if aux is not None and self._moe_children is not None:
+                for child, count in zip(self._moe_children, aux):
+                    child.inc(int(count))
         else:
             scores = np.asarray(slot.scores)[:real]
         if self._rollout_sampler is not None and slot.tokens is not None:
@@ -2334,6 +2348,10 @@ class JaxScorerDetector(CoreDetector):
         self._device_children = (
             m.DEVICE_LINES().labels(device=str(self._device), **labels),
             m.DEVICE_BATCHES().labels(device=str(self._device), **labels))
+        self._moe_children = (
+            m.MOE_ASSIGNMENTS().labels(**labels),
+            m.MOE_HELD_ASSIGNMENTS().labels(**labels),
+            m.MOE_BUSIEST_ASSIGNMENTS().labels(**labels))
         self._idle_clock = device_obs.DeviceIdleClock({
             cause: m.DEVICE_IDLE_SECONDS().labels(cause=cause, **labels)
             for cause in device_obs.DeviceIdleClock.CAUSES})

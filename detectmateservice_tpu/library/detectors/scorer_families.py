@@ -1,0 +1,99 @@
+"""The scorer families ``JaxScorerDetector`` can build, in one table.
+
+Each family says three things of a detector configuration: why it cannot
+run it (by name, at construction, before anything is traced), how to build
+its scorer, and whether a CPU twin of it can score small batches. Importing
+this module imports no jax: the builders import their model on use.
+"""
+from __future__ import annotations
+
+from typing import Any, Callable, Dict, NamedTuple, Optional
+
+
+class ScorerFamily(NamedTuple):
+    # (detector config, model_kw: platform and an explicit dtype) -> scorer
+    build: Callable[[Any, Dict[str, Any]], Any]
+    # detector config -> why this family cannot run it, or None
+    refuses: Callable[[Any], Optional[str]]
+    # detector config -> whether the host CPU twin can run this scorer
+    host_twin: Callable[[Any], bool]
+
+
+def _no_arch(cfg) -> Optional[str]:
+    if cfg.arch is not None:
+        return ("'arch' is the moe_mla family's shape key; "
+                f"model {cfg.model!r} takes dim/depth/heads")
+    return None
+
+
+def _build_mlp(cfg, model_kw):
+    from ...models.mlp import MLPScorer, MLPScorerConfig
+
+    return MLPScorer(MLPScorerConfig(
+        vocab_size=cfg.vocab_size, dim=cfg.dim, seq_len=cfg.seq_len,
+        head_impl=cfg.head_impl, **model_kw))
+
+
+def _build_gru(cfg, model_kw):
+    from ...models.gru import GRUScorer, GRUScorerConfig
+
+    return GRUScorer(GRUScorerConfig(
+        vocab_size=cfg.vocab_size, dim=cfg.dim, depth=cfg.depth,
+        seq_len=cfg.seq_len, score_topk=cfg.score_topk,
+        score_vocab=cfg.score_vocab, head_impl=cfg.head_impl, **model_kw))
+
+
+def _build_logbert(cfg, model_kw):
+    from ...models.logbert import LogBERTConfig, LogBERTScorer
+
+    return LogBERTScorer(LogBERTConfig(
+        vocab_size=cfg.vocab_size, dim=cfg.dim, depth=cfg.depth,
+        heads=cfg.heads, seq_len=cfg.seq_len, score_topk=cfg.score_topk,
+        attn_impl=cfg.attn_impl, score_vocab=cfg.score_vocab,
+        head_impl=cfg.head_impl, **model_kw))
+
+
+def _build_moe_mla(cfg, model_kw):
+    from ...models.moe_mla import MoEMLAArch, MoEMLAConfig, MoEMLAScorer
+
+    return MoEMLAScorer(MoEMLAConfig(
+        arch=MoEMLAArch.from_mapping(cfg.arch), vocab_size=cfg.vocab_size,
+        seq_len=cfg.seq_len, score_topk=cfg.score_topk,
+        attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
+
+
+def _moe_mla_refuses(cfg) -> Optional[str]:
+    """What the sparse-expert family cannot do yet, by the key that asks
+    for it (ROADMAP: the expert layer across chips, a sliced vocabulary's
+    cross-shard logsumexp, quantized experts, a candidate head)."""
+    if not isinstance(cfg.arch, dict):
+        return ("model 'moe_mla' takes its shape from the mapping 'arch' "
+                "(the published config.json keys; docs/configuration.md)")
+    if cfg.mesh_shape:
+        return ("mesh_shape: the moe_mla scorer runs on one device; "
+                "parallel/mesh.py has no expert axis and no rule for it")
+    if cfg.dtype == "int8w":
+        return "dtype 'int8w': models/quant.py does not quantize experts"
+    if cfg.score_vocab > 0:
+        return ("score_vocab > 0: the moe_mla scorer has the exact head "
+                "only")
+    if cfg.attn_impl not in ("auto", "einsum"):
+        return (f"attn_impl {cfg.attn_impl!r}: latent attention is causal "
+                "with 192-wide keys and 128-wide values, which only the "
+                "einsum route computes ('auto' or 'einsum')")
+    return None
+
+
+FAMILIES: Dict[str, ScorerFamily] = {
+    "mlp": ScorerFamily(_build_mlp, _no_arch, lambda cfg: True),
+    "gru": ScorerFamily(_build_gru, _no_arch, lambda cfg: True),
+    # a forced flash kernel would run on the twin in interpret mode and
+    # ring attention is bound to the accelerator mesh: device-only
+    "logbert": ScorerFamily(
+        _build_logbert, _no_arch,
+        lambda cfg: cfg.attn_impl not in ("flash", "ring")),
+    # its scoring call returns counts beside the scores, and a CPU mirror
+    # of a model sized for a chip's memory is no latency path
+    "moe_mla": ScorerFamily(_build_moe_mla, _moe_mla_refuses,
+                            lambda cfg: False),
+}

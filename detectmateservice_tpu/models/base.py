@@ -121,6 +121,10 @@ class ScorerBase:
     """
 
     name = "base"
+    # True where the scoring call returns ``(scores, aux)``: one small
+    # array of counts from the same executable, which the detector reads
+    # back with the scores (models/moe_mla.py: the routing counts)
+    score_aux = False
 
     def __init__(self, config: Any):
         if not config.platform:
@@ -142,6 +146,11 @@ class ScorerBase:
         self.optimizer = optax.adamw(config.learning_rate)
         self._score = jax.jit(self._score_impl)
         self._train = jax.jit(self._train_impl)
+        # the boundary fit's form: parameters and optimizer state are given
+        # up to the step, which returns their successors in the same
+        # buffers — a step then holds one generation of both, not two
+        self._train_donating = jax.jit(self._train_impl,
+                                       donate_argnums=(0, 1))
         self._token_nlls = jax.jit(self._token_nlls_impl)
         self._normscore = jax.jit(self._normscore_impl)
 
@@ -187,10 +196,19 @@ class ScorerBase:
         return params, self.optimizer.init(params)
 
     def score(self, params, tokens) -> jax.Array:
-        return self._score(params, tokens)
+        out = self._score(params, tokens)
+        return out[0] if self.score_aux else out
 
-    def train_step(self, params, opt_state, rng, tokens):
-        return self._train(params, opt_state, rng, tokens)
+    def train_step(self, params, opt_state, rng, tokens,
+                   donate: bool = False):
+        """One optimizer step → ``(params, opt_state, loss)``. With
+        ``donate`` the caller gives up ``params`` and ``opt_state`` (their
+        buffers are reused for the result and must not be read again): the
+        boundary fit's form, where the trees are the detector's own. A
+        caller that keeps the inputs alive — a candidate forked from the
+        live trees — leaves it off."""
+        step = self._train_donating if donate else self._train
+        return step(params, opt_state, rng, tokens)
 
 
 class SequenceScorerBase(ScorerBase):
@@ -285,7 +303,7 @@ class SequenceScorerBase(ScorerBase):
 
     def _token_nlls_candidate(self, params, tokens: jax.Array, dtype,
                               n_cand: int) -> jax.Array:
-        emb = params["params"]["tok_embed"]["embedding"]
+        emb = self._head_matrix(params)
         v = emb.shape[0]
         if n_cand >= v:
             return self._token_nlls_exact(params, tokens, dtype)
@@ -349,9 +367,14 @@ class SequenceScorerBase(ScorerBase):
         never materialize; the target logit comes from the equivalent
         direct hidden·emb[token] dot."""
         hidden = self.model.apply(params, tokens, method="hidden").astype(dtype)
-        emb = params["params"]["tok_embed"]["embedding"].astype(dtype)
+        emb = self._head_matrix(params).astype(dtype)
         with jax.named_scope("head/nll"):
             return self._exact_head(hidden, emb, tokens)
+
+    def _head_matrix(self, params) -> jax.Array:
+        """The head's [V, D] matrix: the tied token embedding, unless the
+        family has a head of its own (models/moe_mla.py: ``lm_head``)."""
+        return params["params"]["tok_embed"]["embedding"]
 
     def _exact_head(self, hidden: jax.Array, emb: jax.Array,
                     tokens: jax.Array) -> jax.Array:

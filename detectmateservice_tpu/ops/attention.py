@@ -44,10 +44,11 @@ def ring_context(mesh, batch_axis: Optional[str] = None, axis_name: str = "seq")
 def attention(
     q: jax.Array,  # [B, H, S, D]
     k: jax.Array,  # [B, H, T, D]
-    v: jax.Array,  # [B, H, T, D]
+    v: jax.Array,  # [B, H, T, Dv]; Dv may differ from D (einsum route)
     key_mask: Optional[jax.Array] = None,  # [B, T] bool; True = attend
     impl: str = "auto",
     platform: Optional[str] = None,
+    causal: bool = False,
 ) -> jax.Array:
     """Route to the right attention implementation.
 
@@ -60,18 +61,29 @@ def attention(
     ``platform`` is the platform of the device the computation is placed on
     (the scorers pass the one their executor resolved); None = the process
     default backend. The flash kernel compiles for ``tpu`` and runs in
-    interpret mode on ``cpu`` — and only there."""
+    interpret mode on ``cpu`` — and only there.
+
+    ``causal`` adds the lower-triangular mask (query s sees keys t <= s;
+    S must equal T). Only the einsum route has it, as it alone takes a
+    value width other than the q·k width: flash, blockwise and ring refuse
+    either by name rather than compute something else."""
     t = k.shape[2]
     if platform is None:
         platform = jax.default_backend()
     if impl == "auto":
         impl = ("flash" if platform == "tpu" and t >= FLASH_MIN_SEQ
                 else "einsum")
+    if impl != "einsum" and (causal or v.shape[-1] != q.shape[-1]):
+        raise ValueError(
+            f"attention impl={impl!r} has no causal mask and one head width "
+            "for q·k and v; only 'einsum' computes causal attention or a "
+            f"value width ({v.shape[-1]}) other than q·k's ({q.shape[-1]})")
     with jax.named_scope(f"attn_{impl}"):
-        return _attention(q, k, v, key_mask, impl, platform)
+        return _attention(q, k, v, key_mask, impl, platform, causal)
 
 
-def _attention(q, k, v, key_mask, impl: str, platform: str) -> jax.Array:
+def _attention(q, k, v, key_mask, impl: str, platform: str,
+               causal: bool = False) -> jax.Array:
     if impl == "ring":
         ctx = _RING_CTX.get()
         if ctx is None:
@@ -94,16 +106,21 @@ def _attention(q, k, v, key_mask, impl: str, platform: str) -> jax.Array:
     mask = None if key_mask is None else key_mask[:, None, None, :]
     if impl == "blockwise":
         return blockwise_attention(q, k, v, mask=mask)
+    if causal:
+        s = q.shape[2]
+        lower = jnp.tril(jnp.ones((s, s), bool))[None, None]
+        mask = lower if mask is None else mask & lower
     return dot_product_attention(q, k, v, mask)
 
 
 def dot_product_attention(
     q: jax.Array,  # [B, H, S, D]
     k: jax.Array,  # [B, H, T, D]
-    v: jax.Array,  # [B, H, T, D]
+    v: jax.Array,  # [B, H, T, Dv]
     mask: Optional[jax.Array] = None,  # broadcastable to [B, H, S, T]; True = attend
 ) -> jax.Array:
-    """Standard softmax attention; accumulates in fp32 regardless of input dtype."""
+    """Standard softmax attention; accumulates in fp32 regardless of input
+    dtype. The scale is the q·k width's; the value width is its own."""
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bhsd,bhtd->bhst", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale

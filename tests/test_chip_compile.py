@@ -1,0 +1,120 @@
+"""Compiles for a DESCRIBED TPU v5e, no chip attached (the
+on-chip-measurement guide's third rehearsal): the kernels of the
+sparse-expert scorer's main path at the published widths, so that what the
+chip's compiler refuses — a tile that does not fit, a shape a kernel cannot
+take — fails here and costs no chip time. Nothing runs; no time or result
+is read.
+
+The topology is described inside a fixture of THIS file only (one process
+may load the TPU's library; a second file would be skipped in silence on
+another worker). Where it cannot be described the tests skip.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+TOKENS, D, M, HELD, ROUTER, K, VOCAB = 32768, 2048, 768, 16, 128, 6, 16032
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means: not here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one: keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def shape(dims, dtype, sharding):
+    return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+
+def test_the_expert_layers_grouped_matmul_compiles_at_published_widths(
+        one_chip, no_compile_cache):
+    """The chunk walk at the cell's widest bucket (the fit's 32-row train
+    batch is one chunk and never meets this size). About 20 s, nearly all
+    of it the sort's compile."""
+    from detectmateservice_tpu.ops import experts as ops
+
+    def layer(x, experts, weights, gate, up, down):
+        out, counts = ops.routed_experts(
+            x, ops.Routing(experts, weights), gate, up, down)
+        return out, counts
+
+    compiled = jax.jit(layer).lower(
+        shape((TOKENS, D), jnp.bfloat16, one_chip),
+        shape((TOKENS, K), jnp.int32, one_chip),
+        shape((TOKENS, K), jnp.float32, one_chip),
+        shape((HELD, D, M), jnp.float32, one_chip),
+        shape((HELD, D, M), jnp.float32, one_chip),
+        shape((HELD, M, D), jnp.float32, one_chip)).compile()
+    text = compiled.as_text()
+    # the TPU's native grouped matmul, three a live chunk (gate, up, down),
+    # inside the chunk walk
+    assert text.count("ragged-dot") >= 3
+    # a scan over the chunks, the dead ones skipped under a conditional
+    assert "while" in text and "conditional" in text
+    assert ops.chunk_rows_for(TOKENS, K) == TOKENS // 2
+    stats = compiled.memory_analysis()
+    # the sorted list, one chunk's rows and the float32 accumulator: far
+    # under the [N*K, D] buffer (805 MB in bfloat16) a one-shot gather needs
+    assert stats.temp_size_in_bytes < 1 << 30
+
+
+def test_the_router_compiles_in_float32(one_chip, no_compile_cache):
+    from detectmateservice_tpu.ops import experts as ops
+
+    def router(x, w, bias, valid):
+        return ops.route(x, w, bias, valid, top_k=K, norm_topk_prob=True,
+                         scaling=2.448)
+
+    compiled = jax.jit(router).lower(
+        shape((TOKENS, D), jnp.float32, one_chip),
+        shape((D, ROUTER), jnp.float32, one_chip),
+        shape((ROUTER,), jnp.float32, one_chip),
+        shape((TOKENS,), jnp.bool_, one_chip)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_the_fused_head_compiles_at_the_slice_of_the_vocabulary(
+        one_chip, no_compile_cache):
+    """V = 16032 is no multiple of the kernel's 128-lane tile and D = 2048 is
+    eight times ``logbert-256x4``'s: the same ``lse_pallas`` kernel."""
+    from detectmateservice_tpu.ops.scorehead import candidate_lse
+
+    compiled = jax.jit(candidate_lse).lower(
+        shape((TOKENS, D), jnp.bfloat16, one_chip),
+        shape((VOCAB, D), jnp.bfloat16, one_chip)).compile()
+    assert "lse_pallas" in compiled.as_text()
+
+
+def test_head_route_takes_the_kernel_at_the_cells_bucket():
+    from detectmateservice_tpu.models.base import head_route
+
+    assert head_route("auto", "tpu", True, 1024 * 32, VOCAB) == "pallas"
+    assert head_route("auto", "cpu", True, 1024 * 32, VOCAB) == "einsum"
