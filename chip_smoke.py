@@ -484,7 +484,10 @@ def run(profile: dict, work: str) -> dict:
                          if alert_scores else None),
         "batches": {"device_path": by_path["device"],
                     "host_path": by_path["host"],
-                    "device_buckets": device_buckets},
+                    "device_buckets": device_buckets,
+                    # which kernels each traced bucket took (auto's answers)
+                    "head_route": xla["buckets"].get("head_route"),
+                    "attn_route": xla["buckets"].get("attn_route")},
         "featurize_rows": {"native": native_rows, "fallback": fallback_rows},
         "host_twin": device["host_twin"]["state"],
         "mesh": device["mesh"],

@@ -56,9 +56,12 @@ class JaxScorerDetectorConfig(CoreDetectorConfig):
     # ~V/C fewer head FLOPs.
     # Threshold units change with the approximation, so it is fit-frozen.
     score_vocab: int = 0
-    # logbert attention path: "auto" (flash kernel on TPU for long
-    # sequences, fused einsum otherwise) | "einsum" | "flash" | "blockwise"
-    # | "ring" (sequence-parallel over the mesh_shape 'seq' axis)
+    # logbert attention path: "auto" (per traced call, from platform, shape
+    # and mesh size: the flash kernel on a TPU for long sequences, the short
+    # kernel on one TPU for whole sequences up to 128 tokens, einsum
+    # otherwise — ops/attention.py attention_route; GET /admin/xla
+    # buckets.attn_route) | "einsum" | "flash" | "short" | "blockwise" |
+    # "ring" (sequence-parallel over the mesh_shape 'seq' axis)
     attn_impl: str = "auto"
     # scoring-head path: "einsum" = S-chunked einsum + logsumexp over
     # materialized logits; "pallas" = fused online-logsumexp kernel
@@ -590,10 +593,11 @@ class JaxScorerDetector(CoreDetector):
         if cfg.score_norm not in ("none", "position"):
             raise LibraryError(
                 f"unknown score_norm {cfg.score_norm!r}; expected 'none' or 'position'")
-        if cfg.attn_impl not in ("auto", "einsum", "flash", "blockwise", "ring"):
+        if cfg.attn_impl not in ("auto", "einsum", "flash", "short",
+                                 "blockwise", "ring"):
             raise LibraryError(
                 f"unknown attn_impl {cfg.attn_impl!r}; expected 'auto', "
-                "'einsum', 'flash', 'blockwise', or 'ring'")
+                "'einsum', 'flash', 'short', 'blockwise', or 'ring'")
         family = FAMILIES.get(cfg.model)
         if family is None:
             raise LibraryError(f"unknown scorer model {cfg.model!r}; "
@@ -2054,19 +2058,23 @@ class JaxScorerDetector(CoreDetector):
 
     def _bucket_state(self) -> Dict[str, Any]:
         """The ledger's bucket-state provider (GET /admin/xla)."""
+        def routes(record: str) -> Dict[str, str]:
+            return {str(rows): route for rows, route in sorted(
+                dict(getattr(self._scorer, record, {})).items())}
+
         return {
             "coalescing": self.config.batch_deadline_ms > 0,
             "warm": self._active_buckets(),
             "retired": sorted(self._retired_buckets),
-            # which head each traced device executable took (models/base.py
-            # head_route; decided at trace time, per bucket). The host
-            # twin's calls are not in it: it is pinned to einsum
-            "head_route": {str(rows): route for rows, route in sorted(
-                dict(getattr(self._scorer, "head_routes", {})).items())},
-            # which expert path each traced executable took (the
-            # sparse-expert scorer's; empty for a scorer without experts)
-            "expert_route": {str(rows): route for rows, route in sorted(
-                dict(getattr(self._scorer, "expert_routes", {})).items())},
+            # which head (models/base.py head_route), which attention
+            # (ops/attention.py attention_route) and which expert path (the
+            # sparse-expert scorer's) each traced device executable took,
+            # by its rows; decided at trace time, empty where the scorer has
+            # no such part. The host twin's calls are not in it: it is
+            # pinned to einsum
+            "head_route": routes("head_routes"),
+            "attn_route": routes("attn_routes"),
+            "expert_route": routes("expert_routes"),
         }
 
     def batching_stats(self) -> Dict[str, Any]:

@@ -87,11 +87,11 @@ def _moe_mla_refuses(cfg) -> Optional[str]:
 FAMILIES: Dict[str, ScorerFamily] = {
     "mlp": ScorerFamily(_build_mlp, _no_arch, lambda cfg: True),
     "gru": ScorerFamily(_build_gru, _no_arch, lambda cfg: True),
-    # a forced flash kernel would run on the twin in interpret mode and
-    # ring attention is bound to the accelerator mesh: device-only
+    # a forced kernel would run on the twin in interpret mode and ring
+    # attention is bound to the accelerator mesh: device-only
     "logbert": ScorerFamily(
         _build_logbert, _no_arch,
-        lambda cfg: cfg.attn_impl not in ("flash", "ring")),
+        lambda cfg: cfg.attn_impl not in ("flash", "short", "ring")),
     # its scoring call returns counts beside the scores, and a CPU mirror
     # of a model sized for a chip's memory is no latency path
     "moe_mla": ScorerFamily(_build_moe_mla, _moe_mla_refuses,

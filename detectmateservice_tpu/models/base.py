@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..ops.attention import placement
 from .tokenizer import PAD_ID
 
 
@@ -142,6 +143,9 @@ class ScorerBase:
         # head_route's answer per traced call, keyed by the call's batch
         # rows: the engagement record GET /admin/xla serves per bucket
         self.head_routes: Dict[int, str] = {}
+        # the same for the model's attention calls (ops/attention.py
+        # attention_route), written by the calls traced under _apply
+        self.attn_routes: Dict[int, str] = {}
         self.model = self._build_model()
         self.optimizer = optax.adamw(config.learning_rate)
         self._score = jax.jit(self._score_impl)
@@ -172,6 +176,12 @@ class ScorerBase:
         raise NotImplementedError
 
     # -- shared surface -------------------------------------------------
+    def _apply(self, params, *args, **kwargs):
+        """``self.model.apply`` with the attention calls it traces told
+        where they run and where to record the route they took."""
+        with placement(self.mesh_devices, self.attn_routes):
+            return self.model.apply(params, *args, **kwargs)
+
     def _head_route(self, exact: bool, rows: int, vocab: int) -> str:
         """:func:`head_route` for one traced call of this scorer."""
         return head_route(getattr(self.config, "head_impl", "auto"),
@@ -307,7 +317,7 @@ class SequenceScorerBase(ScorerBase):
         v = emb.shape[0]
         if n_cand >= v:
             return self._token_nlls_exact(params, tokens, dtype)
-        hidden = self.model.apply(params, tokens, method="hidden").astype(dtype)
+        hidden = self._apply(params, tokens, method="hidden").astype(dtype)
         with jax.named_scope("head/nll"):
             return self._candidate_head(hidden, emb.astype(dtype), tokens,
                                         dtype, n_cand)
@@ -366,7 +376,7 @@ class SequenceScorerBase(ScorerBase):
         kernel — the [B, Sc, V] logits (the exact path's HBM high-water)
         never materialize; the target logit comes from the equivalent
         direct hidden·emb[token] dot."""
-        hidden = self.model.apply(params, tokens, method="hidden").astype(dtype)
+        hidden = self._apply(params, tokens, method="hidden").astype(dtype)
         emb = self._head_matrix(params).astype(dtype)
         with jax.named_scope("head/nll"):
             return self._exact_head(hidden, emb, tokens)

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from ..ops.attention import attention
+from ..ops.attention import self_attention, self_attention_route
 from .base import SequenceScorerBase, positional_z_max, token_nll  # noqa: F401 — token_nll/positional_z_max re-exported for compat
 from .tokenizer import MASK_ID, PAD_ID
 
@@ -47,8 +47,11 @@ class LogBERTConfig:
     # approximation (models/base.py _token_nlls_candidate): ~V/C fewer head
     # FLOPs
     score_vocab: int = 0
-    # "auto" = pallas flash kernel on TPU for long sequences, fused einsum
-    # otherwise; "einsum" | "flash" | "blockwise" force a path
+    # "auto" = per traced call, from platform, shape and mesh size
+    # (ops/attention.py attention_route): on a TPU the flash kernel for long
+    # sequences and, on one device, the short kernel for whole sequences up
+    # to 128 tokens; einsum otherwise. "einsum" | "flash" | "short" |
+    # "blockwise" | "ring" force a path
     attn_impl: str = "auto"
     # scoring-head implementation: "einsum" = S-chunked einsum + logsumexp
     # over materialized logits; "pallas" = fused online-logsumexp kernel
@@ -71,19 +74,21 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, pad_mask: jax.Array) -> jax.Array:
+        """``x``: ``[B, S, D]``, or token-major ``[B * S, D]`` (what
+        ``LogBERT.hidden`` hands over where the short kernel runs);
+        ``pad_mask`` ``[B, S]`` either way."""
         cfg = self.config
-        head_dim = cfg.dim // cfg.heads
+        b, s = pad_mask.shape
         with jax.named_scope(f"layer{self.layer}/attn"):
             y = nn.LayerNorm(dtype=cfg.dtype)(x)
             qkv = nn.Dense(3 * cfg.dim, dtype=cfg.dtype, name="qkv")(y)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            b, s, _ = q.shape
-            reshape = lambda t: t.reshape(b, s, cfg.heads, head_dim).transpose(0, 2, 1, 3)
-            out = attention(reshape(q), reshape(k), reshape(v),
-                            key_mask=pad_mask, impl=cfg.attn_impl,
-                            platform=cfg.platform or None)
-            out = out.transpose(0, 2, 1, 3).reshape(b, s, cfg.dim)
-            x = x + nn.Dense(cfg.dim, dtype=cfg.dtype, name="proj")(out)
+            # the route decides the layout: the short kernel reads the
+            # projection as it lies, the others get head-major copies
+            out = self_attention(qkv.reshape(b, s, 3 * cfg.dim), cfg.heads,
+                                 key_mask=pad_mask, impl=cfg.attn_impl,
+                                 platform=cfg.platform or None)
+            x = x + nn.Dense(cfg.dim, dtype=cfg.dtype, name="proj")(
+                out.reshape(x.shape))
         with jax.named_scope(f"layer{self.layer}/ffn"):
             y = nn.LayerNorm(dtype=cfg.dtype)(x)
             y = nn.Dense(cfg.dim * cfg.mlp_ratio, dtype=cfg.dtype, name="mlp_in")(y)
@@ -112,13 +117,24 @@ class LogBERT(nn.Module):
         [B, S, V] logits tensor — at V=32k and large micro-batches that
         tensor alone exceeds HBM (models/base.py chunked NLL)."""
         cfg = self.config
+        b, s = tokens.shape
         pad_mask = tokens != PAD_ID
         with jax.named_scope("embed"):
             x = self.tok_embed(tokens) + self.pos_embed[
-                None, : tokens.shape[1]].astype(cfg.dtype)
+                None, :s].astype(cfg.dtype)
+        # Where the short kernel runs, the whole stack runs token-major. A
+        # [b, s, ·] activation is laid out sequence-major by XLA on a TPU
+        # ({2,0,1}: the LayerNorm statistics want b in the lanes), and the
+        # kernel's row-major operands were copied there and back in every
+        # layer: 6.2 ms of a layer's 17 (PERF.md section 6, PR 28). A
+        # [b * s, ·] array has one layout
+        if self_attention_route(b, s, cfg.heads, cfg.dim // cfg.heads,
+                                cfg.attn_impl,
+                                cfg.platform or None) == "short":
+            x = x.reshape(b * s, cfg.dim)
         for blk in self.blocks:
             x = blk(x, pad_mask)
-        return self.final_ln(x).astype(jnp.float32)
+        return self.final_ln(x).astype(jnp.float32).reshape(b, s, cfg.dim)
 
     def __call__(self, tokens: jax.Array) -> jax.Array:
         """[B, S] int32 → [B, S, V] fp32 logits (weight-tied head).
@@ -164,7 +180,7 @@ class LogBERTScorer(SequenceScorerBase):
                 jax.random.uniform(mask_rng, tokens.shape) < cfg.mask_prob
             ) & maskable
             corrupted = jnp.where(mask, MASK_ID, tokens)
-            logits = self.model.apply(p, corrupted)
+            logits = self._apply(p, corrupted)
             return masked_lm_loss(logits, tokens, mask)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)

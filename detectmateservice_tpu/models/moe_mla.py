@@ -393,7 +393,7 @@ class MoEMLAScorer(SequenceScorerBase):
     def _score_impl(self, params, tokens: jax.Array):
         tokens = tokens.astype(jnp.int32)
         dtype = self.config.dtype
-        hidden, counts = self.model.apply(params, tokens,
+        hidden, counts = self._apply(params, tokens,
                                           method="hidden_and_counts")
         b, s = tokens.shape
         a = self.config.arch
@@ -414,7 +414,7 @@ class MoEMLAScorer(SequenceScorerBase):
         tokens = tokens.astype(jnp.int32)
 
         def loss_fn(p):
-            return causal_lm_loss(self.model.apply(p, tokens), tokens)
+            return causal_lm_loss(self._apply(p, tokens), tokens)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         updates, opt_state = self.optimizer.update(grads, opt_state, params)

@@ -2,17 +2,31 @@
 
 TPU-first: batched, bfloat16-friendly einsum attention the MXU tiles well,
 with a numerically stable blockwise variant that is the building block for
-ring attention (parallel/ring.py), and the fused pallas kernel (ops/flash.py)
-for long sequences. ``attention()`` routes between them: below
-``FLASH_MIN_SEQ`` the whole score matrix fits one MXU tile and XLA's fused
-einsum has nothing for a kernel to save,
-above it the pallas kernel avoids materializing the [S, T] logits in HBM.
+ring attention (parallel/ring.py), the fused pallas kernel for long
+sequences (ops/flash.py) and the one for whole short sequences
+(ops/shortattn.py). ``attention()`` (head-major q, k, v) and
+``self_attention()`` (the fused projection, as the models write it) route
+between them by :func:`attention_route`: from ``FLASH_MIN_SEQ`` up the
+flash kernel avoids materializing the [S, T] logits in HBM; for S = T <=
+128 on one TPU the short kernel keeps the ``[rows, heads, S, T]`` float32
+logits in VMEM and takes q, k, v where the projection wrote them.
+
+That second route replaces a belief this file used to state — that below
+``FLASH_MIN_SEQ`` "the whole score matrix fits one MXU tile and XLA's fused
+einsum has nothing for a kernel to save". The trace refuted it: at the
+served shape (32768 rows, 4 heads, S 32, D 64) the einsum route's
+``layer<i>/attn`` scopes took 198.5 ms of a 332.9 ms scoring call, 49.6 ms
+a layer, of which the three arithmetic operations were 22 ms and the rest
+(8, 128)-tile padding of the 32-wide float32 logits, twofold lane padding
+of the 64-wide head-major q, k, v, and materialised transposes (ledger, PR
+27); the chip needs 5.5 ms a layer. What the kernel takes: PERF.md section
+6, PR 28.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +35,97 @@ import jax.numpy as jnp
 # at S=8192); below it the einsum path stays. The crossover is not measured
 # on the attached chip (scripts/bench_flash.py, ROADMAP D10)
 FLASH_MIN_SEQ = 2048
+
+# rows from which ``auto`` takes the short kernel on a TPU: the smallest
+# bucket the served path warms. On the attached v5e the whole ``logbert``
+# scoring call is 1.19x faster there (2.25 against 2.69 ms), 1.27x at 512
+# and 1024 rows, 1.62x at 2048, 1.81x at 4096 and 1.79x at 32768 (186.6
+# against 334.2 ms). Under it the kernel is 3-11% ahead too (0.95 against
+# 0.98 ms at 32 rows, 1.58 against 1.76 at 128), a tenth of a millisecond
+# no line waits for; the boundary fit's 32-row train step keeps the route
+# it has always compiled (scripts/bench_flash.py --buckets; my chip runs,
+# PR 28, call 3; PERF.md section 6)
+SHORT_MIN_ROWS = 256
+
+# (mesh_devices, routes) around a scorer's tracing: how many devices the
+# executor spreads the call over, and the scorer's ``attn_routes`` record
+# (rows -> implementation) the resolved route is written to. Tracing-time
+# only, like the ring context below
+_PLACEMENT: contextvars.ContextVar = contextvars.ContextVar(
+    "dm_attention_placement", default=(1, None))
+
+
+@contextlib.contextmanager
+def placement(mesh_devices: int, routes: Optional[Dict[int, str]] = None):
+    """Tell the attention calls traced under this scope where they run
+    (models/base.py wraps every model application in it)."""
+    token = _PLACEMENT.set((mesh_devices, routes))
+    try:
+        yield
+    finally:
+        _PLACEMENT.reset(token)
+
+
+def attention_route(impl: str, platform: str, s: int, t: int, heads: int,
+                    head_dim: int, value_dim: int, causal: bool, rows: int,
+                    mesh_devices: int = 1) -> str:
+    """Which implementation computes one traced attention call.
+
+    ``impl`` is the model's ``attn_impl``; any name but ``"auto"`` forces.
+    ``"auto"`` decides from what the call can observe — the platform it is
+    placed on, query and key lengths, heads, the q·k and value widths,
+    whether it is causal, its rows, and how many devices the executor
+    spread it over:
+
+    * on a TPU from ``FLASH_MIN_SEQ`` keys up: ``"flash"``, as before;
+    * on ONE TPU, whole short self-attention — S = T <= 128 in whole
+      16-row tiles, equal q·k and value widths in whole-vreg lane groups,
+      no causal mask — from ``SHORT_MIN_ROWS`` rows: ``"short"``
+      (ops/shortattn.py: logits stay in VMEM, no head-major copies);
+    * everything else ``"einsum"``: the CPU (tier-1 tests, the host twin —
+      the kernel would run in the Pallas interpreter), a mesh of more than
+      one device (GSPMD does not partition a Pallas call), a causal mask or
+      a value width of its own (models/moe_mla.py), fewer rows.
+    """
+    if impl != "auto":
+        return impl
+    if platform != "tpu":
+        return "einsum"
+    if t >= FLASH_MIN_SEQ:
+        return "flash"
+    if (mesh_devices == 1 and not causal and s == t and value_dim == head_dim
+            and rows >= SHORT_MIN_ROWS):
+        from .shortattn import fits
+
+        if fits(s, heads, head_dim):
+            return "short"
+    return "einsum"
+
+
+def _resolve(impl: str, platform: Optional[str], q_shape, t: int,
+             value_dim: int, causal: bool, record: bool = True) -> tuple:
+    """(implementation, platform) for a call with head-major query shape
+    ``q_shape``, recorded where a scorer listens."""
+    rows, heads, s, head_dim = q_shape
+    if platform is None:
+        platform = jax.default_backend()
+    mesh_devices, routes = _PLACEMENT.get()
+    impl = attention_route(impl, platform, s, t, heads, head_dim, value_dim,
+                           causal, rows, mesh_devices)
+    if record and routes is not None:
+        routes[rows] = impl
+    return impl, platform
+
+
+def self_attention_route(rows: int, s: int, heads: int, head_dim: int,
+                         impl: str = "auto",
+                         platform: Optional[str] = None) -> str:
+    """The route :func:`self_attention` takes for this shape under the
+    current placement, without a call: a model asks before it decides how
+    to lay out its activations (models/logbert.py)."""
+    return _resolve(impl, platform, (rows, heads, s, head_dim), s, head_dim,
+                    False, record=False)[0]
+
 
 # (mesh, batch_axis, seq_axis) for impl="ring" — set by the execution layer
 # (parallel.ShardedScorer) around tracing so the *model* stays mesh-agnostic:
@@ -52,9 +157,13 @@ def attention(
 ) -> jax.Array:
     """Route to the right attention implementation.
 
-    ``impl``: "auto" (flash on TPU for long sequences, einsum otherwise),
-    "einsum", "flash", "blockwise", or "ring" (sequence-parallel exact
-    attention over the mesh provided via ``ring_context``). The mask here is
+    ``impl``: "auto" (:func:`attention_route`: flash on a TPU for long
+    sequences, the short kernel on one TPU for whole short ones, einsum
+    otherwise), "einsum", "flash", "short", "blockwise", or "ring"
+    (sequence-parallel exact attention over the mesh provided via
+    ``ring_context``). A caller that holds the fused projection calls
+    :func:`self_attention` instead: from this head-major layout the short
+    kernel pays the transposes back. The mask here is
     the scorer's PAD-key form ([B, T]); einsum/blockwise broadcast it, ring
     uses it as per-shard key validity.
 
@@ -67,12 +176,8 @@ def attention(
     S must equal T). Only the einsum route has it, as it alone takes a
     value width other than the q·k width: flash, blockwise and ring refuse
     either by name rather than compute something else."""
-    t = k.shape[2]
-    if platform is None:
-        platform = jax.default_backend()
-    if impl == "auto":
-        impl = ("flash" if platform == "tpu" and t >= FLASH_MIN_SEQ
-                else "einsum")
+    impl, platform = _resolve(impl, platform, q.shape, k.shape[2],
+                              v.shape[-1], causal)
     if impl != "einsum" and (causal or v.shape[-1] != q.shape[-1]):
         raise ValueError(
             f"attention impl={impl!r} has no causal mask and one head width "
@@ -82,8 +187,62 @@ def attention(
         return _attention(q, k, v, key_mask, impl, platform, causal)
 
 
+def self_attention(
+    qkv: jax.Array,  # [B, S, 3 * heads * head_dim]: q | k | v, heads side by side
+    heads: int,
+    key_mask: Optional[jax.Array] = None,  # [B, S] bool; True = attend
+    impl: str = "auto",
+    platform: Optional[str] = None,
+) -> jax.Array:
+    """Self-attention from the fused q·k·v projection's own layout →
+    ``[B, S, heads * head_dim]``, ready for the output projection.
+
+    Same routes and ``impl`` / ``platform`` as :func:`attention`. The short
+    kernel reads ``qkv`` as it lies; every other route gets the q / k / v
+    split and the ``[b, s, h, d] <-> [b, h, s, d]`` transposes it needs."""
+    b, s, width = qkv.shape
+    head_dim = width // 3 // heads
+    impl, platform = _resolve(impl, platform, (b, heads, s, head_dim), s,
+                              head_dim, False)
+    with jax.named_scope(f"attn_{impl}"):
+        if impl == "short":
+            from .shortattn import short_attention
+
+            return short_attention(qkv, key_mask, heads, None,
+                                   platform == "cpu")
+        q, k, v = split_heads(qkv, heads)
+        return merge_heads(_attention(q, k, v, key_mask, impl, platform))
+
+
+def split_heads(qkv: jax.Array, heads: int) -> tuple:
+    """The fused projection ``[B, S, 3 * H * D]`` → head-major q, k, v
+    ``[B, H, S, D]``: the copies every route but the short kernel needs."""
+    b, s, width = qkv.shape
+    return tuple(part.reshape(b, s, heads, width // 3 // heads)
+                 .transpose(0, 2, 1, 3)
+                 for part in jnp.split(qkv, 3, axis=-1))
+
+
+def merge_heads(out: jax.Array) -> jax.Array:
+    """Head-major ``[B, H, S, D]`` → ``[B, S, H * D]`` for the projection."""
+    b, h, s, d = out.shape
+    return out.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
 def _attention(q, k, v, key_mask, impl: str, platform: str,
                causal: bool = False) -> jax.Array:
+    if impl == "short":
+        if q.shape != k.shape:
+            raise ValueError(
+                "attention impl='short' is self-attention over whole short "
+                f"sequences: q {q.shape} and k {k.shape} differ")
+        from .shortattn import short_attention
+
+        b, h, s, d = q.shape
+        qkv = jnp.concatenate([merge_heads(part) for part in (q, k, v)],
+                              axis=-1)
+        out = short_attention(qkv, key_mask, h, None, platform == "cpu")
+        return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
     if impl == "ring":
         ctx = _RING_CTX.get()
         if ctx is None:

@@ -1,11 +1,27 @@
 #!/usr/bin/env python
-"""Benchmark the pallas flash-attention kernel against the einsum path.
+"""Benchmark the pallas attention kernels against the einsum path.
 
-Run on TPU: ``python scripts/bench_flash.py``. Informs the FLASH_MIN_SEQ
-routing constant in ops/attention.py.
+Run on TPU. ``python scripts/bench_flash.py`` times the flash kernel at long
+sequences (informs FLASH_MIN_SEQ in ops/attention.py).
+
+``--short`` times the short-sequence kernel (ops/shortattn.py) at the
+scorers' shape — H 4, S 32, D 64, rows 256 ... 32768 — against the einsum
+route as ``logbert``'s block ran it (q / k / v split, head-major transposes,
+``dot_product_attention``, transpose back): ms a layer's core, and the share
+of the memory floor (q, k, v read and the output written once in bfloat16:
+2.15 GB = 2.6 ms at 32768 rows and 819 GB/s). Each time is the slope between
+a short and a long chain of data-dependent calls inside one jit, so dispatch
+and fetch cancel (scripts/bench_scorehead.py's protocol).
+
+``--buckets`` times the whole ``LogBERTScorer.score`` call at the flagship
+shape per power-of-two bucket, ``attn_impl: einsum`` against ``short``, with
+the largest score difference: the table ``attention_route``'s row threshold
+is set from (PERF.md section 6, PR 28).
 """
 from __future__ import annotations
 
+import json
+import statistics
 import sys
 import time
 from pathlib import Path
@@ -52,5 +68,122 @@ def main() -> None:
               f"speedup {te / tf:4.2f}x  max_err {err:.3e}")
 
 
+_SHORT_CHAIN = 4
+_HBM_BYTES_PER_S = 819e9   # TPU v5e (benchmark/peaks.json)
+
+
+def _chained(fn, k):
+    """``k`` data-dependent calls of ``fn(qkv, mask)`` in one program: each
+    call's mask depends on a probe of the previous result, so none is
+    dropped or merged, and the probe is one element."""
+    def run(qkv, mask):
+        def body(_, carry):
+            mask, acc = carry
+            probe = fn(qkv, mask)[0, 0, 0].astype(jnp.float32)
+            return mask | (probe > 1e30), acc + probe
+        return jax.lax.fori_loop(0, k, body, (mask, jnp.float32(0.0)))[1]
+    return jax.jit(run)
+
+
+def _slope_ms(fn, qkv, mask, chain, repeats=5):
+    def timed(k):
+        run = _chained(fn, k)
+        float(run(qkv, mask))
+        ts = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            float(run(qkv, mask))
+            ts.append((time.perf_counter() - t0) * 1e3)
+        return statistics.median(ts)
+    return (timed(chain) - timed(_SHORT_CHAIN)) / (chain - _SHORT_CHAIN)
+
+
+def bench_short() -> None:
+    """One JSON line per row count: the einsum route against the kernel."""
+    from detectmateservice_tpu.ops.shortattn import (einsum_route,
+                                                     short_attention)
+
+    device = jax.devices()[0]
+    on_tpu = device.platform == "tpu"
+    heads, s, d = 4, 32, 64
+    rng = np.random.default_rng(0)
+    rows = 256
+    while rows <= (32768 if on_tpu else 256):
+        qkv = jnp.asarray(rng.standard_normal((rows, s, 3 * heads * d)),
+                          jnp.bfloat16)
+        lengths = rng.integers(1, s + 1, rows)
+        mask = jnp.asarray(np.arange(s)[None] < lengths[:, None])
+        routes = {
+            "einsum": lambda x, m: einsum_route(x, m, heads),
+            "short": lambda x, m: short_attention(x, m, heads, None,
+                                                  not on_tpu),
+        }
+        ref = routes["einsum"](qkv.astype(jnp.float32), mask)
+        floor_ms = rows * s * heads * d * 2 * 4 / _HBM_BYTES_PER_S * 1e3
+        out = {"rows": rows, "device": device.device_kind,
+               "floor_ms": round(floor_ms, 4)}
+        chain = 36 if rows <= 4096 else 12
+        for name, fn in routes.items():
+            got = jax.jit(fn)(qkv, mask).astype(jnp.float32)
+            out[f"{name}_max_err"] = round(float(jnp.abs(got - ref).max()), 5)
+            ms = _slope_ms(fn, qkv, mask, chain)
+            out[f"{name}_ms"] = round(ms, 4)
+            out[f"{name}_floor_share"] = round(100 * floor_ms / ms, 1)
+        out["speedup"] = round(out["einsum_ms"] / out["short_ms"], 2)
+        print(json.dumps(out), flush=True)
+        rows *= 2
+
+
+def bench_buckets() -> None:
+    """One JSON line per bucket: median ms of the whole scoring call with
+    the einsum attention and with the short kernel (the fused head in
+    both), how far the scores part, and what ``auto`` takes."""
+    from detectmateservice_tpu.models.logbert import (LogBERTConfig,
+                                                      LogBERTScorer)
+
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        sys.exit(f"--buckets times the chip; jax reports {device.platform!r}")
+    einsum = LogBERTScorer(LogBERTConfig(attn_impl="einsum"))
+    short = LogBERTScorer(LogBERTConfig(attn_impl="short"))
+    auto = LogBERTScorer(LogBERTConfig())
+    params = jax.device_put(einsum.init(jax.random.PRNGKey(0))[0], device)
+    cfg = einsum.config
+    rng = np.random.default_rng(0)
+
+    def median_ms(scorer, tokens, repeats):
+        jax.block_until_ready(scorer.score(params, tokens))
+        ts = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(scorer.score(params, tokens))
+            ts.append((time.perf_counter() - t0) * 1e3)
+        return statistics.median(ts)
+
+    rows = 32
+    while rows <= 32768:
+        tokens = rng.integers(1, cfg.vocab_size, (rows, cfg.seq_len))
+        lengths = rng.integers(4, cfg.seq_len + 1, rows)
+        tokens[np.arange(cfg.seq_len)[None] >= lengths[:, None]] = 0  # PAD
+        tokens = jax.device_put(tokens.astype(np.uint16), device)
+        repeats = 5 if rows >= 8192 else 20
+        out = {"bucket": rows, "device": device.device_kind,
+               "einsum_ms": round(median_ms(einsum, tokens, repeats), 3),
+               "short_ms": round(median_ms(short, tokens, repeats), 3)}
+        out["speedup"] = round(out["einsum_ms"] / out["short_ms"], 2)
+        out["max_abs_score_diff"] = float(np.max(np.abs(
+            np.asarray(einsum.score(params, tokens))
+            - np.asarray(short.score(params, tokens)))))
+        jax.eval_shape(auto._score_impl, params, tokens)
+        out["auto"] = auto.attn_routes[rows]
+        print(json.dumps(out), flush=True)
+        rows *= 2
+
+
 if __name__ == "__main__":
-    main()
+    if "--short" in sys.argv[1:]:
+        bench_short()
+    elif "--buckets" in sys.argv[1:]:
+        bench_buckets()
+    else:
+        main()

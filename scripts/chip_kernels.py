@@ -1,4 +1,4 @@
-"""Compile both Pallas kernels on the attached TPU and compare each with its
+"""Compile the Pallas kernels on the attached TPU and compare each with its
 ``jax.numpy`` reference at the shapes the scorers use.
 
 One process, ``interpret=False`` throughout; fails without a TPU. Each check
@@ -10,6 +10,9 @@ that quietly fell back to XLA or to the interpreter cannot pass.
   the 32768-row chunks the einsum head uses), at half of it (16384·32) and
   at ``mlp``'s (N = 16384, D = 128, V = 32768), each with the kernel's own
   time (``kernel_ms``, median of three calls);
+* ``ops/shortattn.short_attention`` at the served shape (32768 rows, S 32,
+  4 heads of 64, bf16) and at 300 rows of S 16, PAD masks with a fully
+  padded line, against ``dot_product_attention`` in float32;
 * ``ops/flash.flash_attention`` forward at S = T = 2048 and 8192, D = 64,
   bf16, with a key mask;
 * the flash backward kernels (dq; dk+dv) at the same shapes.
@@ -81,6 +84,30 @@ def check_candidate_lse(n: int, d: int, v: int, ref_chunk: int) -> dict:
             "finite": bool(np.isfinite(got).all()), "ok": err < 2e-2}
 
 
+def check_short_attention(rows: int, s: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from detectmateservice_tpu.ops.shortattn import (einsum_route,
+                                                     short_attention)
+
+    heads, d = 4, 64
+    kq, kl = jax.random.split(jax.random.PRNGKey(rows + s))
+    qkv = jax.random.normal(kq, (rows, s, 3 * heads * d), jnp.float32
+                            ).astype(jnp.bfloat16)
+    lengths = jax.random.randint(kl, (rows,), 1, s + 1).at[0].set(0)
+    key_mask = jnp.arange(s)[None, :] < lengths[:, None]
+    exe, compile_s = _compiled(
+        lambda x, m: short_attention(x, m, heads, None, False), qkv, key_mask)
+    got = np.asarray(exe(qkv, key_mask), np.float32)
+    want = np.asarray(jax.jit(lambda x, m: einsum_route(
+        x.astype(jnp.float32), m, heads))(qkv, key_mask))
+    err = float(np.max(np.abs(got - want)))
+    return {"compile_s": round(compile_s, 2), "max_abs_err": err,
+            "finite": bool(np.isfinite(got).all()), "ok": err < 3e-2}
+
+
 def _flash_inputs(s: int):
     import jax
     import jax.numpy as jnp
@@ -149,6 +176,10 @@ CHECKS = [
      lambda: check_candidate_lse(16384 * 32, 256, 32768, 16384)),
     ("candidate_lse mlp N=16384 D=128 V=32768",
      lambda: check_candidate_lse(16384, 128, 32768, 16384)),
+    ("short_attention logbert served rows=32768 S=32 H=4 D=64",
+     lambda: check_short_attention(32768, 32)),
+    ("short_attention rows=300 S=16 H=4 D=64",
+     lambda: check_short_attention(300, 16)),
     ("flash forward S=2048", lambda: check_flash_forward(2048)),
     ("flash forward S=8192", lambda: check_flash_forward(8192)),
     ("flash backward S=2048", lambda: check_flash_backward(2048)),

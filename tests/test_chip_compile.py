@@ -1,6 +1,7 @@
 """Compiles for a DESCRIBED TPU v5e, no chip attached (the
 on-chip-measurement guide's third rehearsal): the kernels of the
-sparse-expert scorer's main path at the published widths, so that what the
+sparse-expert scorer's main path at the published widths and ``logbert``'s
+widest scoring program with the short-sequence attention kernel, so that what the
 chip's compiler refuses — a tile that does not fit, a shape a kernel cannot
 take — fails here and costs no chip time. Nothing runs; no time or result
 is read.
@@ -111,6 +112,35 @@ def test_the_fused_head_compiles_at_the_slice_of_the_vocabulary(
         shape((TOKENS, D), jnp.bfloat16, one_chip),
         shape((VOCAB, D), jnp.bfloat16, one_chip)).compile()
     assert "lse_pallas" in compiled.as_text()
+
+
+def test_logberts_widest_scoring_program_compiles_with_the_short_kernel(
+        one_chip, no_compile_cache):
+    """``logbert-256x4``'s 32768-row bucket as ``auto`` routes it on one
+    TPU: four ``attn_short`` kernels and the fused head, no copy of an
+    activation between them (the stack runs token-major), and a scratch
+    under the einsum route's 5,930,632,192 bytes (the padded ``[rows, 4,
+    32, 32]`` float32 logits and the head-major copies are gone;
+    3,265,716,736 when this was written, mostly the feed-forward's
+    hidden)."""
+    from detectmateservice_tpu.models.logbert import (LogBERTConfig,
+                                                      LogBERTScorer)
+
+    scorer = LogBERTScorer(LogBERTConfig(platform="tpu"))
+    params = jax.tree_util.tree_map(
+        lambda leaf: shape(leaf.shape, leaf.dtype, one_chip),
+        jax.eval_shape(lambda: scorer.init(jax.random.PRNGKey(0))[0]))
+    compiled = jax.jit(scorer._score_impl).lower(
+        params, shape((32768, 32), jnp.uint16, one_chip)).compile()
+    assert scorer.attn_routes == {32768: "short"}
+    assert scorer.head_routes == {32768: "pallas"}
+    text = compiled.as_text()
+    assert text.count("attn_short") >= 4 and "lse_pallas" in text
+    copies = [line for line in text.splitlines()
+              if " copy(" in line and "[32768,32,256]" in line
+              or " copy(" in line and "[32768,32,768]" in line]
+    assert not copies, copies[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 3_500_000_000
 
 
 def test_head_route_takes_the_kernel_at_the_cells_bucket():
