@@ -41,9 +41,9 @@ OPENLOOP_SECONDS = float(os.environ.get("DETECTMATE_BENCH_OPENLOOP_SECONDS", "6"
 OPENLOOP_DEADLINE_MS = float(os.environ.get("DETECTMATE_BENCH_DEADLINE_MS", "25"))
 
 
-# Canonical bench scorer configuration — ONE home. scripts/bench_overlap.py
-# and scripts/bench_service.py derive from it, so an A/B or service-path run
-# always measures the configuration the headline bench runs.
+# Canonical bench scorer configuration — ONE home. scripts/bench_service.py
+# derives from it, so a service-path run always measures the configuration
+# the headline bench runs.
 BENCH_SCORER_CONFIG = {
     "method_type": "jax_scorer", "auto_config": False, "model": "mlp",
     "data_use_training": 2048, "train_epochs": 2, "async_fit": False,
@@ -52,13 +52,12 @@ BENCH_SCORER_CONFIG = {
 }
 
 
-def build_bench_detector(workers: int = 0, dtype: str = "auto"):
-    """Construct the headline-bench detector (the knob pair the satellite
-    scripts vary: dispatch-overlap workers and compute dtype)."""
+def build_bench_detector():
+    """Construct the headline-bench detector."""
     from detectmateservice_tpu.library.detectors import JaxScorerDetector
 
-    cfg = dict(BENCH_SCORER_CONFIG, dtype=dtype, upload_workers=workers)
-    return JaxScorerDetector(config={"detectors": {"JaxScorerDetector": cfg}})
+    return JaxScorerDetector(
+        config={"detectors": {"JaxScorerDetector": dict(BENCH_SCORER_CONFIG)}})
 
 
 def make_messages(n: int, anomaly_rate: float = 0.01, seed: int = 0):
@@ -89,9 +88,7 @@ def run(n_bench: int) -> dict:
 
     n_train = BENCH_SCORER_CONFIG["data_use_training"]
     batch = BENCH_SCORER_CONFIG["max_batch"]
-    # upload_workers=1 overlaps device upload/dispatch with the engine
-    # thread's featurize (scripts/bench_overlap.py is the A/B)
-    det = build_bench_detector(workers=1)
+    det = build_bench_detector()
     det.setup_io()
     import jax
 

@@ -453,6 +453,12 @@ class FramedTcpListener:
                 raw_conn, peer = self._listener.accept()
             except OSError:
                 return
+            if self._closed.is_set():
+                # accepted by a listener that close() had already taken
+                # down: refuse before the handshake, or the dialer would
+                # believe it reconnected and write frames nobody reads
+                raw_conn.close()
+                return
             try:
                 conn = self._prepare(raw_conn, True)
             except (ssl.SSLError, OSError, TransportError) as exc:
@@ -462,6 +468,11 @@ class FramedTcpListener:
                 continue
             conn.sock.settimeout(_STEADY_TIMEOUT)
             with self._conns_lock:
+                if self._closed.is_set():
+                    # close() ran during the handshake and has cleared
+                    # _conns: this connection is nobody's to read
+                    conn.close()
+                    return
                 self._conns.append(conn)
             threading.Thread(target=self._reader_loop, args=(conn,), daemon=True,
                              name=f"{self._label}-reader").start()
@@ -540,6 +551,16 @@ class FramedTcpListener:
         if self._closed.is_set():
             return
         self._closed.set()
+        # close() alone leaves the accept thread blocked in accept(): the
+        # syscall holds the socket, so the port stays in LISTEN and the
+        # next redial of a peer is accepted by a listener that is gone —
+        # frames the peer then counts written are lost, and a restarted
+        # listener cannot bind (EADDRINUSE). shutdown() ends the accept
+        # at once (tests/test_chaos.py is the witness)
+        try:
+            self._listener.shutdown(_stdsocket.SHUT_RDWR)
+        except OSError:
+            pass
         try:
             self._listener.close()
         except OSError:

@@ -118,12 +118,6 @@ class JaxScorerDetectorConfig(CoreDetectorConfig):
     # retired.
     bucket_retire_interval_s: float = 0.0
     bucket_retire_min_dispatches: int = 2
-    # overlap host→device upload + jit dispatch with the engine thread's
-    # featurize/drain work: >0 moves the _score_dev call for each batch onto
-    # N background dispatch workers. Output order is unaffected: the
-    # in-flight slot is queued at dispatch-call time, workers only fill it
-    # in. 0 = dispatch inline.
-    upload_workers: int = 0
     # fused native featurization: serialized ParserSchema -> token matrix in
     # one GIL-free C call (wire-format walk + tokenize + crc32 hash), rows
     # sharded over a small pthread pool. On by default whenever the native
@@ -144,23 +138,16 @@ class JaxScorerDetectorConfig(CoreDetectorConfig):
     host_score_max_batch: int = 128
     device: Optional[str] = None      # e.g. "tpu:0"; default = first device
     # multi-chip scale-out (BASELINE config #5): a mesh shape like
-    # {"data": 8} shards batches over all chips via parallel.ShardedScorer
-    # (DP) and params per the Megatron rules when "model" > 1 (TP); XLA
-    # inserts the ICI collectives. None = single device.
+    # {"data": 8} shards batches over all chips (DP) and params per the
+    # Megatron rules when "model" > 1 (TP) — parallel.ShardedScorer, the
+    # device executor's mesh placement; XLA inserts the ICI collectives.
+    # None = single device.
     mesh_shape: Optional[Dict[str, int]] = None
     # model compute dtype: "auto" = each family's default (bfloat16 — the
     # MXU-native format); "float32" is the right choice on CPU-only hosts,
     # where XLA:CPU emulates bf16 in software
     dtype: str = "auto"
     seed: int = 0
-
-
-def _batch_span(name: str, batch_kv: Optional[Dict[str, Any]]):
-    """``device_obs.span`` for a served device batch; nothing for a call
-    that is not one (warm-up, fit, parity: ``batch_kv`` None)."""
-    if batch_kv is None:
-        return device_obs.NULL_SPAN
-    return device_obs.span(name, **batch_kv)
 
 
 def _bucket(n: int, max_batch: int) -> int:
@@ -172,19 +159,15 @@ def _bucket(n: int, max_batch: int) -> int:
 
 
 class _InflightSlot:
-    """One scored (or still-scoring) batch in the in-flight queue.
-
-    ``done`` is set once ``scores`` (device array or host numpy) or
-    ``error`` is populated. Inline dispatch fills the slot before it is
-    appended; the upload worker fills it after — but the slot joins
-    ``_inflight`` at dispatch-call time either way, so output order is the
-    dispatch order regardless of which thread ran the jax calls.
+    """One scored (or still-scoring) batch in the in-flight queue, which
+    it joins in dispatch order: ``scores`` is the device array the scoring
+    call handed back (host numpy from the host twin).
 
     Telemetry fields (engine/device_obs.py batch spans): ``t_enqueue`` is
     dispatch-call time (for a coalesced release, the OLDEST held row's
     arrival — so queue-wait telemetry includes the coalescer hold),
     ``t_release`` the dispatch call itself, ``t_start`` when the scoring
-    call actually began (worker pickup), ``t_issued`` when the upload and
+    call actually began, ``t_issued`` when the upload and
     the scoring call had been issued, ``trace_id`` the flight recorder's
     last completed trace at dispatch — the link from a device batch back to
     PR-1 traces — ``release`` why the coalescer let the batch go
@@ -194,10 +177,9 @@ class _InflightSlot:
     nothing unfinished on the device (its release → call issued is then
     device idle time)."""
 
-    __slots__ = ("scores", "aux", "raws", "real", "error", "done",
-                 "t_enqueue", "t_release", "t_start", "t_issued", "bucket",
-                 "path", "trace_id", "release", "tokens", "seq",
-                 "idle_start")
+    __slots__ = ("scores", "aux", "raws", "real", "t_enqueue", "t_release",
+                 "t_start", "t_issued", "bucket", "path", "trace_id",
+                 "release", "tokens", "seq", "idle_start")
 
     def __init__(self, raws, real: int, bucket: int = 0,
                  path: str = "device", trace_id: Optional[str] = None,
@@ -205,8 +187,6 @@ class _InflightSlot:
                  tokens: Optional[np.ndarray] = None, seq: int = 0,
                  t_enqueue: Optional[float] = None,
                  t_release: Optional[float] = None):
-        import threading
-
         self.scores = None
         # what a score_aux scorer's call returned beside the scores (the
         # sparse-expert scorer's routing counts): read back with them
@@ -219,8 +199,6 @@ class _InflightSlot:
         # the rows that produced it). Memory bound: pipeline_depth slots x
         # bucket x seq_len x 4 bytes, None on untapped detectors.
         self.tokens = tokens
-        self.error: Optional[Exception] = None
-        self.done = threading.Event()
         now = time.monotonic()
         self.t_enqueue = now if t_enqueue is None else t_enqueue
         self.t_release = now if t_release is None else t_release
@@ -451,12 +429,11 @@ class JaxScorerDetector(CoreDetector):
             vocab_size=self.config.vocab_size, seq_len=self.config.seq_len
         )
         self._scorer = None
-        self._sharded = None  # parallel.ShardedScorer when mesh_shape is set
-        self._params = None
-        self._opt_state = None
+        # device_executor.DeviceExecutor once the scorer is built: where the
+        # parameters live, how rows reach them and which compiled program
+        # runs (one device or a mesh: this class never asks which)
+        self._exec = None
         self._rng = None
-        self._device = None
-        self._platform: Optional[str] = None   # the resolved device's platform
         self._threshold: Optional[float] = self.config.score_threshold
         # (mean, std) of the calibration scores, kept so a runtime
         # threshold_sigma reconfigure can recompute the threshold refit-free
@@ -490,15 +467,14 @@ class JaxScorerDetector(CoreDetector):
         # selection, queue-wait vs device-time (one .labels() hash per
         # (path) / (bucket, path), never per batch)
         self._ledger = None
-        self._obs_backend = "unknown"
         self._batch_obs: Dict[str, tuple] = {}
         self._bucket_children: Dict[tuple, Any] = {}
         # adaptive continuous batching (batch_deadline_ms > 0): the
         # coalescer holds rows across calls; the warm/retired sets drive
         # its bucket choice (engine-thread-owned, like _inflight). Every
-        # bucket enters _device_warm through an EXPECTED compile (setup_io
-        # warm-up or _warm_device_bucket), so coalesced dispatch can never
-        # page as an unexpected recompile.
+        # bucket enters _device_warm through an EXPECTED compile that the
+        # executor keeps (setup_io warm-up or _warm_device_bucket), so
+        # coalesced dispatch can never page as an unexpected recompile.
         self._coalescer: Optional[_BatchCoalescer] = None
         # tenant of the CURRENT ingress frame (engine note_tenant seam):
         # coalescer.add segments held rows by it so releases stay
@@ -547,16 +523,11 @@ class JaxScorerDetector(CoreDetector):
         from collections import deque
 
         self._inflight = deque()
-        self._upload_queue = None                      # upload_workers > 0
-        self._upload_threads: List = []
         # self-diagnosis (engine/health.py): the hosting Service sets
         # health_monitor; drained_total is the progress counter behind the
-        # device_inflight_stuck watchdog check, the dispatch heartbeat is
-        # stamped by the upload workers (age gauge only — an idle worker
-        # parked on queue.get is healthy, so no age-based check applies)
+        # device_inflight_stuck watchdog check
         self.health_monitor = None
         self._drained_total = 0
-        self._dispatch_hb = None
         # dmroll (rollout/): the Service-owned RolloutManager attaches a
         # traffic sampler here; the dispatch path offers every dispatched
         # token batch to it, and install_candidate is the
@@ -566,20 +537,11 @@ class JaxScorerDetector(CoreDetector):
         # callback feeding the capacity model; None costs one branch
         self._capacity_tap = None
         self._model_version = 0
-        # dmwarm (PR 17): AOT-compiled executables for the warm bucket set,
-        # keyed (kind, bucket). setup_io lowers+compiles them so the first
-        # dispatch EXECUTES without ever entering the jit tracing/compile
-        # path (jax's .lower().compile() does not seed the jit's own
-        # dispatch cache — the executable must be kept and called).
-        self._aot_exec: Dict[tuple, Any] = {}
-        # weight-only int8 serving (dtype: int8w — models/quant.py):
-        # quantized tree + its jitted score paths; live only after the
+        # weight-only int8 serving (dtype: int8w — models/quant.py): the
+        # executor serves the quantized tree only after the
         # differential-parity gate passes (zero alert-decision flips on the
-        # parity corpus), else the float path keeps serving
+        # parity corpus), else the float tree keeps serving
         self._int8w = False
-        self._qparams = None
-        self._qscore = None
-        self._qnormscore = None
         self._parity_corpus = None
         self._int8_report: Optional[Dict[str, Any]] = None
 
@@ -632,8 +594,8 @@ class JaxScorerDetector(CoreDetector):
         AOT-compile (``lower(...).compile()``) the warm bucket set
         (reference hook role: core.py:209-211 'load models here').
 
-        dmwarm (PR 17): the compiled executables are KEPT in ``_aot_exec``
-        and dispatched directly — jax's AOT compile does not seed the jit's
+        dmwarm (PR 17): the executor KEEPS the compiled executables and
+        dispatches them directly — jax's AOT compile does not seed the jit's
         own cache, so warming-by-discarding would recompile on first
         dispatch. Warm-up wall time is split into the three phases
         ``scorer_warmup_seconds{phase=device_put|aot|cache_load}``, and the
@@ -668,7 +630,13 @@ class JaxScorerDetector(CoreDetector):
         # shared persistent compilation cache — compile_cache_dir —
         # amortizes restarts, not first boot)
         position = self.config.score_norm == "position" and self._norm_mu is None
-        dummy_stats = np.ones(self.config.seq_len, np.float32)
+        # the serving kernel: score when raw NLL serves, normscore (over
+        # dummy statistics) when position normalization will
+        kind, extra = "score", ()
+        if position:
+            kind, extra = "normscore", (
+                np.zeros(self.config.seq_len, np.float32),
+                np.ones(self.config.seq_len, np.float32))
         # small buckets are only ever scored on-device when the host path is
         # off; with it on, warming them would waste two accelerator compiles
         # (the host twin warms its own buckets at fit time)
@@ -681,22 +649,19 @@ class JaxScorerDetector(CoreDetector):
         # compiled was invalidated). First touch of a bucket OUTSIDE the
         # warm set is planned growth and pre-warms expected instead
         # (_warm_device_bucket) on both the adaptive and legacy paths.
-        with self._ledger.context(where="warmup", backend=self._obs_backend,
+        with self._ledger.context(where="warmup", backend=self._exec.backend,
                                   expected=True):
             for b in (*small, self.config.train_batch_size, self.config.max_batch):
                 bucket = _bucket(b, self.config.max_batch)
-                tokens = np.zeros((bucket, self.config.seq_len), np.int32)
                 self._device_warm.add(bucket)  # the coalescer's seed warm set
                 with self._ledger.context(bucket=bucket):
-                    self._aot_warm_bucket(bucket, tokens, position,
-                                          dummy_stats)
+                    self._exec.warm(kind, bucket, *extra)
             if position:
                 # fit's calibration pass runs token_nlls at the train bucket
                 bucket = _bucket(self.config.train_batch_size,
                                  self.config.max_batch)
-                tokens = np.zeros((bucket, self.config.seq_len), np.int32)
                 with self._ledger.context(bucket=bucket):
-                    self._aot_warm_kind("token_nlls", bucket, tokens)
+                    self._exec.warm("token_nlls", bucket)
         self._ledger.mark_warmup_complete()
         # the cache_load share of the warm-up is the persistent-cache
         # deserialization time jax reported; the rest of the wall is real
@@ -705,30 +670,6 @@ class JaxScorerDetector(CoreDetector):
         wall = _time.monotonic() - t_warm
         self._ledger.record_warmup_phase("cache_load", cache_load)
         self._ledger.record_warmup_phase("aot", max(0.0, wall - cache_load))
-
-    def _aot_warm_bucket(self, bucket: int, tokens: np.ndarray,
-                         position: bool, dummy_stats: np.ndarray) -> None:
-        """AOT-compile the serving kernel for one bucket (score when raw
-        NLL serves, normscore when position normalization will)."""
-        if position:
-            mu, sigma = np.zeros_like(dummy_stats), dummy_stats
-            self._aot_warm_kind("normscore", bucket, tokens, mu, sigma)
-        else:
-            self._aot_warm_kind("score", bucket, tokens)
-
-    def _aot_warm_kind(self, kind: str, bucket: int, tokens: np.ndarray,
-                       *extra) -> None:
-        """Lower+compile one (kind, bucket) executable into ``_aot_exec``
-        (mesh mode delegates to the sharded scorer's own AOT map)."""
-        if self._sharded is not None:
-            self._sharded.aot_compile_bucket(kind, tokens, *extra)
-            return
-        jit_fn = {"score": self._scorer._score,
-                  "normscore": self._scorer._normscore,
-                  "token_nlls": self._scorer._token_nlls}[kind]
-        # dmlint: ignore[DM-L001] init/warm-up phase; params are live
-        args = (self._params, self._put(tokens), *extra)
-        self._aot_exec[(kind, bucket)] = jit_fn.lower(*args).compile()
 
     def warm_set_spec(self) -> Dict[str, Any]:
         """The AOT warm bucket set as a persistable spec. The rollout
@@ -765,60 +706,41 @@ class JaxScorerDetector(CoreDetector):
         self._validate_static_config()
         import jax.numpy as jnp
 
-        # placement first: kernel routing (compiled vs interpret-mode
-        # Pallas, flash vs einsum) follows the device the scorer runs on,
-        # never the global device list
-        mesh = None
-        if cfg.mesh_shape:
-            from ...parallel.mesh import make_mesh
+        from .device_executor import DeviceExecutor
 
-            mesh = make_mesh(dict(cfg.mesh_shape))
-            self._platform = mesh.devices.flat[0].platform
-        else:
-            self._device = self._resolve_device(cfg.device)
-            self._platform = self._device.platform
-        model_kw = {"platform": self._platform}
         self._int8w = cfg.dtype == "int8w"
-        if self._int8w:
-            # weight-only int8 (models/quant.py): weights live as int8 +
-            # per-channel scales and dequantize INSIDE the jitted impls;
-            # activations use the platform's fast float — bf16 on
-            # accelerators, f32 on CPU-sim (XLA:CPU runs bf16 GEMMs at f32
-            # speed, so the int8 win there is pure weight streaming)
-            model_kw["dtype"] = (jnp.float32 if self._platform == "cpu"
-                                 else jnp.bfloat16)
-        elif cfg.dtype and cfg.dtype != "auto":
-            model_kw["dtype"] = jnp.dtype(cfg.dtype).type
-        try:
-            self._scorer = FAMILIES[cfg.model].build(cfg, model_kw)
-        except ValueError as exc:   # a shape the family refuses, by name
-            raise LibraryError(f"scorer model {cfg.model!r}: {exc}") from exc
-        self._rng = jax.random.PRNGKey(cfg.seed)
-        if mesh is not None:
-            # multi-chip: batches shard over the mesh's data axis, params per
-            # the model rules; ShardedScorer owns the (sharded) params
-            from ...parallel.sharded import ShardedScorer
 
-            self._sharded = ShardedScorer(self._scorer, mesh=mesh, rng=self._rng)
-            self._device = f"mesh({','.join(f'{k}={v}' for k, v in mesh.shape.items())})"
-            self._obs_backend = "mesh"
-            device_obs.export_hbm_gauges(self._obs_labels())
-            self._bind_boundary_counters()
-            if cfg.host_score_max_batch > 0:
-                self._host_twin_state = "unsupported"  # mesh owns the params
-            return
-        self._obs_backend = self._platform
+        def build_scorer(platform: str):
+            model_kw = {"platform": platform}
+            if self._int8w:
+                # weight-only int8 (models/quant.py): weights live as int8 +
+                # per-channel scales and dequantize INSIDE the jitted impls;
+                # activations use the platform's fast float — bf16 on
+                # accelerators, f32 on CPU-sim (XLA:CPU runs bf16 GEMMs at
+                # f32 speed, so the int8 win there is pure weight streaming)
+                model_kw["dtype"] = (jnp.float32 if platform == "cpu"
+                                     else jnp.bfloat16)
+            elif cfg.dtype and cfg.dtype != "auto":
+                model_kw["dtype"] = jnp.dtype(cfg.dtype).type
+            try:
+                return FAMILIES[cfg.model].build(cfg, model_kw)
+            except ValueError as exc:   # a shape the family refuses, by name
+                raise LibraryError(
+                    f"scorer model {cfg.model!r}: {exc}") from exc
+
+        self._rng = jax.random.PRNGKey(cfg.seed)
+        # construction-time, before any other thread can exist
+        self._exec = DeviceExecutor.open(build_scorer, self._rng,
+                                         mesh_shape=cfg.mesh_shape,
+                                         device=cfg.device)
+        self._scorer = self._exec.scorer
         device_obs.export_hbm_gauges(self._obs_labels())
         self._bind_boundary_counters()
-        params, opt_state = self._scorer.init(self._rng)
-        # params pinned in device memory once (HBM residency; north-star
-        # item); construction-time, before any other thread can exist:
-        # dmlint: ignore[DM-L001] init-only write
-        self._params = jax.device_put(params, self._device)
-        # dmlint: ignore[DM-L001] init-only write
-        self._opt_state = jax.device_put(opt_state, self._device)
         if cfg.host_score_max_batch > 0:
-            self._build_host_twin()
+            if self._exec.forkable:
+                self._build_host_twin()
+            else:
+                self._host_twin_state = "unsupported"  # mesh owns the params
 
     def _build_host_twin(self) -> None:
         """Jit the CPU twin of the scorer (params mirror at fit). Why it is
@@ -835,21 +757,21 @@ class JaxScorerDetector(CoreDetector):
             self._host_twin_state = "unsupported"
             log.info("host twin unsupported for model=%r attn_impl=%r: every "
                      "batch scores on %s", cfg.model, cfg.attn_impl,
-                     self._device)
+                     self._exec.label)
             return
         try:
             self._cpu_device = jax.devices("cpu")[0]
         except RuntimeError as exc:
             self._host_twin_state = f"failed: no CPU backend ({exc})"
             log.warning("host twin %s: every batch scores on %s",
-                        self._host_twin_state, self._device)
+                        self._host_twin_state, self._exec.label)
             return
         # the twin shares PARAMS with the device scorer but routes its
         # kernels for the CPU it runs on, and never through the pallas head:
         # in interpret mode per lone message that would be exactly the
         # latency path the twin exists to make fast
         host_scorer = self._scorer
-        if self._platform != "cpu" or cfg.head_impl == "pallas":
+        if self._exec.platform != "cpu" or cfg.head_impl == "pallas":
             host_scorer = type(self._scorer)(dataclasses.replace(
                 self._scorer.config, platform="cpu", head_impl="einsum"))
         # the twin must share the candidate subset too: a restored
@@ -862,27 +784,6 @@ class JaxScorerDetector(CoreDetector):
         self._host_normscore = jax.jit(host_scorer._normscore_impl)
         self._host_twin_state = "pending"
 
-    @staticmethod
-    def _resolve_device(spec: Optional[str]):
-        """``device: "<platform>:<id>"`` → that jax device; None = the first
-        device of the resolved backend. A spec that names no device raises:
-        a replica told to take chip 2 must not land on chip 0."""
-        import jax
-
-        if not spec:
-            return jax.devices()[0]
-        platform, _, index = spec.partition(":")
-        try:
-            want = int(index or 0)
-            for device in jax.devices(platform.lower()):
-                if device.id == want:
-                    return device
-        except (RuntimeError, ValueError) as exc:
-            raise LibraryError(f"device {spec!r}: {exc}") from exc
-        raise LibraryError(
-            f"device {spec!r} names no device of this process "
-            f"(expected '<platform>:<id>', e.g. 'tpu:0')")
-
     def device_info(self) -> Dict[str, Any]:
         """Where this scorer runs and which helper paths are live — the
         ``device`` block of ``GET /admin/xla``, so a jax-free parent (the
@@ -892,12 +793,7 @@ class JaxScorerDetector(CoreDetector):
         from ...utils.backend import requested_platform
         from ...utils.profiling import persistent_cache_dir
 
-        if self._sharded is not None:
-            devices = list(self._sharded.mesh.devices.flat)
-        elif self._device is not None:
-            devices = [self._device]
-        else:
-            devices = []
+        devices = self._exec.devices if self._exec is not None else []
         cfg = self.config
         return {
             "scorer": {
@@ -911,12 +807,12 @@ class JaxScorerDetector(CoreDetector):
                 "trained_rows": self._trained, "fitted": self._fitted,
             },
             "backend_requested": requested_platform(),
-            "platform": self._platform,
+            "platform": devices[0].platform if devices else None,
             "device_kind": devices[0].device_kind if devices else None,
             "device_count": len(jax.devices()),
             "scorer_devices": [str(d) for d in devices],
-            "mesh": (dict(self._sharded.mesh.shape)
-                     if self._sharded is not None else None),
+            "mesh": (self._exec.mesh_shape
+                     if self._exec is not None else None),
             "host_twin": {"state": self._host_twin_state,
                           "max_batch": cfg.host_score_max_batch,
                           "warm_buckets": sorted(self._host_warm)},
@@ -935,9 +831,7 @@ class JaxScorerDetector(CoreDetector):
     def _sync_host_params(self) -> None:
         """Mirror the current params onto the host CPU backend (one transfer,
         after fit / checkpoint load) so small batches can score locally."""
-        # callers (fit, checkpoint load, candidate install) serialize:
-        # dmlint: ignore[DM-L001] ref-atomic reads
-        if self._cpu_device is None or self._params is None:
+        if self._cpu_device is None:
             return
         import jax
         import logging
@@ -951,7 +845,8 @@ class JaxScorerDetector(CoreDetector):
         cap = self.config.host_score_max_batch
         try:
             # dmlint: ignore[DM-L001] ref-atomic mirror write
-            self._host_params = jax.device_put(self._params, self._cpu_device)
+            self._host_params = jax.device_put(self._exec.params,
+                                               self._cpu_device)
             with self._ledger.context(bucket=1, backend="cpu",
                                       where="host_warm", expected=True):
                 jax.block_until_ready(self._score_host(
@@ -961,7 +856,7 @@ class JaxScorerDetector(CoreDetector):
             self._host_params = None
             self._host_twin_state = f"failed: {type(exc).__name__}: {exc}"
             log.exception("host twin failed to mirror/compile; every batch "
-                          "scores on %s", self._device)
+                          "scores on %s", self._exec.label)
             return
         self._host_twin_state = "ready"
 
@@ -995,120 +890,33 @@ class JaxScorerDetector(CoreDetector):
             target=_warm_rest, daemon=False, name="HostBucketWarm")
         self._host_warm_thread.start()
 
-    def _put(self, array: np.ndarray):
-        """Upload a token batch in the narrow wire format (models.tokenizer
-        narrow_tokens has the rule; the jitted impls cast back on device)."""
-        import jax
-
-        from ...models.tokenizer import narrow_tokens
-
-        return jax.device_put(narrow_tokens(array, self.config.vocab_size),
-                              self._device)
+    def _serving(self) -> tuple:
+        """The scoring kind that serves now, with its arguments after the
+        batch: per-position normalization once calibrated (fit)."""
+        if self._norm_mu is not None:
+            return "normscore", (self._norm_mu, self._norm_sigma)
+        return "score", ()
 
     def _score_dev(self, tokens: np.ndarray,
                    batch_kv: Optional[Dict[str, Any]] = None,
-                   slot: Optional["_InflightSlot"] = None):
-        """Dispatch scoring for [n, S] tokens; returns the device array
-        without forcing readback (single device or sharded mesh). Applies
-        per-position normalization once calibrated (fit). Routing order:
-        the int8 quantized path when live (parity-gated), then the bucket's
-        AOT executable — a bucket that has one ALWAYS runs it: an argument
-        it rejects (dtype, sharding, committed device) raises instead of
-        quietly retracing — then, for buckets outside the AOT set, the jit
-        (whose compile the ledger sees).
-
-        ``batch_kv`` (a served device batch: ``_InflightSlot.span_kv``)
-        marks the upload as ``dm.upload`` and the call, with the start of
-        the asynchronous readback, as ``dm.call``; warm-up, fit and parity
-        calls pass none and leave no span. On a mesh the sharded scorer
-        places its own shards, so the whole of it is ``dm.call``. Where the
-        scorer's call returns counts beside the scores (``score_aux``) they
-        go to ``slot.aux`` — read back with the scores at the drain — and
-        nowhere when no slot is given."""
-        if self._sharded is None:
-            with _batch_span("dm.upload", batch_kv):
-                tokens = self._put(tokens)
-        with _batch_span("dm.call", batch_kv):
-            scores = self._call_dev(tokens)
-            aux = None
-            if isinstance(scores, (tuple, list)):
-                scores, aux = scores
-            if slot is not None:
-                slot.aux = aux
-            if batch_kv is not None:
-                try:
-                    scores.copy_to_host_async()
-                    if aux is not None:
-                        aux.copy_to_host_async()
-                except AttributeError:
-                    pass
+                   slot: Optional["_InflightSlot"] = None, params=None):
+        """Dispatch scoring for [n, S] tokens through the device executor
+        (``DeviceExecutor.run`` has the routing and the spans a served
+        batch's ``batch_kv`` leaves); returns the device array without
+        forcing readback. ``params`` scores a candidate's placed tree in
+        place of the live one, under the live position-norm calibration, so
+        live and candidate scores stay in one unit. Where the scorer's call
+        returns counts beside the scores (``score_aux``) they go to
+        ``slot.aux`` — read back with the scores at the drain — and nowhere
+        when no slot is given."""
+        kind, extra = self._serving()
+        scores, aux = self._exec.run(kind, tokens, *extra, params=params,
+                                     batch_kv=batch_kv)
+        if slot is not None:
+            slot.aux = aux
         return scores
 
-    def _call_dev(self, tokens):
-        """The scoring call itself, on tokens already placed (host rows on
-        a mesh: the sharded scorer places them)."""
-        if self._norm_mu is not None:
-            if self._sharded is not None:
-                return self._sharded.normscore_device(
-                    tokens, self._norm_mu, self._norm_sigma)
-            # dmlint: ignore[DM-L001] ref-atomic q-tree swap
-            if self._qparams is not None:
-                return self._qnormscore(self._qparams, tokens,
-                                        self._norm_mu, self._norm_sigma)
-            comp = self._aot_exec.get(("normscore", len(tokens)))
-            if comp is not None:
-                # dmlint: ignore[DM-L001] ref-atomic param swap
-                return comp(self._params, tokens,
-                            self._norm_mu, self._norm_sigma)
-            return self._scorer._normscore(
-                self._params, tokens, self._norm_mu, self._norm_sigma)
-        if self._sharded is not None:
-            return self._sharded.score_device(tokens)
-        # dmlint: ignore[DM-L001] ref-atomic q-tree swap
-        if self._qparams is not None:
-            return self._qscore(self._qparams, tokens)
-        comp = self._aot_exec.get(("score", len(tokens)))
-        if comp is not None:
-            # dmlint: ignore[DM-L001] ref-atomic param swap
-            return comp(self._params, tokens)
-        # dmlint: ignore[DM-L001] ref-atomic param swap; either generation
-        return self._scorer._score(self._params, tokens)
-
-    def _token_nlls_dev(self, tokens: np.ndarray):
-        if self._sharded is not None:
-            return self._sharded.token_nlls_device(tokens)
-        comp = self._aot_exec.get(("token_nlls", len(tokens)))
-        if comp is not None:
-            # dmlint: ignore[DM-L001] ref-atomic param swap
-            return comp(self._params, self._put(tokens))
-        # dmlint: ignore[DM-L001] ref-atomic param swap; either generation
-        return self._scorer._token_nlls(self._params, self._put(tokens))
-
     # -- weight-only int8 serving (dtype: int8w — models/quant.py) -------
-    def _build_qjits(self) -> None:
-        """Jit the quantized-serving twins once: the same model impls over
-        ``dequantize_tree`` — XLA fuses the int8→float dequant into the
-        weight read, so the GEMMs stream 4× fewer weight bytes."""
-        if self._qscore is not None:
-            return
-        import jax
-
-        from ...models.quant import dequantize_tree
-
-        scorer = self._scorer
-        compute_dtype = scorer.config.dtype
-
-        def _qscore_impl(qparams, tokens):
-            return scorer._score_impl(
-                dequantize_tree(qparams, compute_dtype), tokens)
-
-        def _qnormscore_impl(qparams, tokens, mu, sigma):
-            return scorer._normscore_impl(
-                dequantize_tree(qparams, compute_dtype), tokens, mu, sigma)
-
-        self._qscore = jax.jit(_qscore_impl)
-        self._qnormscore = jax.jit(_qnormscore_impl)
-
     def _parity_scores(self, tokens: np.ndarray) -> np.ndarray:
         """Served-path scores for the parity corpus, chunked on the (warm)
         train bucket so the differential run never grows the compile set."""
@@ -1131,35 +939,24 @@ class JaxScorerDetector(CoreDetector):
         INSTALL time) and cut the serving path over — gated on differential
         parity: the quantized path must flip ZERO alert decisions on the
         parity corpus vs the float path, or the float path stays live."""
-        import jax
-
         from ...models import quant
 
-        cfg = self.config
         report: Dict[str, Any] = {"activated": False, "where": where,
                                   "rows": 0, "flips": 0, "flip_ratio": 0.0}
         threshold = (float(self._threshold)
                      if self._threshold is not None else float("inf"))
         corpus = self._parity_corpus
         with self._ledger.context(where="int8_install",
-                                  backend=self._obs_backend, expected=True):
+                                  backend=self._exec.backend, expected=True):
             # install paths serialize: the fit thread is joined before an
             # install and the manager thread owns every promote
-            params = (self._sharded.params if self._sharded is not None
-                      # dmlint: ignore[DM-L001] install-path serialized read
-                      else self._params)
-            qparams = quant.quantize_tree(params)
+            qparams = quant.quantize_tree(self._exec.params)
             float_scores = None
             if corpus is not None and len(corpus):
                 float_scores = self._parity_scores(
                     np.asarray(corpus, np.int32))
             # tentative install, then judge the q path on the same corpus
-            if self._sharded is not None:
-                self._sharded.install_quantized(qparams)
-            else:
-                self._build_qjits()
-                # dmlint: ignore[DM-L001] ref-atomic q-tree swap
-                self._qparams = jax.device_put(qparams, self._device)
+            self._exec.install_quantized(qparams)
             ok = True
             if float_scores is not None:
                 q_scores = self._parity_scores(np.asarray(corpus, np.int32))
@@ -1171,18 +968,15 @@ class JaxScorerDetector(CoreDetector):
                 ok = flips == 0
             if not ok:
                 # parity broke: the quantized tree never serves
-                if self._sharded is not None:
-                    self._sharded.clear_quantized()
-                else:
-                    self._qparams = None
+                self._exec.clear_quantized()
             else:
                 # parity held (or no corpus yet — a restored process before
                 # its first fit): warm every warm bucket through the q path
                 # so the dispatch path stays compile-free
+                kind, extra = self._serving()
                 for b in sorted(self._device_warm):
-                    tokens = np.zeros((b, cfg.seq_len), np.int32)
                     with self._ledger.context(bucket=b):
-                        jax.block_until_ready(self._score_dev(tokens))
+                        self._exec.warm(kind, b, *extra)
                 report["activated"] = True
                 report["gated"] = float_scores is not None
                 report["bytes"] = quant.quant_stats(qparams)
@@ -1207,7 +1001,8 @@ class JaxScorerDetector(CoreDetector):
             if real < bucket:
                 chunk = np.concatenate(
                     [chunk, np.zeros((bucket - real,) + chunk.shape[1:], chunk.dtype)])
-            chunks.append(np.asarray(self._token_nlls_dev(chunk))[:real])
+            chunks.append(np.asarray(
+                self._exec.run("token_nlls", chunk)[0])[:real])
         nlls = np.concatenate(chunks)[: len(data)]
         mask = (data != PAD_ID).astype(np.float32)
         cnt = np.maximum(mask.sum(0), 1.0)
@@ -1223,19 +1018,6 @@ class JaxScorerDetector(CoreDetector):
         zmax = z.max(-1)
         # match positional_z_max: only all-PAD (-inf) rows become 0
         return np.where(np.isneginf(zmax), 0.0, zmax).astype(np.float32)
-
-    def _train_step(self, step_rng, batch: np.ndarray) -> float:
-        if self._sharded is not None:
-            return self._sharded.train_step(step_rng, batch)
-        # the boundary fit owns these trees until _finish_fit hands off
-        # (install_candidate joins the fit before swapping), so both are
-        # given up to the step and rebound at once: a step holds one
-        # generation of parameters and moments, not two
-        # dmlint: ignore[DM-L001] single-writer fit phase
-        self._params, self._opt_state, loss_arr = self._scorer.train_step(
-            self._params, self._opt_state, step_rng, self._put(batch),
-            donate=True)
-        return float(loss_arr)
 
     # -- featurization (CPU side) ---------------------------------------
     def featurize(self, input_: ParserSchema) -> np.ndarray:
@@ -1258,7 +1040,7 @@ class JaxScorerDetector(CoreDetector):
         # the boundary fit legitimately compiles (train step, calibration
         # buckets) after warm-up — attributed here so it never counts as an
         # unexpected recompile
-        with self._ledger.context(where="fit", backend=self._obs_backend,
+        with self._ledger.context(where="fit", backend=self._exec.backend,
                                   expected=True):
             return self._fit_impl()
 
@@ -1277,10 +1059,7 @@ class JaxScorerDetector(CoreDetector):
             # training updates the FLOAT tree; the previous generation's
             # quantized tree must not serve (or calibrate) stale scores
             # mid-fit — _activate_int8 re-quantizes at the end
-            # dmlint: ignore[DM-L001] ref-atomic q-tree clear
-            self._qparams = None
-            if self._sharded is not None:
-                self._sharded.clear_quantized()
+            self._exec.clear_quantized()
         bs = min(cfg.train_batch_size, len(data))
         loss = float("nan")
         rng = np.random.default_rng(cfg.seed)
@@ -1301,7 +1080,9 @@ class JaxScorerDetector(CoreDetector):
             for start in range(0, len(train_data) - bs + 1, bs):
                 batch = train_data[order[start:start + bs]]
                 self._rng, step_rng = jax.random.split(self._rng)
-                loss = self._train_step(step_rng, batch)
+                # the boundary fit owns the live trees until _finish_fit
+                # hands off (install_candidate joins the fit before swapping)
+                loss = self._exec.train_step(step_rng, batch)
         if cfg.score_norm == "position":
             # calibrate BEFORE thresholding so the threshold is in z units;
             # the returned z-max scores reuse the same forward pass
@@ -1350,7 +1131,7 @@ class JaxScorerDetector(CoreDetector):
             # stay expected — the storm detector watches the batched
             # dispatch path, not per-message scoring
             with self._ledger.context(bucket=bucket, where="detect",
-                                      backend=self._obs_backend,
+                                      backend=self._exec.backend,
                                       expected=True):
                 scores = np.asarray(self._score_dev(chunk))
             out[start:start + min(bucket, n - start)] = scores[: min(bucket, n - start)]
@@ -1624,9 +1405,7 @@ class JaxScorerDetector(CoreDetector):
         """True when the oldest in-flight batch's scores are host-readable
         without blocking (host-path numpy results always are)."""
         slot = self._inflight[0]
-        if not slot.done.is_set():
-            return False  # a worker still owns the dispatch call
-        if slot.error is not None or isinstance(slot.scores, np.ndarray):
+        if isinstance(slot.scores, np.ndarray):
             return True
         is_ready = getattr(slot.scores, "is_ready", None)
         if callable(is_ready):
@@ -1782,7 +1561,8 @@ class JaxScorerDetector(CoreDetector):
                   route: Optional[tuple] = None,
                   seq: Optional[int] = None,
                   t_release: Optional[float] = None) -> None:
-        """Asynchronously score [n, S] tokens, padded to a compile bucket.
+        """Asynchronously score [n, S] tokens, padded to a compile bucket:
+        issued here, on the caller's thread, and drained later.
 
         Small batches score synchronously on the CPU twin instead
         (``_route``), skipping the upload and readback. The host result
@@ -1820,15 +1600,11 @@ class JaxScorerDetector(CoreDetector):
             with self._ledger.context(bucket=bucket, backend="cpu",
                                       where="host", expected=False):
                 slot.scores = np.asarray(self._score_host(chunk))[:n]
-            slot.done.set()
             # synchronous path: scores are host-readable now — record
             # the span/occupancy here, not at drain
             self._observe_batch(slot, time.monotonic() - slot.t_start)
             self._inflight.append(slot)
             return
-        use_workers = self.config.upload_workers > 0
-        if use_workers:
-            self._ensure_upload_workers()
         for start in range(0, n, bucket):
             chunk = tokens[start:start + bucket]
             real = len(chunk)
@@ -1852,23 +1628,16 @@ class JaxScorerDetector(CoreDetector):
                     slot.t_release, self._release_at())
             self._dev_unfinished += 1
             self._inflight.append(slot)
-            if use_workers:
-                self._upload_queue.put((slot, chunk))
-            else:
-                # inline: fill before returning; dispatch errors propagate
-                # to the caller exactly as before
-                try:
-                    slot.scores = self._issue(slot, chunk)
-                finally:
-                    slot.done.set()
+            # a dispatch error propagates to the caller
+            slot.scores = self._issue(slot, chunk)
 
     def _issue(self, slot: "_InflightSlot", chunk: np.ndarray):
         """Upload, scoring call and the start of the readback for one
-        device batch (dispatch worker, or the engine thread inline)."""
+        device batch, on the engine thread."""
         slot.t_start = time.monotonic()  # queue wait ends here
         try:
             with self._ledger.context(bucket=slot.bucket,
-                                      backend=self._obs_backend,
+                                      backend=self._exec.backend,
                                       where="dispatch", expected=False):
                 return self._score_dev(chunk, slot.span_kv(), slot)
         finally:
@@ -2007,17 +1776,12 @@ class JaxScorerDetector(CoreDetector):
         EXPECTED compile (where="bucket_warm"): neither adaptive warm-set
         growth nor post-retirement resurrection may page as a recompile
         storm. The compile stalls this one release (like any planned warm),
-        and every later dispatch on the bucket is cache-hot."""
+        and every later dispatch on the bucket runs the kept executable."""
         self._ensure_scorer()
-        import jax
-
-        tokens = np.zeros((bucket, self.config.seq_len), np.int32)
-        with self._ledger.context(bucket=bucket, backend=self._obs_backend,
+        kind, extra = self._serving()
+        with self._ledger.context(bucket=bucket, backend=self._exec.backend,
                                   where="bucket_warm", expected=True):
-            if self._sharded is not None:
-                self._sharded.warm_bucket(tokens)
-            else:
-                jax.block_until_ready(self._score_dev(tokens))
+            self._exec.warm(kind, bucket, *extra)
         self._device_warm.add(bucket)
 
     def _maybe_retire_buckets(self, now: float) -> None:
@@ -2121,44 +1885,6 @@ class JaxScorerDetector(CoreDetector):
             self._release_children[reason] = child
         child.inc()
 
-    def _ensure_upload_workers(self) -> None:
-        if self._upload_threads and all(t.is_alive() for t in self._upload_threads):
-            return
-        import queue as _queue
-        import threading
-
-        if self._upload_queue is None:
-            self._upload_queue = _queue.Queue()
-        if self._dispatch_hb is None and self.health_monitor is not None:
-            self._dispatch_hb = self.health_monitor.register_heartbeat(
-                "scorer_dispatch")
-        self._upload_threads = [t for t in self._upload_threads if t.is_alive()]
-        for i in range(len(self._upload_threads), self.config.upload_workers):
-            t = threading.Thread(target=self._upload_loop, daemon=True,
-                                 name=f"ScorerDispatch-{i}")
-            self._upload_threads.append(t)
-            t.start()
-
-    def _upload_loop(self) -> None:
-        """Dispatch worker: runs the device upload + jit call for queued
-        slots. jax dispatch is thread-safe; a failure is stored on the slot
-        (surfaced and counted at drain) so a poisoned batch can never leave
-        the engine thread waiting on a slot that nobody will complete."""
-        # dmlint: hot-loop
-        while True:
-            item = self._upload_queue.get()
-            if item is None:
-                return
-            if self._dispatch_hb is not None:
-                self._dispatch_hb.beat()
-            slot, chunk = item
-            try:
-                slot.scores = self._issue(slot, chunk)
-            except Exception as exc:  # noqa: BLE001 — containment boundary
-                slot.error = exc
-            finally:
-                slot.done.set()
-
     def _score_host(self, tokens: np.ndarray):
         """Score a small batch on the CPU backend with the mirrored params."""
         import jax
@@ -2174,19 +1900,9 @@ class JaxScorerDetector(CoreDetector):
 
     def _drain_one(self) -> List[Optional[bytes]]:
         slot = self._inflight.popleft()
-        slot.done.wait()
         self._drained_total += 1
         on_device = slot.path != "host"
         kv = slot.span_kv()
-        if slot.error is not None:
-            # worker-path dispatch failure: same containment rule as the
-            # engine's per-message processing — count EVERY lost message
-            # (error-rate dashboards must see the real magnitude), emit
-            # nothing, live on
-            self._device_batch_done(slot, time.monotonic())
-            self.count_processing_errors(
-                slot.real, f"batch dispatch failed: {slot.error}")
-            return []
         raws, real = slot.raws, slot.real
         if on_device:
             with device_obs.span("dm.readback", **kv):
@@ -2228,7 +1944,7 @@ class JaxScorerDetector(CoreDetector):
         return out
 
     def _device_batch_done(self, slot: "_InflightSlot", now: float) -> None:
-        """A device batch was seen readable (or failed) at ``now``: close
+        """A device batch was seen readable at ``now``: close
         its share of the idle account, and start the idle clock when
         nothing else is unfinished on the device."""
         if slot.path == "host":
@@ -2260,27 +1976,12 @@ class JaxScorerDetector(CoreDetector):
     def flush_final(self) -> List[Optional[bytes]]:
         """Stop-time drain: waits for a running boundary fit so its pending
         backlog is scored and emitted before sockets close (and for the host
-        bucket warmer, so post-restore usage sees a deterministic state).
-        Upload workers are stopped after the drain — a detector that keeps
-        processing afterwards (tests do) just respawns them on next
-        dispatch; a torn-down one leaks no thread pinning it alive."""
+        bucket warmer, so post-restore usage sees a deterministic state)."""
         self._finish_fit(wait=True)
         warm = self._host_warm_thread
         if warm is not None and warm.is_alive():
             warm.join()
-        out = self.flush()
-        self._stop_upload_workers()
-        return out
-
-    def _stop_upload_workers(self) -> None:
-        if self._upload_queue is None:
-            return
-        for t in self._upload_threads:
-            if t.is_alive():
-                self._upload_queue.put(None)   # one sentinel per live worker
-        for t in self._upload_threads:
-            t.join(timeout=5)
-        self._upload_threads = []
+        return self.flush()
 
     def _make_alert_pb(self, msg, score: float) -> bytes:
         """Alert construction straight on the generated pb2 classes — at a
@@ -2354,8 +2055,8 @@ class JaxScorerDetector(CoreDetector):
             self._rows_released_children[reason] = m.ROWS_RELEASED().labels(
                 reason=reason, **labels)
         self._device_children = (
-            m.DEVICE_LINES().labels(device=str(self._device), **labels),
-            m.DEVICE_BATCHES().labels(device=str(self._device), **labels))
+            m.DEVICE_LINES().labels(device=self._exec.label, **labels),
+            m.DEVICE_BATCHES().labels(device=self._exec.label, **labels))
         self._moe_children = (
             m.MOE_ASSIGNMENTS().labels(**labels),
             m.MOE_HELD_ASSIGNMENTS().labels(**labels),
@@ -2461,16 +2162,14 @@ class JaxScorerDetector(CoreDetector):
             else float("inf")
 
     def rollout_ready(self) -> bool:
-        """Whether the continuous fine-tune/shadow cycle can run: a fitted,
-        single-device scorer with live params. Mesh (sharded) mode serves
-        hot-swaps of externally-built checkpoints (install_candidate /
-        load_params_checkpoint) but not in-process fine-tuning — the train
+        """Whether the continuous fine-tune/shadow cycle can run: a fitted
+        scorer whose executor can fork its live params. Mesh (sharded) mode
+        serves hot-swaps of externally-built checkpoints (install_candidate
+        / load_params_checkpoint) but not in-process fine-tuning — the train
         path donates the sharded trees in place."""
         # dmlint: ignore[DM-L001] racy pre-check; install paths re-sync
         return (self._fitted and self._fit_thread is None
-                and self._sharded is None
-                # dmlint: ignore[DM-L001] presence probe; cycle re-reads
-                and self._params is not None)
+                and self._exec is not None and self._exec.forkable)
 
     def rollout_fine_tune(self, rows: np.ndarray, epochs: int = 1,
                           seed: int = 0):
@@ -2482,13 +2181,6 @@ class JaxScorerDetector(CoreDetector):
         zero-unexpected-recompile contract while training runs on the
         manager thread."""
         self._ensure_scorer()
-        # dmlint: ignore[DM-L001] presence probe
-        if self._sharded is None and self._params is None:
-            raise LibraryError("scorer has no live params to fine-tune from")
-        if self._sharded is not None:
-            raise LibraryError(
-                "continuous fine-tuning is not supported in mesh (sharded) "
-                "mode; deploy externally-trained checkpoints instead")
         import jax
 
         cfg = self.config
@@ -2498,36 +2190,24 @@ class JaxScorerDetector(CoreDetector):
         bs = min(cfg.train_batch_size, len(rows))
         # a concurrent swap just means the candidate forks from the
         # pre-swap generation; the shadow gate judges it against whatever
-        # is live at promote time:
-        # dmlint: ignore[DM-L001] snapshot read
-        params, opt_state = self._params, self._opt_state
+        # is live at promote time
+        params, opt_state = self._exec.fork()
         rng = jax.random.PRNGKey(cfg.seed + 1 + seed)
         order_rng = np.random.default_rng(cfg.seed + seed)
         loss, steps = float("nan"), 0
         with self._ledger.context(where="rollout_fit",
-                                  backend=self._obs_backend, expected=True):
+                                  backend=self._exec.backend, expected=True):
             for _ in range(max(1, epochs)):
                 order = order_rng.permutation(len(rows))
                 for start in range(0, len(rows) - bs + 1, bs):
                     batch = rows[order[start:start + bs]]
                     rng, step_rng = jax.random.split(rng)
-                    params, opt_state, loss_arr = self._scorer.train_step(
-                        params, opt_state, step_rng, self._put(batch))
+                    params, opt_state, loss_arr = self._exec.fork_step(
+                        params, opt_state, step_rng, batch)
                     loss = float(loss_arr)
                     steps += 1
         return params, opt_state, {"steps": steps, "loss": loss,
                                    "batch_size": bs}
-
-    def _score_with_params(self, params, tokens: np.ndarray):
-        """Score a padded chunk with an explicit param tree (None = live);
-        applies the live position-norm calibration either way so live and
-        candidate scores stay in one unit."""
-        if params is None:
-            return self._score_dev(tokens)
-        if self._norm_mu is not None:
-            return self._scorer._normscore(params, self._put(tokens),
-                                           self._norm_mu, self._norm_sigma)
-        return self._scorer.score(params, self._put(tokens))
 
     def rollout_scores(self, params, tokens: np.ndarray) -> np.ndarray:
         """Shadow-scoring path: [n, S] tokens → [n] fp32 scores under the
@@ -2535,7 +2215,7 @@ class JaxScorerDetector(CoreDetector):
         shape (guaranteed warm since the boundary fit) under an expected
         ``shadow`` ledger context."""
         self._ensure_scorer()
-        if self._sharded is not None and params is not None:
+        if params is not None and not self._exec.forkable:
             raise LibraryError(
                 "shadow scoring with explicit params is not supported in "
                 "mesh (sharded) mode")
@@ -2545,15 +2225,17 @@ class JaxScorerDetector(CoreDetector):
             return np.zeros(0, np.float32)
         bucket = _bucket(self.config.train_batch_size, self.config.max_batch)
         out = np.empty(n, np.float32)
+        if params is not None:
+            params, _ = self._exec.place_trees(params, None)
         with self._ledger.context(bucket=bucket, where="shadow",
-                                  backend=self._obs_backend, expected=True):
+                                  backend=self._exec.backend, expected=True):
             for start in range(0, n, bucket):
                 chunk = tokens[start:start + bucket]
                 real = len(chunk)
                 if real < bucket:
                     chunk = np.concatenate([chunk, np.zeros(
                         (bucket - real, tokens.shape[1]), np.int32)])
-                scores = np.asarray(self._score_with_params(params, chunk))
+                scores = np.asarray(self._score_dev(chunk, params=params))
                 out[start:start + real] = scores[:real]
         return out
 
@@ -2583,13 +2265,14 @@ class JaxScorerDetector(CoreDetector):
         *before* cutover, then swap the dispatch path's param refs under
         the ``_fit_lock`` handoff. The coalescer keeps draining while the
         warm runs on the caller's (manager) thread; because the candidate's
-        avals match the live tree every warm call is an XLA cache hit, and
-        any surprise compile is attributed expected here rather than
-        paging as a recompile storm. The host CPU twin's mirror is computed
-        pre-swap too, so small batches never score a stale model. Under
-        ``dtype: int8w`` the candidate is re-quantized after the swap and
-        the parity gate re-judged — a candidate that flips decisions under
-        quantization serves float."""
+        avals match the live tree every warm call runs the executable the
+        bucket already keeps (a candidate it rejects raises here, before the
+        swap), and a compile for a bucket only the spec names is attributed
+        expected here rather than paging as a recompile storm. The host
+        CPU twin's mirror is computed pre-swap too, so small batches never
+        score a stale model. Under ``dtype: int8w`` the candidate is
+        re-quantized after the swap and the parity gate re-judged — a
+        candidate that flips decisions under quantization serves float."""
         self._ensure_scorer()
         import jax
 
@@ -2598,31 +2281,17 @@ class JaxScorerDetector(CoreDetector):
         self._finish_fit(wait=True)
         cfg = self.config
         warmed = self._resolve_warm_set(warm_set)
+        kind, extra = self._serving()
         with self._ledger.context(where="model_swap",
-                                  backend=self._obs_backend, expected=True):
-            if self._sharded is not None:
-                # serve float while the swap + requant are in flight
-                self._sharded.clear_quantized()
-                self._sharded.install_params(params, opt_state)
-                for b in warmed:
-                    self._device_warm.add(b)
-                    self._sharded.warm_bucket(
-                        np.zeros((b, cfg.seq_len), np.int32))
-                with self._fit_lock:
-                    self._model_version = int(version)
-                result = {"swapped": True, "version": int(version),
-                          "prewarmed_buckets": warmed, "backend": "mesh"}
-                if self._int8w:
-                    result["int8"] = self._activate_int8(where="install")
-                return result
-            dev_params = jax.device_put(params, self._device)
-            dev_opt = jax.device_put(opt_state, self._device)
+                                  backend=self._exec.backend, expected=True):
+            dev_params, dev_opt = self._exec.place_trees(params, opt_state)
             for b in warmed:
                 tokens = np.zeros((b, cfg.seq_len), np.int32)
                 self._device_warm.add(b)
                 with self._ledger.context(bucket=b):
+                    self._exec.warm(kind, b, *extra, params=dev_params)
                     jax.block_until_ready(
-                        self._score_with_params(dev_params, tokens))
+                        self._score_dev(tokens, params=dev_params))
             # the mirror itself is recomputed from the candidate and
             # swapped under the lock; a mirror that cannot be made takes the
             # twin out of service rather than leave it scoring the old model
@@ -2639,19 +2308,16 @@ class JaxScorerDetector(CoreDetector):
 
                     logging.getLogger(__name__).exception(
                         "host twin could not mirror the candidate; every "
-                        "batch scores on %s", self._device)
+                        "batch scores on %s", self._exec.label)
             with self._fit_lock:
-                self._params = dev_params
-                self._opt_state = dev_opt
-                # the old generation's quantized tree must not outlive its
-                # float source; requantized below from the candidate
-                self._qparams = None
+                # float serves until the candidate is requantized below
+                self._exec.install(dev_params, dev_opt)
                 if host_live:
                     self._host_params = host_params
                 self._model_version = int(version)
         result = {"swapped": True, "version": int(version),
                   "prewarmed_buckets": warmed,
-                  "backend": self._obs_backend}
+                  "backend": self._exec.backend}
         if self._int8w:
             result["int8"] = self._activate_int8(where="install")
         return result
@@ -2676,13 +2342,10 @@ class JaxScorerDetector(CoreDetector):
 
         self._ensure_scorer()
         accepted = COMPATIBLE_TREE_VERSIONS.get(self.config.model, {1})
-        if self._sharded is not None:
-            return load_scorer_state(
-                directory, self._sharded.params, self._sharded.opt_state,
-                accepted_tree_versions=accepted)
-        # any live generation's tree structure restores identically:
-        # dmlint: ignore[DM-L001] template read
-        return load_scorer_state(directory, self._params, self._opt_state,
+        # any live generation's tree structure restores identically, each
+        # leaf with the live one's placement
+        return load_scorer_state(directory, self._exec.params,
+                                 self._exec.opt_state,
                                  accepted_tree_versions=accepted)
 
     # -- runtime reconfigure (POST /admin/reconfigure end-to-end) --------
@@ -2766,15 +2429,9 @@ class JaxScorerDetector(CoreDetector):
         self._finish_fit(wait=True)
 
         version = MODEL_TREE_VERSIONS.get(self.config.model, 1)
-        if self._sharded is not None:
-            save_scorer_state(directory, self._sharded.params,
-                              self._sharded.opt_state, self.state_dict(),
-                              tree_version=version)
-        else:
-            # _finish_fit(wait=True) above ended the only racing writer:
-            # dmlint: ignore[DM-L001] post-join read
-            save_scorer_state(directory, self._params, self._opt_state,
-                              self.state_dict(), tree_version=version)
+        # _finish_fit(wait=True) above ended the only racing writer
+        save_scorer_state(directory, self._exec.params, self._exec.opt_state,
+                          self.state_dict(), tree_version=version)
 
     def load_checkpoint(self, directory: str) -> None:
         from ...utils.checkpoint import (COMPATIBLE_TREE_VERSIONS,
@@ -2782,21 +2439,13 @@ class JaxScorerDetector(CoreDetector):
 
         self._ensure_scorer()
         accepted = COMPATIBLE_TREE_VERSIONS.get(self.config.model, {1})
-        if self._sharded is not None:
-            # restore against the sharded targets so each leaf comes back
-            # with its mesh placement intact
-            params, opt_state, meta = load_scorer_state(
-                directory, self._sharded.params, self._sharded.opt_state,
-                accepted_tree_versions=accepted,
-            )
-            self._sharded.params, self._sharded.opt_state = params, opt_state
-        else:
-            params, opt_state, meta = load_scorer_state(
-                # dmlint: ignore[DM-L001] template read (tree structure only)
-                directory, self._params, self._opt_state,
-                accepted_tree_versions=accepted,
-            )
-            self._params, self._opt_state = params, opt_state
+        # restore against the live trees so each leaf comes back with its
+        # placement (one device, or its mesh sharding) intact
+        params, opt_state, meta = load_scorer_state(
+            directory, self._exec.params, self._exec.opt_state,
+            accepted_tree_versions=accepted,
+        )
+        self._exec.install(params, opt_state)
         self._trained = int(meta.get("trained", 0))
         self._fitted = bool(meta.get("fitted", False))
         cand_key, cand_ids = meta.get("cand_key"), meta.get("cand_ids")

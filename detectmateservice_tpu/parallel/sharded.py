@@ -10,7 +10,7 @@ collectives. Training steps psum gradients across ``data`` automatically
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
@@ -32,7 +32,17 @@ class ShardedScorer:
 
     ``score(tokens)`` and ``train_step(rng, tokens)`` own the params/opt-state
     internally (sharded once at construction) so callers just stream batches.
+    It is also the mesh placement of the detector's device executor
+    (library/detectors/device_executor.py), beside that module's one-device
+    placement: same attributes, same methods.
     """
+
+    # a candidate tree cannot be trained or scored beside the live ones: the
+    # train step donates the sharded trees in place
+    forkable = False
+    # placing the shards is part of the call (one ``dm.call`` span), not an
+    # upload step of its own
+    uploads_apart = False
 
     def __init__(
         self,
@@ -43,6 +53,13 @@ class ShardedScorer:
     ):
         self.scorer = scorer
         self.mesh = mesh if mesh is not None else make_mesh()
+        # where this placement runs, and its name in logs and labels
+        self.devices = list(self.mesh.devices.flat)
+        self.platform = self.devices[0].platform
+        self.backend = "mesh"
+        self.label = "mesh({})".format(
+            ",".join(f"{k}={v}" for k, v in self.mesh.shape.items()))
+        self.mesh_shape = dict(self.mesh.shape)
         # placement fact for the scorer's kernel routing (models/base.py
         # head_route): GSPMD does not partition a Pallas call, so on more
         # than one device ``head_impl: auto`` keeps the einsum head
@@ -87,8 +104,7 @@ class ShardedScorer:
                     params, opt_state = scorer.init(init_rng)
         self._param_sharding = tree_shardings(self.mesh, params, rules)
         self._opt_sharding = tree_shardings(self.mesh, opt_state, rules)
-        self.params = jax.device_put(params, self._param_sharding)
-        self.opt_state = jax.device_put(opt_state, self._opt_sharding)
+        self.params, self.opt_state = self.place_trees(params, opt_state)
         # tokens are [B, S]: batch over 'data' when present, sequence over
         # 'seq' when present — so activations start out seq-sharded and the
         # ring's shard_map needs no initial reshard
@@ -116,100 +132,43 @@ class ShardedScorer:
             out_shardings=(self._param_sharding, self._opt_sharding, None),
             donate_argnums=(0, 1),
         )
-        # dmwarm (PR 17): AOT-compiled executables keyed (kind, padded_B) —
-        # the detector's setup_io lowers+compiles the warm bucket set here
-        # so mesh dispatch executes without entering the jit compile path
-        self._aot: Dict[Tuple[str, int], Any] = {}
-        # weight-only int8 serving (models/quant.py): installed by the
-        # detector after its parity gate passes; None = float path serves
-        self._qparams = None
-        self._qscore = None
-        self._qnormscore = None
+        # the programs the executor's table is filled from
+        self.jits = {"score": self._score, "normscore": self._normscore,
+                     "token_nlls": self._token_nlls}
 
     @property
     def data_parallelism(self) -> int:
         return int(self.mesh.shape.get(AXIS_DATA, 1))
 
-    def install_params(self, params, opt_state) -> None:
-        """Hot-swap the served param/opt trees (model rollout): the new
-        trees are placed with the SAME shardings the jitted executables
-        were compiled against, so every cached executable keeps hitting —
-        the swap itself is a reference assignment, never a recompile."""
-        self.params = jax.device_put(params, self._param_sharding)
-        self.opt_state = jax.device_put(opt_state, self._opt_sharding)
+    def place_trees(self, params, opt_state):
+        """Parameter and optimiser trees placed with the shardings the
+        jitted programs were compiled against: a tree placed here serves
+        from every compiled program, never through a recompile."""
+        return (jax.device_put(params, self._param_sharding),
+                jax.device_put(opt_state, self._opt_sharding))
 
-    # -- AOT warm-start (dmwarm) -----------------------------------------
-    def aot_compile_bucket(self, kind: str, tokens: np.ndarray,
-                           *extra) -> None:
-        """Lower+compile one (kind, bucket) sharded executable and KEEP it
-        (jax's AOT compile does not seed the jit's dispatch cache). The
-        batch pads to the mesh's data-axis multiple first, so the key is
-        the padded shape every later dispatch of this bucket produces."""
-        jit_fn = {"score": self._score, "normscore": self._normscore,
-                  "token_nlls": self._token_nlls}[kind]
-        tokens, _ = self._pad_batch(np.asarray(tokens))
-        tokens = jax.device_put(tokens, self._batch_sharding)
-        args = (self.params, tokens, *extra)
-        with device_obs.get_ledger().context(bucket=tokens.shape[0],
-                                             backend="mesh",
-                                             where="sharded"):
-            if self._seq_axis is None:
-                self._aot[(kind, tokens.shape[0])] = (
-                    jit_fn.lower(*args).compile())
-            else:
-                from ..ops.attention import ring_context
+    # -- weight-only int8 serving ----------------------------------------
+    def _quant_sharding(self):
+        """Shardings of ``quantize_tree(params)``: the int8 payloads shard
+        exactly like their float leaves, the per-channel scales along the
+        leaf's last-axis placement."""
+        from ..models.quant import quant_shardings
 
-                with ring_context(self.mesh, batch_axis=self._data_axis,
-                                  axis_name=self._seq_axis):
-                    self._aot[(kind, tokens.shape[0])] = (
-                        jit_fn.lower(*args).compile())
+        return quant_shardings(self.params, self._param_sharding, self.mesh)
 
-    def _aot_call(self, kind: str, batch: int, *args):
-        """The kept executable for (kind, batch), called directly — None
-        only when the bucket has none (the caller then takes the jit). An
-        argument the executable rejects raises: retracing quietly would
-        turn every batch into a compile nobody sees."""
-        comp = self._aot.get((kind, batch))
-        return None if comp is None else comp(*args)
+    def place_quantized(self, qparams):
+        return jax.device_put(qparams, self._quant_sharding())
 
-    # -- weight-only int8 serving (dmwarm) -------------------------------
-    def install_quantized(self, qparams) -> None:
-        """Install a quantized tree (models/quant.quantize_tree of the live
-        params): the int8 payloads shard exactly like their float leaves,
-        the per-channel scales along the leaf's last-axis placement. The
-        detector's parity gate decides whether this tree ever serves."""
-        from ..models.quant import dequantize_tree, quant_shardings
+    def jit_quantized(self, impl, n_extra: int):
+        """Jit a scoring impl that takes a quantized tree, the batch and
+        ``n_extra`` replicated arguments."""
+        return jax.jit(impl, in_shardings=(
+            self._quant_sharding(), self._batch_sharding,
+            *(None,) * n_extra))
 
-        qshard = quant_shardings(self.params, self._param_sharding,
-                                 self.mesh)
-        qparams = jax.device_put(qparams, qshard)
-        if self._qscore is None:
-            scorer = self.scorer
-            compute_dtype = scorer.config.dtype
-
-            def _qscore_impl(qp, tokens):
-                return scorer._score_impl(
-                    dequantize_tree(qp, compute_dtype), tokens)
-
-            def _qnormscore_impl(qp, tokens, mu, sigma):
-                return scorer._normscore_impl(
-                    dequantize_tree(qp, compute_dtype), tokens, mu, sigma)
-
-            self._qscore = jax.jit(
-                _qscore_impl, in_shardings=(qshard, self._batch_sharding))
-            self._qnormscore = jax.jit(
-                _qnormscore_impl,
-                in_shardings=(qshard, self._batch_sharding, None, None))
-        self._qparams = qparams
-
-    def clear_quantized(self) -> None:
-        """Back to the float path (parity flip, or a fresh candidate swap
-        whose requant has not been judged yet)."""
-        self._qparams = None
-
-    def _traced(self, fn, *args, bucket: Optional[int] = None):
-        """Invoke a jitted fn; on a seq mesh, tracing happens inside
-        ring_context so the model's ``attention(impl="ring")`` resolves to
+    def traced(self, fn, *args, bucket: Optional[int] = None):
+        """Invoke a jitted fn (or a thunk that lowers one); on a seq mesh,
+        tracing happens inside ring_context so the model's ``attention(impl="ring")`` resolves to
         this mesh. Trace-time only: cached executions skip the context.
 
         Compiles fired here attribute to the padded batch bucket on the
@@ -226,77 +185,28 @@ class ShardedScorer:
                               axis_name=self._seq_axis):
                 return fn(*args)
 
-    def _pad_batch(self, tokens: np.ndarray) -> Tuple[np.ndarray, int]:
-        """Pad the batch to a multiple of the data-axis size (and narrow to
-        the wire dtype — see __init__)."""
-        n = len(tokens)
+    def padded_rows(self, n: int) -> int:
+        """``n`` rounded up to a multiple of the data-axis size."""
         dp = self.data_parallelism
-        padded = ((n + dp - 1) // dp) * dp
-        if padded != n:
-            pad = np.zeros((padded - n,) + tokens.shape[1:], tokens.dtype)
+        return ((n + dp - 1) // dp) * dp
+
+    def place(self, tokens: np.ndarray) -> jax.Array:
+        """Pad the batch to a multiple of the data-axis size (rows beyond
+        the caller's are padding: the caller slices), narrow it to the wire
+        dtype (see __init__) and shard it over the mesh."""
+        tokens = np.asarray(tokens)
+        padded = self.padded_rows(len(tokens))
+        if padded != len(tokens):
+            pad = np.zeros((padded - len(tokens),) + tokens.shape[1:],
+                           tokens.dtype)
             tokens = np.concatenate([tokens, pad])
-        return narrow_tokens(tokens, self._vocab_size), n
+        return jax.device_put(narrow_tokens(tokens, self._vocab_size),
+                              self._batch_sharding)
 
     def score(self, tokens: np.ndarray) -> np.ndarray:
-        tokens, n = self._pad_batch(np.asarray(tokens))
-        tokens = jax.device_put(tokens, self._batch_sharding)
-        return np.asarray(self._traced(self._score, self.params, tokens,
-                                       bucket=len(tokens)))[:n]
-
-    def warm_bucket(self, tokens: np.ndarray) -> None:
-        """Pre-compile the sharded score path for this batch shape and block
-        until the executable exists. The detector's adaptive batcher warms
-        buckets BEFORE their first dispatch use (adaptive warm-set growth,
-        post-retirement resurrection), so the compile attributes as an
-        expected ``bucket_warm`` — never an unexpected-recompile page."""
-        with device_obs.get_ledger().context(bucket=len(tokens),
-                                             backend="mesh",
-                                             where="bucket_warm",
-                                             expected=True):
-            jax.block_until_ready(self.score_device(tokens))
-
-    def score_device(self, tokens: np.ndarray) -> jax.Array:
-        """Asynchronous scoring: dispatch and return the device array without
-        forcing a host readback (rows beyond the caller's real batch are
-        padding — the caller slices). Lets the detector's pipelined hot path
-        overlap readback with the next batch's featurization. Routing: the
-        int8 quantized path when live, then the bucket's AOT executable,
-        then the jit (whose compile the ledger attributes)."""
-        tokens, _ = self._pad_batch(np.asarray(tokens))
-        tokens = jax.device_put(tokens, self._batch_sharding)
-        if self._qparams is not None:
-            return self._traced(self._qscore, self._qparams, tokens,
-                                bucket=tokens.shape[0])
-        out = self._aot_call("score", tokens.shape[0], self.params, tokens)
-        if out is not None:
-            return out
-        return self._traced(self._score, self.params, tokens,
-                            bucket=tokens.shape[0])
-
-    def token_nlls_device(self, tokens: np.ndarray) -> jax.Array:
-        """[n, S] → [n_padded, S] per-position NLLs on device."""
-        tokens, _ = self._pad_batch(np.asarray(tokens))
-        tokens = jax.device_put(tokens, self._batch_sharding)
-        out = self._aot_call("token_nlls", tokens.shape[0],
-                             self.params, tokens)
-        if out is not None:
-            return out
-        return self._traced(self._token_nlls, self.params, tokens,
-                            bucket=tokens.shape[0])
-
-    def normscore_device(self, tokens: np.ndarray, mu, sigma) -> jax.Array:
-        """Per-position-normalized scores (models.logbert.positional_z_max)."""
-        tokens, _ = self._pad_batch(np.asarray(tokens))
-        tokens = jax.device_put(tokens, self._batch_sharding)
-        if self._qparams is not None:
-            return self._traced(self._qnormscore, self._qparams, tokens,
-                                mu, sigma, bucket=tokens.shape[0])
-        out = self._aot_call("normscore", tokens.shape[0],
-                             self.params, tokens, mu, sigma)
-        if out is not None:
-            return out
-        return self._traced(self._normscore, self.params, tokens, mu, sigma,
-                            bucket=tokens.shape[0])
+        placed = self.place(tokens)
+        return np.asarray(self.traced(self._score, self.params, placed,
+                                      bucket=len(placed)))[:len(tokens)]
 
     def train_step(self, rng: jax.Array, tokens: np.ndarray) -> float:
         # pad by wrapping real rows, NOT zeros: synthetic all-PAD rows would
@@ -304,8 +214,7 @@ class ShardedScorer:
         # normal; duplicating real rows only slightly oversamples them
         tokens = np.asarray(tokens)
         n = len(tokens)
-        dp = self.data_parallelism
-        padded = ((n + dp - 1) // dp) * dp
+        padded = self.padded_rows(n)
         if padded != n:
             # modular repetition handles n < padded - n too (e.g. a 3-row
             # final batch on a data=8 mesh); a plain slice would come up
@@ -313,7 +222,7 @@ class ShardedScorer:
             tokens = tokens[np.arange(padded) % n]
         tokens = jax.device_put(narrow_tokens(tokens, self._vocab_size),
                                 self._batch_sharding)
-        self.params, self.opt_state, loss = self._traced(
+        self.params, self.opt_state, loss = self.traced(
             self._train, self.params, self.opt_state, rng, tokens,
             bucket=tokens.shape[0]
         )

@@ -92,10 +92,6 @@ def main() -> None:
     ap.add_argument("n", nargs="?", type=int, default=262144)
     ap.add_argument("--shards", type=int, default=1,
                     help="ingress shard count (and sender process count)")
-    ap.add_argument("--upload-workers", type=int, default=0,
-                    help="scorer upload_workers: >0 overlaps device "
-                         "upload/dispatch with engine-thread featurize "
-                         "(the r5 MFU lever; A/B against 0)")
     ap.add_argument("--sender", nargs=5, metavar=("ADDR", "N", "SEED",
                                                   "READY", "GO"))
     args = ap.parse_args()
@@ -132,13 +128,12 @@ def main() -> None:
         settings["engine_ingress_addrs"] = shard_addrs
     else:
         shard_addrs = [settings["engine_addr"]]
-    # the canonical headline-bench scorer config (ONE home: bench.py), plus
-    # this script's single knob. The host twin is off: the progress counter
+    # the canonical headline-bench scorer config (ONE home: bench.py). The
+    # host twin is off: the progress counter
     # (scrape_processed) counts rows the DEVICE path scored, and a probe
     # message or a burst's remainder that rode the twin would never reach it
     config = {"detectors": {"JaxScorerDetector": dict(
-        B.BENCH_SCORER_CONFIG, upload_workers=args.upload_workers,
-        host_score_max_batch=0)}}
+        B.BENCH_SCORER_CONFIG, host_score_max_batch=0)}}
     import yaml
 
     with open(f"{work}/settings.yaml", "w") as f:
@@ -244,7 +239,6 @@ def main() -> None:
             "value": round(n / elapsed, 1),
             "unit": "lines/s",
             "shards": shards,
-            "upload_workers": args.upload_workers,
             "processed": processed,
             "alerts": len(alerts),
             "n": n,

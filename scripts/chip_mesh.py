@@ -42,9 +42,9 @@ def check_mesh(config: dict, batch: int) -> dict:
                             rng=jax.random.PRNGKey(0))
     tokens = np.random.default_rng(0).integers(
         1, config["vocab_size"], (batch, config["seq_len"]), dtype=np.int32)
-    placed = jax.device_put(tokens, sharded._batch_sharding)
+    placed = sharded.place(tokens)
     leaf = max(jax.tree_util.tree_leaves(sharded.params), key=lambda x: x.size)
-    mesh_scores = np.asarray(sharded.score_device(tokens))[:batch]
+    mesh_scores = sharded.score(tokens)
     one = jax.device_put(jax.device_get(sharded.params), devices[0])
     one_scores = np.asarray(jax.jit(scorer._score_impl)(
         one, jax.device_put(tokens, devices[0])))

@@ -850,9 +850,9 @@ def main() -> int:
 
                     det = det_service.library_component
                     broken = jax.tree_util.tree_map(lambda a: a * 10.0,
-                                                    det._params)
+                                                    det._exec.params)
                     mgr.inject_candidate(
-                        broken, det._opt_state, tag="broken-injected",
+                        broken, det._exec.opt_state, tag="broken-injected",
                         min_samples=10**9,
                         timeout_s=max(5.0, fault_s - 10.0))
                     time.sleep(fault_s)

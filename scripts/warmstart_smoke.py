@@ -42,7 +42,7 @@ BOOT_CONFIG = {
     "method_type": "jax_scorer", "auto_config": False, "model": "mlp",
     "data_use_training": 32, "train_epochs": 1, "threshold_sigma": 4.0,
     "seq_len": 16, "dim": 32, "max_batch": 64, "pipeline_depth": 2,
-    "dtype": "float32", "upload_workers": 0,
+    "dtype": "float32",
 }
 
 

@@ -219,7 +219,7 @@ class TestScoredRowsCounter:
     def _sample(self, det, name):
         from prometheus_client import REGISTRY
 
-        labels = dict(det._obs_labels(), device=str(det._device))
+        labels = dict(det._obs_labels(), device=det._exec.label)
         return REGISTRY.get_sample_value(name, labels) or 0.0
 
     def test_ticks_at_drain_not_on_arrival(self):

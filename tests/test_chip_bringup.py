@@ -263,11 +263,11 @@ class TestKernelRouting:
     def test_detector_hands_its_device_platform_to_the_model(self):
         det = make_detector()
         det._ensure_scorer()
-        assert det._platform == det._device.platform == "cpu"
+        assert det._exec.platform == det._exec.devices[0].platform == "cpu"
         assert det._scorer.config.platform == "cpu"
         info = det.device_info()
         assert info["platform"] == "cpu" and info["device_count"] >= 1
-        assert info["device_kind"] == det._device.device_kind
+        assert info["device_kind"] == det._exec.devices[0].device_kind
         assert info["scorer"]["model"] == "mlp"
         assert info["host_twin"]["state"] == "pending"   # mirrors at fit
         assert info["native_featurize"]["loaded"] is True
@@ -303,13 +303,14 @@ class TestKernelRouting:
         assert all(shape == [4, 8] for _, shape in report["batch"]["shards"])
 
     def test_device_spec_names_a_device_or_fails(self):
-        from detectmateservice_tpu.library.detectors import JaxScorerDetector
+        from detectmateservice_tpu.library.detectors.device_executor import (
+            resolve_device)
 
-        assert JaxScorerDetector._resolve_device("cpu:1").id == 1
+        assert resolve_device("cpu:1").id == 1
         with pytest.raises(LibraryError, match="cpu:99"):
-            JaxScorerDetector._resolve_device("cpu:99")
+            resolve_device("cpu:99")
         with pytest.raises(LibraryError, match="tpu:0"):
-            JaxScorerDetector._resolve_device("tpu:0")
+            resolve_device("tpu:0")
 
 
 # ---------------------------------------------------------------------------
@@ -320,15 +321,12 @@ class TestNoQuietFallbacks:
         det = make_detector(host_score_max_batch=0)
         det.setup_io()
         bucket = det.config.max_batch
-        assert ("score", bucket) in det._aot_exec
-
-        def rejects(*args):
-            raise TypeError("Argument types differ from the types for "
-                            "which this computation was compiled")
-
-        det._aot_exec[("score", bucket)] = rejects
+        assert ("score", bucket, False) in det._exec.kept_programs()
+        # float rows where the kept executable was compiled for the narrow
+        # integer wire format
         with pytest.raises(TypeError, match="Argument types differ"):
-            det._score_dev(np.zeros((bucket, det.config.seq_len), np.int32))
+            det._exec.run("score", np.zeros((bucket, det.config.seq_len),
+                                            np.float32))
 
     def test_missing_cpu_backend_is_a_visible_twin_failure(self, monkeypatch):
         import jax

@@ -463,7 +463,7 @@ def _span_detector(name: str, **overrides):
         "seq_len": 16, "dim": 32, "max_batch": 32, "pipeline_depth": 2,
         "async_fit": False, "host_score_max_batch": 0,
         "batch_deadline_ms": 10_000.0, "batch_target_occupancy": 0.9,
-        "score_threshold": -1e9, "upload_workers": 1,
+        "score_threshold": -1e9,
     }
     base.update(overrides)
     return JaxScorerDetector(name=name,
@@ -498,7 +498,7 @@ class TestBoundaryCountersFromBoot:
             assert line("detector_rows_released_total",
                         f'reason="{reason}"') in text
         assert line("detector_row_hold_seconds_total") in text
-        device = f'device="{det._device}"'
+        device = f'device="{det._exec.label}"'
         assert line("detector_device_lines_total", device) in text
         assert line("detector_device_batches_total", device) in text
         det.flush_final()

@@ -156,38 +156,16 @@ class TestDetection:
         assert isinstance(out, list)
 
 
-class TestUploadWorkers:
-    """upload_workers > 0 moves device dispatch onto background workers so
-    host→device RPC floors overlap the engine thread's featurize/drain work
-    (the r4 MFU lever). The contract: byte-identical outputs, dispatch-order
-    delivery, and failure containment."""
-
-    def _pair(self, **overrides):
-        cfg = dict(host_score_max_batch=0, async_fit=False, **overrides)
-        inline = JaxScorerDetector(config=scorer_config(**cfg))
-        overlap = JaxScorerDetector(config=scorer_config(upload_workers=1, **cfg))
-        for det in (inline, overlap):
-            det.process_batch(normal_msgs(32))
-            det.flush_final()
-        return inline, overlap
-
-    def test_alerts_identical_to_inline_dispatch(self):
-        inline, overlap = self._pair()
-        weird = [msg("segfault <*> exploit <*>", ["0xdead", "shellcode"],
-                     log_id=str(100 + i)) for i in range(8)]
-        traffic = normal_msgs(24) + weird
-        outs = []
-        for det in (inline, overlap):
-            out = det.process_batch(traffic)
-            out += det.flush_final()
-            outs.append(sorted(
-                tuple(DetectorSchema.from_bytes(o).logIDs)
-                for o in out if o is not None))
-        assert outs[0] == outs[1]
-        assert outs[0], "anomalies must alert on both paths"
+class TestInlineDispatch:
+    """Every device batch is issued on the caller's thread and joins the
+    in-flight queue in dispatch order."""
 
     def test_dispatch_order_preserved_across_batches(self):
-        _, det = self._pair(max_batch=8, pipeline_depth=8)
+        det = JaxScorerDetector(config=scorer_config(
+            host_score_max_batch=0, async_fit=False, max_batch=8,
+            pipeline_depth=8))
+        det.process_batch(normal_msgs(32))
+        det.flush_final()
         # several max_batch-sized dispatches, each with one anomaly whose
         # logID encodes the batch index — drain order must match
         for b in range(4):
@@ -200,17 +178,6 @@ class TestUploadWorkers:
                for o in out if o is not None]
         batch_ids = [i for i in ids if i.startswith("batch-")]
         assert batch_ids == sorted(batch_ids), ids
-
-    def test_worker_dispatch_failure_is_contained(self):
-        _, det = self._pair()
-
-        def boom(chunk):
-            raise RuntimeError("injected dispatch failure")
-
-        det._score_dev = boom
-        det.process_batch(normal_msgs(16, salt="x"))
-        out = det.flush_final()      # must not raise, must not hang
-        assert [o for o in out if o is not None] == []
         assert len(det._inflight) == 0
 
 
@@ -373,8 +340,7 @@ class TestMeshSharded:
     def test_train_detect_over_mesh(self):
         det = self._mesh_detector()
         assert det.process_batch(normal_msgs(32)) == []
-        assert det._sharded is not None
-        assert det._sharded.data_parallelism == 8
+        assert det._exec.mesh_shape == {"data": 8}
         weird = [msg("segfault <*> exploit <*>", ["0xdead", f"x{i}"], log_id=str(100 + i))
                  for i in range(4)]
         out = det.process_batch(normal_msgs(8) + weird) + det.flush()
@@ -445,7 +411,7 @@ class TestMeshSharded:
             model="logbert", mesh_shape={"data": 4, "model": 2},
             dim=32, depth=1, seq_len=16, threshold_sigma=8.0, async_fit=False))
         assert det.process_batch(normal_msgs(32)) == []
-        assert det._sharded is not None
+        assert det._exec.mesh_shape == {"data": 4, "model": 2}
         out = det.process_batch(normal_msgs(8)) + det.flush()
         assert isinstance(out, list)
 

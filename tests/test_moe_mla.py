@@ -448,7 +448,7 @@ def test_detector_life_fit_checkpoint_restore_and_counters(tmp_path):
     assert ok.all()
     padded = np.concatenate([tokens, np.zeros((8, SEQ), np.int32)])
     _, chosen = reference.token_nlls(
-        as_numpy(det._params), padded, det.config.arch, with_routing=True)
+        as_numpy(det._exec.params), padded, det.config.arch, with_routing=True)
     chosen = np.asarray(chosen)
     held = (chosen >= 2) & (chosen < 6)
     want = [int((chosen >= 0).sum()), int(held.sum()),
@@ -460,7 +460,7 @@ def test_detector_life_fit_checkpoint_restore_and_counters(tmp_path):
     assert det.device_info()["scorer"]["arch"]["kv_lora_rank"] == 32
     assert det.device_info()["host_twin"]["state"] == "off"
     scores = det.score_tokens(tokens)
-    want_scores = reference.score(as_numpy(det._params), tokens,
+    want_scores = reference.score(as_numpy(det._exec.params), tokens,
                                   {"arch": det.config.arch})
     assert np.abs(scores - want_scores).max() < 1e-4
     det.save_checkpoint(str(tmp_path / "ckpt"))

@@ -151,6 +151,21 @@ class TestWireFormat:
         listener.close()
 
 
+    def test_closed_listener_refuses_the_next_dial_and_frees_the_port(
+            self, free_port):
+        """close() has to end the accept, not only mark the listener closed:
+        a thread left in accept() keeps the port in LISTEN, so the peer's
+        next redial is accepted by a listener that is gone — the peer counts
+        frames written that nobody reads (tests/test_chaos.py's churn under
+        load) — and a restarted listener cannot bind."""
+        addr = f"nng+tcp://127.0.0.1:{free_port}"
+        listener = NngTcpSocketFactory().create(addr)
+        listener.close()
+        with pytest.raises(ConnectionRefusedError):
+            socket.create_connection(("127.0.0.1", free_port), timeout=5)
+        NngTcpSocketFactory().create(addr).close()     # binds at once
+
+
 def _send_raises(sock, payload: bytes) -> bool:
     try:
         sock.send(payload, block=False)

@@ -349,14 +349,14 @@ class TestDetectorRollout:
         import jax
 
         det = fitted_detector
-        live_leaf = np.array(jax.tree_util.tree_leaves(det._params)[0])
+        live_leaf = np.array(jax.tree_util.tree_leaves(det._exec.params)[0])
         rows = np.random.default_rng(0).integers(
             0, 100, size=(64, det.config.seq_len)).astype(np.int32)
         params, opt_state, info = det.rollout_fine_tune(rows, epochs=2,
                                                         seed=1)
         assert info["steps"] >= 2 and np.isfinite(info["loss"])
         assert np.array_equal(
-            live_leaf, np.array(jax.tree_util.tree_leaves(det._params)[0]))
+            live_leaf, np.array(jax.tree_util.tree_leaves(det._exec.params)[0]))
         cand_leaf = np.array(jax.tree_util.tree_leaves(params)[0])
         assert not np.array_equal(live_leaf, cand_leaf)
 
@@ -382,7 +382,7 @@ class TestDetectorRollout:
         rows = np.random.default_rng(2).integers(
             0, 100, size=(20, det.config.seq_len)).astype(np.int32)
         live = det.rollout_scores(None, rows)
-        same = det.rollout_scores(det._params, rows)
+        same = det.rollout_scores(det._exec.params, rows)
         assert np.allclose(live, same)
         assert live.shape == (20,)
 
@@ -414,8 +414,8 @@ class TestRolloutManager:
         mgr, sink = make_manager(det, tmp_path)
         try:
             feed(det, 2000)
-            broken = jax.tree_util.tree_map(lambda a: a * 10.0, det._params)
-            version = mgr.inject_candidate(broken, det._opt_state,
+            broken = jax.tree_util.tree_map(lambda a: a * 10.0, det._exec.params)
+            version = mgr.inject_candidate(broken, det._exec.opt_state,
                                            tag="broken", min_samples=8)
             outcome = None
             for _ in range(20):

@@ -315,15 +315,12 @@ class TestRealComponentPipeline:
         assert dict(alert.alertsObtain) == {"Global - Component": "Unknown value: 'rootkit'"}
         assert list(alert.logIDs) == ["9"]
 
-    # (upload_workers, host_score_max_batch): default host-twin path,
-    # device-dispatch path inline, and device-dispatch path on the r5
-    # overlap worker — the engine's drain_ready short-poll, flush, and
-    # stop paths cross the slot machinery in all three
-    @pytest.mark.parametrize("upload_workers,host_cap",
-                             [(0, 128), (0, 0), (1, 0)])
-    def test_jax_scorer_service_micro_batched(self, upload_workers, host_cap,
-                                              run_service, inproc_factory,
-                                              tmp_path):
+    # host_score_max_batch: the default host-twin path and the device
+    # dispatch path — the engine's drain_ready short-poll, flush, and stop
+    # paths cross the slot machinery in both
+    @pytest.mark.parametrize("host_cap", [128, 0])
+    def test_jax_scorer_service_micro_batched(self, host_cap, run_service,
+                                              inproc_factory, tmp_path):
         config = tmp_path / "j.yaml"
         config.write_text(yaml.safe_dump({"detectors": {"JaxScorerDetector": {
             "method_type": "jax_scorer", "auto_config": False, "model": "mlp",
@@ -331,10 +328,9 @@ class TestRealComponentPipeline:
             "seq_len": 16, "dim": 32, "max_batch": 32,
             "pipeline_depth": 1, "threshold_sigma": 4.0,
             "host_score_max_batch": host_cap,
-            "upload_workers": upload_workers,
         }}}))
-        addr = f"inproc://jax-det-{upload_workers}-{host_cap}"
-        out = f"inproc://jax-out-{upload_workers}-{host_cap}"
+        addr = f"inproc://jax-det-{host_cap}"
+        out = f"inproc://jax-out-{host_cap}"
         make_service(run_service, inproc_factory, addr,
                      component_type="detectors.jax_scorer.JaxScorerDetector",
                      config_file=str(config),

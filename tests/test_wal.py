@@ -561,7 +561,7 @@ class TestEngineDurableIngress:
         store = CheckpointStore(tmp_path / "store")
         version = store.allocate_version()
         det.save_params_checkpoint(str(store.version_dir(version)),
-                                   det._params, det._opt_state)
+                                   det._exec.params, det._exec.opt_state)
         store.record(version, {"model": "mlp"})
         report = shadow_replay(tmp_path / "wal", det,
                                store_dir=str(tmp_path / "store"))
@@ -571,7 +571,7 @@ class TestEngineDurableIngress:
         assert report["verdict"] == "promote"
 
         # a scaled candidate diverges; worst offenders carry spool seqs
-        broken = jax.tree_util.tree_map(lambda a: a * 10.0, det._params)
+        broken = jax.tree_util.tree_map(lambda a: a * 10.0, det._exec.params)
         report2 = shadow_replay(tmp_path / "wal", det, params=broken,
                                 max_mean_delta=1e-6, track_top=4)
         assert report2["mean_abs_delta"] > 0.0

@@ -3,7 +3,7 @@
 Covers the warm-start contract end to end:
 
 * setup_io AOT-compiles the warm bucket set (``lower().compile()`` kept in
-  ``_aot_exec``) BEFORE ``mark_warmup_complete``, so the first dispatch
+  the device executor's table) BEFORE ``mark_warmup_complete``, so the first dispatch
   after boot records **zero** ledger compiles — the boot→ACTIVE honesty
   gate, with ``WarmupPendingCheck`` refusing ACTIVE while warm-up is in
   flight;
@@ -78,8 +78,9 @@ class TestAotWarmStart:
         det = warm_detector
         assert det._device_warm, "setup_io left the warm bucket set empty"
         # every warm bucket owns a kept executable for the serving kind
-        kinds = {k for (k, _) in det._aot_exec}
-        buckets = {b for (_, b) in det._aot_exec}
+        kept = det._exec.kept_programs()
+        kinds = {kind for (kind, _, _) in kept}
+        buckets = {rows for (_, rows, _) in kept}
         assert kinds & {"score", "normscore"}
         assert set(det._device_warm) <= buckets
 
@@ -191,16 +192,17 @@ class TestInt8Parity:
 
     def test_int8_decisions_match_float_path(self, int8_detector):
         det = int8_detector
-        assert det._qparams is not None
+        qparams = det._exec.qparams
+        assert qparams is not None
         tokens = np.random.default_rng(11).integers(
             0, 100, size=(det.config.max_batch,
                           det.config.seq_len)).astype(np.int32)
         q_scores = det.score_tokens(tokens)
-        qparams, det._qparams = det._qparams, None
+        det._exec.clear_quantized()
         try:
             f_scores = det.score_tokens(tokens)
         finally:
-            det._qparams = qparams
+            det._exec.install_quantized(qparams)
         assert np.all(np.isfinite(q_scores))
         thr = det._threshold
         assert np.array_equal(q_scores > thr, f_scores > thr), (
@@ -224,7 +226,7 @@ class TestInt8Parity:
         rep = det._activate_int8(where="test")
         assert not rep["activated"]
         assert rep["flips"] > 0
-        assert det._qparams is None, "refused tree left installed"
+        assert det._exec.qparams is None, "refused tree left installed"
         # float path keeps serving
         scores = det.score_tokens(
             np.zeros((det.config.max_batch, det.config.seq_len), np.int32))
