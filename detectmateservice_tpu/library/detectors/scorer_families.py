@@ -77,10 +77,11 @@ def _moe_mla_refuses(cfg) -> Optional[str]:
     if cfg.score_vocab > 0:
         return ("score_vocab > 0: the moe_mla scorer has the exact head "
                 "only")
-    if cfg.attn_impl not in ("auto", "einsum"):
+    if cfg.attn_impl not in ("auto", "einsum", "short"):
         return (f"attn_impl {cfg.attn_impl!r}: latent attention is causal "
-                "with 192-wide keys and 128-wide values, which only the "
-                "einsum route computes ('auto' or 'einsum')")
+                "with two q·k widths and a value width of its own, which "
+                "the einsum route and the short-sequence kernel compute "
+                "('auto', 'einsum' or 'short')")
     return None
 
 

@@ -26,7 +26,12 @@ residual after, the residual stream in float32):
   and ``k_rope`` (one for all heads); ``c·W_kvb`` → per head
   ``k_nope ‖ v``; rotary positions on ``q_rope`` and ``k_rope``
   (interleaved pairs); ``softmax(q·kᵀ/√(nope+rope) + causal and PAD
-  mask)·v`` → ``W_o``.
+  mask)·v`` → ``W_o``. The activations run token-major (``[B·S, ·]``)
+  and the two projections write every head's first part, then every
+  head's second (:class:`HeadSplitDense`: a column view of the stored
+  kernel), so that the core (ops/attention.py::latent_attention) reads
+  the 128-wide and 64-wide parts as column blocks and never builds a
+  192-wide head.
 * dense layers (the first ``first_k_dense_replace``):
   ``W_down(silu(W_gate·x) ⊙ W_up·x)`` at ``intermediate_size``.
 * expert layers: ops/experts.py — router over all ``router_experts`` in
@@ -65,11 +70,10 @@ from typing import Any, Dict, Mapping, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
 
 from ..ops import experts as expert_ops
-from ..ops.attention import attention
+from ..ops.attention import latent_attention
 from .base import SequenceScorerBase, reduce_nlls
 from .gru import causal_lm_loss
 from .tokenizer import CLS_ID, PAD_ID
@@ -172,8 +176,9 @@ class MoEMLAConfig:
     learning_rate: float = 1e-4
     initializer_range: float = 0.02
     score_topk: int = 0
-    # "auto" and "einsum" are the same route here: the einsum attention is
-    # the only one with a causal mask and a value width of its own
+    # "auto" (ops/attention.py::attention_route: the two-width kernel of
+    # ops/shortattn.py on one TPU from 256 rows where the widths fill lane
+    # groups, einsum everywhere else) | "einsum" | "short"
     attn_impl: str = "auto"
     head_impl: str = "auto"
     platform: str = ""
@@ -185,30 +190,33 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions over the last axis of ``x`` [..., S, R], pairs
-    interleaved: ``x[2i], x[2i+1]`` turn by ``pos · theta^(-2i/R)`` and stay
-    where they are (the published code moves the pairs' halves apart; q and
-    k share either layout, and only q·k is read). The pair swap is a matmul
-    with a constant ±1 matrix — exact, and on the MXU — because a reshape to
-    ``[..., R/2, 2]`` costs a relayout of the whole tensor on the TPU (7 ms
-    a layer at 32768 tokens against 1). Angles and products in float32."""
-    s, r = x.shape[-2], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
-    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)              # [S, R]
-    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)
-    swap = np.zeros((r, r), np.float32)
-    swap[np.arange(1, r, 2), np.arange(0, r, 2)] = -1.0   # out[2i] = -x[2i+1]
-    swap[np.arange(0, r, 2), np.arange(1, r, 2)] = 1.0    # out[2i+1] = x[2i]
-    turned = jnp.dot(x, jnp.asarray(swap, x.dtype),
-                     preferred_element_type=jnp.float32)
-    return x.astype(jnp.float32) * cos + turned * sin
-
-
 def _dense(features: int, cfg: MoEMLAConfig, name: str) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=cfg.dtype, name=name,
                     kernel_init=nn.initializers.normal(cfg.initializer_range))
+
+
+class HeadSplitDense(nn.Module):
+    """``nn.Dense`` without bias over a kernel stored head by head —
+    column ``h * (split + rest) + i`` is head ``h``'s ``i``-th — that writes
+    every head's first ``split`` columns side by side, then every head's
+    rest: ``[N, heads * split | heads * rest]``. The regrouping is a column
+    view of the kernel taken once a call (25 MB for ``q_proj``, 17 MB for
+    ``kv_up``, against 400 MB of activations a layer); the parameter's
+    name, shape and stored layout are ``nn.Dense``'s."""
+    features: int
+    heads: int
+    split: int
+    config: MoEMLAConfig
+
+    @nn.compact
+    def __call__(self, y: jax.Array) -> jax.Array:
+        cfg, d = self.config, y.shape[-1]
+        by_head = self.param(
+            "kernel", nn.initializers.normal(cfg.initializer_range),
+            (d, self.features)).astype(cfg.dtype).reshape(d, self.heads, -1)
+        return jnp.dot(y.astype(cfg.dtype), jnp.concatenate(
+            [by_head[..., :self.split].reshape(d, -1),
+             by_head[..., self.split:].reshape(d, -1)], axis=-1))
 
 
 class Block(nn.Module):
@@ -220,7 +228,8 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, key_mask: jax.Array, valid: jax.Array
                  ) -> Tuple[jax.Array, jax.Array]:
-        """``x`` [B, S, D] float32 → (x', [3] int32 routing counts)."""
+        """``x`` [B·S, D] float32, token-major; ``key_mask`` and ``valid``
+        [B, S] → (x', [3] int32 routing counts)."""
         cfg, a = self.config, self.config.arch
         with jax.named_scope(f"layer{self.layer}/attn"):
             x = x + self._attention(x, key_mask)
@@ -237,38 +246,29 @@ class Block(nn.Module):
 
     def _attention(self, x: jax.Array, key_mask: jax.Array) -> jax.Array:
         cfg, a = self.config, self.config.arch
-        b, s, _ = x.shape
         h, nope, rope = (a.num_attention_heads, a.qk_nope_head_dim,
                          a.qk_rope_head_dim)
         y = rms_norm(x, self.param("attn_norm", nn.initializers.ones,
                                    (a.hidden_size,)),
                      a.rms_norm_eps).astype(cfg.dtype)
         with jax.named_scope("q_proj"):
-            q = _dense(h * (nope + rope), cfg, "q_proj")(y)
-            q = q.reshape(b, s, h, nope + rope).transpose(0, 2, 1, 3)
+            q = HeadSplitDense(h * (nope + rope), h, nope, cfg,
+                               name="q_proj")(y)
         with jax.named_scope("kv_down"):
             kva = _dense(a.kv_lora_rank + rope, cfg, "kv_down")(y)
             c = rms_norm(kva[..., :a.kv_lora_rank],
                          self.param("kv_norm", nn.initializers.ones,
                                     (a.kv_lora_rank,)),
                          a.rms_norm_eps).astype(cfg.dtype)
-            k_rope = kva[..., a.kv_lora_rank:]                 # [B, S, R]
+            k_rope = kva[..., a.kv_lora_rank:]                 # [B·S, R]
         with jax.named_scope("kv_up"):
-            kv = _dense(h * (nope + a.v_head_dim), cfg, "kv_up")(c)
-            kv = kv.reshape(b, s, h, nope + a.v_head_dim).transpose(
-                0, 2, 1, 3)
-        with jax.named_scope("rope"):
-            q_rope = rotary(q[..., nope:], a.rope_theta).astype(cfg.dtype)
-            k_rope = rotary(k_rope[:, None], a.rope_theta).astype(cfg.dtype)
-            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :nope],
-                 jnp.broadcast_to(k_rope, (b, h, s, rope))], axis=-1)
+            kv = HeadSplitDense(h * (nope + a.v_head_dim), h, nope, cfg,
+                                name="kv_up")(c)
         with jax.named_scope("core"):
-            out = attention(q, k, kv[..., nope:], key_mask=key_mask,
-                            impl="einsum", platform=cfg.platform or None,
-                            causal=True)
-            out = out.transpose(0, 2, 1, 3).reshape(b, s, h * a.v_head_dim)
+            out = latent_attention(q, kv, k_rope, key_mask, h, nope,
+                                   a.rope_theta, impl=cfg.attn_impl,
+                                   platform=cfg.platform or None,
+                                   causal=True)
         with jax.named_scope("out_proj"):
             return _dense(a.hidden_size, cfg, "out_proj")(out).astype(
                 jnp.float32)
@@ -284,10 +284,10 @@ class Block(nn.Module):
     def _experts(self, y: jax.Array, valid: jax.Array
                  ) -> Tuple[jax.Array, jax.Array]:
         cfg, a = self.config, self.config.arch
-        b, s, d = y.shape
+        d = y.shape[-1]
         init = nn.initializers.normal(cfg.initializer_range)
         m, held = a.moe_intermediate_size, a.n_routed_experts
-        flat, flat_valid = y.reshape(b * s, d), valid.reshape(b * s)
+        valid = valid.reshape(-1)
         router = self.param("router", init, (d, a.router_experts))
         if held < a.router_experts:
             # a share's fit sees only the held experts' part of the result,
@@ -297,15 +297,15 @@ class Block(nn.Module):
             router = jax.lax.stop_gradient(router)
         with jax.named_scope("router"):
             routing = expert_ops.route(
-                flat, router,
+                y, router,
                 self.param("router_bias", nn.initializers.zeros,
                            (a.router_experts,)),
-                flat_valid, top_k=a.num_experts_per_tok,
+                valid, top_k=a.num_experts_per_tok,
                 norm_topk_prob=a.norm_topk_prob,
                 scaling=a.routed_scaling_factor,
                 scoring_func=a.scoring_func)
         routed, per_expert = expert_ops.routed_experts(
-            flat.astype(cfg.dtype), routing,
+            y.astype(cfg.dtype), routing,
             self.param("experts_gate", init, (held, d, m)),
             self.param("experts_up", init, (held, d, m)),
             self.param("experts_down", init, (held, m, d)),
@@ -314,9 +314,9 @@ class Block(nn.Module):
             shared = self._gated(y.astype(cfg.dtype),
                                  a.n_shared_experts * m, "shared_")
         with jax.named_scope("combine"):
-            out = routed.reshape(b, s, d) + shared.astype(jnp.float32)
+            out = routed + shared.astype(jnp.float32)
         counts = jnp.stack([
-            flat_valid.sum(dtype=jnp.int32) * a.num_experts_per_tok,
+            valid.sum(dtype=jnp.int32) * a.num_experts_per_tok,
             per_expert.sum(dtype=jnp.int32), per_expert.max()])
         return out, counts
 
@@ -348,14 +348,18 @@ class MoEMLALM(nn.Module):
             inputs = jnp.concatenate(
                 [jnp.full_like(tokens[:, :1], CLS_ID), tokens[:, :-1]],
                 axis=1)
-            x = self.tok_embed(inputs).astype(jnp.float32)
+            # token-major from here on: a [B·S, ·] array has one layout on
+            # the TPU, a [B, S, ·] one is laid out sequence-major and
+            # copied before every kernel (PERF.md section 6, PR 28)
+            x = self.tok_embed(inputs).astype(jnp.float32).reshape(
+                -1, self.config.arch.hidden_size)
         key_mask, valid = inputs != PAD_ID, tokens != PAD_ID
         counts = jnp.zeros((3,), jnp.int32)
         for block in self.layers:
             x, layer_counts = block(x, key_mask, valid)
             counts = counts + layer_counts
-        return (rms_norm(x, self.final_norm, self.config.arch.rms_norm_eps),
-                counts)
+        return (rms_norm(x, self.final_norm, self.config.arch.rms_norm_eps
+                         ).reshape(*tokens.shape, -1), counts)
 
     def hidden(self, tokens: jax.Array) -> jax.Array:
         return self.hidden_and_counts(tokens)[0]

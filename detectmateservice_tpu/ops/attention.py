@@ -4,12 +4,14 @@ TPU-first: batched, bfloat16-friendly einsum attention the MXU tiles well,
 with a numerically stable blockwise variant that is the building block for
 ring attention (parallel/ring.py), the fused pallas kernel for long
 sequences (ops/flash.py) and the one for whole short sequences
-(ops/shortattn.py). ``attention()`` (head-major q, k, v) and
-``self_attention()`` (the fused projection, as the models write it) route
-between them by :func:`attention_route`: from ``FLASH_MIN_SEQ`` up the
-flash kernel avoids materializing the [S, T] logits in HBM; for S = T <=
-128 on one TPU the short kernel keeps the ``[rows, heads, S, T]`` float32
-logits in VMEM and takes q, k, v where the projection wrote them.
+(ops/shortattn.py). ``attention()`` (head-major q, k, v),
+``self_attention()`` (the fused projection, as the models write it) and
+``latent_attention()`` (latent attention's two q·k widths and value width,
+token-major, as its projections write them) route between them by
+:func:`attention_route`: from ``FLASH_MIN_SEQ`` up the flash kernel avoids
+materializing the [S, T] logits in HBM; for S = T <= 128 on one TPU the
+short kernels keep the ``[rows, heads, S, T]`` float32 logits in VMEM and
+take q, k, v where the projections wrote them.
 
 That second route replaces a belief this file used to state — that below
 ``FLASH_MIN_SEQ`` "the whole score matrix fits one MXU tile and XLA's fused
@@ -21,6 +23,18 @@ a layer, of which the three arithmetic operations were 22 ms and the rest
 of the 64-wide head-major q, k, v, and materialised transposes (ledger, PR
 27); the chip needs 5.5 ms a layer. What the kernel takes: PERF.md section
 6, PR 28.
+
+Latent attention (models/moe_mla.py) had the same disease in another form
+and kept einsum until PR 30, because its core is causal and its keys
+(128 ‖ 64) and values (128) differ in width: 32 heads reshaped to
+``[b, s, 32, 192]`` and transposed head-major, sliced at 128, the 64-wide
+part turned and concatenated back, one ``k_rope`` for all heads broadcast
+to 32 and concatenated onto ``k_nope`` — 41 ms a 1024-row call for rope's
+slices and concatenations and 46 ms for the einsum core over six layers,
+where the traffic needs 8.9 ms (my chip runs, PR 27; call 5).
+:func:`latent_attention` takes the operands in their two widths and never
+builds the 192-wide heads; its einsum form is what the CPU, a mesh and the
+fit's 32-row step run, and what the kernel's backward differentiates.
 """
 from __future__ import annotations
 
@@ -30,6 +44,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # from here up the kernel avoids the [S, S] fp32 logits (1 GB per batch-head
 # at S=8192); below it the einsum path stays. The crossover is not measured
@@ -68,24 +83,31 @@ def placement(mesh_devices: int, routes: Optional[Dict[int, str]] = None):
 
 def attention_route(impl: str, platform: str, s: int, t: int, heads: int,
                     head_dim: int, value_dim: int, causal: bool, rows: int,
-                    mesh_devices: int = 1) -> str:
+                    mesh_devices: int = 1, rope_dim: int = 0) -> str:
     """Which implementation computes one traced attention call.
 
     ``impl`` is the model's ``attn_impl``; any name but ``"auto"`` forces.
     ``"auto"`` decides from what the call can observe — the platform it is
-    placed on, query and key lengths, heads, the q·k and value widths,
-    whether it is causal, its rows, and how many devices the executor
-    spread it over:
+    placed on, query and key lengths, heads, the q·k and value widths
+    (``rope_dim`` of ``head_dim`` is the second, position-turned q·k
+    operand of a :func:`latent_attention` call; 0 = one operand), whether
+    it is causal, its rows, and how many devices the executor spread it
+    over:
 
     * on a TPU from ``FLASH_MIN_SEQ`` keys up: ``"flash"``, as before;
     * on ONE TPU, whole short self-attention — S = T <= 128 in whole
-      16-row tiles, equal q·k and value widths in whole-vreg lane groups,
-      no causal mask — from ``SHORT_MIN_ROWS`` rows: ``"short"``
-      (ops/shortattn.py: logits stay in VMEM, no head-major copies);
+      16-row tiles — from ``SHORT_MIN_ROWS`` rows: ``"short"``
+      (ops/shortattn.py: logits stay in VMEM, no head-major copies), in
+      the form the call's widths name: one q·k width that is the value
+      width too, in whole-vreg lane groups, no causal mask (``logbert``);
+      or two q·k widths and a value width of whole lane groups, the
+      second q·k width dividing one, causal or not (latent attention);
     * everything else ``"einsum"``: the CPU (tier-1 tests, the host twin —
       the kernel would run in the Pallas interpreter), a mesh of more than
-      one device (GSPMD does not partition a Pallas call), a causal mask or
-      a value width of its own (models/moe_mla.py), fewer rows.
+      one device (GSPMD does not partition a Pallas call), head-major
+      calls with a causal mask or a value width of their own
+      (:func:`attention`), widths off the lane groups, fewer rows (the
+      fit's 32-row step).
     """
     if impl != "auto":
         return impl
@@ -93,17 +115,22 @@ def attention_route(impl: str, platform: str, s: int, t: int, heads: int,
         return "einsum"
     if t >= FLASH_MIN_SEQ:
         return "flash"
-    if (mesh_devices == 1 and not causal and s == t and value_dim == head_dim
-            and rows >= SHORT_MIN_ROWS):
-        from .shortattn import fits
+    if mesh_devices == 1 and s == t and rows >= SHORT_MIN_ROWS:
+        from .shortattn import fits, fits_latent
 
-        if fits(s, heads, head_dim):
+        if rope_dim:
+            if fits_latent(s, heads, head_dim - rope_dim, rope_dim,
+                           value_dim):
+                return "short"
+        elif (not causal and value_dim == head_dim
+                and fits(s, heads, head_dim)):
             return "short"
     return "einsum"
 
 
 def _resolve(impl: str, platform: Optional[str], q_shape, t: int,
-             value_dim: int, causal: bool, record: bool = True) -> tuple:
+             value_dim: int, causal: bool, record: bool = True,
+             rope_dim: int = 0) -> tuple:
     """(implementation, platform) for a call with head-major query shape
     ``q_shape``, recorded where a scorer listens."""
     rows, heads, s, head_dim = q_shape
@@ -111,7 +138,7 @@ def _resolve(impl: str, platform: Optional[str], q_shape, t: int,
         platform = jax.default_backend()
     mesh_devices, routes = _PLACEMENT.get()
     impl = attention_route(impl, platform, s, t, heads, head_dim, value_dim,
-                           causal, rows, mesh_devices)
+                           causal, rows, mesh_devices, rope_dim)
     if record and routes is not None:
         routes[rows] = impl
     return impl, platform
@@ -173,9 +200,11 @@ def attention(
     interpret mode on ``cpu`` — and only there.
 
     ``causal`` adds the lower-triangular mask (query s sees keys t <= s;
-    S must equal T). Only the einsum route has it, as it alone takes a
-    value width other than the q·k width: flash, blockwise and ring refuse
-    either by name rather than compute something else."""
+    S must equal T). From this head-major layout only the einsum route has
+    it, as it alone takes a value width other than the q·k width: flash,
+    short, blockwise and ring refuse either by name rather than compute
+    something else (latent attention's causal kernel takes its operands
+    token-major: :func:`latent_attention`)."""
     impl, platform = _resolve(impl, platform, q.shape, k.shape[2],
                               v.shape[-1], causal)
     if impl != "einsum" and (causal or v.shape[-1] != q.shape[-1]):
@@ -212,6 +241,110 @@ def self_attention(
                                    platform == "cpu")
         q, k, v = split_heads(qkv, heads)
         return merge_heads(_attention(q, k, v, key_mask, impl, platform))
+
+
+def rotary_tables(s: int, r: int, theta: float) -> tuple:
+    """(cos, sin) ``[S, R]`` float32 of rotary positions 0..S-1 over R
+    interleaved lanes: lanes ``2i`` and ``2i+1`` share ``pos ·
+    theta^(-2i/R)``."""
+    inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    return (jnp.repeat(jnp.cos(angle), 2, axis=-1),
+            jnp.repeat(jnp.sin(angle), 2, axis=-1))
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions over the last axis of ``x`` [..., S, R], pairs
+    interleaved: ``x[2i], x[2i+1]`` turn by ``pos · theta^(-2i/R)`` and stay
+    where they are (the published code moves the pairs' halves apart; q and
+    k share either layout, and only q·k is read). The pair swap is a matmul
+    with a constant ±1 matrix — exact, and on the MXU — because a reshape to
+    ``[..., R/2, 2]`` costs a relayout of the whole tensor on the TPU (7 ms
+    a layer at 32768 tokens against 1). Angles and products in float32."""
+    r = x.shape[-1]
+    cos, sin = rotary_tables(x.shape[-2], r, theta)
+    swap = np.zeros((r, r), np.float32)
+    swap[np.arange(1, r, 2), np.arange(0, r, 2)] = -1.0   # out[2i] = -x[2i+1]
+    swap[np.arange(0, r, 2), np.arange(1, r, 2)] = 1.0    # out[2i+1] = x[2i]
+    turned = jnp.dot(x, jnp.asarray(swap, x.dtype),
+                     preferred_element_type=jnp.float32)
+    return x.astype(jnp.float32) * cos + turned * sin
+
+
+def latent_attention(
+    q: jax.Array,        # [B * S, H * nope + H * rope]: every head's nope part, then every head's rope part
+    kv: jax.Array,       # [B * S, H * nope + H * Dv]: every head's k_nope, then every head's values
+    k_rope: jax.Array,   # [B * S, rope]: one for all heads, not yet turned
+    key_mask: jax.Array,  # [B, S] bool; True = attend
+    heads: int,
+    nope: int,
+    theta: float,
+    impl: str = "auto",
+    platform: Optional[str] = None,
+    causal: bool = False,
+) -> jax.Array:
+    """Latent attention's core from its projections' own, token-major
+    layout → ``[B * S, H * Dv]``, ready for the output projection.
+
+    A head's logits are ``q_nope·k_nopeᵀ + rot(q_rope)·rot(k_rope)ᵀ``
+    scaled by ``(nope + rope)^-0.5`` — the sum the ``nope + rope``-wide
+    contraction computes, so the two parts are never concatenated — with
+    rotary positions (:func:`rotary`, base ``theta``) on the rope parts
+    only, turned in float32 and cast to the operands' dtype. ``impl`` /
+    ``platform`` / ``causal`` as :func:`attention`; the routes here are
+    ``short`` (ops/shortattn.py's two-width kernel: nothing head-major, no
+    ``k_rope`` broadcast over heads and no float32 logits reach HBM) and
+    ``einsum`` (:func:`latent_einsum`)."""
+    b, s = key_mask.shape
+    rope = k_rope.shape[-1]
+    value_dim = kv.shape[-1] // heads - nope
+    impl, platform = _resolve(impl, platform, (b, heads, s, nope + rope), s,
+                              value_dim, causal, rope_dim=rope)
+    with jax.named_scope(f"attn_{impl}"):
+        if impl == "short":
+            from .shortattn import short_latent_attention
+
+            return short_latent_attention(q, kv, k_rope, key_mask, heads,
+                                          nope, theta, causal, None,
+                                          platform == "cpu")
+        if impl != "einsum":
+            raise ValueError(
+                f"attention impl={impl!r} does not compute latent "
+                "attention (two q·k widths, a value width of its own): "
+                "'short' and 'einsum' do")
+        return latent_einsum(q, kv, k_rope, key_mask, heads, nope, theta,
+                             causal)
+
+
+def latent_einsum(q: jax.Array, kv: jax.Array, k_rope: jax.Array,
+                  key_mask: jax.Array, heads: int, nope: int, theta: float,
+                  causal: bool) -> jax.Array:
+    """:func:`latent_attention` through ``dot_product_attention`` on
+    ``nope + rope``-wide heads: head-major copies, the rope parts turned
+    and concatenated back, ``k_rope`` broadcast over the heads — what the
+    kernel does without, and what its backward differentiates."""
+    b, s = key_mask.shape
+    rope = k_rope.shape[-1]
+
+    def head_major(x: jax.Array) -> jax.Array:
+        return x.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
+
+    q_nope, q_rope = (head_major(x) for x in
+                      jnp.split(q, [heads * nope], axis=-1))
+    k_nope, v = (head_major(x) for x in
+                 jnp.split(kv, [heads * nope], axis=-1))
+    with jax.named_scope("rope"):
+        q_rope = rotary(q_rope, theta).astype(q.dtype)
+        k_rope = rotary(k_rope.reshape(b, 1, s, rope), theta).astype(q.dtype)
+    mask = key_mask[:, None, None, :]
+    if causal:
+        mask = mask & jnp.tril(jnp.ones((s, s), bool))[None, None]
+    out = dot_product_attention(
+        jnp.concatenate([q_nope, q_rope], axis=-1),
+        jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (b, heads, s, rope))], axis=-1),
+        v, mask)
+    return merge_heads(out).reshape(b * s, -1)
 
 
 def split_heads(qkv: jax.Array, heads: int) -> tuple:

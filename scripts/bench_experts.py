@@ -10,7 +10,9 @@ it on the v5e (a gather combine out of an [N*K, D] buffer; every held expert
 over every token) is in PERF.md section 6, PR 27, with its numbers.
 
 ``--calls`` times the whole ``moe_mla`` scoring call per bucket instead
-(random weights at the benchmark configuration's shape).
+(random weights at the benchmark configuration's shape), with ``attn_impl:
+einsum`` and as ``auto`` routes it (latent attention's two-width kernel from
+256 rows), and how far the two calls' scores part.
 
 One JSON line per reading; run it ON the TPU:
     python scripts/bench_experts.py [--tokens 32768] [--calls]
@@ -86,9 +88,10 @@ def bench_calls() -> None:
     config = read_json(os.path.join(repo, "benchmark", "configs",
                                     "kanana2-30b-a3b-ep8.json"))
     (block,) = config["stages"]["detector"]["component"]["detectors"].values()
-    scorer = MoEMLAScorer(MoEMLAConfig(
+    einsum, scorer = (MoEMLAScorer(MoEMLAConfig(
         arch=MoEMLAArch.from_mapping(block["arch"]),
-        vocab_size=block["vocab_size"], seq_len=block["seq_len"]))
+        vocab_size=block["vocab_size"], seq_len=block["seq_len"],
+        attn_impl=impl)) for impl in ("einsum", "auto"))
     params = jax.jit(lambda k: scorer.init(k)[0])(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     for rows in (32, 256, 512, 1024):
@@ -97,8 +100,16 @@ def bench_calls() -> None:
         tokens[:, 0] = 2
         tokens = jnp.asarray(tokens)
         ms = timed(scorer._score, params, tokens)
-        counts = [int(c) for c in scorer._score(params, tokens)[1]]
-        print(json.dumps({"rows": rows, "call_ms": ms, "counts": counts,
+        scores, counts = scorer._score(params, tokens)
+        gap = np.asarray(einsum._score(params, tokens)[0]) - np.asarray(scores)
+        print(json.dumps({"rows": rows, "call_ms": ms,
+                          "einsum_call_ms": timed(einsum._score, params,
+                                                  tokens),
+                          "score_gap_max_nats": float(np.abs(gap).max()),
+                          "score_gap_rms_nats": float(np.sqrt(
+                              (gap ** 2).mean())),
+                          "counts": [int(c) for c in counts],
+                          "attn_route": scorer.attn_routes.get(rows),
                           "head_route": scorer.head_routes.get(rows),
                           "lines_per_s": 1e3 * rows / ms}), flush=True)
 

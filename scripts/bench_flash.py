@@ -13,6 +13,14 @@ of the memory floor (q, k, v read and the output written once in bfloat16:
 a short and a long chain of data-dependent calls inside one jit, so dispatch
 and fetch cancel (scripts/bench_scorehead.py's protocol).
 
+``--latent`` times latent attention's causal two-width kernel
+(``short_latent_attention``: 32 heads of 128 ‖ 64, values 128, S 32) against
+``latent_einsum`` — the head-major copies, rope's slices and concatenations
+and the einsum core it replaced — at the sparse-expert scorer's 256-, 512-
+and 1024-row buckets: ms a layer's rope and core, and the share of the
+memory floor (q, k_nope, v and k_rope read, the output written once: 1.21 GB
+= 1.5 ms at 1024 rows).
+
 ``--buckets`` times the whole ``LogBERTScorer.score`` call at the flagship
 shape per power-of-two bucket, ``attn_impl: einsum`` against ``short``, with
 the largest score difference: the table ``attention_route``'s row threshold
@@ -134,6 +142,53 @@ def bench_short() -> None:
         rows *= 2
 
 
+def bench_latent() -> None:
+    """One JSON line per row count: ``latent_einsum`` against the kernel
+    (median of ten calls; a call is milliseconds, dispatch a tenth of
+    one)."""
+    from detectmateservice_tpu.ops.attention import latent_einsum
+    from detectmateservice_tpu.ops.shortattn import short_latent_attention
+
+    device = jax.devices()[0]
+    on_tpu = device.platform == "tpu"
+    heads, nope, rope, dv, s, theta = 32, 128, 64, 128, 32, 1e6
+    rng = np.random.default_rng(0)
+
+    def median_ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        ts = []
+        for _ in range(10):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            ts.append((time.perf_counter() - t0) * 1e3)
+        return statistics.median(ts)
+
+    for rows in ((256, 512, 1024) if on_tpu else (8,)):
+        n = rows * s
+        q, kv, k_rope = (
+            jnp.asarray(rng.standard_normal((n, w)), jnp.bfloat16)
+            for w in (heads * (nope + rope), heads * (nope + dv), rope))
+        lengths = rng.integers(1, s + 1, rows)
+        mask = jnp.asarray(np.arange(s)[None] < lengths[:, None])
+        einsum = jax.jit(lambda q, kv, kr, m: latent_einsum(
+            q, kv, kr, m, heads, nope, theta, True))
+        short = jax.jit(lambda q, kv, kr, m: short_latent_attention(
+            q, kv, kr, m, heads, nope, theta, True, None, not on_tpu))
+        floor_ms = 2 * n * (heads * (2 * nope + rope + 2 * dv) + rope
+                            ) / _HBM_BYTES_PER_S * 1e3
+        ref = einsum(*(x.astype(jnp.float32) for x in (q, kv, k_rope)), mask)
+        out = {"rows": rows, "device": device.device_kind,
+               "floor_ms": round(floor_ms, 4)}
+        for name, fn in (("einsum", einsum), ("short", short)):
+            got = fn(q, kv, k_rope, mask).astype(jnp.float32)
+            out[f"{name}_max_err"] = round(float(jnp.abs(got - ref).max()), 5)
+            ms = median_ms(fn, q, kv, k_rope, mask)
+            out[f"{name}_ms"] = round(ms, 4)
+            out[f"{name}_floor_share"] = round(100 * floor_ms / ms, 1)
+        out["speedup"] = round(out["einsum_ms"] / out["short_ms"], 2)
+        print(json.dumps(out), flush=True)
+
+
 def bench_buckets() -> None:
     """One JSON line per bucket: median ms of the whole scoring call with
     the einsum attention and with the short kernel (the fused head in
@@ -183,6 +238,8 @@ def bench_buckets() -> None:
 if __name__ == "__main__":
     if "--short" in sys.argv[1:]:
         bench_short()
+    elif "--latent" in sys.argv[1:]:
+        bench_latent()
     elif "--buckets" in sys.argv[1:]:
         bench_buckets()
     else:

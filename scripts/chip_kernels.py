@@ -13,6 +13,10 @@ that quietly fell back to XLA or to the interpreter cannot pass.
 * ``ops/shortattn.short_attention`` at the served shape (32768 rows, S 32,
   4 heads of 64, bf16) and at 300 rows of S 16, PAD masks with a fully
   padded line, against ``dot_product_attention`` in float32;
+* ``ops/shortattn.short_latent_attention`` (latent attention's causal,
+  two-width form) at the sparse-expert scorer's served shape (1024 rows, S
+  32, 32 heads of 128 ‖ 64, values 128, bf16) and at 300 rows of S 16,
+  against ``ops/attention.latent_einsum`` in float32;
 * ``ops/flash.flash_attention`` forward at S = T = 2048 and 8192, D = 64,
   bf16, with a key mask;
 * the flash backward kernels (dq; dk+dv) at the same shapes.
@@ -20,7 +24,8 @@ that quietly fell back to XLA or to the interpreter cannot pass.
 Prints one JSON line per check and a final summary line; the full record
 goes to ``chiprun_out/chip_kernels.json``. Exit code 1 if any check failed.
 
-Usage: python scripts/chip_kernels.py
+Usage: python scripts/chip_kernels.py [WORD]   (only the checks whose name
+holds WORD, e.g. ``short``)
 """
 from __future__ import annotations
 
@@ -108,6 +113,39 @@ def check_short_attention(rows: int, s: int) -> dict:
             "finite": bool(np.isfinite(got).all()), "ok": err < 3e-2}
 
 
+def check_short_latent_attention(rows: int, s: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from detectmateservice_tpu.ops.attention import latent_einsum
+    from detectmateservice_tpu.ops.shortattn import short_latent_attention
+
+    heads, nope, rope, dv, theta = 32, 128, 64, 128, 1e6
+    keys = jax.random.split(jax.random.PRNGKey(rows + s), 4)
+    q, kv, k_rope = (
+        jax.random.normal(key, (rows * s, width), jnp.float32
+                          ).astype(jnp.bfloat16)
+        for key, width in zip(keys, (heads * (nope + rope),
+                                     heads * (nope + dv), rope)))
+    lengths = jax.random.randint(keys[3], (rows,), 1, s + 1).at[0].set(0)
+    key_mask = jnp.arange(s)[None, :] < lengths[:, None]
+    exe, compile_s = _compiled(
+        lambda q, kv, kr, m: short_latent_attention(
+            q, kv, kr, m, heads, nope, theta, True, None, False),
+        q, kv, k_rope, key_mask)
+    got = np.asarray(exe(q, kv, k_rope, key_mask), np.float32)
+    want = np.asarray(jax.jit(lambda q, kv, kr, m: latent_einsum(
+        q.astype(jnp.float32), kv.astype(jnp.float32),
+        kr.astype(jnp.float32), m, heads, nope, theta, True))(
+        q, kv, k_rope, key_mask))
+    err = float(np.max(np.abs(got - want)))
+    # the turned rope parts are rounded to bfloat16 on the kernel's side
+    # only: logits of magnitude ~14 move by ~0.05
+    return {"compile_s": round(compile_s, 2), "max_abs_err": err,
+            "finite": bool(np.isfinite(got).all()), "ok": err < 6e-2}
+
+
 def _flash_inputs(s: int):
     import jax
     import jax.numpy as jnp
@@ -180,6 +218,10 @@ CHECKS = [
      lambda: check_short_attention(32768, 32)),
     ("short_attention rows=300 S=16 H=4 D=64",
      lambda: check_short_attention(300, 16)),
+    ("short_latent_attention moe_mla served rows=1024 S=32 H=32 128|64 v128",
+     lambda: check_short_latent_attention(1024, 32)),
+    ("short_latent_attention rows=300 S=16 H=32 128|64 v128",
+     lambda: check_short_latent_attention(300, 16)),
     ("flash forward S=2048", lambda: check_flash_forward(2048)),
     ("flash forward S=8192", lambda: check_flash_forward(8192)),
     ("flash backward S=2048", lambda: check_flash_backward(2048)),
@@ -196,7 +238,10 @@ def main() -> int:
               "the kernels compile for the chip only", file=sys.stderr)
         return 1
     results = []
+    word = sys.argv[1] if len(sys.argv) > 1 else ""
     for name, check in CHECKS:
+        if word not in name:
+            continue
         entry = {"check": name}
         try:
             entry.update(check())

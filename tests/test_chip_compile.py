@@ -1,6 +1,7 @@
 """Compiles for a DESCRIBED TPU v5e, no chip attached (the
 on-chip-measurement guide's third rehearsal): the kernels of the
-sparse-expert scorer's main path at the published widths and ``logbert``'s
+sparse-expert scorer's main path at the published widths, its widest
+scoring program with latent attention's two-width kernel, and ``logbert``'s
 widest scoring program with the short-sequence attention kernel, so that what the
 chip's compiler refuses — a tile that does not fit, a shape a kernel cannot
 take — fails here and costs no chip time. Nothing runs; no time or result
@@ -141,6 +142,51 @@ def test_logberts_widest_scoring_program_compiles_with_the_short_kernel(
               or " copy(" in line and "[32768,32,768]" in line]
     assert not copies, copies[:2]
     assert compiled.memory_analysis().temp_size_in_bytes < 3_500_000_000
+
+
+def test_moe_mlas_widest_scoring_program_holds_no_192_wide_head(
+        one_chip, no_compile_cache):
+    """``kanana2-30b-a3b-ep8``'s 1024-row bucket as ``auto`` routes it on
+    one TPU: six two-width attention kernels and the fused head; nothing of
+    shape ``[1024, 32, 32, ·]`` — no 192-wide head, no float32 ``[1024, 32,
+    32, 32]`` logits, no head-major copy of q, k or v (the einsum route's
+    program holds 798 such arrays) — and a scratch under that route's
+    2,719,007,232 bytes at the parent (1,510,098,432 when this was written:
+    the dense layer's hidden and the expert walk's chunk). About 20 s."""
+    import re
+
+    from benchmark.lib.manifest import read_json
+    from detectmateservice_tpu.models.moe_mla import (
+        MoEMLAArch, MoEMLAConfig, MoEMLAScorer)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    (block,) = read_json(os.path.join(
+        repo, "benchmark", "configs", "kanana2-30b-a3b-ep8.json"))[
+        "stages"]["detector"]["component"]["detectors"].values()
+    scorer = MoEMLAScorer(MoEMLAConfig(
+        arch=MoEMLAArch.from_mapping(block["arch"]),
+        vocab_size=block["vocab_size"], seq_len=block["seq_len"],
+        platform="tpu"))
+    params = jax.tree_util.tree_map(
+        lambda leaf: shape(leaf.shape, leaf.dtype, one_chip),
+        jax.eval_shape(lambda: scorer.init(jax.random.PRNGKey(0))[0]))
+    compiled = jax.jit(scorer._score_impl).lower(
+        params, shape((1024, 32), jnp.uint16, one_chip)).compile()
+    assert scorer.attn_routes == {1024: "short"}
+    assert scorer.head_routes == {1024: "pallas"}
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "attn_short_latent" in line]
+    assert len(kernels) == 6, len(kernels)
+    assert "lse_pallas" in text
+    head_major = sorted(set(re.findall(r"\w+\[1024,32,32,\d+\]", text)))
+    assert not head_major, head_major
+    wide = [line for line in text.splitlines()
+            if " transpose(" in line and re.search(
+                r"\[32768,(4096|6144|8192)\]|\[1024,32,(4096|6144|8192)\]",
+                line)]
+    assert not wide, wide[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_600_000_000
 
 
 def test_head_route_takes_the_kernel_at_the_cells_bucket():

@@ -400,6 +400,106 @@ def test_attention_causal_and_value_width():
         attention(q, k, v, impl="blockwise", platform="cpu")
 
 
+# -- the attention route (ops/attention.py::latent_attention) ---------------
+
+@pytest.mark.parametrize("dtype,rms_limit,max_limit", [
+    # the benchmark's own limits for this family (its file's ``check``):
+    # score_gap_rms_nats 0.0065, score_gap_max_nats 0.10
+    (jnp.bfloat16, 0.0065, 0.10),
+    (jnp.float32, 1e-5, 1e-4),
+])
+def test_scores_agree_between_the_forced_kernel_and_einsum(dtype, rms_limit,
+                                                           max_limit):
+    """``attn_impl: short`` (the two-width kernel, here in the Pallas
+    interpreter) against ``einsum`` on the same parameters: scores, the
+    routing counts and the recorded route."""
+    import dataclasses
+
+    einsum, params, _ = make_scorer(dtype=dtype, init=0.1)
+    einsum.config = dataclasses.replace(einsum.config, attn_impl="einsum")
+    short = MoEMLAScorer(dataclasses.replace(einsum.config,
+                                             attn_impl="short"))
+    tokens = jnp.asarray(make_tokens(rows=24, seed=3))
+    (a, counts_a), (b, counts_b) = (einsum._score(params, tokens),
+                                    short._score(params, tokens))
+    gap = np.asarray(a, np.float64) - np.asarray(b, np.float64)
+    assert np.isfinite(np.asarray(b)).all()
+    assert np.sqrt((gap ** 2).mean()) <= rms_limit
+    assert np.abs(gap).max() <= max_limit
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(np.asarray(counts_a),
+                                      np.asarray(counts_b))
+    assert einsum.attn_routes == {24: "einsum"}
+    assert short.attn_routes == {24: "short"}
+
+
+def test_one_train_step_through_the_kernels_vjp_agrees():
+    """The fit differentiates through ``short_latent_attention``'s custom
+    vjp where the kernel is forced: loss and updated parameters against
+    the einsum route's."""
+    import dataclasses
+
+    import optax
+
+    einsum, params, _ = make_scorer(init=0.1)
+    short = MoEMLAScorer(dataclasses.replace(einsum.config,
+                                             attn_impl="short"))
+    einsum.optimizer = short.optimizer = optax.sgd(0.1)
+    opt = einsum.optimizer.init(params)
+    tokens, rng = jnp.asarray(make_tokens(rows=8)), jax.random.PRNGKey(1)
+    p_e, _, loss_e = einsum.train_step(params, opt, rng, tokens)
+    p_s, _, loss_s = short.train_step(params, opt, rng, tokens)
+    np.testing.assert_allclose(float(loss_s), float(loss_e), rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(p_e),
+                    jax.tree_util.tree_leaves(p_s)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5)
+
+
+def test_published_widths_take_the_kernel_from_256_rows_on_one_tpu():
+    """32 heads of 128 ‖ 64 with values 128: ``auto`` records ``short``
+    for the served 256-, 512- and 1024-row programs and ``einsum`` for the
+    fit's 32-row step; on the CPU, or on a mesh, ``einsum`` for all.
+    Traced only (``eval_shape``): nothing is lowered for a chip that is not
+    here."""
+    arch = arch_with(hidden_size=256, num_attention_heads=32,
+                     qk_nope_head_dim=128, qk_rope_head_dim=64,
+                     v_head_dim=128, num_hidden_layers=2)
+
+    def routes(platform, mesh_devices=1):
+        scorer = MoEMLAScorer(MoEMLAConfig(
+            arch=MoEMLAArch.from_mapping(arch), vocab_size=VOCAB,
+            seq_len=32, platform=platform, head_impl="einsum"))
+        scorer.mesh_devices = mesh_devices
+        params = jax.eval_shape(lambda: scorer.init(jax.random.PRNGKey(0))[0])
+        for rows in (32, 256, 512, 1024):
+            jax.eval_shape(scorer._score_impl, params,
+                           jax.ShapeDtypeStruct((rows, 32), jnp.uint16))
+        return scorer.attn_routes
+
+    assert routes("tpu") == {32: "einsum", 256: "short", 512: "short",
+                             1024: "short"}
+    assert set(routes("cpu").values()) == {"einsum"}
+    assert set(routes("tpu", mesh_devices=4).values()) == {"einsum"}
+
+
+def test_the_projections_keep_nn_denses_parameters():
+    """``HeadSplitDense`` regroups columns of a kernel whose name, shape
+    and stored layout are ``nn.Dense``'s: column ``h * 24 + i`` of
+    ``q_proj`` is head h's i-th, nope first (the reference and every
+    checkpoint read it so)."""
+    scorer, params, _ = make_scorer()
+    attn = params["params"]["layers_0"]
+    assert attn["q_proj"]["kernel"].shape == (64, 4 * (16 + 8))
+    assert attn["kv_up"]["kernel"].shape == (32, 4 * (16 + 16))
+    assert set(attn["q_proj"]) == set(attn["kv_up"]) == {"kernel"}
+
+
+def test_detector_admits_the_kernel_by_name():
+    det = JaxScorerDetector(config=detector_config(attn_impl="short"))
+    assert det.config.attn_impl == "short"
+
+
 # -- through JaxScorerDetector ----------------------------------------------
 
 def detector_config(**overrides):

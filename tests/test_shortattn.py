@@ -11,10 +11,11 @@ import numpy as np
 import pytest
 
 from detectmateservice_tpu.ops.attention import (
-    attention, attention_route, dot_product_attention, merge_heads,
-    self_attention, split_heads)
+    attention, attention_route, dot_product_attention, latent_attention,
+    latent_einsum, merge_heads, rotary, self_attention, split_heads)
 from detectmateservice_tpu.ops.shortattn import (
-    einsum_route, fits, heads_per_lane_group, short_attention)
+    einsum_route, fits, fits_latent, heads_per_lane_group, short_attention,
+    short_latent_attention)
 
 
 def make_qkv(b, s, h, d, dtype=jnp.bfloat16, seed=0):
@@ -173,6 +174,237 @@ class TestRouteRule:
                    mesh, want):
         assert attention_route(impl, platform, s, t, heads, d, dv, causal,
                                rows, mesh) == want
+
+    @pytest.mark.parametrize(
+        "impl,platform,s,heads,nope,rope,dv,causal,rows,mesh,want", [
+            # latent attention at the published widths (32 heads of
+            # 128 ‖ 64, values 128) on one TPU: the two-width kernel for
+            # the served 256-, 512- and 1024-row programs, causal or not
+            ("auto", "tpu", 32, 32, 128, 64, 128, True, 1024, 1, "short"),
+            ("auto", "tpu", 32, 32, 128, 64, 128, True, 512, 1, "short"),
+            ("auto", "tpu", 32, 32, 128, 64, 128, True, 256, 1, "short"),
+            ("auto", "tpu", 16, 32, 128, 64, 128, True, 256, 1, "short"),
+            ("auto", "tpu", 32, 32, 128, 64, 128, False, 1024, 1, "short"),
+            ("auto", "tpu", 32, 4, 256, 128, 128, True, 256, 1, "short"),
+            # the fit's 32-row step, the CPU, a mesh: einsum
+            ("auto", "tpu", 32, 32, 128, 64, 128, True, 32, 1, "einsum"),
+            ("auto", "tpu", 32, 32, 128, 64, 128, True, 255, 1, "einsum"),
+            ("auto", "cpu", 32, 32, 128, 64, 128, True, 1024, 1, "einsum"),
+            ("auto", "tpu", 32, 32, 128, 64, 128, True, 1024, 4, "einsum"),
+            # widths off the lane groups (the test scorers'), lines that do
+            # not pack, an odd group of rope parts
+            ("auto", "tpu", 16, 4, 16, 8, 16, True, 256, 1, "einsum"),
+            ("auto", "tpu", 32, 32, 64, 64, 128, True, 1024, 1, "einsum"),
+            ("auto", "tpu", 32, 32, 128, 64, 64, True, 1024, 1, "einsum"),
+            ("auto", "tpu", 24, 32, 128, 64, 128, True, 1024, 1, "einsum"),
+            ("auto", "tpu", 32, 3, 128, 64, 128, True, 1024, 1, "einsum"),
+            # forcing
+            ("einsum", "tpu", 32, 32, 128, 64, 128, True, 1024, 1, "einsum"),
+            ("short", "cpu", 16, 4, 16, 8, 16, True, 8, 1, "short"),
+        ])
+    def test_latent_route(self, impl, platform, s, heads, nope, rope, dv,
+                          causal, rows, mesh, want):
+        assert attention_route(impl, platform, s, s, heads, nope + rope, dv,
+                               causal, rows, mesh, rope_dim=rope) == want
+
+    @pytest.mark.parametrize("seq,heads,nope,rope,dv,per,want", [
+        (32, 32, 128, 64, 128, 2, True), (16, 32, 128, 64, 128, 2, True),
+        (128, 4, 256, 128, 128, 1, True), (32, 8, 128, 32, 256, 4, True),
+        (16, 4, 16, 8, 16, 4, False),     # interpret mode only
+        (32, 32, 64, 64, 128, 2, False), (32, 32, 128, 64, 64, 2, False),
+        (32, 3, 128, 64, 128, 1, False), (24, 32, 128, 64, 128, 2, False),
+    ])
+    def test_what_the_compiled_latent_kernel_takes(self, seq, heads, nope,
+                                                   rope, dv, per, want):
+        assert heads_per_lane_group(heads, rope) == per
+        assert fits_latent(seq, heads, nope, rope, dv) is want
+
+
+def make_latent(b, s, h, nope, rope, dv, dtype=jnp.bfloat16, seed=0):
+    """Latent attention's token-major operands as the projections write
+    them — q ``[B·S, H·nope | H·rope]``, kv ``[B·S, H·nope | H·dv]``, one
+    ``k_rope [B·S, rope]`` — and a PAD mask of random line lengths whose
+    first line is all PAD and whose last is full."""
+    rng = np.random.default_rng(seed + b + s)
+    q, kv, k_rope = (jnp.asarray(rng.standard_normal((b * s, w)), dtype)
+                     for w in (h * (nope + rope), h * (nope + dv), rope))
+    lengths = rng.integers(1, s + 1, b)
+    lengths[0], lengths[-1] = 0, s
+    return q, kv, k_rope, jnp.asarray(np.arange(s)[None] < lengths[:, None])
+
+
+def latent_reference(q, kv, k_rope, mask, h, nope, theta, causal=True):
+    """``dot_product_attention`` on concatenated, head-major float32
+    operands — written out here, apart from ``latent_einsum``."""
+    b, s = mask.shape
+    q, kv, k_rope = (x.astype(jnp.float32) for x in (q, kv, k_rope))
+
+    def heads_of(x):
+        return x.reshape(b, s, h, -1).transpose(0, 2, 1, 3)
+
+    q_rope = rotary(heads_of(q[:, h * nope:]), theta)
+    k_rot = rotary(k_rope.reshape(b, 1, s, -1), theta)
+    keys = jnp.concatenate([heads_of(kv[:, :h * nope]),
+                            jnp.broadcast_to(k_rot, q_rope.shape)], -1)
+    allowed = mask[:, None, None, :]
+    if causal:
+        allowed = allowed & jnp.tril(jnp.ones((s, s), bool))
+    out = dot_product_attention(
+        jnp.concatenate([heads_of(q[:, :h * nope]), q_rope], -1), keys,
+        heads_of(kv[:, h * nope:]), allowed)
+    return merge_heads(out).reshape(b * s, -1)
+
+
+THETA = 1e6
+
+
+class TestLatentKernelParity:
+    """The causal two-width route (``short_latent_attention``) against
+    ``dot_product_attention`` on concatenated operands."""
+
+    # the published heads and widths at S 32 and 16; a small shape off the
+    # lane groups (the test scorers'); one head a rope group; B off the
+    # block multiple and several grid steps with the last part padding
+    @pytest.mark.parametrize("b,s,h,nope,rope,dv,block", [
+        (9, 32, 32, 128, 64, 128, None),
+        (11, 16, 32, 128, 64, 128, None),
+        (70, 32, 4, 128, 64, 128, 1024),
+        (12, 16, 4, 16, 8, 16, None),
+        (9, 32, 2, 128, 128, 256, None),
+    ])
+    def test_matches_dot_product_attention_bf16(self, b, s, h, nope, rope,
+                                                dv, block):
+        q, kv, k_rope, mask = make_latent(b, s, h, nope, rope, dv)
+        want = latent_reference(q, kv, k_rope, mask, h, nope, THETA)
+        got = short_latent_attention(q, kv, k_rope, mask, h, nope, THETA,
+                                     True, block, True)
+        assert got.shape == (b * s, h * dv) and got.dtype == jnp.bfloat16
+        assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+        err = float(jnp.abs(got.astype(jnp.float32) - want).max())
+        # bfloat16's error on outputs of magnitude ~1: the turned rope
+        # parts', the probabilities' and the output's roundings
+        assert err < 4e-2
+        # and no further from float32 than the einsum route is
+        einsum = latent_einsum(q, kv, k_rope, mask, h, nope, THETA,
+                               True).astype(jnp.float32)
+        assert err <= 2 * float(jnp.abs(einsum - want).max()) + 1e-3
+
+    @pytest.mark.parametrize("b,s,h,nope,rope,dv,causal", [
+        (9, 32, 32, 128, 64, 128, True),
+        (9, 16, 32, 128, 64, 128, True),
+        (12, 16, 4, 16, 8, 16, True),
+        (12, 16, 4, 16, 8, 16, False),
+        (9, 32, 4, 128, 64, 128, False),
+    ])
+    def test_matches_in_float32(self, b, s, h, nope, rope, dv, causal):
+        q, kv, k_rope, mask = make_latent(b, s, h, nope, rope, dv,
+                                          jnp.float32)
+        got = short_latent_attention(q, kv, k_rope, mask, h, nope, THETA,
+                                     causal, None, True)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(latent_reference(
+                q, kv, k_rope, mask, h, nope, THETA, causal)),
+            rtol=2e-5, atol=2e-5)
+
+    def test_a_fully_padded_line_attends_uniformly_over_its_own_keys(self):
+        """Every key PAD: ``finfo(float32).min`` everywhere, the causal
+        mask with it, so every position's output is the mean of the line's
+        S values — not NaN, not its tile neighbours'."""
+        h, nope, dv, s = 4, 128, 128, 32
+        q, kv, k_rope, mask = make_latent(8, s, h, nope, 64, dv, jnp.float32)
+        got = short_latent_attention(q, kv, k_rope, mask, h, nope, THETA,
+                                     True, None, True)
+        v = kv[:s, h * nope:]
+        np.testing.assert_allclose(
+            np.asarray(got[:s]),
+            np.broadcast_to(np.asarray(v.mean(0)), (s, h * dv)),
+            rtol=1e-5, atol=1e-5)
+
+    def test_causal_a_later_token_moves_no_earlier_output(self):
+        q, kv, k_rope, mask = make_latent(8, 32, 4, 128, 64, 128,
+                                          jnp.float32)
+        mask = jnp.ones_like(mask)
+        at = 20                                  # position within line 3
+        row = 3 * 32 + at
+        moved = [x.at[row].add(1.0) for x in (q, kv, k_rope)]
+        a = short_latent_attention(q, kv, k_rope, mask, 4, 128, THETA, True,
+                                   None, True).reshape(8, 32, -1)
+        b = short_latent_attention(*moved, mask, 4, 128, THETA, True, None,
+                                   True).reshape(8, 32, -1)
+        np.testing.assert_array_equal(np.asarray(a[3, :at]),
+                                      np.asarray(b[3, :at]))
+        assert float(jnp.abs(a[3, at:] - b[3, at:]).max()) > 1e-3
+        np.testing.assert_array_equal(np.asarray(a[:3]), np.asarray(b[:3]))
+
+    @pytest.mark.parametrize("shapes,heads,nope", [
+        (((4 * 24, 4 * 24), (4 * 24, 4 * 32), (4 * 24, 8)), 4, 16),  # S 24
+        (((64, 4 * 24), (64, 4 * 32), (64, 8)), 4, 12),   # widths disagree
+        (((64, 4 * 40), (64, 4 * 32), (64, 24)), 4, 16),  # nope off a block
+    ])
+    def test_refuses_what_it_cannot_pack(self, shapes, heads, nope):
+        q, kv, k_rope = (jnp.zeros(shape, jnp.bfloat16) for shape in shapes)
+        rows = q.shape[0] // (24 if q.shape[0] == 96 else 16)
+        mask = jnp.ones((rows, q.shape[0] // rows), bool)
+        with pytest.raises(ValueError, match="short_latent_attention"):
+            short_latent_attention(q, kv, k_rope, mask, heads, nope, THETA,
+                                   True, None, True)
+
+    def test_the_compiled_kernel_refuses_narrow_widths_by_name(self):
+        q, kv, k_rope, mask = make_latent(4, 16, 4, 16, 8, 16)
+        with pytest.raises(ValueError, match="whole lane groups"):
+            short_latent_attention(q, kv, k_rope, mask, 4, 16, THETA, True,
+                                   None, False)
+
+
+class TestLatentGradients:
+    @pytest.mark.parametrize("b,s,h,nope,rope,dv", [
+        (9, 32, 4, 128, 64, 128), (12, 16, 4, 16, 8, 16)])
+    def test_gradients_are_the_einsum_routes(self, b, s, h, nope, rope, dv):
+        """The backward is ``latent_einsum``'s vjp recomputed from the
+        operands: the gradients of q, kv and k_rope against that route's
+        own."""
+        operands = make_latent(b, s, h, nope, rope, dv, jnp.float32)
+        mask = operands[3]
+        w = jnp.asarray(np.random.default_rng(1).standard_normal(
+            (b * s, h * dv)), jnp.float32)
+
+        def loss(fn):
+            return lambda q, kv, k_rope: jnp.sum(fn(q, kv, k_rope) * w)
+
+        got = jax.grad(loss(lambda *x: short_latent_attention(
+            *x, mask, h, nope, THETA, True, None, True)),
+            argnums=(0, 1, 2))(*operands[:3])
+        want = jax.grad(loss(lambda *x: latent_einsum(
+            *x, mask, h, nope, THETA, True)), argnums=(0, 1, 2))(
+            *operands[:3])
+        for name, g, r in zip(("q", "kv", "k_rope"), got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                       rtol=1e-6, atol=1e-6, err_msg=name)
+            assert float(jnp.abs(g).max()) > 0
+
+
+class TestLatentEntry:
+    def test_forced_short_matches_einsum_and_both_are_recorded(self):
+        from detectmateservice_tpu.ops.attention import placement
+
+        q, kv, k_rope, mask = make_latent(16, 16, 4, 16, 8, 16, jnp.float32)
+        routes = {}
+        with placement(1, routes):
+            a = latent_attention(q, kv, k_rope, mask, 4, 16, THETA,
+                                 impl="einsum", causal=True)
+            assert routes == {16: "einsum"}
+            b = latent_attention(q, kv, k_rope, mask, 4, 16, THETA,
+                                 impl="short", platform="cpu", causal=True)
+            assert routes == {16: "short"}
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("impl", ["flash", "ring", "blockwise"])
+    def test_other_routes_refuse_latent_attention_by_name(self, impl):
+        q, kv, k_rope, mask = make_latent(4, 16, 4, 16, 8, 16, jnp.float32)
+        with pytest.raises(ValueError, match="latent"):
+            latent_attention(q, kv, k_rope, mask, 4, 16, THETA, impl=impl,
+                             platform="cpu", causal=True)
 
 
 class TestAttentionEntries:
