@@ -35,19 +35,23 @@ from .scorer_families import FAMILIES
 
 class JaxScorerDetectorConfig(CoreDetectorConfig):
     method_type: str = "jax_scorer"
-    # "mlp" | "gru" | "logbert" | "moe_mla" (scorer_families.FAMILIES)
+    # "mlp" | "gru" | "logbert" | "moe_mla" | "moe_conv"
+    # (scorer_families.FAMILIES)
     model: str = "mlp"
     vocab_size: int = 32768
     seq_len: int = 32
     dim: int = 128
     depth: int = 2                    # logbert/gru layers
     heads: int = 4                    # logbert only
-    # moe_mla only: the model's shape as the published config.json keys
-    # under their published names (hidden_size, num_attention_heads,
-    # kv_lora_rank, n_routed_experts ...; models/moe_mla.py MoEMLAArch),
+    # moe_mla and moe_conv only: the model's shape as the published
+    # config.json keys under their published names (hidden_size,
+    # num_attention_heads, kv_lora_rank, n_routed_experts ...:
+    # models/moe_mla.py MoEMLAArch; layer_types, conv_L_cache,
+    # num_key_value_heads, num_experts ...: models/moe_conv.py MoEConvArch),
     # plus the chip's share of an expert-parallel group: router_experts
-    # (the published count the router scores over; n_routed_experts is
-    # then the count HELD here) and expert_offset (the first one held)
+    # (the published count the router scores over; the published expert
+    # count's key is then the count HELD here) and expert_offset (the
+    # first one held)
     arch: Optional[Dict[str, Any]] = None
     score_topk: int = 0               # logbert/gru: 0=mean NLL, k>0=top-k mean
     # logbert/gru: candidate-vocab approximate scoring NLL. 0 = exact
@@ -1831,13 +1835,15 @@ class JaxScorerDetector(CoreDetector):
             "warm": self._active_buckets(),
             "retired": sorted(self._retired_buckets),
             # which head (models/base.py head_route), which attention
-            # (ops/attention.py attention_route) and which expert path (the
-            # sparse-expert scorer's) each traced device executable took,
+            # (ops/attention.py attention_route), which short convolution
+            # (ops/shortconv.py conv_route) and which expert path (the
+            # sparse-expert scorers') each traced device executable took,
             # by its rows; decided at trace time, empty where the scorer has
             # no such part. The host twin's calls are not in it: it is
             # pinned to einsum
             "head_route": routes("head_routes"),
             "attn_route": routes("attn_routes"),
+            "conv_route": routes("conv_routes"),
             "expert_route": routes("expert_routes"),
         }
 

@@ -21,7 +21,7 @@ class ScorerFamily(NamedTuple):
 
 def _no_arch(cfg) -> Optional[str]:
     if cfg.arch is not None:
-        return ("'arch' is the moe_mla family's shape key; "
+        return ("'arch' is the moe_mla and moe_conv families' shape key; "
                 f"model {cfg.model!r} takes dim/depth/heads")
     return None
 
@@ -62,27 +62,40 @@ def _build_moe_mla(cfg, model_kw):
         attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
 
 
-def _moe_mla_refuses(cfg) -> Optional[str]:
-    """What the sparse-expert family cannot do yet, by the key that asks
-    for it (ROADMAP: the expert layer across chips, a sliced vocabulary's
-    cross-shard logsumexp, quantized experts, a candidate head)."""
-    if not isinstance(cfg.arch, dict):
-        return ("model 'moe_mla' takes its shape from the mapping 'arch' "
-                "(the published config.json keys; docs/configuration.md)")
-    if cfg.mesh_shape:
-        return ("mesh_shape: the moe_mla scorer runs on one device; "
-                "parallel/mesh.py has no expert axis and no rule for it")
-    if cfg.dtype == "int8w":
-        return "dtype 'int8w': models/quant.py does not quantize experts"
-    if cfg.score_vocab > 0:
-        return ("score_vocab > 0: the moe_mla scorer has the exact head "
-                "only")
-    if cfg.attn_impl not in ("auto", "einsum", "short"):
-        return (f"attn_impl {cfg.attn_impl!r}: latent attention is causal "
-                "with two q·k widths and a value width of its own, which "
-                "the einsum route and the short-sequence kernel compute "
-                "('auto', 'einsum' or 'short')")
-    return None
+def _build_moe_conv(cfg, model_kw):
+    from ...models.moe_conv import MoEConvArch, MoEConvConfig, MoEConvScorer
+
+    return MoEConvScorer(MoEConvConfig(
+        arch=MoEConvArch.from_mapping(cfg.arch), vocab_size=cfg.vocab_size,
+        seq_len=cfg.seq_len, score_topk=cfg.score_topk,
+        attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
+
+
+def _expert_family_refuses(attn_impls: tuple, attn_why: str
+                           ) -> Callable[[Any], Optional[str]]:
+    """What no sparse-expert family can do yet, by the key that asks for it
+    (ROADMAP: the expert layer across chips, a sliced vocabulary's
+    cross-shard logsumexp, quantized experts, a candidate head), and an
+    ``attn_impl`` outside ``attn_impls``, the routes that compute the
+    family's form of attention (``attn_why`` names it)."""
+    def refuses(cfg) -> Optional[str]:
+        if not isinstance(cfg.arch, dict):
+            return (f"model {cfg.model!r} takes its shape from the mapping "
+                    "'arch' (the published config.json keys; "
+                    "docs/configuration.md)")
+        if cfg.mesh_shape:
+            return (f"mesh_shape: the {cfg.model} scorer runs on one device; "
+                    "parallel/mesh.py has no expert axis and no rule for it")
+        if cfg.dtype == "int8w":
+            return "dtype 'int8w': models/quant.py does not quantize experts"
+        if cfg.score_vocab > 0:
+            return (f"score_vocab > 0: the {cfg.model} scorer has the exact "
+                    "head only")
+        if cfg.attn_impl not in attn_impls:
+            return (f"attn_impl {cfg.attn_impl!r}: {attn_why} (expected one "
+                    f"of {list(attn_impls)})")
+        return None
+    return refuses
 
 
 FAMILIES: Dict[str, ScorerFamily] = {
@@ -93,8 +106,21 @@ FAMILIES: Dict[str, ScorerFamily] = {
     "logbert": ScorerFamily(
         _build_logbert, _no_arch,
         lambda cfg: cfg.attn_impl not in ("flash", "short", "ring")),
-    # its scoring call returns counts beside the scores, and a CPU mirror
+    # their scoring calls return counts beside the scores, and a CPU mirror
     # of a model sized for a chip's memory is no latency path
-    "moe_mla": ScorerFamily(_build_moe_mla, _moe_mla_refuses,
-                            lambda cfg: False),
+    "moe_mla": ScorerFamily(
+        _build_moe_mla,
+        _expert_family_refuses(
+            ("auto", "einsum", "short"),
+            "latent attention is causal with two q·k widths and a value "
+            "width of its own, which the einsum route and the "
+            "short-sequence kernel compute"),
+        lambda cfg: False),
+    "moe_conv": ScorerFamily(
+        _build_moe_conv,
+        _expert_family_refuses(
+            ("auto", "einsum"),
+            "grouped-query attention (fewer key/value heads than query "
+            "heads, causal) is computed by the grouped einsum"),
+        lambda cfg: False),
 }

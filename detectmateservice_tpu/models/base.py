@@ -146,6 +146,8 @@ class ScorerBase:
         # the same for the model's attention calls (ops/attention.py
         # attention_route), written by the calls traced under _apply
         self.attn_routes: Dict[int, str] = {}
+        # and for its short convolutions (ops/shortconv.py conv_route)
+        self.conv_routes: Dict[int, str] = {}
         self.model = self._build_model()
         self.optimizer = optax.adamw(config.learning_rate)
         self._score = jax.jit(self._score_impl)
@@ -177,9 +179,11 @@ class ScorerBase:
 
     # -- shared surface -------------------------------------------------
     def _apply(self, params, *args, **kwargs):
-        """``self.model.apply`` with the attention calls it traces told
-        where they run and where to record the route they took."""
-        with placement(self.mesh_devices, self.attn_routes):
+        """``self.model.apply`` with the attention and convolution calls it
+        traces told where they run and where to record the route they
+        took."""
+        with placement(self.mesh_devices, self.attn_routes,
+                       self.conv_routes):
             return self.model.apply(params, *args, **kwargs)
 
     def _head_route(self, exact: bool, rows: int, vocab: int) -> str:

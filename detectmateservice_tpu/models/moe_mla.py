@@ -65,18 +65,15 @@ held share of the assignments read 8-9% where even routing gives 12.5
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Mapping, Tuple
+from typing import Any, Mapping, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 
-from ..ops import experts as expert_ops
 from ..ops.attention import latent_attention
-from .base import SequenceScorerBase, reduce_nlls
-from .gru import causal_lm_loss
-from .tokenizer import CLS_ID, PAD_ID
+from .blocks import (ExpertLMScorer, ExpertSpec, arch_keys, causal_stack,
+                     check_share, dense, expert_layer, gated_unit, rms_norm)
 
 # published keys this family reads but implements one value of: a config
 # that says otherwise is refused by name instead of being run as something
@@ -125,36 +122,11 @@ class MoEMLAArch:
         cannot compute yet."""
         arch = dict(arch)
         arch.setdefault("router_experts", arch.get("n_routed_experts"))
-        for key, only in _ONE_VALUE.items():
-            if key in arch and arch.pop(key) != only:
-                raise ValueError(
-                    f"arch.{key}: the moe_mla scorer computes only "
-                    f"{key} = {only!r}")
-        for key in _UNREAD:
-            arch.pop(key, None)
-        fields = {f.name for f in dataclasses.fields(cls)}
-        unknown = sorted(set(arch) - fields)
-        if unknown:
-            raise ValueError(f"arch: unknown key(s) {unknown}")
-        missing = sorted(f.name for f in dataclasses.fields(cls)
-                         if f.default is dataclasses.MISSING
-                         and arch.get(f.name) is None)
-        if missing:
-            raise ValueError(f"arch: missing key(s) {missing}")
-        out = cls(**arch)
+        out = cls(**arch_keys(cls, arch, _ONE_VALUE, _UNREAD, "moe_mla"))
         if not 0 < out.first_k_dense_replace <= out.num_hidden_layers:
             raise ValueError("arch.first_k_dense_replace must lie in "
                              "1..num_hidden_layers")
-        if not (0 <= out.expert_offset and out.n_routed_experts > 0
-                and out.expert_offset + out.n_routed_experts
-                <= out.router_experts):
-            raise ValueError(
-                f"arch: held experts {out.expert_offset}.."
-                f"{out.expert_offset + out.n_routed_experts - 1} do not lie "
-                f"within the router's {out.router_experts}")
-        if out.num_experts_per_tok > out.router_experts:
-            raise ValueError("arch.num_experts_per_tok exceeds "
-                             "router_experts")
+        check_share(out.expert_spec)
         if out.scoring_func not in ("sigmoid", "softmax"):
             raise ValueError(f"arch.scoring_func {out.scoring_func!r}: "
                              "expected 'sigmoid' or 'softmax'")
@@ -165,6 +137,16 @@ class MoEMLAArch:
     @property
     def expert_layers(self) -> int:
         return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def expert_spec(self) -> ExpertSpec:
+        return ExpertSpec(
+            width=self.moe_intermediate_size, held=self.n_routed_experts,
+            router_experts=self.router_experts, offset=self.expert_offset,
+            top_k=self.num_experts_per_tok,
+            norm_topk_prob=self.norm_topk_prob,
+            scaling=self.routed_scaling_factor,
+            scoring_func=self.scoring_func, shared=self.n_shared_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,17 +164,6 @@ class MoEMLAConfig:
     attn_impl: str = "auto"
     head_impl: str = "auto"
     platform: str = ""
-
-
-def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """float32 in, float32 out: statistics and scaling in float32."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale
-
-
-def _dense(features: int, cfg: MoEMLAConfig, name: str) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype, name=name,
-                    kernel_init=nn.initializers.normal(cfg.initializer_range))
 
 
 class HeadSplitDense(nn.Module):
@@ -237,11 +208,11 @@ class Block(nn.Module):
                                    (a.hidden_size,)), a.rms_norm_eps)
         if self.layer < a.first_k_dense_replace:
             with jax.named_scope(f"layer{self.layer}/ffn"):
-                out = self._gated(y.astype(cfg.dtype), a.intermediate_size,
-                                  "")
+                out = gated_unit(y.astype(cfg.dtype), a.intermediate_size,
+                                 a.hidden_size, cfg)
             return x + out.astype(jnp.float32), jnp.zeros((3,), jnp.int32)
         with jax.named_scope(f"layer{self.layer}/moe"):
-            out, counts = self._experts(y, valid)
+            out, counts = expert_layer(self, y, valid, a.expert_spec, cfg)
         return x + out, counts
 
     def _attention(self, x: jax.Array, key_mask: jax.Array) -> jax.Array:
@@ -255,7 +226,7 @@ class Block(nn.Module):
             q = HeadSplitDense(h * (nope + rope), h, nope, cfg,
                                name="q_proj")(y)
         with jax.named_scope("kv_down"):
-            kva = _dense(a.kv_lora_rank + rope, cfg, "kv_down")(y)
+            kva = dense(a.kv_lora_rank + rope, cfg, "kv_down")(y)
             c = rms_norm(kva[..., :a.kv_lora_rank],
                          self.param("kv_norm", nn.initializers.ones,
                                     (a.kv_lora_rank,)),
@@ -270,55 +241,8 @@ class Block(nn.Module):
                                    platform=cfg.platform or None,
                                    causal=True)
         with jax.named_scope("out_proj"):
-            return _dense(a.hidden_size, cfg, "out_proj")(out).astype(
+            return dense(a.hidden_size, cfg, "out_proj")(out).astype(
                 jnp.float32)
-
-    def _gated(self, y: jax.Array, width: int, prefix: str) -> jax.Array:
-        """``W_down(silu(W_gate·y) ⊙ W_up·y)`` at ``width``."""
-        cfg, a = self.config, self.config.arch
-        gate = _dense(width, cfg, prefix + "gate_proj")(y)
-        up = _dense(width, cfg, prefix + "up_proj")(y)
-        return _dense(a.hidden_size, cfg, prefix + "down_proj")(
-            nn.silu(gate) * up)
-
-    def _experts(self, y: jax.Array, valid: jax.Array
-                 ) -> Tuple[jax.Array, jax.Array]:
-        cfg, a = self.config, self.config.arch
-        d = y.shape[-1]
-        init = nn.initializers.normal(cfg.initializer_range)
-        m, held = a.moe_intermediate_size, a.n_routed_experts
-        valid = valid.reshape(-1)
-        router = self.param("router", init, (d, a.router_experts))
-        if held < a.router_experts:
-            # a share's fit sees only the held experts' part of the result,
-            # so its gradient pulls the router towards them (at a tiny size
-            # a boundary fit moved 25% of the assignments on the held
-            # experts to 83%): the router of a share is not trained here
-            router = jax.lax.stop_gradient(router)
-        with jax.named_scope("router"):
-            routing = expert_ops.route(
-                y, router,
-                self.param("router_bias", nn.initializers.zeros,
-                           (a.router_experts,)),
-                valid, top_k=a.num_experts_per_tok,
-                norm_topk_prob=a.norm_topk_prob,
-                scaling=a.routed_scaling_factor,
-                scoring_func=a.scoring_func)
-        routed, per_expert = expert_ops.routed_experts(
-            y.astype(cfg.dtype), routing,
-            self.param("experts_gate", init, (held, d, m)),
-            self.param("experts_up", init, (held, d, m)),
-            self.param("experts_down", init, (held, m, d)),
-            offset=a.expert_offset)
-        with jax.named_scope("shared"):
-            shared = self._gated(y.astype(cfg.dtype),
-                                 a.n_shared_experts * m, "shared_")
-        with jax.named_scope("combine"):
-            out = routed + shared.astype(jnp.float32)
-        counts = jnp.stack([
-            valid.sum(dtype=jnp.int32) * a.num_experts_per_tok,
-            per_expert.sum(dtype=jnp.int32), per_expert.max()])
-        return out, counts
 
 
 class MoEMLALM(nn.Module):
@@ -342,24 +266,8 @@ class MoEMLALM(nn.Module):
         int32 routing counts of the call: assignments of non-PAD positions
         over all experts, those that fell on held experts, and the busiest
         held expert's count summed over the expert layers)."""
-        with jax.named_scope("embed"):
-            # teacher-forced shift-right: the input at step t is token t-1,
-            # at step 0 CLS's own embedding
-            inputs = jnp.concatenate(
-                [jnp.full_like(tokens[:, :1], CLS_ID), tokens[:, :-1]],
-                axis=1)
-            # token-major from here on: a [B·S, ·] array has one layout on
-            # the TPU, a [B, S, ·] one is laid out sequence-major and
-            # copied before every kernel (PERF.md section 6, PR 28)
-            x = self.tok_embed(inputs).astype(jnp.float32).reshape(
-                -1, self.config.arch.hidden_size)
-        key_mask, valid = inputs != PAD_ID, tokens != PAD_ID
-        counts = jnp.zeros((3,), jnp.int32)
-        for block in self.layers:
-            x, layer_counts = block(x, key_mask, valid)
-            counts = counts + layer_counts
-        return (rms_norm(x, self.final_norm, self.config.arch.rms_norm_eps
-                         ).reshape(*tokens.shape, -1), counts)
+        return causal_stack(tokens, self.tok_embed, self.layers,
+                            self.final_norm, self.config.arch.rms_norm_eps)
 
     def hidden(self, tokens: jax.Array) -> jax.Array:
         return self.hidden_and_counts(tokens)[0]
@@ -373,53 +281,15 @@ class MoEMLALM(nn.Module):
                           preferred_element_type=jnp.float32)
 
 
-class MoEMLAScorer(SequenceScorerBase):
-    """Causal sparse-expert LM scorer. The scoring call returns the
-    routing counts beside the scores (``score_aux``): one [3] int32 array
-    from the same executable, so the detector's counters ride the scores'
-    readback."""
+class MoEMLAScorer(ExpertLMScorer):
+    """Causal sparse-expert LM scorer with latent attention and an untied
+    head; scoring call, routing counts and train step are
+    :class:`~.blocks.ExpertLMScorer`'s."""
 
     name = "moe_mla"
-    score_aux = True
-
-    def __init__(self, config: MoEMLAConfig):
-        super().__init__(config)
-        # which expert path each traced executable took, by batch rows
-        # (GET /admin/xla -> buckets.expert_route)
-        self.expert_routes: Dict[int, str] = {}
 
     def _build_model(self) -> MoEMLALM:
         return MoEMLALM(self.config)
 
     def _head_matrix(self, params) -> jax.Array:
         return params["params"]["lm_head"]
-
-    def _score_impl(self, params, tokens: jax.Array):
-        tokens = tokens.astype(jnp.int32)
-        dtype = self.config.dtype
-        hidden, counts = self._apply(params, tokens,
-                                          method="hidden_and_counts")
-        b, s = tokens.shape
-        a = self.config.arch
-        k = a.num_experts_per_tok
-        self.expert_routes[b] = (
-            f"sorted ragged_dot, {a.n_routed_experts} of "
-            f"{a.router_experts} experts from {a.expert_offset}, chunks of "
-            f"{expert_ops.chunk_rows_for(b * s, k)} of {b * s * k} slots")
-        with jax.named_scope("head/nll"):
-            nlls = self._exact_head(
-                hidden.astype(dtype),
-                self._head_matrix(params).astype(dtype), tokens)
-        mask = (tokens != PAD_ID).astype(jnp.float32)
-        return reduce_nlls(nlls, mask, self.config.score_topk), counts
-
-    def _train_impl(self, params, opt_state, rng, tokens):
-        del rng  # teacher forcing is deterministic
-        tokens = tokens.astype(jnp.int32)
-
-        def loss_fn(p):
-            return causal_lm_loss(self._apply(p, tokens), tokens)
-
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        updates, opt_state = self.optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss

@@ -7,7 +7,9 @@ sequences (ops/flash.py) and the one for whole short sequences
 (ops/shortattn.py). ``attention()`` (head-major q, k, v),
 ``self_attention()`` (the fused projection, as the models write it) and
 ``latent_attention()`` (latent attention's two q·k widths and value width,
-token-major, as its projections write them) route between them by
+token-major, as its projections write them) and
+``grouped_query_attention()`` (fewer key/value heads than query heads,
+causal, rotary over the whole head; token-major) route between them by
 :func:`attention_route`: from ``FLASH_MIN_SEQ`` up the flash kernel avoids
 materializing the [S, T] logits in HBM; for S = T <= 128 on one TPU the
 short kernels keep the ``[rows, heads, S, T]`` float32 logits in VMEM and
@@ -62,37 +64,49 @@ FLASH_MIN_SEQ = 2048
 # PR 28, call 3; PERF.md section 6)
 SHORT_MIN_ROWS = 256
 
-# (mesh_devices, routes) around a scorer's tracing: how many devices the
-# executor spreads the call over, and the scorer's ``attn_routes`` record
-# (rows -> implementation) the resolved route is written to. Tracing-time
-# only, like the ring context below
+# (mesh_devices, routes, conv_routes) around a scorer's tracing: how many
+# devices the executor spreads the call over, and the scorer's
+# ``attn_routes`` and ``conv_routes`` records (rows -> implementation) the
+# resolved routes are written to. Tracing-time only, like the ring context
+# below
 _PLACEMENT: contextvars.ContextVar = contextvars.ContextVar(
-    "dm_attention_placement", default=(1, None))
+    "dm_attention_placement", default=(1, None, None))
 
 
 @contextlib.contextmanager
-def placement(mesh_devices: int, routes: Optional[Dict[int, str]] = None):
-    """Tell the attention calls traced under this scope where they run
+def placement(mesh_devices: int, routes: Optional[Dict[int, str]] = None,
+              conv_routes: Optional[Dict[int, str]] = None):
+    """Tell the attention calls (and the short convolutions:
+    ops/shortconv.py) traced under this scope where they run
     (models/base.py wraps every model application in it)."""
-    token = _PLACEMENT.set((mesh_devices, routes))
+    token = _PLACEMENT.set((mesh_devices, routes, conv_routes))
     try:
         yield
     finally:
         _PLACEMENT.reset(token)
 
 
+def current_placement() -> tuple:
+    """``(mesh_devices, attn_routes, conv_routes)`` of the scope a call is
+    traced under; ``(1, None, None)`` outside any."""
+    return _PLACEMENT.get()
+
+
 def attention_route(impl: str, platform: str, s: int, t: int, heads: int,
                     head_dim: int, value_dim: int, causal: bool, rows: int,
-                    mesh_devices: int = 1, rope_dim: int = 0) -> str:
+                    mesh_devices: int = 1, rope_dim: int = 0,
+                    kv_heads: int = 0) -> str:
     """Which implementation computes one traced attention call.
 
     ``impl`` is the model's ``attn_impl``; any name but ``"auto"`` forces.
     ``"auto"`` decides from what the call can observe — the platform it is
     placed on, query and key lengths, heads, the q·k and value widths
     (``rope_dim`` of ``head_dim`` is the second, position-turned q·k
-    operand of a :func:`latent_attention` call; 0 = one operand), whether
-    it is causal, its rows, and how many devices the executor spread it
-    over:
+    operand of a :func:`latent_attention` call; 0 = one operand; ``kv_heads``
+    under ``heads`` is a :func:`grouped_query_attention` call, each key /
+    value head serving ``heads / kv_heads`` query heads; 0 = as many as
+    ``heads``), whether it is causal, its rows, and how many devices the
+    executor spread it over:
 
     * on a TPU from ``FLASH_MIN_SEQ`` keys up: ``"flash"``, as before;
     * on ONE TPU, whole short self-attention — S = T <= 128 in whole
@@ -101,7 +115,10 @@ def attention_route(impl: str, platform: str, s: int, t: int, heads: int,
       the form the call's widths name: one q·k width that is the value
       width too, in whole-vreg lane groups, no causal mask (``logbert``);
       or two q·k widths and a value width of whole lane groups, the
-      second q·k width dividing one, causal or not (latent attention);
+      second q·k width dividing one, causal or not (latent attention).
+      The third form — causal, one width, fewer key/value heads than
+      query heads — has no kernel body yet and takes the grouped einsum,
+      which reads each key/value head in place for its query heads;
     * everything else ``"einsum"``: the CPU (tier-1 tests, the host twin —
       the kernel would run in the Pallas interpreter), a mesh of more than
       one device (GSPMD does not partition a Pallas call), head-major
@@ -115,6 +132,8 @@ def attention_route(impl: str, platform: str, s: int, t: int, heads: int,
         return "einsum"
     if t >= FLASH_MIN_SEQ:
         return "flash"
+    if kv_heads and kv_heads != heads:
+        return "einsum"
     if mesh_devices == 1 and s == t and rows >= SHORT_MIN_ROWS:
         from .shortattn import fits, fits_latent
 
@@ -130,15 +149,15 @@ def attention_route(impl: str, platform: str, s: int, t: int, heads: int,
 
 def _resolve(impl: str, platform: Optional[str], q_shape, t: int,
              value_dim: int, causal: bool, record: bool = True,
-             rope_dim: int = 0) -> tuple:
+             rope_dim: int = 0, kv_heads: int = 0) -> tuple:
     """(implementation, platform) for a call with head-major query shape
     ``q_shape``, recorded where a scorer listens."""
     rows, heads, s, head_dim = q_shape
     if platform is None:
         platform = jax.default_backend()
-    mesh_devices, routes = _PLACEMENT.get()
+    mesh_devices, routes, _ = _PLACEMENT.get()
     impl = attention_route(impl, platform, s, t, heads, head_dim, value_dim,
-                           causal, rows, mesh_devices, rope_dim)
+                           causal, rows, mesh_devices, rope_dim, kv_heads)
     if record and routes is not None:
         routes[rows] = impl
     return impl, platform
@@ -243,29 +262,43 @@ def self_attention(
         return merge_heads(_attention(q, k, v, key_mask, impl, platform))
 
 
-def rotary_tables(s: int, r: int, theta: float) -> tuple:
+def rotary_tables(s: int, r: int, theta: float,
+                  interleaved: bool = True) -> tuple:
     """(cos, sin) ``[S, R]`` float32 of rotary positions 0..S-1 over R
-    interleaved lanes: lanes ``2i`` and ``2i+1`` share ``pos ·
-    theta^(-2i/R)``."""
+    lanes: interleaved, lanes ``2i`` and ``2i+1`` share ``pos ·
+    theta^(-2i/R)``; in the rotate-half form lanes ``i`` and ``i + R/2``
+    do."""
     inv = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    return (jnp.repeat(jnp.cos(angle), 2, axis=-1),
-            jnp.repeat(jnp.sin(angle), 2, axis=-1))
+    if interleaved:
+        return (jnp.repeat(jnp.cos(angle), 2, axis=-1),
+                jnp.repeat(jnp.sin(angle), 2, axis=-1))
+    return (jnp.tile(jnp.cos(angle), 2), jnp.tile(jnp.sin(angle), 2))
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions over the last axis of ``x`` [..., S, R], pairs
+def rotary(x: jax.Array, theta: float, interleaved: bool = True,
+           heads_inside: bool = False) -> jax.Array:
+    """Rotary positions over the last axis of ``x`` [..., S, R] (or, with
+    ``heads_inside``, [..., S, H, R]: positions before the heads, as a
+    token-major projection reshapes). Pairs
     interleaved: ``x[2i], x[2i+1]`` turn by ``pos · theta^(-2i/R)`` and stay
     where they are (the published code moves the pairs' halves apart; q and
-    k share either layout, and only q·k is read). The pair swap is a matmul
-    with a constant ±1 matrix — exact, and on the MXU — because a reshape to
-    ``[..., R/2, 2]`` costs a relayout of the whole tensor on the TPU (7 ms
-    a layer at 32768 tokens against 1). Angles and products in float32."""
+    k share either layout, and only q·k is read). Rotate-half
+    (``interleaved`` false): the pair is ``x[i], x[i + R/2]``. The pair swap
+    is a matmul with a constant ±1 matrix — exact, and on the MXU — because
+    a reshape to ``[..., R/2, 2]`` costs a relayout of the whole tensor on
+    the TPU (7 ms a layer at 32768 tokens against 1). Angles and products
+    in float32."""
     r = x.shape[-1]
-    cos, sin = rotary_tables(x.shape[-2], r, theta)
+    cos, sin = rotary_tables(x.shape[-3 if heads_inside else -2], r, theta,
+                             interleaved)
+    if heads_inside:
+        cos, sin = cos[:, None, :], sin[:, None, :]
+    first, second = ((np.arange(0, r, 2), np.arange(1, r, 2)) if interleaved
+                     else (np.arange(r // 2), np.arange(r // 2, r)))
     swap = np.zeros((r, r), np.float32)
-    swap[np.arange(1, r, 2), np.arange(0, r, 2)] = -1.0   # out[2i] = -x[2i+1]
-    swap[np.arange(0, r, 2), np.arange(1, r, 2)] = 1.0    # out[2i+1] = x[2i]
+    swap[second, first] = -1.0      # out[first] = -x[second]
+    swap[first, second] = 1.0       # out[second] = x[first]
     turned = jnp.dot(x, jnp.asarray(swap, x.dtype),
                      preferred_element_type=jnp.float32)
     return x.astype(jnp.float32) * cos + turned * sin
@@ -314,6 +347,60 @@ def latent_attention(
                 "'short' and 'einsum' do")
         return latent_einsum(q, kv, k_rope, key_mask, heads, nope, theta,
                              causal)
+
+
+def grouped_query_attention(
+    q: jax.Array,        # [B * S, H * D]: the query heads side by side
+    k: jax.Array,        # [B * S, G * D]: the key heads, G dividing H
+    v: jax.Array,        # [B * S, G * D]
+    key_mask: jax.Array,  # [B, S] bool; True = attend
+    heads: int,
+    kv_heads: int,
+    theta: float,
+    impl: str = "auto",
+    platform: Optional[str] = None,
+    causal: bool = True,
+) -> jax.Array:
+    """Grouped-query self-attention's core from its projections' own,
+    token-major layout → ``[B * S, H * D]``, ready for the output
+    projection: key/value head ``g`` serves the query heads ``g·H/G ..
+    (g+1)·H/G − 1``; rotary positions (base ``theta``) over the whole head
+    in the rotate-half form on q and k, turned in float32 and cast back;
+    ``softmax(q·kᵀ/√D + causal and PAD mask)·v``.
+
+    The one route is the grouped einsum (:func:`attention_route` answers
+    ``einsum`` for fewer key/value heads than query heads): the query
+    heads are viewed ``[B, S, G, H/G, D]`` and contracted against ``[B, S,
+    G, D]`` keys and values, so no key/value head is repeated in HBM, and
+    the rotation is :func:`rotary`'s ±1 matmul, so no ``[..., D/2, 2]``
+    pair reshape is built. A forced kernel is refused by name."""
+    b, s = key_mask.shape
+    d = q.shape[-1] // heads
+    impl, _ = _resolve(impl, platform, (b, heads, s, d), s, d, causal,
+                       kv_heads=kv_heads)
+    if impl != "einsum":
+        raise ValueError(
+            f"attention impl={impl!r} does not compute grouped-query "
+            f"attention ({kv_heads} key/value heads for {heads} query "
+            "heads): 'einsum' does")
+    group = heads // kv_heads
+    with jax.named_scope("attn_einsum"):
+        q = q.reshape(b, s, heads, d)
+        k = k.reshape(b, s, kv_heads, d)
+        v = v.reshape(b, s, kv_heads, d)
+        with jax.named_scope("rope"):
+            q = rotary(q, theta, False, heads_inside=True).astype(q.dtype)
+            k = rotary(k, theta, False, heads_inside=True).astype(k.dtype)
+        q = q.reshape(b, s, kv_heads, group, d)
+        logits = jnp.einsum("bsgrd,btgd->bgrst", q, k,
+                            preferred_element_type=jnp.float32) * d ** -0.5
+        mask = key_mask[:, None, None, None, :]
+        if causal:
+            mask = mask & jnp.tril(jnp.ones((s, s), bool))
+        logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bgrst,btgd->bsgrd", probs.astype(v.dtype), v)
+        return out.reshape(b * s, heads * d)
 
 
 def latent_einsum(q: jax.Array, kv: jax.Array, k_rope: jax.Array,

@@ -57,12 +57,15 @@ class Routing(NamedTuple):
 
 def route(x: jax.Array, router: jax.Array, bias: jax.Array,
           valid: jax.Array, *, top_k: int, norm_topk_prob: bool,
-          scaling: float, scoring_func: str = "sigmoid") -> Routing:
+          scaling: float, scoring_func: str = "sigmoid",
+          norm_eps: float = 1e-20) -> Routing:
     """Score ``x`` [N, D] against ``router`` [D, E] in float32 and choose
     ``top_k`` of the E experts by ``score + bias`` (``bias`` is the
     selection-only correction buffer: it moves the choice, never the
     weight, and no gradient reaches it). ``valid`` [N] marks non-PAD
-    tokens; a PAD token's experts are -1."""
+    tokens; a PAD token's experts are -1. ``norm_eps`` is what the
+    published code adds to the chosen scores' sum before it divides (the
+    sources differ: 1e-20, 1e-6)."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if scoring_func == "sigmoid":
@@ -78,7 +81,7 @@ def route(x: jax.Array, router: jax.Array, bias: jax.Array,
     chosen = experts[..., None] == jnp.arange(scores.shape[-1])
     weights = jnp.where(chosen, scores[:, None, :], 0.0).sum(-1)
     if norm_topk_prob:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(-1, keepdims=True) + norm_eps)
     weights = weights * scaling
     experts = jnp.where(valid[:, None], experts.astype(jnp.int32), -1)
     return Routing(experts, weights)

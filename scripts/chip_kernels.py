@@ -17,6 +17,9 @@ that quietly fell back to XLA or to the interpreter cannot pass.
   two-width form) at the sparse-expert scorer's served shape (1024 rows, S
   32, 32 heads of 128 ‖ 64, values 128, bf16) and at 300 rows of S 16,
   against ``ops/attention.latent_einsum`` in float32;
+* ``ops/shortconv.gated_conv`` (the gated short convolution's elementwise
+  core) at the served shape (1024 rows, S 32, D 2048, 3 taps, bf16) and at
+  300 rows of S 16, against ``gated_conv_xla`` in float32;
 * ``ops/flash.flash_attention`` forward at S = T = 2048 and 8192, D = 64,
   bf16, with a key mask;
 * the flash backward kernels (dq; dk+dv) at the same shapes.
@@ -146,6 +149,27 @@ def check_short_latent_attention(rows: int, s: int) -> dict:
             "finite": bool(np.isfinite(got).all()), "ok": err < 6e-2}
 
 
+def check_gated_conv(rows: int, s: int, d: int = 2048) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from detectmateservice_tpu.ops.shortconv import (gated_conv,
+                                                     gated_conv_xla)
+
+    kb, kw = jax.random.split(jax.random.PRNGKey(rows + s))
+    bcx = jax.random.normal(kb, (rows * s, 3 * d), jnp.float32
+                            ).astype(jnp.bfloat16)
+    weight = jax.random.normal(kw, (d, 3), jnp.float32)
+    exe, compile_s = _compiled(lambda b, w: gated_conv(b, w, s), bcx, weight)
+    got = np.asarray(exe(bcx, weight), np.float32)
+    want = np.asarray(jax.jit(lambda b, w: gated_conv_xla(
+        b.astype(jnp.float32), w, s))(bcx, weight))
+    err = float(np.max(np.abs(got - want) / (1.0 + np.abs(want))))
+    return {"compile_s": round(compile_s, 2), "max_rel_err": err,
+            "finite": bool(np.isfinite(got).all()), "ok": err < 1e-2}
+
+
 def _flash_inputs(s: int):
     import jax
     import jax.numpy as jnp
@@ -222,6 +246,10 @@ CHECKS = [
      lambda: check_short_latent_attention(1024, 32)),
     ("short_latent_attention rows=300 S=16 H=32 128|64 v128",
      lambda: check_short_latent_attention(300, 16)),
+    ("gated_conv moe_conv served rows=1024 S=32 D=2048 K=3",
+     lambda: check_gated_conv(1024, 32)),
+    ("gated_conv rows=300 S=16 D=256 K=3",
+     lambda: check_gated_conv(300, 16, 256)),
     ("flash forward S=2048", lambda: check_flash_forward(2048)),
     ("flash forward S=8192", lambda: check_flash_forward(8192)),
     ("flash backward S=2048", lambda: check_flash_backward(2048)),

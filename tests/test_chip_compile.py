@@ -194,3 +194,110 @@ def test_head_route_takes_the_kernel_at_the_cells_bucket():
 
     assert head_route("auto", "tpu", True, 1024 * 32, VOCAB) == "pallas"
     assert head_route("auto", "cpu", True, 1024 * 32, VOCAB) == "einsum"
+
+
+# -- the gated-short-convolution, grouped-query family (moe_conv) -----------
+
+def test_the_gated_convolutions_kernel_compiles_at_the_published_width(
+        one_chip, no_compile_cache):
+    """``gated_conv`` at the cell's widest bucket: 32768 tokens in lines of
+    32, D = 2048, 3 taps; B, C and x̃ are three column-block views of the
+    one ``[32768, 6144]`` buffer (no split is copied) and the shift down the
+    sublanes stays inside the kernel."""
+    from detectmateservice_tpu.ops.shortconv import gated_conv
+
+    compiled = jax.jit(lambda b, w: gated_conv(b, w, 32)).lower(
+        shape((TOKENS, 3 * D), jnp.bfloat16, one_chip),
+        shape((D, 3), jnp.float32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gated_conv" in text
+    copies = [line for line in text.splitlines()
+              if " copy(" in line and "[32768," in line
+              or " slice(" in line and "[32768,2048]" in line]
+    assert not copies, copies[:2]
+    # the transposed taps, nothing of the activations' size
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.fixture(scope="module")
+def moe_conv_scorer():
+    from benchmark.lib.manifest import read_json
+    from detectmateservice_tpu.models.moe_conv import (
+        MoEConvArch, MoEConvConfig, MoEConvScorer)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    (block,) = read_json(os.path.join(
+        repo, "benchmark", "configs", "lfm2-24b-a2b-ep8.json"))[
+        "stages"]["detector"]["component"]["detectors"].values()
+    return MoEConvScorer(MoEConvConfig(
+        arch=MoEConvArch.from_mapping(block["arch"]),
+        vocab_size=block["vocab_size"], seq_len=block["seq_len"],
+        platform="tpu"))
+
+
+def _described(tree, one_chip):
+    return jax.tree_util.tree_map(
+        lambda leaf: shape(leaf.shape, leaf.dtype, one_chip), tree)
+
+
+def test_moe_convs_widest_scoring_program_and_its_bytes(
+        moe_conv_scorer, one_chip, no_compile_cache):
+    """``lfm2-24b-a2b-ep8``'s 1024-row bucket as ``auto`` routes it on one
+    TPU: six gated-convolution kernels, the grouped einsum for the two
+    attention layers, the fused head; no ``[..., 32, 2]`` pair reshape for
+    the rotation and no key/value head repeated for its four query heads
+    (a ``[1024, 32, 32, 64]`` key or value would be one); scratch
+    1,884,570,624 bytes when this was written, beside 2.95 GB of float32
+    parameters. About 20 s."""
+    import re
+
+    scorer = moe_conv_scorer
+    params = _described(jax.eval_shape(
+        lambda: scorer.init(jax.random.PRNGKey(0))[0]), one_chip)
+    compiled = jax.jit(scorer._score_impl).lower(
+        params, shape((1024, 32), jnp.uint16, one_chip)).compile()
+    assert scorer.attn_routes == {1024: "einsum"}
+    assert scorer.conv_routes == {1024: "fused"}
+    assert scorer.head_routes == {1024: "pallas"}
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "gated_conv" in line]
+    assert len(kernels) == 6, len(kernels)
+    assert "lse_pallas" in text
+    pairs = sorted(set(re.findall(r"\w+\[[\d,]*,32,2\]", text)))
+    assert not pairs, pairs
+    # keys and values stay 8 heads wide: [1024,32,8,64], never [.., 32, 64]
+    # per query head outside q itself and the attention's output
+    repeated = [line for line in text.splitlines()
+                if re.search(r"broadcast\(.*\[1024,32,8,64\]", line)
+                and "[1024,32,8,4,64]" in line]
+    assert not repeated, repeated[:2]
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 2_000_000_000
+    assert stats.argument_size_in_bytes == pytest.approx(
+        4 * 736_959_104, rel=1e-3)
+
+
+def test_moe_convs_donated_train_step_fits_the_chip(
+        moe_conv_scorer, one_chip, no_compile_cache):
+    """The boundary fit's 32-row donated train step at the published widths
+    and the cut's eight layers: 16 bytes a parameter while a gradient lives.
+    XLA's buffer assignment for a described v5e read 8,843,681,280 bytes of
+    arguments (parameters and both moments, aliased to the outputs) and
+    2,315,088,384 of temporaries = 11.16 GB when this was written; the
+    configuration's fall-back to six layers is for a reading above 13.5 GB.
+    About 25 s."""
+    scorer = moe_conv_scorer
+    params, opt_state = _described(jax.eval_shape(
+        lambda: scorer.init(jax.random.PRNGKey(0))), one_chip)
+    compiled = jax.jit(scorer._train_impl, donate_argnums=(0, 1)).lower(
+        params, opt_state, shape((2,), jnp.uint32, one_chip),
+        shape((32, 32), jnp.int32, one_chip)).compile()
+    assert scorer.conv_routes[32] == "xla"       # the fit keeps XLA's form
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == pytest.approx(
+        12 * 736_959_104, rel=1e-3)
+    held = (stats.argument_size_in_bytes + stats.output_size_in_bytes
+            - stats.alias_size_in_bytes + stats.temp_size_in_bytes)
+    assert held < 13_500_000_000
+    assert held == pytest.approx(11_158_771_200, rel=0.05)
