@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import optax
 
 from ..ops import experts as expert_ops
+from ..ops.attention import current_placement
 from .base import SequenceScorerBase, reduce_nlls
 from .gru import causal_lm_loss
 from .tokenizer import CLS_ID, PAD_ID
@@ -105,6 +106,25 @@ def gated_unit(y: jax.Array, width: int, out_features: int, cfg: Any,
     return dense(out_features, cfg, prefix + "down_proj")(nn.silu(gate) * up)
 
 
+# jitted, so that a stack's expert layers share one trace and one lowering
+# of the routed part (a scoring program's text is a third shorter, and the
+# warm-up traces it once a bucket, not once a layer); what the trace reads
+# of its surroundings — the platform, the way back — is a static argument
+_routed_experts = jax.jit(
+    expert_ops.routed_experts,
+    static_argnames=("offset", "chunk_rows", "combine", "platform"))
+
+
+def expert_walk(tokens: int, width: int, spec: ExpertSpec, platform: str,
+                mesh_devices: int) -> Tuple[int, str]:
+    """``(rows a chunk of the sorted list, the way back to the tokens)``
+    of one traced call of ``tokens`` tokens by ``width`` columns: what an
+    expert layer runs and what ``expert_routes`` records."""
+    chunk = expert_ops.chunk_rows_for(tokens, spec.top_k)
+    return chunk, expert_ops.combine_route(platform, tokens, chunk, width,
+                                           mesh_devices)
+
+
 def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
                  spec: ExpertSpec, cfg: Any) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of ``Σ w_i·E_i(y)`` (ops/experts.py) plus the
@@ -135,12 +155,15 @@ def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
             valid, top_k=spec.top_k, norm_topk_prob=spec.norm_topk_prob,
             scaling=spec.scaling, scoring_func=spec.scoring_func,
             norm_eps=spec.norm_eps)
-    out, per_expert = expert_ops.routed_experts(
+    chunk, combine = expert_walk(y.shape[0], d, spec, cfg.platform,
+                                 current_placement()[0])
+    out, per_expert = _routed_experts(
         y.astype(cfg.dtype), routing,
         mod.param("experts_gate", init, (held, d, m)),
         mod.param("experts_up", init, (held, d, m)),
         mod.param("experts_down", init, (held, m, d)),
-        offset=spec.offset)
+        offset=spec.offset, chunk_rows=chunk, combine=combine,
+        platform=cfg.platform)
     if spec.shared:
         with jax.named_scope("shared"):
             shared = gated_unit(y.astype(cfg.dtype), spec.shared * m, d, cfg,
@@ -200,11 +223,12 @@ class ExpertLMScorer(SequenceScorerBase):
                                      method="hidden_and_counts")
         b, s = tokens.shape
         spec = self.config.arch.expert_spec
+        chunk, combine = expert_walk(b * s, hidden.shape[-1], spec,
+                                     self.config.platform, self.mesh_devices)
         self.expert_routes[b] = (
             f"sorted ragged_dot, {spec.held} of {spec.router_experts} "
-            f"experts from {spec.offset}, chunks of "
-            f"{expert_ops.chunk_rows_for(b * s, spec.top_k)} of "
-            f"{b * s * spec.top_k} slots")
+            f"experts from {spec.offset}, chunks of {chunk} of "
+            f"{b * s * spec.top_k} slots, combine {combine}")
         with jax.named_scope("head/nll"):
             nlls = self._exact_head(
                 hidden.astype(dtype),

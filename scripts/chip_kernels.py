@@ -20,6 +20,13 @@ that quietly fell back to XLA or to the interpreter cannot pass.
 * ``ops/shortconv.gated_conv`` (the gated short convolution's elementwise
   core) at the served shape (1024 rows, S 32, D 2048, 3 taps, bf16) and at
   300 rows of S 16, against ``gated_conv_xla`` in float32;
+* ``ops/experts.segment_sum_add`` (the routed experts' way back to the
+  tokens) at the served shape (32768 tokens, a chunk of 16384 rows, D
+  2048) and at the fit's (1024 tokens, 4096 rows), with runs of unrouted
+  tokens — whole blocks of tokens without a row, one run on a row block's
+  edge — NaN in the rows past the live ones and in the memory freed before
+  the call, without an accumulator (every block written) and with one
+  (updated in place), against ``numpy.add.at`` in float64;
 * ``ops/flash.flash_attention`` forward at S = T = 2048 and 8192, D = 64,
   bf16, with a key mask;
 * the flash backward kernels (dq; dk+dv) at the same shapes.
@@ -170,6 +177,55 @@ def check_gated_conv(rows: int, s: int, d: int = 2048) -> dict:
             "finite": bool(np.isfinite(got).all()), "ok": err < 1e-2}
 
 
+def check_segment_sum(tokens: int, chunk: int, d: int = 2048) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from detectmateservice_tpu.ops.experts import segment_sum_add
+
+    rng = np.random.default_rng(tokens + chunk)
+    live = chunk - 300
+    # unrouted: tokens 128..639 (rows 127 and 128 stand five blocks of
+    # tokens apart), the last block and, where there is room, a run inside
+    routed = np.ones(tokens, bool)
+    routed[128:640] = routed[tokens - 128:] = False
+    if tokens >= 4096:
+        routed[tokens // 2:tokens // 2 + 400] = False
+    token = np.full(chunk, tokens, np.int32)
+    token[:128] = np.sort(rng.integers(0, 128, 128))
+    token[128:live] = np.sort(rng.choice(np.flatnonzero(routed)[128:],
+                                         live - 128))
+    y = rng.normal(size=(chunk, d)).astype(np.float32)
+    weight = rng.uniform(0.1, 1.0, chunk).astype(np.float32)
+    acc = rng.normal(size=(tokens, d)).astype(np.float32)
+    want = np.zeros((tokens, d))
+    np.add.at(want, token[:live], y[:live].astype(np.float64)
+              * weight[:live, None])
+    y[live:] = np.nan
+    args = (jnp.asarray(y), jnp.asarray(token), jnp.asarray(weight),
+            jnp.int32(live))
+    out = {"ok": True}
+    for name, first in (("no_accumulator", ()), ("accumulator", (acc,))):
+        exe, compile_s = _compiled(
+            lambda *a: segment_sum_add(a[0] if first else None,
+                                       *a[len(first):], tokens),
+            *map(jnp.asarray, first), *args)
+        # what an unwritten block would read
+        jax.block_until_ready(jnp.full((tokens, d), jnp.nan, jnp.float32))
+        got = np.asarray(exe(*map(jnp.asarray, first), *args), np.float64)
+        err = float(np.nanmax(np.abs(got - want - (acc if first else 0.0))))
+        finite = bool(np.isfinite(got).all())
+        out[name] = {"compile_s": round(compile_s, 2), "max_abs_err": err,
+                     "finite": finite,
+                     "unrouted_rows_nonzero": int(
+                         (got[~routed] != (acc[~routed] if first else 0.0)
+                          ).any(-1).sum())}
+        out["ok"] &= finite and err < 2e-5 and not out[name][
+            "unrouted_rows_nonzero"]
+    return out
+
+
 def _flash_inputs(s: int):
     import jax
     import jax.numpy as jnp
@@ -250,6 +306,10 @@ CHECKS = [
      lambda: check_gated_conv(1024, 32)),
     ("gated_conv rows=300 S=16 D=256 K=3",
      lambda: check_gated_conv(300, 16, 256)),
+    ("segment_sum_add served tokens=32768 chunk=16384 D=2048",
+     lambda: check_segment_sum(32768, 16384)),
+    ("segment_sum_add fit tokens=1024 chunk=4096 D=2048",
+     lambda: check_segment_sum(1024, 4096)),
     ("flash forward S=2048", lambda: check_flash_forward(2048)),
     ("flash forward S=8192", lambda: check_flash_forward(8192)),
     ("flash backward S=2048", lambda: check_flash_backward(2048)),

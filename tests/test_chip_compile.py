@@ -56,6 +56,15 @@ def shape(dims, dtype, sharding):
     return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
 
+def colliding_scatters(text):
+    """The compiled program's scatters under an expert layer whose indices
+    may collide (XLA's row loop on the v5e): the way back to the tokens is
+    a segment sum and leaves none."""
+    return [line.strip()[:200] for line in text.splitlines()
+            if " scatter(" in line and "/moe/" in line
+            and "unique_indices=true" not in line]
+
+
 def test_the_expert_layers_grouped_matmul_compiles_at_published_widths(
         one_chip, no_compile_cache):
     """The chunk walk at the cell's widest bucket (the fit's 32-row train
@@ -65,7 +74,8 @@ def test_the_expert_layers_grouped_matmul_compiles_at_published_widths(
 
     def layer(x, experts, weights, gate, up, down):
         out, counts = ops.routed_experts(
-            x, ops.Routing(experts, weights), gate, up, down)
+            x, ops.Routing(experts, weights), gate, up, down, platform="tpu",
+            combine=ops.combine_route("tpu", TOKENS, TOKENS // 2, D))
         return out, counts
 
     compiled = jax.jit(layer).lower(
@@ -81,6 +91,16 @@ def test_the_expert_layers_grouped_matmul_compiles_at_published_widths(
     assert text.count("ragged-dot") >= 3
     # a scan over the chunks, the dead ones skipped under a conditional
     assert "while" in text and "conditional" in text
+    # the way back: the segment-sum kernel, once for the first chunk (it
+    # makes the accumulator) and once inside the walk (the accumulator its
+    # operand and its result); no scatter over the [N, D] accumulator
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "segment_sum_add" in line]
+    assert len(kernels) == 2, len(kernels)
+    assert sum("output_to_operand_aliasing" in line
+               for line in kernels) == 1
+    assert not [line for line in text.splitlines() if " scatter(" in line
+                and f"f32[{TOKENS},{D}]" in line.split(" scatter(")[0]]
     assert ops.chunk_rows_for(TOKENS, K) == TOKENS // 2
     stats = compiled.memory_analysis()
     # the sorted list, one chunk's rows and the float32 accumulator: far
@@ -186,7 +206,11 @@ def test_moe_mlas_widest_scoring_program_holds_no_192_wide_head(
                 r"\[32768,(4096|6144|8192)\]|\[1024,32,(4096|6144|8192)\]",
                 line)]
     assert not wide, wide[:2]
-    assert compiled.memory_analysis().temp_size_in_bytes < 1_600_000_000
+    assert "combine segment_sum" in scorer.expert_routes[1024]
+    assert text.count("segment_sum_add") and not colliding_scatters(text)
+    # what it read before the segment sum (under 1.6e9) plus one
+    # token-ordered [16384, 2048] float32 block and the way back's lists
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_900_000_000
 
 
 def test_head_route_takes_the_kernel_at_the_cells_bucket():
@@ -272,8 +296,12 @@ def test_moe_convs_widest_scoring_program_and_its_bytes(
                 if re.search(r"broadcast\(.*\[1024,32,8,64\]", line)
                 and "[1024,32,8,4,64]" in line]
     assert not repeated, repeated[:2]
+    assert "combine segment_sum" in scorer.expert_routes[1024]
+    assert text.count("segment_sum_add") and not colliding_scatters(text)
     stats = compiled.memory_analysis()
-    assert stats.temp_size_in_bytes < 2_000_000_000
+    # under 2.0e9 before the segment sum, plus one token-ordered
+    # [16384, 2048] float32 block and the way back's lists
+    assert stats.temp_size_in_bytes < 2_300_000_000
     assert stats.argument_size_in_bytes == pytest.approx(
         4 * 736_959_104, rel=1e-3)
 
@@ -294,6 +322,12 @@ def test_moe_convs_donated_train_step_fits_the_chip(
         params, opt_state, shape((2,), jnp.uint32, one_chip),
         shape((32, 32), jnp.int32, one_chip)).compile()
     assert scorer.conv_routes[32] == "xla"       # the fit keeps XLA's form
+    # and takes the segment sum back to the tokens: one kernel a layer,
+    # every block of tokens written (one chunk, no accumulator before it)
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and "segment_sum_add" in line]
+    assert len(kernels) == 6, len(kernels)
+    assert not any("output_to_operand_aliasing" in line for line in kernels)
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes == pytest.approx(
         12 * 736_959_104, rel=1e-3)
