@@ -35,7 +35,7 @@ from .scorer_families import FAMILIES
 
 class JaxScorerDetectorConfig(CoreDetectorConfig):
     method_type: str = "jax_scorer"
-    # "mlp" | "gru" | "logbert" | "moe_mla" | "moe_conv"
+    # "mlp" | "gru" | "logbert" | "moe_mla" | "moe_conv" | "moe_delta"
     # (scorer_families.FAMILIES)
     model: str = "mlp"
     vocab_size: int = 32768
@@ -43,11 +43,13 @@ class JaxScorerDetectorConfig(CoreDetectorConfig):
     dim: int = 128
     depth: int = 2                    # logbert/gru layers
     heads: int = 4                    # logbert only
-    # moe_mla and moe_conv only: the model's shape as the published
-    # config.json keys under their published names (hidden_size,
+    # moe_mla, moe_conv and moe_delta only: the model's shape as the
+    # published config.json keys under their published names (hidden_size,
     # num_attention_heads, kv_lora_rank, n_routed_experts ...:
     # models/moe_mla.py MoEMLAArch; layer_types, conv_L_cache,
-    # num_key_value_heads, num_experts ...: models/moe_conv.py MoEConvArch),
+    # num_key_value_heads, num_experts ...: models/moe_conv.py MoEConvArch;
+    # full_attention_interval, linear_num_value_heads, partial_rotary_factor
+    # ...: models/moe_delta.py MoEDeltaArch),
     # plus the chip's share of an expert-parallel group: router_experts
     # (the published count the router scores over; the published expert
     # count's key is then the count HELD here) and expert_offset (the
@@ -1836,7 +1838,8 @@ class JaxScorerDetector(CoreDetector):
             "retired": sorted(self._retired_buckets),
             # which head (models/base.py head_route), which attention
             # (ops/attention.py attention_route), which short convolution
-            # (ops/shortconv.py conv_route) and which expert path (the
+            # (ops/shortconv.py conv_route), which form of the delta rule
+            # (ops/deltarule.py delta_route) and which expert path (the
             # sparse-expert scorers') each traced device executable took,
             # by its rows; decided at trace time, empty where the scorer has
             # no such part. The host twin's calls are not in it: it is
@@ -1844,6 +1847,7 @@ class JaxScorerDetector(CoreDetector):
             "head_route": routes("head_routes"),
             "attn_route": routes("attn_routes"),
             "conv_route": routes("conv_routes"),
+            "delta_route": routes("delta_routes"),
             "expert_route": routes("expert_routes"),
         }
 

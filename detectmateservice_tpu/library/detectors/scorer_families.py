@@ -21,8 +21,8 @@ class ScorerFamily(NamedTuple):
 
 def _no_arch(cfg) -> Optional[str]:
     if cfg.arch is not None:
-        return ("'arch' is the moe_mla and moe_conv families' shape key; "
-                f"model {cfg.model!r} takes dim/depth/heads")
+        return ("'arch' is the moe_mla, moe_conv and moe_delta families' "
+                f"shape key; model {cfg.model!r} takes dim/depth/heads")
     return None
 
 
@@ -67,6 +67,16 @@ def _build_moe_conv(cfg, model_kw):
 
     return MoEConvScorer(MoEConvConfig(
         arch=MoEConvArch.from_mapping(cfg.arch), vocab_size=cfg.vocab_size,
+        seq_len=cfg.seq_len, score_topk=cfg.score_topk,
+        attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
+
+
+def _build_moe_delta(cfg, model_kw):
+    from ...models.moe_delta import (MoEDeltaArch, MoEDeltaConfig,
+                                     MoEDeltaScorer)
+
+    return MoEDeltaScorer(MoEDeltaConfig(
+        arch=MoEDeltaArch.from_mapping(cfg.arch), vocab_size=cfg.vocab_size,
         seq_len=cfg.seq_len, score_topk=cfg.score_topk,
         attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
 
@@ -122,5 +132,13 @@ FAMILIES: Dict[str, ScorerFamily] = {
             ("auto", "einsum"),
             "grouped-query attention (fewer key/value heads than query "
             "heads, causal) is computed by the grouped einsum"),
+        lambda cfg: False),
+    "moe_delta": ScorerFamily(
+        _build_moe_delta,
+        _expert_family_refuses(
+            ("auto", "einsum"),
+            "gated grouped-query attention (fewer key/value heads than "
+            "query heads, causal, rotary on a head's first lanes) is "
+            "computed by the grouped einsum"),
         lambda cfg: False),
 }

@@ -148,6 +148,8 @@ class ScorerBase:
         self.attn_routes: Dict[int, str] = {}
         # and for its short convolutions (ops/shortconv.py conv_route)
         self.conv_routes: Dict[int, str] = {}
+        # and for its delta-rule layers (ops/deltarule.py delta_route)
+        self.delta_routes: Dict[int, str] = {}
         self.model = self._build_model()
         self.optimizer = optax.adamw(config.learning_rate)
         self._score = jax.jit(self._score_impl)
@@ -179,11 +181,11 @@ class ScorerBase:
 
     # -- shared surface -------------------------------------------------
     def _apply(self, params, *args, **kwargs):
-        """``self.model.apply`` with the attention and convolution calls it
-        traces told where they run and where to record the route they
-        took."""
+        """``self.model.apply`` with the attention, convolution and
+        delta-rule calls it traces told where they run and where to record
+        the route they took."""
         with placement(self.mesh_devices, self.attn_routes,
-                       self.conv_routes):
+                       self.conv_routes, self.delta_routes):
             return self.model.apply(params, *args, **kwargs)
 
     def _head_route(self, exact: bool, rows: int, vocab: int) -> str:

@@ -41,6 +41,8 @@ class ExpertSpec(NamedTuple):
     scoring_func: str = "sigmoid"
     shared: int = 0         # shared experts: one gated unit at shared x width
     norm_eps: float = 1e-20  # the weights' normalisation: w / (sum + eps)
+    # the shared unit times sigmoid(y · w_s), a per-token gate ([D, 1])
+    shared_gate: bool = False
 
 
 def arch_keys(cls: type, arch: Mapping[str, Any], one_value: Mapping[str, Any],
@@ -135,7 +137,8 @@ def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
     ``router`` [D, E], ``router_bias`` [E] (zeros; selection only, no
     gradient), ``experts_gate`` / ``experts_up`` [held, D, M],
     ``experts_down`` [held, M, D] and, with shared experts,
-    ``shared_{gate,up,down}_proj``."""
+    ``shared_{gate,up,down}_proj`` (and ``shared_gate`` [D, 1] where the
+    spec sets it: float32, the router's precision)."""
     d = y.shape[-1]
     init = nn.initializers.normal(cfg.initializer_range)
     m, held = spec.width, spec.held
@@ -156,7 +159,7 @@ def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
             scaling=spec.scaling, scoring_func=spec.scoring_func,
             norm_eps=spec.norm_eps)
     chunk, combine = expert_walk(y.shape[0], d, spec, cfg.platform,
-                                 current_placement()[0])
+                                 current_placement().mesh_devices)
     out, per_expert = _routed_experts(
         y.astype(cfg.dtype), routing,
         mod.param("experts_gate", init, (held, d, m)),
@@ -168,6 +171,11 @@ def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
         with jax.named_scope("shared"):
             shared = gated_unit(y.astype(cfg.dtype), spec.shared * m, d, cfg,
                                 "shared_")
+        if spec.shared_gate:
+            with jax.named_scope("shared_gate"):
+                shared = shared.astype(jnp.float32) * jax.nn.sigmoid(jnp.dot(
+                    y, mod.param("shared_gate", init, (d, 1)),
+                    precision=jax.lax.Precision.HIGHEST))
         with jax.named_scope("combine"):
             out = out + shared.astype(jnp.float32)
     counts = jnp.stack([

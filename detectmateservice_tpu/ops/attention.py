@@ -9,8 +9,8 @@ sequences (ops/flash.py) and the one for whole short sequences
 ``latent_attention()`` (latent attention's two q·k widths and value width,
 token-major, as its projections write them) and
 ``grouped_query_attention()`` (fewer key/value heads than query heads,
-causal, rotary over the whole head; token-major) route between them by
-:func:`attention_route`: from ``FLASH_MIN_SEQ`` up the flash kernel avoids
+causal, rotary over the whole head or its first lanes; token-major) route
+between them by :func:`attention_route`: from ``FLASH_MIN_SEQ`` up the flash kernel avoids
 materializing the [S, T] logits in HBM; for S = T <= 128 on one TPU the
 short kernels keep the ``[rows, heads, S, T]`` float32 logits in VMEM and
 take q, k, v where the projections wrote them.
@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -64,31 +64,40 @@ FLASH_MIN_SEQ = 2048
 # PR 28, call 3; PERF.md section 6)
 SHORT_MIN_ROWS = 256
 
-# (mesh_devices, routes, conv_routes) around a scorer's tracing: how many
-# devices the executor spreads the call over, and the scorer's
-# ``attn_routes`` and ``conv_routes`` records (rows -> implementation) the
-# resolved routes are written to. Tracing-time only, like the ring context
-# below
+class Placement(NamedTuple):
+    """What surrounds a scorer's tracing: how many devices the executor
+    spreads the call over, and the scorer's records (rows -> implementation)
+    the resolved routes are written to. Tracing-time only, like the ring
+    context below."""
+    mesh_devices: int = 1
+    attn_routes: Optional[Dict[int, str]] = None
+    conv_routes: Optional[Dict[int, str]] = None
+    delta_routes: Optional[Dict[int, str]] = None
+
+
 _PLACEMENT: contextvars.ContextVar = contextvars.ContextVar(
-    "dm_attention_placement", default=(1, None, None))
+    "dm_attention_placement", default=Placement())
 
 
 @contextlib.contextmanager
 def placement(mesh_devices: int, routes: Optional[Dict[int, str]] = None,
-              conv_routes: Optional[Dict[int, str]] = None):
-    """Tell the attention calls (and the short convolutions:
-    ops/shortconv.py) traced under this scope where they run
-    (models/base.py wraps every model application in it)."""
-    token = _PLACEMENT.set((mesh_devices, routes, conv_routes))
+              conv_routes: Optional[Dict[int, str]] = None,
+              delta_routes: Optional[Dict[int, str]] = None):
+    """Tell the attention calls (and the short convolutions,
+    ops/shortconv.py, and the delta rule, ops/deltarule.py) traced under
+    this scope where they run (models/base.py wraps every model
+    application in it)."""
+    token = _PLACEMENT.set(Placement(mesh_devices, routes, conv_routes,
+                                     delta_routes))
     try:
         yield
     finally:
         _PLACEMENT.reset(token)
 
 
-def current_placement() -> tuple:
-    """``(mesh_devices, attn_routes, conv_routes)`` of the scope a call is
-    traced under; ``(1, None, None)`` outside any."""
+def current_placement() -> Placement:
+    """The :class:`Placement` a call is traced under; one device and no
+    record outside any."""
     return _PLACEMENT.get()
 
 
@@ -155,11 +164,12 @@ def _resolve(impl: str, platform: Optional[str], q_shape, t: int,
     rows, heads, s, head_dim = q_shape
     if platform is None:
         platform = jax.default_backend()
-    mesh_devices, routes, _ = _PLACEMENT.get()
+    placed = _PLACEMENT.get()
     impl = attention_route(impl, platform, s, t, heads, head_dim, value_dim,
-                           causal, rows, mesh_devices, rope_dim, kv_heads)
-    if record and routes is not None:
-        routes[rows] = impl
+                           causal, rows, placed.mesh_devices, rope_dim,
+                           kv_heads)
+    if record and placed.attn_routes is not None:
+        placed.attn_routes[rows] = impl
     return impl, platform
 
 
@@ -277,7 +287,8 @@ def rotary_tables(s: int, r: int, theta: float,
 
 
 def rotary(x: jax.Array, theta: float, interleaved: bool = True,
-           heads_inside: bool = False) -> jax.Array:
+           heads_inside: bool = False,
+           rotary_dim: Optional[int] = None) -> jax.Array:
     """Rotary positions over the last axis of ``x`` [..., S, R] (or, with
     ``heads_inside``, [..., S, H, R]: positions before the heads, as a
     token-major projection reshapes). Pairs
@@ -288,14 +299,23 @@ def rotary(x: jax.Array, theta: float, interleaved: bool = True,
     is a matmul with a constant ±1 matrix — exact, and on the MXU — because
     a reshape to ``[..., R/2, 2]`` costs a relayout of the whole tensor on
     the TPU (7 ms a layer at 32768 tokens against 1). Angles and products
-    in float32."""
+    in float32. ``rotary_dim`` under R turns the first ``rotary_dim`` lanes
+    only (a partial rotary factor): the tables read cos 1 and sin 0 on the
+    rest and the swap matrix is zero there, so no lane is sliced off and
+    put back."""
     r = x.shape[-1]
-    cos, sin = rotary_tables(x.shape[-3 if heads_inside else -2], r, theta,
-                             interleaved)
+    turned_r = r if rotary_dim is None else rotary_dim
+    cos, sin = rotary_tables(x.shape[-3 if heads_inside else -2], turned_r,
+                             theta, interleaved)
+    if turned_r < r:
+        cos = jnp.pad(cos, ((0, 0), (0, r - turned_r)), constant_values=1.0)
+        sin = jnp.pad(sin, ((0, 0), (0, r - turned_r)))
     if heads_inside:
         cos, sin = cos[:, None, :], sin[:, None, :]
-    first, second = ((np.arange(0, r, 2), np.arange(1, r, 2)) if interleaved
-                     else (np.arange(r // 2), np.arange(r // 2, r)))
+    first, second = ((np.arange(0, turned_r, 2), np.arange(1, turned_r, 2))
+                     if interleaved else
+                     (np.arange(turned_r // 2),
+                      np.arange(turned_r // 2, turned_r)))
     swap = np.zeros((r, r), np.float32)
     swap[second, first] = -1.0      # out[first] = -x[second]
     swap[first, second] = 1.0       # out[second] = x[first]
@@ -360,12 +380,14 @@ def grouped_query_attention(
     impl: str = "auto",
     platform: Optional[str] = None,
     causal: bool = True,
+    rotary_dim: Optional[int] = None,
 ) -> jax.Array:
     """Grouped-query self-attention's core from its projections' own,
     token-major layout → ``[B * S, H * D]``, ready for the output
     projection: key/value head ``g`` serves the query heads ``g·H/G ..
     (g+1)·H/G − 1``; rotary positions (base ``theta``) over the whole head
-    in the rotate-half form on q and k, turned in float32 and cast back;
+    — or its first ``rotary_dim`` lanes, a partial rotary factor — in the
+    rotate-half form on q and k, turned in float32 and cast back;
     ``softmax(q·kᵀ/√D + causal and PAD mask)·v``.
 
     The one route is the grouped einsum (:func:`attention_route` answers
@@ -389,8 +411,8 @@ def grouped_query_attention(
         k = k.reshape(b, s, kv_heads, d)
         v = v.reshape(b, s, kv_heads, d)
         with jax.named_scope("rope"):
-            q = rotary(q, theta, False, heads_inside=True).astype(q.dtype)
-            k = rotary(k, theta, False, heads_inside=True).astype(k.dtype)
+            q = rotary(q, theta, False, True, rotary_dim).astype(q.dtype)
+            k = rotary(k, theta, False, True, rotary_dim).astype(k.dtype)
         q = q.reshape(b, s, kv_heads, group, d)
         logits = jnp.einsum("bsgrd,btgd->bgrst", q, k,
                             preferred_element_type=jnp.float32) * d ** -0.5
@@ -401,6 +423,13 @@ def grouped_query_attention(
         probs = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("bgrst,btgd->bsgrd", probs.astype(v.dtype), v)
         return out.reshape(b * s, heads * d)
+
+
+def sigmoid_gate(out: jax.Array, gate: jax.Array) -> jax.Array:
+    """An attention core's output gate: ``out ⊙ sigmoid(gate)``, the
+    product in float32, in ``out``'s dtype."""
+    return (out.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
 
 
 def latent_einsum(q: jax.Array, kv: jax.Array, k_rope: jax.Array,

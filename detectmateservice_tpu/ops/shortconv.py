@@ -30,6 +30,10 @@ Two forms, told apart by :func:`conv_route`:
   of whole lines by a block of channels, reads B, C and x̃ once from the one
   buffer (three column-block views, no split copied), shifts ``u`` down the
   sublanes in VMEM (``pltpu.roll``) and writes ``out`` once.
+
+:func:`causal_conv_silu` is the same tap walk with no gate on either side
+and SiLU behind it (a linear-attention layer's short convolution,
+models/moe_delta.py), in the plain form only.
 """
 from __future__ import annotations
 
@@ -87,11 +91,11 @@ def gated_short_conv(bcx: jax.Array, weight: jax.Array, seq: int,
     ``seq`` tokens → ``C ⊙ conv_K(B ⊙ x̃)`` [tokens, D] in ``bcx``'s dtype.
     Differentiable in both operands."""
     tokens, width = bcx.shape[0], bcx.shape[1] // 3
-    mesh_devices, _, routes = current_placement()
+    placed = current_placement()
     route = conv_route(impl, platform or jax.default_backend(), tokens, seq,
-                       width, weight.shape[1], mesh_devices)
-    if routes is not None:
-        routes[tokens // seq] = route
+                       width, weight.shape[1], placed.mesh_devices)
+    if placed.conv_routes is not None:
+        placed.conv_routes[tokens // seq] = route
     with jax.named_scope(f"conv_{route}"):
         if route == "fused":
             if not fits(tokens, seq, width, weight.shape[1]):
@@ -106,19 +110,41 @@ def gated_short_conv(bcx: jax.Array, weight: jax.Array, seq: int,
         return gated_conv_xla(bcx, weight, seq)
 
 
-def gated_conv_xla(bcx: jax.Array, weight: jax.Array, seq: int) -> jax.Array:
-    """The plain form: three shifted multiply-adds over rows, each masked by
-    the row's place in its line."""
-    tokens, taps = bcx.shape[0], weight.shape[1]
-    b, c, x = (part.astype(jnp.float32) for part in jnp.split(bcx, 3, -1))
-    u = b * x
+def causal_taps(u: jax.Array, weight: jax.Array, seq: int) -> jax.Array:
+    """The tap walk both plain forms share: ``u`` [tokens, D], ``weight``
+    [D, K] → float32 ``v[t] = Σ_j w[:, j] ⊙ u[t − (K − 1) + j]`` as K
+    shifted multiply-adds over rows, each masked by the row's place in its
+    line. The rows are shifted in ``u``'s own dtype and widened tap by tap:
+    widened first, XLA stores a float32 copy of a bfloat16 ``u`` and reads it
+    K times (10.1 ms at 32768 tokens by 8,192 channels on the v5e)."""
+    tokens, taps = u.shape[0], weight.shape[1]
     place = (jnp.arange(tokens, dtype=jnp.int32) % seq)[:, None]
     w = weight.astype(jnp.float32)
-    v = u * w[:, taps - 1]
+    v = u.astype(jnp.float32) * w[:, taps - 1]
     for shift in range(1, taps):
-        back = jnp.pad(u, ((shift, 0), (0, 0)))[:tokens]    # back[t] = u[t - shift]
+        # back[t] = u[t - shift]
+        back = jnp.pad(u, ((shift, 0), (0, 0)))[:tokens].astype(jnp.float32)
         v = v + jnp.where(place >= shift, back, 0.0) * w[:, taps - 1 - shift]
-    return (c * v).astype(bcx.dtype)
+    return v
+
+
+def gated_conv_xla(bcx: jax.Array, weight: jax.Array, seq: int) -> jax.Array:
+    """The plain form: the tap walk between the two gates."""
+    b, c, x = (part.astype(jnp.float32) for part in jnp.split(bcx, 3, -1))
+    return (c * causal_taps(b * x, weight, seq)).astype(bcx.dtype)
+
+
+def causal_conv_silu(x: jax.Array, weight: jax.Array, seq: int) -> jax.Array:
+    """``silu(conv_K(x))``: the depthwise causal convolution of ``x``
+    [tokens, D] with ``weight`` [D, K] over each line's positions (zeros
+    left of the line, no bias), then SiLU — a linear-attention layer's
+    short convolution (models/moe_delta.py), no gate on either side.
+    Products, sums and the activation in float32, the result in ``x``'s
+    dtype. XLA's fusion: at 1024 rows of 32 tokens and 8,192 channels it
+    has to read and write 537 MB each in bfloat16, 1.3 ms at the v5e's
+    819 GB/s, behind a projection of 8.4 ms."""
+    with jax.named_scope("conv_xla"):
+        return jax.nn.silu(causal_taps(x, weight, seq)).astype(x.dtype)
 
 
 def _kernel(b_ref, c_ref, x_ref, w_ref, o_ref, *, seq: int, taps: int):

@@ -335,3 +335,90 @@ def test_moe_convs_donated_train_step_fits_the_chip(
             - stats.alias_size_in_bytes + stats.temp_size_in_bytes)
     assert held < 13_500_000_000
     assert held == pytest.approx(11_158_771_200, rel=0.05)
+
+
+@pytest.fixture(scope="module")
+def moe_delta_scorer():
+    from benchmark.lib.manifest import read_json
+    from detectmateservice_tpu.models.moe_delta import (
+        MoEDeltaArch, MoEDeltaConfig, MoEDeltaScorer)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    (block,) = read_json(os.path.join(
+        repo, "benchmark", "configs", "qwen3-next-80b-a3b-ep16.json"))[
+        "stages"]["detector"]["component"]["detectors"].values()
+    return MoEDeltaScorer(MoEDeltaConfig(
+        arch=MoEDeltaArch.from_mapping(block["arch"]),
+        vocab_size=block["vocab_size"], seq_len=block["seq_len"],
+        platform="tpu"))
+
+
+def test_moe_deltas_widest_scoring_program_and_its_bytes(
+        moe_delta_scorer, one_chip, no_compile_cache):
+    """``qwen3-next-80b-a3b-ep16``'s 1024-row bucket as ``auto`` routes it
+    on one TPU: the delta rule's chunked form at one chunk a line (no scan
+    over positions or chunks is left in the program), the grouped einsum
+    for the gated attention layer with no ``[..., 32, 2]`` pair reshape for
+    its partial rotation, the fused head, the segment sum back from the
+    experts; scratch 3,672,502,272 bytes when this was written (4,276,482,048
+    while the convolution widened its rows before shifting them), beside
+    2.50 GB of float32 parameters — with the 8.10 GB the fitted detector
+    holds (parameters, both moments and the allocator's slack) 11.8 GB of
+    the chip's 16. About 50 s."""
+    import re
+
+    scorer = moe_delta_scorer
+    params = _described(jax.eval_shape(
+        lambda: scorer.init(jax.random.PRNGKey(0))[0]), one_chip)
+    compiled = jax.jit(scorer._score_impl).lower(
+        params, shape((1024, 32), jnp.uint16, one_chip)).compile()
+    assert scorer.attn_routes == {1024: "einsum"}
+    assert scorer.delta_routes == {1024: "chunked 32"}
+    assert scorer.head_routes == {1024: "pallas"}
+    assert "32 of 512 experts from 0" in scorer.expert_routes[1024]
+    assert "combine segment_sum" in scorer.expert_routes[1024]
+    text = compiled.as_text()
+    assert "lse_pallas" in text
+    assert text.count("segment_sum_add") and not colliding_scatters(text)
+    # a pair reshape of the 64 turned lanes would be [1024,32,heads,32,2]
+    # ([1024,32,2] alone is the two key heads' norm statistics)
+    pairs = sorted(set(re.findall(r"\w+\[1024,32,\d+,32,2\]", text)))
+    assert not pairs, pairs
+    # one chunk a line: the only loops left are the expert layers' walks
+    loops = [line for line in text.splitlines()
+             if " while(" in line and "/delta/" in line]
+    assert not loops, loops[:2]
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 4_800_000_000
+    assert stats.argument_size_in_bytes == pytest.approx(
+        4 * 625_669_184, rel=1e-3)
+    # beside what the fitted detector holds: under the chip's 16 GB
+    assert 12 * 625_669_184 + stats.temp_size_in_bytes < 13_000_000_000
+
+
+def test_moe_deltas_donated_train_step_fits_the_chip(
+        moe_delta_scorer, one_chip, no_compile_cache):
+    """The boundary fit's 32-row donated train step at the published widths
+    and the cut's four layers: 16 bytes a parameter while a gradient lives.
+    XLA's buffer assignment for a described v5e read 7,508,054,016 bytes of
+    arguments (parameters and both moments, aliased to the outputs) and
+    2,842,418,176 of temporaries = 10.35 GB when this was written. The
+    delta rule takes its chunked form here too (the scan's reverse pass
+    would keep 67 MB of state a position and layer). About 55 s."""
+    scorer = moe_delta_scorer
+    params, opt_state = _described(jax.eval_shape(
+        lambda: scorer.init(jax.random.PRNGKey(0))), one_chip)
+    compiled = jax.jit(scorer._train_impl, donate_argnums=(0, 1)).lower(
+        params, opt_state, shape((2,), jnp.uint32, one_chip),
+        shape((32, 32), jnp.int32, one_chip)).compile()
+    assert scorer.delta_routes[32] == "chunked 32"
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and "segment_sum_add" in line]
+    assert len(kernels) == 4, len(kernels)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == pytest.approx(
+        12 * 625_669_184, rel=1e-3)
+    held = (stats.argument_size_in_bytes + stats.output_size_in_bytes
+            - stats.alias_size_in_bytes + stats.temp_size_in_bytes)
+    assert held < 12_500_000_000
+    assert held == pytest.approx(10_350_473_728, rel=0.05)
