@@ -34,6 +34,8 @@ events):
 * :class:`DeviceIdleClock` — what the host knows of the device's idle time,
   split by what the coalescer held meanwhile
   (``detector_device_idle_seconds_total{cause}``).
+* :class:`CaptureSpans` — while a profiler capture runs, the longest span
+  of each name, for the capture's own record (``utils/profiling.py``).
 * :func:`export_hbm_gauges` — ``device_hbm_bytes{device,kind}`` computed at
   scrape time from ``jax.Device.memory_stats()`` (absent on CPU backends,
   which return ``None`` — then nothing is exported).
@@ -654,6 +656,42 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class CaptureSpans:
+    """The longest ``dm.*`` span of each name while a ``POST /admin/profile``
+    capture runs (``utils/profiling.py`` arms one before ``start_trace`` and
+    disarms it after ``stop_trace``): seconds, the monotonic stamp of its
+    start and its ``batch`` where it has one. Written by whichever thread
+    closes the span, read by the capture thread once it is disarmed."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        # name -> (seconds, started at (monotonic), batch or None)
+        self.longest: Dict[str, Tuple[float, float, Optional[int]]] = {}
+
+    def note(self, name: str, started: float, seconds: float,
+             batch: Optional[int]) -> None:
+        with self._lock:
+            held = self.longest.get(name)
+            if held is None or seconds > held[0]:
+                self.longest[name] = (seconds, started, batch)
+
+    def snapshot(self) -> Dict[str, Tuple[float, float, Optional[int]]]:
+        """A copy: a span opened while armed may still close after."""
+        with self._lock:
+            return dict(self.longest)
+
+
+# the armed capture's record; None with no capture running, and span()
+# then takes the paths it always took
+_CAPTURE: Optional[CaptureSpans] = None
+
+
+def arm_capture(record: Optional[CaptureSpans]) -> None:
+    """Arm (``None``: disarm) the per-name span maxima of one capture."""
+    global _CAPTURE
+    _CAPTURE = record
+
+
 class _Span:
     __slots__ = ("_annotation", "_phase", "_t0")
 
@@ -678,6 +716,30 @@ class _Span:
         return False
 
 
+class _CaptureSpan(_Span):
+    """A span closed while a capture is armed: also timed for the capture's
+    own record, whatever its name."""
+
+    __slots__ = ("_record", "_name", "_batch", "_started")
+
+    def __init__(self, record, name, batch, annotation, phase) -> None:
+        super().__init__(annotation, phase)
+        self._record = record
+        self._name = name
+        self._batch = batch
+        self._started = 0.0
+
+    def __enter__(self) -> None:
+        self._started = time.monotonic()
+        super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        self._record.note(self._name, self._started,
+                          time.monotonic() - self._started, self._batch)
+        return False
+
+
 def span(name: str, **kv):
     """Context manager marking one layer boundary. Enters a
     ``TraceAnnotation(name, **kv)`` — an event on the profiler's host plane
@@ -685,8 +747,14 @@ def span(name: str, **kv):
     :data:`PHASE_SPANS`, adds the elapsed monotonic time to
     ``detector_phase_seconds_total{phase}`` and one to
     ``detector_phase_total{phase}``. Where neither sink is armed it returns
-    a shared no-op."""
+    a shared no-op. While a capture is armed (:func:`arm_capture`) every
+    span is also timed for the capture's record, in every stage."""
     phase = _PHASE_CHILDREN.get(name)
+    record = _CAPTURE
+    if record is not None:
+        return _CaptureSpan(
+            record, name, kv.get("batch"),
+            None if _ANNOTATION is None else _ANNOTATION(name, **kv), phase)
     if _ANNOTATION is not None:
         return _Span(_ANNOTATION(name, **kv), phase)
     return NULL_SPAN if phase is None else _Span(None, phase)
@@ -749,17 +817,34 @@ class DeviceIdleClock:
         if self._mark is None:
             self._mark = now
 
+    @staticmethod
+    def _split(mark: float, now: float, release_at: Optional[float]):
+        """The idle stretch ``mark`` → ``now`` as (cause, seconds) pairs."""
+        if release_at is None:
+            return (("no_rows", now - mark),)
+        met = min(max(release_at, mark), now)
+        return (("fill", met - mark), ("host", now - met))
+
     def advance(self, now: float, release_at: Optional[float]) -> None:
         mark = self._mark
         if mark is None or now <= mark:
             return
-        if release_at is None:
-            self._add("no_rows", now - mark)
-        else:
-            met = min(max(release_at, mark), now)
-            self._add("fill", met - mark)
-            self._add("host", now - met)
+        for cause, seconds in self._split(mark, now, release_at):
+            self._add(cause, seconds)
         self._mark = now
+
+    def reading(self, now: float,
+                release_at: Optional[float]) -> Dict[str, float]:
+        """The account as :meth:`advance` would leave it at ``now``, an idle
+        stretch still open counted up to ``now`` — and nothing changed. The
+        one read another thread may make (a capture's marks): it sees the
+        owner's totals as they stand, between two of its updates."""
+        seconds = dict(self.seconds)
+        mark = self._mark
+        if mark is not None and now > mark:
+            for cause, part in self._split(mark, now, release_at):
+                seconds[cause] += max(0.0, part)
+        return seconds
 
     def busy_from(self, now: float, release_at: Optional[float]) -> bool:
         """A batch was released to the device at ``now``. True when it

@@ -587,6 +587,48 @@ DEVICE_IDLE_SECONDS = _series(
     IDLE_LABELS,
 )
 
+# what a POST /admin/profile capture cost the process that took it
+# (utils/profiling.py ProfileManager): every gauge is absent until a
+# capture has ended, set when one ends with state "done" and cleared when
+# the next one ends. seconds: phase=start (the call of start_trace), stop
+# (the call of stop_trace: the profiler collecting and writing the file),
+# traced (between the two: the stretch the capture holds). stall: a 5 ms
+# heartbeat thread's late wakes over the capture, stat=max (the longest
+# time the interpreter or the processor was withheld from a waiting
+# thread) and sum. span_max: the longest single dm.<span> of the capture,
+# by span (dm.recv_wait, the fill, is not exported). idle_share: the
+# DeviceIdleClock's totals by cause over the traced stretch, in per cent
+# of it — the host's reading of what the capture's device plane shows.
+CAPTURE_PHASE_LABELS = ("component_type", "component_id", "phase")
+PROFILE_CAPTURE_SECONDS = _series(
+    Gauge, "profile_capture_seconds",
+    "The last profiler capture's own phases, by phase: start (start_trace), "
+    "stop (stop_trace), traced (between the two)",
+    CAPTURE_PHASE_LABELS)
+CAPTURE_STAT_LABELS = ("component_type", "component_id", "stat")
+PROFILE_CAPTURE_STALL = _series(
+    Gauge, "profile_capture_stall_seconds",
+    "Late wakes (over 20 ms) of the 5 ms heartbeat thread that ran for the "
+    "last profiler capture: stat=max the longest, stat=sum their total",
+    CAPTURE_STAT_LABELS)
+CAPTURE_SPAN_LABELS = ("component_type", "component_id", "span")
+PROFILE_CAPTURE_SPAN_MAX = _series(
+    Gauge, "profile_capture_span_max_seconds",
+    "The longest single dm.<span> closed during the last profiler capture, "
+    "by span name",
+    CAPTURE_SPAN_LABELS)
+PROFILE_CAPTURE_IDLE_SHARE = _series(
+    Gauge, "profile_capture_idle_share",
+    "Host-known device idle time over the last profiler capture's traced "
+    "stretch, in per cent of it, by cause: fill / no_rows / host",
+    IDLE_LABELS)
+CAPTURE_STATE_LABELS = ("component_type", "component_id", "state")
+PROFILE_CAPTURES = _series(
+    Counter, "profile_captures_total",
+    "Profiler captures ended, by state: done, or error (start_trace or "
+    "stop_trace raised, or no *.xplane.pb was left)",
+    CAPTURE_STATE_LABELS)
+
 # multi-tenant admission control (shed/, dmshed): the ingress overload
 # contract. Cardinality discipline — tenant-attributed series carry the
 # quota tier and a BOUNDED hashed tenant bucket (shed_tenant_buckets label

@@ -2074,6 +2074,17 @@ class JaxScorerDetector(CoreDetector):
         self._idle_clock = device_obs.DeviceIdleClock({
             cause: m.DEVICE_IDLE_SECONDS().labels(cause=cause, **labels)
             for cause in device_obs.DeviceIdleClock.CAUSES})
+        # a POST /admin/profile capture reads the account at its two marks
+        from ...utils.profiling import PROFILER
+
+        PROFILER.set_idle_reader(self._idle_reading)
+
+    def _idle_reading(self, now: float) -> Dict[str, float]:
+        """The idle account as it stands at ``now``, a stretch still open
+        counted up to it. Called on a capture's thread, not the engine's:
+        it changes nothing, and the caller makes it again where it fell
+        into an update of what the coalescer holds."""
+        return self._idle_clock.reading(now, self._release_at())
 
     def _current_trace_id(self) -> Optional[str]:
         """Flight recorder's last completed trace id (the PR-1 link a

@@ -298,15 +298,16 @@ def _profile_start(service, query, payload) -> Response:
     payload = payload or {}
     seconds = _float_param(query, "seconds")
     if seconds is None:
-        seconds = payload.get("seconds")
-    if seconds is None:
-        # legacy body shape from the pre-ledger profile endpoint
-        seconds = float(payload.get("duration_ms", 1000)) / 1000.0
+        seconds = payload.get("seconds", 1.0)
     base_dir = (payload.get("out_dir") or service.settings.profile_dir
                 or PROFILER.default_dir())
+    labels = dict(
+        component_type=service.settings.component_type,
+        component_id=service.settings.component_id or "unknown")
     try:
         info = PROFILER.start(base_dir, float(seconds),
-                              service.settings.profile_max_captures)
+                              service.settings.profile_max_captures,
+                              labels=labels)
     except ProfileBusyError as exc:
         return Response(409, {"detail": str(exc)})
     info["detail"] = "capture started"
