@@ -1839,7 +1839,8 @@ class JaxScorerDetector(CoreDetector):
             # which head (models/base.py head_route), which attention
             # (ops/attention.py attention_route), which short convolution
             # (ops/shortconv.py conv_route), which form of the delta rule
-            # (ops/deltarule.py delta_route) and which expert path (the
+            # (ops/deltarule.py delta_route: the kernel, the chunked
+            # jax.numpy form or the scan) and which expert path (the
             # sparse-expert scorers') each traced device executable took,
             # by its rows; decided at trace time, empty where the scorer has
             # no such part. The host twin's calls are not in it: it is

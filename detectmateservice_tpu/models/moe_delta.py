@@ -35,9 +35,10 @@ a block's two norms, the final norm and the per-head norms of q and k):
   head; q and k L2-normalised per head, q scaled by ``Dk^-0.5``; per value
   head ``S' = exp(g_t) S_{t−1}``, ``u_t = β_t (v_t − S'ᵀ k_t)``, ``S_t = S'
   + k_t u_tᵀ``, ``o_t = S_tᵀ q_t`` from ``S_0 = 0``
-  (ops/deltarule.py::gated_delta_rule, the chunked form); ``o ← w ⊙ o ·
-  rsqrt(mean(o²) + eps) ⊙ silu(z)`` per head (the plain norm, w ones);
-  then ``W_out``.
+  (ops/deltarule.py::gated_delta_rule: the chunked closed form, as one
+  Pallas kernel on the served buckets of one TPU, as ``jax.numpy``
+  elsewhere); ``o ← w ⊙ o · rsqrt(mean(o²) + eps) ⊙ silu(z)`` per head (the
+  plain norm, w ones); then ``W_out``.
 * gated full attention (H query and G key/value heads of ``head_dim``):
   ``q | gate | k | v = W_qkv·y`` (each query head with a gate as wide);
   zero-centred RMSNorm on q and on k per head; rotary positions,
@@ -195,7 +196,8 @@ class MoEDeltaConfig:
     # "auto" | "einsum" (ops/attention.py::attention_route: fewer key/value
     # heads than query heads take the grouped einsum everywhere)
     attn_impl: str = "auto"
-    # "auto" | "chunked" | "scan" (ops/deltarule.py::delta_route)
+    # "auto" | "fused" | "chunked" | "scan" (ops/deltarule.py::delta_route:
+    # auto = the kernel on one TPU from 256 rows, else the chunked form)
     delta_impl: str = "auto"
     head_impl: str = "auto"
     platform: str = ""
@@ -259,7 +261,8 @@ class Block(nn.Module):
                 qkv[:, :key_w].reshape(n, hk, dk),
                 qkv[:, key_w:2 * key_w].reshape(n, hk, dk),
                 qkv[:, 2 * key_w:].reshape(n, hv, dv), g, beta, seq,
-                chunk=DELTA_CHUNK, impl=cfg.delta_impl, dtype=cfg.dtype)
+                chunk=DELTA_CHUNK, impl=cfg.delta_impl, dtype=cfg.dtype,
+                platform=cfg.platform, mixed=qkv)
         with jax.named_scope("norm_gate"):
             z = qkvz[:, mixed_w:].reshape(n, hv, dv).astype(jnp.float32)
             out = rms_norm(out, self.param("out_norm", nn.initializers.ones,

@@ -14,10 +14,11 @@ per head here (``q`` also scaled by ``Dk^-0.5``); value head ``h`` reads key
 head ``h // (Hv / Hk)``. Operands are token-major (``[B·S, heads, width]``,
 as the stacks run since PR 28); a line never reads its neighbours.
 
-Two forms, told apart by :func:`delta_route`:
+One algorithm in two forms, and the closed form written twice; told apart by
+:func:`delta_route` from what the call can observe:
 
 * ``scan`` — the recurrence as written, a ``lax.scan`` over positions with
-  the state in float32. What the tests hold the other form to; its reverse
+  the state in float32. What the tests hold the other forms to; its reverse
   pass keeps a state per position (67 MB a step and layer at 32 rows of 32
   value heads), so nothing served or fitted takes it.
 * ``chunked`` — positions in chunks of ``chunk``; inside a chunk the
@@ -31,48 +32,90 @@ Two forms, told apart by :func:`delta_route`:
       o = (e^γ ⊙ q) S + ((q kᵀ) ⊙ e^{γ_t − γ_s}, s <= t) u
       S ← e^{γ_C} S + (e^{γ_C − γ} ⊙ k)ᵀ u
 
-  At the served shape (lines of 32 positions, one chunk) there is no
-  entering state and no scan. The inverse is forward substitution over the
-  chunk's rows with the (line, chunk, head) index on the lanes — ``[C, C,
-  B·H]`` float32 — because a ``[.., 32, 32]`` float32 matrix per head pads
-  its rows fourfold on the TPU and a loop over them would walk the padding
-  31 times; the matmuls around it take their batch dimensions first, as
-  the MXU wants them. Both forms are differentiable; the fit's 32-row step
-  takes the chunked one.
+  As ``jax.numpy``: the inverse is forward substitution over the chunk's
+  rows with the (line, chunk, head) index on the lanes — ``[C, C, B·H]``
+  float32 — because a ``[.., 32, 32]`` float32 matrix per head pads its
+  rows fourfold on the TPU and a loop over them would walk the padding 31
+  times; the matmuls around it take their batch dimensions first, as the
+  MXU wants them, and XLA copies between the two layouts. Differentiable,
+  and what the CPU, a mesh, a line of several chunks and the fit's 32-row
+  step take.
+* ``fused`` — the same closed form where a line is one chunk (no entering
+  state, no scan: the served shape, lines of 32 positions) as one Pallas
+  kernel, :func:`gated_delta`: a block of lines' ``q``, ``k``, ``v`` read
+  once in the dtype they arrive in and in place (no slice of ``q | k | v``,
+  no float32 copy), the gates read once, ``o`` written once, and the norms,
+  ``γ``, ``k kᵀ``, ``q kᵀ``, ``A``, the inverse and both products with
+  nothing between them leaving VMEM — no padded ``[32, 32, ·]``
+  intermediate and no copy between layouts reaches HBM. What ``auto`` takes
+  on one TPU from ``FUSED_MIN_ROWS`` rows where the shapes tile
+  (:func:`fits`). Its reverse pass is the chunked form's, recomputed from
+  the saved operands.
 
-Precision: gates, decays, L2 norms, the inverse, ``T``'s products and the
-state in float32 (``Precision.HIGHEST`` where a float32 matmul would
-otherwise run in one bfloat16 pass); ``k kᵀ``, ``q kᵀ`` and the product
-with ``u`` take operands in ``dtype`` (bfloat16 as served) with float32
-accumulation.
+Precision, the same in the chunked form and the kernel: gates, decays, L2
+norms, the inverse, ``T``'s products and the state in float32
+(``Precision.HIGHEST`` where a float32 matmul would otherwise run in one
+bfloat16 pass; the kernel's inverse is float32 multiply-adds on the VPU and
+its ``T diag(β) v`` splits ``T diag(β)`` into three bfloat16 parts against
+``v`` as it arrived, which gives the float32 product whole); ``k kᵀ``,
+``q kᵀ`` and the product with ``u`` take operands in ``dtype`` (bfloat16
+as served) with float32 accumulation.
 """
 from __future__ import annotations
 
-from typing import Any, Tuple
+import functools
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .attention import current_placement
 
 _HIGHEST = jax.lax.Precision.HIGHEST
-IMPLS = ("auto", "chunked", "scan")
+IMPLS = ("auto", "chunked", "scan", "fused")
+LANES = 128
+# rows from which ``auto`` takes the kernel on one TPU: the smallest served
+# bucket; the fit's 32-row step keeps the chunked form, whose backward it
+# needs anyway
+FUSED_MIN_ROWS = 256
 
 
-def delta_route(impl: str, seq: int, chunk: int) -> str:
-    """``"chunked <C>"`` or ``"scan"`` for one traced call. ``impl`` other
-    than ``"auto"`` forces; ``auto`` is the chunked form everywhere (the
-    scan's reverse pass does not fit beside the fit's parameters), with the
-    chunk cut to the line where the line is shorter."""
+def delta_route(impl: str, seq: int, chunk: int, platform: str = "",
+                rows: int = 0, key_dim: int = 0, value_dim: int = 0,
+                mesh_devices: int = 1, rep: int = 1) -> str:
+    """``"chunked <C>"``, ``"scan"`` or ``"fused"`` for one traced call.
+    ``impl`` other than ``"auto"`` forces. ``auto`` takes the kernel on ONE
+    TPU (GSPMD does not partition a Pallas call) from ``FUSED_MIN_ROWS``
+    rows where a line is one chunk and the shapes tile (:func:`fits`), and
+    the chunked form everywhere else — the CPU, a mesh, a line of several
+    chunks, the fit's 32-row step (the scan's reverse pass does not fit
+    beside the fit's parameters) — with the chunk cut to the line where
+    the line is shorter."""
     if impl not in IMPLS:
         raise ValueError(f"delta impl {impl!r}: expected one of {list(IMPLS)}")
-    if impl == "scan":
-        return "scan"
+    if impl in ("scan", "fused"):
+        return impl
     chunk = min(chunk, seq)
     if seq % chunk:
         raise ValueError(f"delta rule: chunks of {chunk} do not divide a "
                          f"line's {seq} positions")
+    if (impl == "auto" and platform == "tpu" and mesh_devices == 1
+            and rows >= FUSED_MIN_ROWS and chunk == seq
+            and fits(seq, key_dim, value_dim, rep)):
+        return "fused"
     return f"chunked {chunk}"
+
+
+def fits(seq: int, key_dim: int, value_dim: int, rep: int = 1) -> bool:
+    """What the kernel tiles: head widths in whole lane groups, lines in
+    whole 8-row sublane tiles that fill a 128-token tile, and ``rep`` value
+    heads a key head that divide a lane group's 128 (value head, tile)
+    units into eight tiles each or more."""
+    return (key_dim > 0 and key_dim % LANES == 0 and value_dim > 0
+            and value_dim % LANES == 0 and seq % 8 == 0 and LANES % seq == 0
+            and 0 < rep <= LANES // 8 and LANES % rep == 0)
 
 
 def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -82,17 +125,64 @@ def l2_normalise(x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      beta: jax.Array, seq: int, chunk: int = 32,
-                     impl: str = "auto", dtype: Any = jnp.bfloat16
-                     ) -> jax.Array:
+                     impl: str = "auto", dtype: Any = jnp.bfloat16,
+                     platform: str = "",
+                     mixed: Optional[jax.Array] = None) -> jax.Array:
     """``q``, ``k`` [N, Hk, Dk], ``v`` [N, Hv, Dv], ``g`` (log decay, <= 0)
     and ``beta`` [N, Hv] over ``N = B·seq`` tokens in lines of ``seq`` →
     ``o`` [N, Hv, Dv] float32. ``q`` and ``k`` arrive unnormalised.
     ``chunk`` is a static argument of the operation, not a key of any
-    configuration."""
-    route = delta_route(impl, seq, chunk)
-    records = current_placement().delta_routes
-    if records is not None:
-        records[q.shape[0] // seq] = route
+    configuration; ``platform`` is where the call is placed (the default
+    backend when empty). ``mixed`` is the ``[N, 2·Hk·Dk + Hv·Dv]`` array
+    ``q | k | v`` were sliced from, where the caller has it: the kernel
+    then reads its column blocks in place, and no slice is copied for it."""
+    placed = current_placement()
+    platform = platform or jax.default_backend()
+    rows = q.shape[0] // seq
+    heads = Heads(q.shape[1], v.shape[1], q.shape[2], v.shape[2])
+    route = delta_route(impl, seq, chunk, platform, rows, heads.dk, heads.dv,
+                        placed.mesh_devices, heads.rep)
+    if placed.delta_routes is not None:
+        placed.delta_routes[rows] = route
+    if route == "fused":
+        if chunk < seq or not fits(seq, heads.dk, heads.dv, heads.rep):
+            raise ValueError(
+                f"delta impl 'fused': lines of {seq} positions in chunks of "
+                f"{chunk} with {heads.rep} value heads of {heads.dv} a key "
+                f"head of {heads.dk} do not tile (one chunk a line, 8 | seq "
+                f"| {LANES}, head widths in multiples of {LANES}, value "
+                f"heads a key head a power of two up to {LANES // 8})")
+        # in place where v starts on a whole block of a key head's values
+        in_place = (mixed is not None and (2 * heads.hk * heads.dk)
+                    % (heads.rep * heads.dv) == 0)
+        with jax.named_scope("delta_fused"):
+            return _fused((mixed,) if in_place else (q, k, v), g, beta, heads,
+                          seq, dtype, platform != "tpu")
+    return _plain(q, k, v, g, beta, seq, chunk, dtype, route)
+
+
+class Heads(NamedTuple):
+    """Key heads, value heads and their widths."""
+    hk: int
+    hv: int
+    dk: int
+    dv: int
+
+    @property
+    def rep(self) -> int:
+        return self.hv // self.hk
+
+    def split(self, mixed: jax.Array) -> Tuple[jax.Array, ...]:
+        """``q``, ``k``, ``v`` by head out of ``q | k | v`` [N, ·]."""
+        n, key_w = mixed.shape[0], self.hk * self.dk
+        return (mixed[:, :key_w].reshape(n, self.hk, self.dk),
+                mixed[:, key_w:2 * key_w].reshape(n, self.hk, self.dk),
+                mixed[:, 2 * key_w:].reshape(n, self.hv, self.dv))
+
+
+def _plain(q, k, v, g, beta, seq: int, chunk: int, dtype, route: str
+           ) -> jax.Array:
+    """The two ``jax.numpy`` forms over unnormalised token-major operands."""
     n, hk, dk = q.shape
     hv = v.shape[1]
     lines = n // seq
@@ -283,3 +373,253 @@ def delta_gates(a: jax.Array, b: jax.Array, a_log: jax.Array,
     g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
         a.astype(jnp.float32) + dt_bias.astype(jnp.float32))
     return g, jax.nn.sigmoid(b.astype(jnp.float32))
+
+
+# -- the kernel ---------------------------------------------------------------
+
+def _kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, kk_ref, qk_ref,
+            gamma_ref, beta_ref, a_ref, w_ref, t_ref, p_ref, *, seq: int,
+            rep: int, dv: int, dtype):
+    """One grid step: a block of whole 128-token tiles of one key head and
+    its ``rep`` value heads; a tile holds ``128 / seq`` lines. Four phases,
+    everything between them in VMEM:
+
+    1. a tile at a time on the MXU: ``k kᵀ`` and ``q kᵀ`` of the 128 tokens
+       in one product, the lines' diagonal ``[seq, seq]`` blocks kept side
+       by side along the lanes (``[seq, (line, s)]``; the products between
+       different lines are the price of whole MXU tiles);
+    2. row t of every tile's block gathered and transposed, so that a
+       (value head, tile) unit owns a lane: ``A`` and the decay-masked
+       ``q kᵀ`` as ``[t, (line, s), unit]``, the gates, the decays and the
+       masks elementwise with nothing padded;
+    3. ``(I + A)^-1`` by forward substitution over rows, in place (row t of
+       the inverse is ``e_t − Σ_{j<t} A[t, j] · row_j``): float32
+       multiply-adds over whole vregs of units, no MXU pass;
+    4. back to a row a sublane, and a tile at a time on the MXU again:
+       ``u = T diag(β) v`` and ``o = (q kᵀ ⊙ decay) u`` per value head, the
+       blocks spread block-diagonally over the tile's 128 tokens."""
+    tiles = q_ref.shape[0] // LANES
+    dk = q_ref.shape[1]
+    lines, units = LANES // seq, rep * tiles
+    line_of_lane = jax.lax.broadcasted_iota(jnp.int32, (seq, LANES), 1) // seq
+    one_line = (jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0) // seq
+                == jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+                // seq)
+
+    def fold(x):
+        # [128, 128] → [seq, (line, s)]: each line's diagonal block
+        out = x[:seq]
+        for i in range(1, lines):
+            out = jnp.where(line_of_lane == i, x[i * seq:(i + 1) * seq], out)
+        return out
+
+    def unfold(x):
+        # and back: block-diagonal over the tile's lines
+        return jnp.where(one_line, jnp.concatenate([x] * lines, axis=0),
+                         jnp.zeros((), x.dtype))
+
+    def over_tiles(body):
+        # a few tiles an iteration: independent work for the scheduler to
+        # lay over each other's MXU latency
+        unroll = next(u for u in (4, 2, 1) if tiles % u == 0)
+
+        def step(i, carry):
+            for r in range(unroll):
+                body(i * unroll + r)
+            return carry
+
+        jax.lax.fori_loop(0, tiles // unroll, step, 0)
+
+    def tile_rows(c):
+        return pl.ds(pl.multiple_of(c * LANES, LANES), LANES)
+
+    def block_rows(index):
+        return pl.ds(pl.multiple_of(index * seq, seq), seq)
+
+    def products(c):
+        k = l2_normalise(k_ref[tile_rows(c), :]).astype(dtype)
+        q = (l2_normalise(q_ref[tile_rows(c), :]) * dk ** -0.5).astype(dtype)
+        both = jax.lax.dot_general(
+            jnp.concatenate([k, q], axis=0), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        kk_ref[block_rows(c), :] = fold(both[:LANES])
+        qk_ref[block_rows(c), :] = fold(both[LANES:])
+
+    over_tiles(products)
+
+    # the gates, unit j · tiles + c a row: γ the running sum of g inside
+    # each line along the lanes, then a unit a lane
+    gamma = jnp.concatenate([g_ref[j] for j in range(rep)], axis=0)
+    place = jax.lax.broadcasted_iota(jnp.int32, gamma.shape, 1) % seq
+    shift = 1
+    while shift < seq:
+        gamma = gamma + jnp.where(place >= shift,
+                                  pltpu.roll(gamma, shift, 1), 0.0)
+        shift *= 2
+    gamma_ref[...] = gamma.T                             # [(line, t), unit]
+    beta_ref[...] = jnp.concatenate([b_ref[j] for j in range(rep)], axis=0).T
+    s_at = jax.lax.broadcasted_iota(jnp.int32, (seq, units), 0)
+
+    def lanes_last(t, carry):
+        def rows_t(ref):
+            row = ref[pl.ds(t, tiles, stride=seq), :]    # row t, every tile
+            return jnp.concatenate([row] * rep, axis=0).T
+
+        kk, qk = rows_t(kk_ref), rows_t(qk_ref)          # [(line, s), unit]
+        for i in range(lines):
+            at, here = slice(i * seq, (i + 1) * seq), pl.ds(i * seq + t, 1)
+            decay = jnp.exp(jnp.minimum(
+                gamma_ref[here, :] - gamma_ref[at, :], 0.0))
+            a_ref[t, at, :] = jnp.where(
+                s_at < t, beta_ref[here, :] * decay * kk[at], 0.0)
+            w_ref[t, at, :] = jnp.where(s_at <= t, decay * qk[at], 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, seq, lanes_last, 0)
+
+    def solve(t, carry):
+        def term(j, acc):
+            return tuple(
+                acc[i] + a_ref[t, pl.ds(i * seq + j, 1), :]
+                * a_ref[j, i * seq:(i + 1) * seq, :] for i in range(lines))
+
+        acc = jax.lax.fori_loop(0, t, term, tuple(
+            jnp.zeros((seq, units), jnp.float32) for _ in range(lines)))
+        for i in range(lines):
+            a_ref[t, i * seq:(i + 1) * seq, :] = jnp.where(
+                s_at == t, 1.0, 0.0) - acc[i]
+        return carry
+
+    jax.lax.fori_loop(0, seq, solve, 0)
+
+    def rows_last(t, carry):
+        # T diag(β): its product with v then takes v as it arrived
+        t_ref[pl.ds(t, units, stride=seq), :] = (a_ref[t] * beta_ref[...]).T
+        p_ref[pl.ds(t, units, stride=seq), :] = w_ref[t].T
+        return carry
+
+    jax.lax.fori_loop(0, seq, rows_last, 0)
+
+    def apply(c):
+        for j in range(rep):
+            at, cols = block_rows(j * tiles + c), slice(j * dv, (j + 1) * dv)
+            t_beta, v = t_ref[at, :], v_ref[tile_rows(c), cols]
+            if v.dtype == jnp.bfloat16:
+                # three bfloat16 parts hold a float32, and v is bfloat16 as
+                # it is: three MXU passes give the float32 product whole
+                # (Precision.HIGHEST would split v too, in six)
+                high = t_beta.astype(jnp.bfloat16)
+                rest = t_beta - high.astype(jnp.float32)
+                mid = rest.astype(jnp.bfloat16)
+                low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+                parts = jnp.dot(
+                    jnp.concatenate([unfold(x) for x in (high, mid, low)],
+                                    axis=0), v,
+                    preferred_element_type=jnp.float32)
+                u = parts[:LANES] + parts[LANES:2 * LANES] + parts[2 * LANES:]
+            else:
+                u = jnp.dot(unfold(t_beta), v.astype(jnp.float32),
+                            precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
+            o_ref[tile_rows(c), cols] = jnp.dot(
+                unfold(p_ref[at, :].astype(dtype)), u.astype(dtype),
+                preferred_element_type=jnp.float32)
+
+    over_tiles(apply)
+
+
+def _block_tiles(tiles: int, rep: int, interpret: bool = False) -> int:
+    """Tiles a grid step owns: a (value head, tile) unit a lane, so a whole
+    lane group of them, in whole sublane tiles; the interpreter, which tiles
+    nothing, is not made to walk the empty ones of a short call."""
+    block = LANES // rep
+    return min(block, tiles) if interpret else block
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "seq", "dtype", "interpret"))
+def gated_delta(operands: Tuple[jax.Array, ...], g: jax.Array,
+                beta: jax.Array, heads: Heads, seq: int,
+                dtype: Any = jnp.bfloat16, interpret: bool = False
+                ) -> jax.Array:
+    """The kernel: the closed form of one chunk a line. ``operands`` is
+    ``(q, k, v)`` as :func:`gated_delta_rule` takes them, or ``(mixed,)``,
+    the one array that holds ``q | k | v`` side by side, read in place.
+    jitted, so that a stack's layers share one trace of its body (PERF.md
+    section 6, PR 28). Lines that do not fill the last block of tiles are
+    followed by empty ones."""
+    hk, hv, dk, dv = heads
+    rep = heads.rep
+    n = g.shape[0]
+    block = _block_tiles(-(-n // LANES), rep, interpret)
+    pad = -n % (block * LANES)
+    tiles = (n + pad) // LANES
+
+    def whole_blocks(x: jax.Array) -> jax.Array:
+        return jnp.pad(x.reshape(n, -1), ((0, pad), (0, 0))) if pad else (
+            x.reshape(n, -1))
+
+    if len(operands) == 1:
+        q = k = v = whole_blocks(operands[0])
+        k_at, v_at = hk, 2 * hk * dk // (rep * dv)
+    else:
+        q, k, v = (whole_blocks(x) for x in operands)
+        k_at = v_at = 0
+
+    def by_tile(x: jax.Array) -> jax.Array:
+        # [N, Hv] → [Hk, rep, tiles, 128]: a tile's tokens along the lanes
+        return whole_blocks(x.astype(jnp.float32)).T.reshape(
+            hk, rep, tiles, LANES)
+
+    gates = pl.BlockSpec((None, rep, block, LANES), lambda i, h: (h, 0, i, 0))
+    units = block * rep
+    out = pl.pallas_call(
+        functools.partial(_kernel, seq=seq, rep=rep, dv=dv, dtype=dtype),
+        grid=(tiles // block, hk),
+        in_specs=[pl.BlockSpec((block * LANES, dk), lambda i, h: (i, h)),
+                  pl.BlockSpec((block * LANES, dk),
+                               lambda i, h: (i, k_at + h)),
+                  pl.BlockSpec((block * LANES, rep * dv),
+                               lambda i, h: (i, v_at + h)),
+                  gates, gates],
+        out_specs=pl.BlockSpec((block * LANES, rep * dv),
+                               lambda i, h: (i, h)),
+        out_shape=jax.ShapeDtypeStruct((n + pad, hv * dv), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((block * seq, LANES), jnp.float32),       # k kᵀ
+            pltpu.VMEM((block * seq, LANES), jnp.float32),       # q kᵀ
+            pltpu.VMEM((LANES, units), jnp.float32),             # γ
+            pltpu.VMEM((LANES, units), jnp.float32),             # β
+            pltpu.VMEM((seq, LANES, units), jnp.float32),        # A, then T
+            pltpu.VMEM((seq, LANES, units), jnp.float32),        # q kᵀ ⊙ decay
+            pltpu.VMEM((units * seq, LANES), jnp.float32),       # T diag(β)
+            pltpu.VMEM((units * seq, LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=100 << 20),
+        interpret=interpret, name="gated_delta",
+    )(q, k, v, by_tile(g), by_tile(beta))
+    return out[:n].reshape(n, hv, dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _fused(operands, g, beta, heads, seq, dtype, interpret):
+    return gated_delta(operands, g, beta, heads, seq, dtype, interpret)
+
+
+def _fused_fwd(operands, g, beta, heads, seq, dtype, interpret):
+    return (gated_delta(operands, g, beta, heads, seq, dtype, interpret),
+            (operands, g, beta))
+
+
+def _fused_bwd(heads, seq, dtype, interpret, saved, grad):
+    # exact: the chunked form recomputed from the operands. The fit's 32-row
+    # step takes that form anyway; a backward kernel would buy nothing
+    def chunked(operands, g, beta):
+        q, k, v = heads.split(*operands) if len(operands) == 1 else operands
+        return _plain(q, k, v, g, beta, seq, seq, dtype, f"chunked {seq}")
+
+    return jax.vjp(chunked, *saved)[1](grad)
+
+
+_fused.defvjp(_fused_fwd, _fused_bwd)

@@ -1,13 +1,13 @@
 """Gated-delta-rule microbench on the chip, and the ``moe_delta`` scoring
 call per bucket.
 
-Default: the delta rule's core (ops/deltarule.py, the chunked form as
-served: 16 key and 32 value heads of 128, lines of 32 positions, one chunk a
-line) per row count, with its share of the memory floor (q, k, v in and o
-out once in bfloat16, the gates in float32, at 819 GB/s), the triangular
-inverse alone, how far the core parts from the position-by-position scan at
-256 rows, and the 4-tap convolution with SiLU over the 8,192 q, k, v
-channels.
+Default: the delta rule's core (ops/deltarule.py: 16 key and 32 value heads
+of 128, lines of 32 positions, one chunk a line) per row count — the kernel
+``gated_delta`` as served from 256 rows beside the chunked form, each with
+its share of the memory floor (q, k, v in and o out once in bfloat16, the
+gates in float32, at 819 GB/s) — the chunked form's triangular inverse
+alone, how far either parts from the position-by-position scan at 256 rows,
+and the 4-tap convolution with SiLU over the 8,192 q, k, v channels.
 
 ``--calls`` times the whole ``moe_delta`` scoring call per bucket instead
 (random weights at the benchmark configuration's shape) and the fit's
@@ -42,9 +42,11 @@ def bench_core() -> None:
                                                      unit_lower_inverse)
     from detectmateservice_tpu.ops.shortconv import causal_conv_silu
 
-    core = jax.jit(lambda q, k, v, g, b: gated_delta_rule(q, k, v, g, b, SEQ))
-    scan = jax.jit(lambda q, k, v, g, b: gated_delta_rule(
-        q, k, v, g, b, SEQ, impl="scan"))
+    def form(impl):
+        return jax.jit(lambda q, k, v, g, b: gated_delta_rule(
+            q, k, v, g, b, SEQ, impl=impl))
+
+    core, fused, scan = form("chunked"), form("fused"), form("scan")
     for rows in ROWS:
         n = rows * SEQ
         keys = jax.random.split(jax.random.PRNGKey(rows), 6)
@@ -58,15 +60,21 @@ def bench_core() -> None:
         ms = timed(core, q, k, v, g, beta)
         a = jnp.tril(jax.random.normal(keys[5], (SEQ, SEQ, rows * HV),
                                        jnp.float32) * 0.1, -1)
-        line = {"core": "gated_delta_rule chunked", "rows": rows,
-                "floor_ms": floor_ms, "ms": ms,
-                "share_of_floor": floor_ms / ms,
+        fused_ms = timed(fused, q, k, v, g, beta)
+        line = {"core": "gated_delta_rule", "rows": rows,
+                "floor_ms": floor_ms, "chunked_ms": ms,
+                "chunked_share_of_floor": floor_ms / ms,
+                "fused_ms": fused_ms,
+                "fused_share_of_floor": floor_ms / fused_ms,
                 "inverse_ms": timed(jax.jit(unit_lower_inverse), a)}
         if rows == 256:
-            gap = (np.asarray(core(q, k, v, g, beta))
-                   - np.asarray(scan(q, k, v, g, beta)))
-            line.update(scan_ms=timed(scan, q, k, v, g, beta),
-                        max_abs_gap_to_scan=float(np.abs(gap).max()))
+            want = np.asarray(scan(q, k, v, g, beta))
+            line.update(
+                scan_ms=timed(scan, q, k, v, g, beta),
+                chunked_max_abs_gap_to_scan=float(np.abs(
+                    np.asarray(core(q, k, v, g, beta)) - want).max()),
+                fused_max_abs_gap_to_scan=float(np.abs(
+                    np.asarray(fused(q, k, v, g, beta)) - want).max()))
         print(json.dumps(line), flush=True)
     conv = jax.jit(causal_conv_silu, static_argnames=("seq",))
     for rows in ROWS[1::2]:

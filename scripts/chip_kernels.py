@@ -20,6 +20,12 @@ that quietly fell back to XLA or to the interpreter cannot pass.
 * ``ops/shortconv.gated_conv`` (the gated short convolution's elementwise
   core) at the served shape (1024 rows, S 32, D 2048, 3 taps, bf16) and at
   300 rows of S 16, against ``gated_conv_xla`` in float32;
+* ``ops/deltarule.gated_delta`` (the gated delta rule's closed form, one
+  chunk a line) at the served shape (256, 512 and 1024 rows, S 32, 16 key
+  and 32 value heads of 128, bf16 operands), an all-PAD line and a PAD tail
+  among them, against the chunked form (bf16 operands) and the
+  position-by-position scan (float32), reading q | k | v in place from one
+  array as the served layer hands them over and as three arrays;
 * ``ops/experts.segment_sum_add`` (the routed experts' way back to the
   tokens) at the served shape (32768 tokens, a chunk of 16384 rows, D
   2048) and at the fit's (1024 tokens, 4096 rows), with runs of unrouted
@@ -62,6 +68,18 @@ def _compiled(fn, *args):
     return exe, time.perf_counter() - t0
 
 
+def _kernel_ms(exe, *args) -> float:
+    """The middle of three timed calls of a compiled executable, in ms."""
+    import jax
+
+    times = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(exe(*args))
+        times.append((time.perf_counter() - t0) * 1000)
+    return round(sorted(times)[1], 3)
+
+
 def check_candidate_lse(n: int, d: int, v: int, ref_chunk: int) -> dict:
     import jax
     import jax.numpy as jnp
@@ -76,11 +94,7 @@ def check_candidate_lse(n: int, d: int, v: int, ref_chunk: int) -> dict:
     exe, compile_s = _compiled(
         lambda h, e: candidate_lse(h, e, interpret=False), hidden, emb)
     got = np.asarray(exe(hidden, emb))
-    times = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        jax.block_until_ready(exe(hidden, emb))
-        times.append((time.perf_counter() - t0) * 1000)
+    kernel_ms = _kernel_ms(exe, hidden, emb)
 
     @jax.jit
     def ref_chunked(h, e):
@@ -95,7 +109,7 @@ def check_candidate_lse(n: int, d: int, v: int, ref_chunk: int) -> dict:
     want = np.asarray(ref_chunked(hidden, emb))
     err = float(np.max(np.abs(got - want)))
     return {"compile_s": round(compile_s, 2), "max_abs_err": err,
-            "kernel_ms": round(sorted(times)[1], 3),
+            "kernel_ms": kernel_ms,
             "finite": bool(np.isfinite(got).all()), "ok": err < 2e-2}
 
 
@@ -175,6 +189,54 @@ def check_gated_conv(rows: int, s: int, d: int = 2048) -> dict:
     err = float(np.max(np.abs(got - want) / (1.0 + np.abs(want))))
     return {"compile_s": round(compile_s, 2), "max_rel_err": err,
             "finite": bool(np.isfinite(got).all()), "ok": err < 1e-2}
+
+
+def check_gated_delta(rows: int, s: int = 32) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from detectmateservice_tpu.ops.deltarule import (Heads, gated_delta,
+                                                     gated_delta_rule)
+
+    heads = Heads(16, 32, 128, 128)
+    n = rows * s
+    km, kg, kb = jax.random.split(jax.random.PRNGKey(rows + s), 3)
+    mixed = jax.random.normal(km, (n, 2 * 2048 + 4096), jnp.float32)
+    # a PAD tail in line 1, line 2 all PAD: zero q, k and v
+    mixed = mixed.at[s + s // 2:3 * s].set(0.0).astype(jnp.bfloat16)
+    g = -jax.random.uniform(kg, (n, 32), jnp.float32, 0.0, 3.0)
+    beta = jax.random.uniform(kb, (n, 32), jnp.float32)
+    exe, compile_s = _compiled(
+        lambda m, g, b: gated_delta((m,), g, b, heads, s), mixed, g, beta)
+    got = np.asarray(exe(mixed, g, beta))
+    apart, _ = _compiled(lambda m, g, b: gated_delta(
+        heads.split(m), g, b, heads, s), mixed, g, beta)
+    kernel_ms = _kernel_ms(exe, mixed, g, beta)
+
+    def plain(impl, dtype):
+        return np.asarray(jax.jit(lambda m, g, b: gated_delta_rule(
+            *heads.split(m), g, b, s, impl=impl, dtype=dtype))(mixed, g, beta))
+
+    chunked, scan = plain("chunked", jnp.bfloat16), plain("scan", jnp.float32)
+    out = {"compile_s": round(compile_s, 2),
+           "kernel_ms": kernel_ms,
+           "max_abs_gap_to_chunked": float(np.abs(got - chunked).max()),
+           "max_abs_gap_to_scan": float(np.abs(got - scan).max()),
+           "chunked_max_abs_gap_to_scan": float(np.abs(chunked - scan).max()),
+           "in_place_equals_apart": bool(
+               (got == np.asarray(apart(mixed, g, beta))).all()),
+           "pad_rows_zero": bool((got[s + s // 2:3 * s] == 0.0).all()),
+           "scale": float(np.abs(scan).max()),
+           "finite": bool(np.isfinite(got).all())}
+    # bfloat16 operands of k kᵀ, q kᵀ and the last product on either side:
+    # the kernel may part from the scan no further than the chunked form
+    # does, with room for the order of the sums
+    out["ok"] = (out["finite"] and out["pad_rows_zero"]
+                 and out["in_place_equals_apart"]
+                 and out["max_abs_gap_to_scan"]
+                 < 2.0 * out["chunked_max_abs_gap_to_scan"] + 1e-4)
+    return out
 
 
 def check_segment_sum(tokens: int, chunk: int, d: int = 2048) -> dict:
@@ -306,6 +368,12 @@ CHECKS = [
      lambda: check_gated_conv(1024, 32)),
     ("gated_conv rows=300 S=16 D=256 K=3",
      lambda: check_gated_conv(300, 16, 256)),
+    ("gated_delta moe_delta served rows=1024 S=32 Hk=16 Hv=32 D=128",
+     lambda: check_gated_delta(1024)),
+    ("gated_delta rows=512 S=32 Hk=16 Hv=32 D=128",
+     lambda: check_gated_delta(512)),
+    ("gated_delta rows=256 S=32 Hk=16 Hv=32 D=128",
+     lambda: check_gated_delta(256)),
     ("segment_sum_add served tokens=32768 chunk=16384 D=2048",
      lambda: check_segment_sum(32768, 16384)),
     ("segment_sum_add fit tokens=1024 chunk=4096 D=2048",

@@ -356,15 +356,17 @@ def moe_delta_scorer():
 def test_moe_deltas_widest_scoring_program_and_its_bytes(
         moe_delta_scorer, one_chip, no_compile_cache):
     """``qwen3-next-80b-a3b-ep16``'s 1024-row bucket as ``auto`` routes it
-    on one TPU: the delta rule's chunked form at one chunk a line (no scan
-    over positions or chunks is left in the program), the grouped einsum
-    for the gated attention layer with no ``[..., 32, 2]`` pair reshape for
-    its partial rotation, the fused head, the segment sum back from the
-    experts; scratch 3,672,502,272 bytes when this was written (4,276,482,048
-    while the convolution widened its rows before shifting them), beside
-    2.50 GB of float32 parameters — with the 8.10 GB the fitted detector
-    holds (parameters, both moments and the allocator's slack) 11.8 GB of
-    the chip's 16. About 50 s."""
+    on one TPU: the delta rule's core as the kernel ``gated_delta``, once a
+    delta layer, reading q | k | v in place from the convolution's output
+    (no scan over positions or chunks is left in the program, and no
+    ``[32, 32, lines × heads]`` intermediate), the grouped einsum for the
+    gated attention layer with no ``[..., 32, 2]`` pair reshape for its
+    partial rotation, the fused head, the segment sum back from the
+    experts; scratch 3,034,655,232 bytes when this was written
+    (3,672,502,272 with the core as XLA's batched matmuls), beside 2.50 GB
+    of float32 parameters — with the 8.10 GB the fitted detector holds
+    (parameters, both moments and the allocator's slack) 11.1 GB of the
+    chip's 16. About 45 s."""
     import re
 
     scorer = moe_delta_scorer
@@ -373,7 +375,7 @@ def test_moe_deltas_widest_scoring_program_and_its_bytes(
     compiled = jax.jit(scorer._score_impl).lower(
         params, shape((1024, 32), jnp.uint16, one_chip)).compile()
     assert scorer.attn_routes == {1024: "einsum"}
-    assert scorer.delta_routes == {1024: "chunked 32"}
+    assert scorer.delta_routes == {1024: "fused"}
     assert scorer.head_routes == {1024: "pallas"}
     assert "32 of 512 experts from 0" in scorer.expert_routes[1024]
     assert "combine segment_sum" in scorer.expert_routes[1024]
@@ -388,12 +390,41 @@ def test_moe_deltas_widest_scoring_program_and_its_bytes(
     loops = [line for line in text.splitlines()
              if " while(" in line and "/delta/" in line]
     assert not loops, loops[:2]
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "/core/delta_fused/" in line]
+    assert len(kernels) == 3, len(kernels)
+    # each reads the convolution's one output three times, no slice of it
+    operands = re.findall(r"custom-call\(([^)]*)\)", kernels[0])[0].split(", ")
+    assert len(set(operands[:3])) == 1, operands
+    matrices = sorted(set(re.findall(r"f32\[32,32,\d+\]", text)))
+    assert not matrices, matrices
     stats = compiled.memory_analysis()
-    assert stats.temp_size_in_bytes < 4_800_000_000
+    assert stats.temp_size_in_bytes < 3_400_000_000
     assert stats.argument_size_in_bytes == pytest.approx(
         4 * 625_669_184, rel=1e-3)
     # beside what the fitted detector holds: under the chip's 16 GB
     assert 12 * 625_669_184 + stats.temp_size_in_bytes < 13_000_000_000
+
+
+@pytest.mark.parametrize("rows", [256, 512])
+def test_the_delta_rules_kernel_compiles_at_the_other_served_buckets(
+        rows, one_chip, no_compile_cache):
+    """``gated_delta`` alone at 256 and 512 rows of the published heads
+    (the 1024-row bucket compiles it inside its scoring program above),
+    q | k | v in place from one bfloat16 array. About 5 s each."""
+    from detectmateservice_tpu.ops.deltarule import Heads, gated_delta
+
+    heads, n = Heads(16, 32, 128, 128), rows * 32
+    lowered = jax.jit(lambda m, g, b: gated_delta((m,), g, b, heads, 32)
+                      ).lower(shape((n, 8192), jnp.bfloat16, one_chip),
+                              shape((n, 32), jnp.float32, one_chip),
+                              shape((n, 32), jnp.float32, one_chip))
+    assert "tpu_custom_call" in lowered.as_text()
+    stats = lowered.compile().memory_analysis()
+    assert stats.output_size_in_bytes == n * 4096 * 4
+    # the result by head (a relayout only where nothing consumes it) and
+    # the gates by tile: nothing of q, k, v is copied
+    assert stats.temp_size_in_bytes < n * 4096 * 4 + 4 * n * 32 * 4
 
 
 def test_moe_deltas_donated_train_step_fits_the_chip(

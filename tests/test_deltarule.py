@@ -2,8 +2,12 @@
 chunked closed form against the position-by-position scan at three chunk
 lengths over a 32-long line — forward and gradient, so the state carried
 between chunks and the triangular inverse's own reverse pass are both
-exercised — a line's independence of its neighbours, the inverse against
-numpy's, the route's record and refusals; and what the family's other new
+exercised — and the kernel ``gated_delta`` in the Pallas interpreter beside
+it (heads of 128, as its tiles want them; forward, its ``custom_vjp``,
+PAD lines, the published head counts, q | k | v read in place); a line's
+independence of its neighbours and of the tile and block it falls in, the
+inverse against numpy's, the route's record and refusals; and what the
+family's other new
 operations add beside it: the 4-tap convolution with SiLU against a plain
 loop (ops/shortconv.py) and the partial rotation (ops/attention.py)."""
 import jax
@@ -13,78 +17,161 @@ import pytest
 
 from detectmateservice_tpu.ops.attention import (grouped_query_attention,
                                                  placement, rotary)
-from detectmateservice_tpu.ops.deltarule import (delta_gates, delta_route,
+from detectmateservice_tpu.ops.deltarule import (Heads, delta_gates,
+                                                 delta_route,
                                                  gated_delta_rule,
                                                  unit_lower_inverse)
 from detectmateservice_tpu.ops.shortconv import (causal_conv_silu,
                                                  gated_conv_xla)
 
 SEQ, HK, HV, D = 32, 2, 4, 16
+# a form of the operation as (impl, chunk, head width): the kernel wants
+# heads in whole lane groups and runs in the Pallas interpreter here
+CHUNKED_8, CHUNKED_32, SCAN = ("chunked", 8, D), ("chunked", 32, D), (
+    "scan", 32, D)
+FUSED = ("fused", 32, 128)
 
 
-def operands(lines=3, seed=0, seq=SEQ):
+def operands(lines=3, seed=0, seq=SEQ, d=D, hk=HK, hv=HV):
     """Seeded q, k, v, g, beta for ``lines`` lines; line 1's tail and all of
     the last line are what a PAD tail gives at its worst: zero keys, queries
     and values."""
     rng = np.random.default_rng(seed)
     n = lines * seq
-    q, k = (rng.normal(size=(n, HK, D)) for _ in range(2))
-    v = rng.normal(size=(n, HV, D))
-    g = -rng.uniform(0.0, 2.0, size=(n, HV))
-    beta = rng.uniform(0.0, 1.0, size=(n, HV))
+    q, k = (rng.normal(size=(n, hk, d)) for _ in range(2))
+    v = rng.normal(size=(n, hv, d))
+    g = -rng.uniform(0.0, 2.0, size=(n, hv))
+    beta = rng.uniform(0.0, 1.0, size=(n, hv))
     for x in (q, k, v):
         x[seq + seq // 2:2 * seq] = 0.0
         x[(lines - 1) * seq:] = 0.0
     return tuple(jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta))
 
 
-def run(args, chunk=32, impl="chunked", dtype=jnp.float32, seq=SEQ):
-    return gated_delta_rule(*args, seq, chunk=chunk, impl=impl, dtype=dtype)
+def run(args, chunk=32, impl="chunked", dtype=jnp.float32, seq=SEQ, **kw):
+    return gated_delta_rule(*args, seq, chunk=chunk, impl=impl, dtype=dtype,
+                            **kw)
 
 
-@pytest.mark.parametrize("chunk", [8, 16, 32])
-def test_the_chunked_form_is_the_scan_forward_and_backward(chunk):
-    args = operands()
+@pytest.mark.parametrize("impl,chunk,d", [
+    ("chunked", 8, D), ("chunked", 16, D), CHUNKED_32, FUSED])
+def test_the_closed_forms_are_the_scan_forward_and_backward(impl, chunk, d):
+    """The chunked form at three chunk lengths, and the kernel with its
+    ``custom_vjp`` (whose backward is the chunked form's), against the
+    position-by-position scan."""
+    args = operands(d=d)
     want = run(args, impl="scan")
-    got = run(args, chunk)
-    assert got.shape == (3 * SEQ, HV, D)
+    got = run(args, chunk, impl)
+    assert got.shape == (3 * SEQ, HV, d)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
-    assert float(jnp.abs(want).max()) > 0.1
+    assert float(jnp.abs(want).max()) > 0.04
 
     def loss(impl, c):
         return lambda *a: (run(a, c, impl) ** 2).sum()
 
     want_grads = jax.grad(loss("scan", 32), argnums=(0, 1, 2, 3, 4))(*args)
-    got_grads = jax.grad(loss("chunked", chunk), argnums=(0, 1, 2, 3, 4))(
-        *args)
+    got_grads = jax.grad(loss(impl, chunk), argnums=(0, 1, 2, 3, 4))(*args)
     for name, a, b in zip("qkvgb", got_grads, want_grads):
         scale = float(jnp.abs(b).max())
         assert scale > 0 and bool(jnp.isfinite(a).all()), name
         assert float(jnp.abs(a - b).max()) < 1e-5 * max(scale, 1.0), name
 
 
-def test_an_all_pad_line_and_a_pad_tail_stay_finite_and_zero():
-    args = operands()
-    for out in (run(args, 8), run(args, impl="scan")):
-        out = np.asarray(out).reshape(3, SEQ, HV, D)
-        assert np.isfinite(out).all()
-        assert np.abs(out[2]).max() == 0.0          # nothing written or read
-        assert np.abs(out[1, SEQ // 2:]).max() == 0.0   # q = 0 reads nothing
-        assert np.abs(out[1, :SEQ // 2]).max() > 0.01
+def test_the_kernels_backward_is_the_chunked_forms_gradient():
+    args = operands(d=128, seed=5)
+    w = jnp.asarray(np.random.default_rng(6).normal(size=(3 * SEQ, HV, 128)),
+                    jnp.float32)
+
+    def loss(impl):
+        return lambda *a: (run(a, 32, impl) * w).sum()
+
+    got = jax.grad(loss("fused"), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(loss("chunked"), argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip("qkvgb", got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6,
+                                   err_msg=name)
 
 
-@pytest.mark.parametrize("chunk", [8, 32])
-def test_a_lines_result_does_not_depend_on_its_neighbours(chunk):
-    args = operands(lines=4, seed=1)
-    whole = np.asarray(run(args, chunk)).reshape(4, SEQ, HV, D)
-    alone = np.asarray(run(tuple(x[SEQ:2 * SEQ] for x in args), chunk))
-    np.testing.assert_allclose(whole[1], alone.reshape(SEQ, HV, D), atol=1e-6)
-    other = operands(lines=4, seed=2)
+@pytest.mark.parametrize("lines", [4, 5])
+def test_the_kernel_at_the_published_head_counts(lines):
+    """16 key and 32 value heads of 128, lines of 32: one whole 128-token
+    tile, and five lines (three empty ones pad the second tile)."""
+    args = operands(lines=lines, seed=7, d=128, hk=16, hv=32)
+    got = np.asarray(run(args, impl="fused"))
+    assert got.shape == (lines * SEQ, 32, 128)
+    np.testing.assert_allclose(got, np.asarray(run(args, impl="scan")),
+                               atol=2e-6)
+    np.testing.assert_allclose(got, np.asarray(run(args)), atol=2e-6)
+
+
+def test_the_kernel_reads_q_k_v_in_place_from_the_array_they_were_cut_from():
+    q, k, v, g, beta = operands(lines=8, seed=8, d=128)
+    mixed = jnp.concatenate([x.reshape(8 * SEQ, -1) for x in (q, k, v)], -1)
+    apart = run((q, k, v, g, beta), impl="fused")
+    in_place = run((q, k, v, g, beta), impl="fused", mixed=mixed)
+    np.testing.assert_array_equal(np.asarray(in_place), np.asarray(apart))
+    # the other forms take their slices and never look at it
+    np.testing.assert_array_equal(
+        np.asarray(run((q, k, v, g, beta), mixed=mixed * 0.0)),
+        np.asarray(run((q, k, v, g, beta))))
+
+    def loss(m):
+        return (gated_delta_rule(*Heads(HK, HV, 128, 128).split(m), g, beta,
+                                 SEQ, impl="fused", dtype=jnp.float32,
+                                 mixed=m) ** 2).sum()
+
+    def plain_loss(m):
+        return (gated_delta_rule(*Heads(HK, HV, 128, 128).split(m), g, beta,
+                                 SEQ, impl="chunked", dtype=jnp.float32
+                                 ) ** 2).sum()
+
+    np.testing.assert_allclose(np.asarray(jax.grad(loss)(mixed)),
+                               np.asarray(jax.grad(plain_loss)(mixed)),
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("impl,chunk,d", [CHUNKED_8, SCAN, FUSED])
+def test_an_all_pad_line_and_a_pad_tail_stay_finite_and_zero(impl, chunk, d):
+    out = np.asarray(run(operands(d=d), chunk, impl)).reshape(3, SEQ, HV, d)
+    assert np.isfinite(out).all()
+    assert np.abs(out[2]).max() == 0.0          # nothing written or read
+    assert np.abs(out[1, SEQ // 2:]).max() == 0.0   # q = 0 reads nothing
+    assert np.abs(out[1, :SEQ // 2]).max() > 0.01
+
+
+@pytest.mark.parametrize("impl,chunk,d", [CHUNKED_8, CHUNKED_32, FUSED])
+def test_a_lines_result_does_not_depend_on_its_neighbours(impl, chunk, d):
+    args = operands(lines=4, seed=1, d=d)
+    whole = np.asarray(run(args, chunk, impl)).reshape(4, SEQ, HV, d)
+    alone = np.asarray(run(tuple(x[SEQ:2 * SEQ] for x in args), chunk, impl))
+    np.testing.assert_allclose(whole[1], alone.reshape(SEQ, HV, d), atol=1e-6)
+    other = operands(lines=4, seed=2, d=d)
     mixed = tuple(jnp.concatenate([o[:SEQ], a[SEQ:2 * SEQ], o[2 * SEQ:]])
                   for a, o in zip(args, other))
     np.testing.assert_allclose(
-        np.asarray(run(mixed, chunk)).reshape(4, SEQ, HV, D)[1], whole[1],
-        atol=1e-6)
+        np.asarray(run(mixed, chunk, impl)).reshape(4, SEQ, HV, d)[1],
+        whole[1], atol=1e-6)
+
+
+def test_a_lines_result_does_not_depend_on_the_block_it_falls_in():
+    """A grid step owns 128 (value head, tile) units, a lane each: 64 tiles
+    of a key head with two value heads. 262 lines are 66 tiles, the last
+    half empty: a whole block and one that the call's rows do not fill;
+    their first 256 are one block alone, the other six two tiles of a short
+    one."""
+    from detectmateservice_tpu.ops.deltarule import _block_tiles
+
+    assert [_block_tiles(t, 2) for t in (1, 64, 66, 256)] == [64] * 4
+    assert [_block_tiles(t, 2, True) for t in (1, 2, 64, 66)] == [1, 2, 64, 64]
+    assert _block_tiles(256, 1) == 128 and _block_tiles(256, 16) == 8
+    args = operands(lines=262, seed=9, d=128, hk=1, hv=2)
+    whole = np.asarray(run(args, impl="fused"))
+    head = np.asarray(run(tuple(x[:256 * SEQ] for x in args), impl="fused"))
+    tail = np.asarray(run(tuple(x[256 * SEQ:] for x in args), impl="fused"))
+    np.testing.assert_allclose(whole[:256 * SEQ], head, atol=1e-6)
+    np.testing.assert_allclose(whole[256 * SEQ:], tail, atol=1e-6)
+    np.testing.assert_allclose(whole, np.asarray(run(args, impl="scan")),
+                               atol=2e-6)
 
 
 def test_it_is_the_recurrence_written_out_in_numpy():
@@ -104,10 +191,17 @@ def test_it_is_the_recurrence_written_out_in_numpy():
                                        atol=2e-6)
 
 
-def test_bfloat16_operands_stay_near_the_float32_core():
-    args = operands(seed=3)
+@pytest.mark.parametrize("impl,chunk,d", [CHUNKED_32, FUSED])
+def test_bfloat16_operands_stay_near_the_float32_core(impl, chunk, d):
+    args = operands(seed=3, d=d)
     want = np.asarray(run(args, impl="scan"))
-    got = np.asarray(run(args, dtype=jnp.bfloat16))
+    got = np.asarray(run(args, chunk, impl, dtype=jnp.bfloat16))
+    assert 1e-5 < np.abs(got - want).max() < 0.02
+    # as served: the operands arrive in bfloat16 and are read as they are
+    served = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
+    got = np.asarray(run(served, chunk, impl, dtype=jnp.bfloat16))
+    want = np.asarray(run(served, impl="scan"))
+    assert got.dtype == np.float32
     assert 1e-5 < np.abs(got - want).max() < 0.02
 
 
@@ -144,11 +238,43 @@ def test_the_inverses_reverse_pass_is_autodiffs_of_a_solve():
     assert float(jnp.abs(jnp.triu(jnp.moveaxis(got, -1, 0))).max()) == 0.0
 
 
+@pytest.mark.parametrize("platform,rows,seq,chunk,dk,dv,mesh,want", [
+    ("tpu", 256, 32, 32, 128, 128, 1, "fused"),
+    ("tpu", 1024, 32, 32, 128, 128, 1, "fused"),
+    ("tpu", 1024, 16, 32, 256, 128, 1, "fused"),     # the chunk cut to the line
+    ("cpu", 1024, 32, 32, 128, 128, 1, "chunked 32"),
+    ("tpu", 1024, 32, 32, 128, 128, 4, "chunked 32"),    # a mesh
+    ("tpu", 32, 32, 32, 128, 128, 1, "chunked 32"),      # the fit's step
+    ("tpu", 1024, 64, 32, 128, 128, 1, "chunked 32"),    # an entering state
+    ("tpu", 1024, 32, 32, 64, 64, 1, "chunked 32"),      # half a lane group
+    ("tpu", 1024, 32, 32, 128, 192, 1, "chunked 32"),
+    ("tpu", 1024, 24, 32, 128, 128, 1, "chunked 24"),    # 24 does not fill 128
+    ("tpu", 1024, 4, 32, 128, 128, 1, "chunked 4"),      # half a sublane tile
+])
+def test_auto_takes_the_kernel_where_it_can_see_that_it_fits(
+        platform, rows, seq, chunk, dk, dv, mesh, want):
+    assert delta_route("auto", seq, chunk, platform, rows, dk, dv,
+                       mesh) == want
+    # a name forces, whatever the call looks like
+    assert delta_route("fused", seq, chunk, platform, rows, dk, dv,
+                       mesh) == "fused"
+    assert delta_route("chunked", seq, chunk, platform, rows, dk, dv,
+                       mesh) == f"chunked {min(seq, chunk)}"
+    assert delta_route("scan", seq, chunk, platform, rows, dk, dv,
+                       mesh) == "scan"
+    # value heads a key head: a power of two that leaves each 8 tiles
+    for rep, fused in ((2, True), (16, True), (3, False), (32, False)):
+        assert (delta_route("auto", seq, chunk, platform, rows, dk, dv,
+                            mesh, rep) == "fused") == (fused
+                                                       and want == "fused")
+
+
 def test_the_route_is_recorded_and_refused_by_name():
     assert delta_route("auto", 32, 32) == "chunked 32"
     assert delta_route("auto", 16, 32) == "chunked 16"     # cut to the line
     assert delta_route("chunked", 32, 8) == "chunked 8"
     assert delta_route("scan", 32, 8) == "scan"
+    assert delta_route("fused", 32, 32) == "fused"
     with pytest.raises(ValueError, match="pallas"):
         delta_route("pallas", 32, 32)
     with pytest.raises(ValueError, match="do not divide"):
@@ -157,7 +283,14 @@ def test_the_route_is_recorded_and_refused_by_name():
     with placement(1, None, None, routes):
         run(operands(), 16)
         run(operands(lines=2), impl="scan")
-    assert routes == {3: "chunked 16", 2: "scan"}
+        run(operands(lines=4, d=128), impl="fused")
+        run(operands(lines=5, d=128), impl="auto", platform="cpu")
+    assert routes == {3: "chunked 16", 2: "scan", 4: "fused",
+                      5: "chunked 32"}
+    # forced where it cannot tile: refused, with what would
+    for args, kw in ((operands(), {}), (operands(d=128), {"chunk": 16})):
+        with pytest.raises(ValueError, match="do not tile"):
+            run(args, impl="fused", **kw)
 
 
 def test_the_gates_are_the_published_ones():

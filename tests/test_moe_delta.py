@@ -147,6 +147,62 @@ def test_the_chunk_length_moves_no_score(chunk, monkeypatch):
     assert scanned.delta_routes == {8: "scan"}
 
 
+# delta-rule heads in whole lane groups, as the kernel tiles them
+WIDE = dict(linear_key_head_dim=128, linear_value_head_dim=128,
+            linear_num_key_heads=1, linear_num_value_heads=2)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 0.06)])
+def test_the_kernel_moves_no_score(dtype, tol):
+    """``delta_impl: fused`` (the Pallas interpreter here) through the whole
+    scorer, reading q | k | v in place from the convolution's output: the
+    reference's scores, and the chunked form's to the same tolerance."""
+    arch = arch_with(**SHARE, **WIDE)
+    scorer, params, _ = make_scorer(arch, dtype, init=0.2)
+    tokens = make_tokens()
+    want = reference.score(as_numpy(params), tokens, {"arch": arch})
+    fused = MoEDeltaScorer(dataclasses.replace(scorer.config,
+                                               delta_impl="fused"))
+    scores, _ = fused._score(params, tokens)
+    assert fused.delta_routes == {8: "fused"}
+    assert float(np.abs(np.asarray(scores) - want).max()) < tol
+    assert np.isfinite(np.asarray(scores)).all()
+    plain, _ = scorer._score(params, tokens)
+    assert scorer.delta_routes == {8: "chunked 32"}
+    assert float(np.abs(np.asarray(scores) - np.asarray(plain)).max()) < tol
+
+
+def test_the_scorer_records_the_delta_route_by_bucket():
+    """What ``auto`` resolves to where the scorer is placed on one TPU,
+    read from a trace alone (nothing is lowered or run): the kernel from
+    256 rows, the chunked form for the fit's 32-row step, on a mesh and
+    where the heads do not fill a lane group."""
+    def traced(scorer, params, opt_state, rows=(32, 256, 512, 1024)):
+        for n in rows:
+            jax.eval_shape(scorer._score_impl, params,
+                           jnp.zeros((n, SEQ), jnp.uint16))
+        return scorer.delta_routes
+
+    scorer, params, opt_state = make_scorer(arch_with(**SHARE, **WIDE),
+                                            platform="tpu")
+    assert traced(scorer, params, opt_state) == {
+        32: "chunked 32", 256: "fused", 512: "fused", 1024: "fused"}
+    scorer.delta_routes.clear()
+    jax.eval_shape(scorer._train_impl, params, opt_state,
+                   jax.random.PRNGKey(0), jnp.zeros((32, SEQ), jnp.int32))
+    assert scorer.delta_routes == {32: "chunked 32"}
+    scorer.delta_routes.clear()
+    scorer.mesh_devices = 4
+    assert traced(scorer, params, opt_state, (256,)) == {256: "chunked 32"}
+    narrow, params, opt_state = make_scorer(arch_with(**SHARE),
+                                            platform="tpu")
+    assert traced(narrow, params, opt_state, (256,)) == {256: "chunked 32"}
+    host, params, opt_state = make_scorer(arch_with(**SHARE, **WIDE),
+                                          platform="cpu")
+    assert traced(host, params, opt_state, (256,)) == {256: "chunked 32"}
+
+
 def test_reference_lower_control_changes_the_scores():
     _, params, _ = make_scorer()
     tokens = make_tokens()
