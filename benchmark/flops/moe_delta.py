@@ -140,11 +140,13 @@ def head_ops_and_bytes(scorer: dict, rows: int) -> tuple:
 
 def delta_ops_and_bytes(scorer: dict, rows: int) -> tuple:
     """Least work of ONE delta layer's core (the recurrence between the
-    convolution and the output norm) for one call: ``_delta_core_macs`` a
-    position; bytes, which bound it — q, k and v in and o out once in
-    bfloat16, the gates in float32. No kernel computes the core yet
-    (PERF.md section 7): this is what its roofline share would be measured
-    against."""
+    convolution and the output norm) for one call, which is one call of the
+    kernel ``gated_delta`` (``gated_delta_roofline``): ``_delta_core_macs`` a
+    position; bytes, which bound it — what the kernel has to move: a line's
+    q, k and v in and o out once in bfloat16, the two gates a value head in
+    float32. The ``[128, 128]`` intermediates of a tile (decay-masked
+    ``k kᵀ``, its inverse, ``q kᵀ``) never leave the chip's fast memory and
+    are not counted, so the share can only read low."""
     a = _shape(scorer)
     mixed, values = _delta_widths(a)
     tokens = rows * scorer["seq_len"]

@@ -13,12 +13,16 @@ Two steps, so that the second can be checked on a small recorded trace:
   through it and each event gains two entries, ``[…, scope, category]``. The
   times stay ``ProfileData``'s, so what was read before reads the same;
 * ``reduce`` turns those lists into the device's busy time, the captured
-  window, the operations that took most time, the longest idle gaps, the
-  executions of each XLA module (``modules``), the device time of every
-  custom call by kernel name and module (``kernels``: seconds and count,
-  within whole executions) and — where the events carry scopes — device self
-  time by scope, over everything (``scopes``) and within the whole
-  executions of each module (``module_scopes``). Whole means at least
+  window, the operations that took most time (control-flow wrappers —
+  ``while``, ``conditional`` — are left out: they hold their bodies'
+  operations, which are listed themselves), the longest idle gaps (of
+  ``MIN_GAP_S`` and more: what lies between two operations of one call is
+  no gap anyone waits out), the executions of each XLA module
+  (``modules``), the device time of every custom call by kernel name and
+  module (``kernels``: seconds and count, within whole executions) and —
+  where the events carry scopes — device self time by scope, over
+  everything (``scopes``) and within the whole executions of each module
+  (``module_scopes``). Whole means at least
   ``WHOLE`` of the module's median length: a call cut anywhere holds some
   scopes and not others (``modules``' own ``whole_*`` keep their older rule,
   half the median, so that what reads them reads as before).
@@ -55,11 +59,13 @@ NO_SCOPE = "no scope"
 TOP = 10
 NAME_CHARS = 96     # an op's name is its whole HLO line: keep its head
 WHOLE = 0.9         # of the median: an execution the capture did not cut
+MIN_GAP_S = 1e-3    # a shorter idle stretch is not listed among the gaps
 XPLANE_PB2 = ("tensorflow.tsl.profiler.protobuf.xplane_pb2",
               "tsl.profiler.protobuf.xplane_pb2",
               "xprof.protobuf.xplane_pb2")
 _WRAPPER = re.compile(r"^(jit|pjit|pmap|xmap|shard_map)\(.*\)$")
 _KERNEL = re.compile(r"^%?([^\s=]+?)(?:\.\d+)? = .*?\bcustom-call\(")
+_CONTROL_FLOW = re.compile(r"^%?[^\s=]+ = .*?\b(?:while|conditional)\(")
 
 
 def xplane_pb2():
@@ -205,6 +211,15 @@ def kernel_of(event: list) -> Optional[str]:
     return found[1] if found else None
 
 
+def wraps_others(event: list) -> bool:
+    """Whether the event is a control-flow wrapper (``%while.3 = … while(…``,
+    ``%conditional.1 = … conditional(…``): its time is its body's, whose
+    operations have events of their own."""
+    if len(event) > 4 and event[4] in ("while", "conditional"):
+        return True
+    return bool(_CONTROL_FLOW.match(event[0]))
+
+
 def annotations(trace: dict) -> Dict[str, List[tuple]]:
     """``{name: [(start, end)]}`` of the program's host annotations; a name
     ends where its arguments begin (``dm.upload#batch=7,…#``)."""
@@ -272,7 +287,8 @@ def reduce(trace: dict) -> dict:
             scope = event[3] if len(event) > 3 else None
             name = (event[0] if scope is None
                     else f"{scope}: {event[0]}")[:NAME_CHARS]
-            op_seconds[name] = op_seconds.get(name, 0.0) + event[2] / 1e9
+            if not wraps_others(event):
+                op_seconds[name] = op_seconds.get(name, 0.0) + event[2] / 1e9
             if scope is not None:
                 entry = scopes.setdefault(scope, {"self_s": 0.0, "events": 0})
                 entry["self_s"] += own / 1e9
@@ -291,7 +307,9 @@ def reduce(trace: dict) -> dict:
                 per["seconds"] += event[2] / 1e9
                 per["count"] += 1
     by_name = annotations(trace)
-    longest = sorted(gaps, key=lambda gap: gap[0] - gap[1])[:TOP]
+    longest = sorted((gap for gap in gaps
+                      if gap[1] - gap[0] >= MIN_GAP_S * 1e9),
+                     key=lambda gap: gap[0] - gap[1])[:TOP]
     covers = [cover(gap, by_name) for gap in longest]
     top_ops = sorted(op_seconds.items(), key=lambda kv: -kv[1])[:TOP]
     out = {

@@ -16,7 +16,8 @@ outside both — the float32 reference in a CPU child and the verdict.
 
 The last line of stdout is the result object and nothing else; the report a
 person reads goes on the lines before it. Without a TPU the detector cannot
-boot, and the command exits non-zero and prints no result.
+boot, and the command exits non-zero and prints no result; so does a traced
+run whose capture ended in ``error`` or holds no operation of the device.
 """
 from __future__ import annotations
 
@@ -319,6 +320,17 @@ def reduce_trace(work: str) -> dict:
     return manifest.read_json(out)
 
 
+def refuse_failed_capture(status: dict) -> None:
+    """``GET /admin/profile`` after the capture has ended: a capture whose
+    record says ``error`` (``stop_trace`` left no file, or raised) ends the
+    run with that error and no line. A program that keeps no ``state``
+    (before PR 38) is taken at its word."""
+    last = status.get("last") or {}
+    if last.get("state") == "error":
+        raise HarnessFailure("the profiler capture failed: "
+                             f"{last.get('error')}")
+
+
 def hbm_in_use(series) -> float:
     """Bytes in use on the fullest chip, as the detector's jax reports."""
     per_device = {}
@@ -344,10 +356,13 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
 
 
 def measure(root: str, workload: str, seed: int, seconds: float,
-            trace: bool, platform: str, t_start: float) -> dict:
+            trace: bool, platform: str, t_start: float,
+            checkpoint: bool = True) -> dict:
     """Boot, set-up, the window, the drain and the checkpoint; the stages
     have stopped when it returns. What it returns is ``conclude``'s to read;
-    the run's work directory (``work``) is the caller's to remove."""
+    the run's work directory (``work``) is the caller's to remove. Without
+    ``checkpoint`` (``sweep.py --flood``, which reads the drain and nothing
+    else) no parameter is written and ``conclude`` cannot follow."""
     import zmq
 
     from detectmateservice_tpu.schemas import LogSchema
@@ -480,7 +495,10 @@ def measure(root: str, workload: str, seed: int, seconds: float,
             if capture is not None and capture_buckets is None:
                 capture_buckets = memory.dispatched_buckets(
                     capture["before"], final["detector"])
-        http_json(det.port, "/admin/checkpoint", post=True, timeout=300.0)
+            refuse_failed_capture(http_json(det.port, "/admin/profile"))
+        if checkpoint:
+            http_json(det.port, "/admin/checkpoint", post=True,
+                      timeout=300.0)
     except BaseException:
         if gen is not None:
             gen.halt.set()
@@ -530,6 +548,14 @@ def conclude(measured: dict) -> dict:
     device, scorer = measured["device"], measured["scorer"]
     trace, platform = measured["trace"], measured["platform"]
     config, traffic = cell["config"], cell["traffic"]
+    # a traced run on the chip whose capture holds no operation of the
+    # device has nothing to report: no line, rather than one without
+    # ``busy_s`` and ``window_s`` (the CPU has no device plane to hold one)
+    trace_doc = reduce_trace(work) if trace else None
+    if trace and platform == "tpu" and not trace_doc.get("devices"):
+        raise HarnessFailure(
+            "the capture's device plane holds no event (planes and lines: "
+            f"{trace_doc.get('inventory')})")
     frame_lines, n_pool_frames = t["frame_lines"], len(pool.frames)
     pool_ids = {pool.pool_id(i) for i in range(len(pool.lines))}
     alerts, other_records = parse_alerts(records, pool_ids)
@@ -616,7 +642,6 @@ def conclude(measured: dict) -> dict:
         p50, p95 = (quantiles.quantile(latencies, q) for q in (0.5, 0.95))
         values["alert_p50_ms"] = p50
 
-    trace_doc = reduce_trace(work) if trace else None
     held = memory.peak(obs["hbm_drained"], obs["programs"],
                        obs["allocator_at_exit"], str(device["platform"]),
                        obs["window_buckets"])
@@ -678,6 +703,18 @@ def conclude(measured: dict) -> dict:
         f"records; expected {judged['expected_alerts']}; "
         f"{judged['lines_in_band']} scored lines inside the band; fitted "
         f"threshold {ref['threshold']:.4f}, tolerance {tol:g} nats")
+    # a line the pool's cycle sent twice was scored in two batches: what
+    # its two alerts say should not differ (four decimals in the text)
+    again = [max(s) - min(s) for s in alerts_by_id.values() if len(s) > 1]
+    say(f"served scores: {len(again)} lines alerted more than once, the "
+        f"widest difference between one line's alerts "
+        f"{max(again, default=0.0):.4f} nats")
+    scored = [i for i in alerts_by_id if i in ref["scores"]]
+    if scored:
+        worst = max(scored, key=lambda i: max(
+            abs(score - ref["scores"][i]) for score in alerts_by_id[i]))
+        say(f"widest gap to the reference at line {worst}: served "
+            f"{alerts_by_id[worst]}, reference {ref['scores'][worst]:.4f}")
     if latencies:
         say(f"send-to-alert, frames due in the window: n={len(latencies)} "
             f"({quantiles.samples_beyond(len(latencies), 0.95)} beyond p95) "

@@ -3,7 +3,7 @@ knee), and the control of its ``correct``, through the harness's own
 ``measure`` and ``conclude``.
 
     python3 benchmark/sweep.py --workload <cell> [--rates 1e7,60000]
-        [--seconds 10] [--seeds 1,2] [--control float8_e4m3fn]
+        [--seconds 10] [--seeds 1,2] [--control float8_e4m3fn] [--flood]
 
 Each run uses a temporary copy of the data files in which only the cell's
 rate differs; nothing in the repo is edited. A cell whose files exist but
@@ -14,6 +14,13 @@ ingress accepts: completions per second (the ``value: lines_per_s`` line)
 is then the knee. ``--control`` also puts the reference in the program's
 place in that lower precision (``lib/control.py``) and prints its numbers.
 One ``RESULT`` line per run goes to stdout.
+
+A cell of ``max_batch 1024`` cannot drain ``1e7`` within ``run.py``'s 240 s,
+so its knee is read from the time to drain a flood at about twice what the
+chip completes: ``--flood`` sends at ``--rates`` for the ramp and the window,
+waits for the drain and prints one ``FLOOD`` line — the lines sent over
+(sending + drain − the quiet second ``run.py`` waits out) — with no
+checkpoint, reference or verdict.
 """
 from __future__ import annotations
 
@@ -94,6 +101,22 @@ def one_run(root: str, workload: str, seed: int, seconds: float,
         shutil.rmtree(measured["work"], ignore_errors=True)
 
 
+def one_flood(root: str, workload: str, seed: int, seconds: float,
+              platform: str = "tpu") -> dict:
+    """The completed lines/s of one flood, from the time to drain."""
+    measured = run.measure(root, workload, seed, seconds, False, platform,
+                           time.monotonic(), checkpoint=False)
+    shutil.rmtree(measured["work"], ignore_errors=True)
+    t, gen = measured["t"], measured["gen"]
+    send_s = t["w1"] - t["t0"]
+    took_s = send_s + t["drain_s"] - run.QUIET_S
+    return {"stream_lines": t["stream_lines"], "send_s": send_s,
+            "drain_s": t["drain_s"], "completed_in_s": took_s,
+            "lines_per_s": t["stream_lines"] / took_s,
+            "setup_s": t["setup_s"], "boot_s": t["boot_s"],
+            "warm_s": t["warm_s"], "generator_blocked_s": gen.blocked_s}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -104,12 +127,21 @@ def main() -> int:
     ap.add_argument("--control", default="",
                     help="also put the reference in the program's place in "
                          "this lower precision, e.g. float8_e4m3fn")
+    ap.add_argument("--flood", action="store_true",
+                    help="read each rate's completions from the time to "
+                         "drain; no checkpoint, reference or verdict")
     args = ap.parse_args()
     failures = 0
     for rate in (float(r) for r in args.rates.split(",")):
         for seed in (int(s) for s in args.seeds.split(",")):
             root = variant_root(args.workload, rate)
             try:
+                if args.flood:
+                    print("FLOOD " + json.dumps(dict(
+                        one_flood(root, args.workload, seed, args.seconds),
+                        workload=args.workload, rate=rate, seed=seed)),
+                        flush=True)
+                    continue
                 result = one_run(root, args.workload, seed, args.seconds,
                                  args.control)
             except HarnessFailure as exc:

@@ -8,7 +8,7 @@ import re
 
 import pytest
 
-from bench_helpers import REPO, read_json, room_root
+from bench_helpers import REPO, ROOM_TRAFFIC, read_json, room_root
 from benchmark.lib import manifest as manifest_lib
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -17,11 +17,15 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 KINDS = {"prom-delta", "prom-gauge", "generator", "trace"}
 
 
-@pytest.fixture(scope="module", params=["repo", "room"])
+@pytest.fixture(scope="module",
+                params=["repo"] + ["room-" + mix for mix in ROOM_TRAFFIC])
 def root(request, tmp_path_factory):
+    """The repo's own manifest, and the rehearsal's copy with a further
+    cell of either traffic mix."""
     if request.param == "repo":
         return REPO
-    return room_root(tmp_path_factory.mktemp("room"))[0]
+    return room_root(tmp_path_factory.mktemp(request.param),
+                     traffic=request.param.split("-", 1)[1])[0]
 
 
 @pytest.fixture(scope="module")
@@ -66,7 +70,7 @@ def test_names_units_and_lines(manifest):
     for entry in manifest["workloads"]:
         assert set(entry) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(entry["config"]) and NAME.match(entry["traffic"])
-        assert entry["chips"] == 1
+        assert entry["chips"] in (1, 4)
         assert 1 <= len(entry["why"]) <= 200 and "\n" not in entry["why"]
     for entry in manifest["configs"]:
         assert set(entry) == {"name", "source", "file", "reduced", "why"}

@@ -1,23 +1,14 @@
 """The third configuration, ``lfm2-24b-a2b-ep8`` (a gated-short-convolution,
 grouped-query, sparse-expert scorer cut to one of eight chips' share), and
-its cell ``lfm2-24b-a2b-ep8.steady64``: the manifest with any number of
-configurations, the configuration's file against the source's published
-``config.json``, ``flops/moe_conv.py`` against a hand count, the reference's
-control, and the cell's path end to end on the CPU at a tiny size
-(``backend: cpu`` set by the test).
-
-Three pins written for fewer configurations hold no longer by construction
-and are the benchmark's to edit, not a ``model_config`` PR's:
-``test_bench_moe_mla.py::test_logbert_256x4_still_runs_as_published`` lists
-the workloads as exactly two, its
-``test_the_generic_metrics_list_both_cells_and_the_own_ones_one`` wants every
-generic list to be exactly those two and the expert metrics to list one
-cell, and ``test_bench_room.py::test_no_file_that_was_there_is_edited``
-expects the rehearsed ``.steady`` cell on *every* per-layer list, which a
-metric that lists a ``.steady64`` cell alone cannot give. What the three
-guard is restated here for any number of configurations and traffic mixes
-(PERF.md section 7 names them, with ``test_bench_room.py``'s older two, for
-a ``benchmark`` PR)."""
+its cell ``lfm2-24b-a2b-ep8.steady64``: its manifest entries, the
+configuration's file against the source's published ``config.json``,
+``flops/moe_conv.py`` against a hand count, the reference's control, and the
+cell's path end to end on the CPU at a tiny size (``backend: cpu`` set by
+the test). What holds of the manifest for any number of configurations and
+traffic mixes is in ``test_bench_room.py``, read from the cells' own files
+(``family_metrics``, PR 40; until then this file restated
+``test_bench_moe_mla.py``'s pins and was restated by
+``test_bench_moe_delta.py``'s)."""
 import bench_helpers  # noqa: F401  (puts the repo root on sys.path)
 import importlib
 import json
@@ -27,13 +18,13 @@ import time
 import numpy as np
 import pytest
 
-from bench_helpers import REPO, read_json, room_root, temp_root, write_json
+from bench_helpers import (REPO, entry_of, metrics_due, read_json, temp_root,
+                           write_json)
 from benchmark.flops import moe_conv as flops
 from benchmark.lib import manifest
 
 CONFIG, CELL = "lfm2-24b-a2b-ep8", "lfm2-24b-a2b-ep8.steady64"
-# per-layer metrics that read one family's scopes, kernels or counters
-EXPERT_METRICS = {"moe_share_of_call", "expert_held_share", "expert_skew"}
+# the per-layer metrics this family alone reports
 OWN_METRICS = {"conv_share_of_call", "gated_conv_roofline"}
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 # the source's config.json as the model-configs catalog gives it
@@ -79,89 +70,11 @@ def scorer_of(config):
     return block
 
 
-# -- the manifest, for any number of configurations ---------------------------
-
-def test_logbert_256x4_runs_as_published_among_any_number(listed):
-    entry = listed["configs"][0]
-    assert entry["name"] == "logbert-256x4"
-    file = read_json(os.path.join(REPO, entry["file"]))
-    assert entry["reduced"] == file["reduced"] == []
-    assert manifest.reduced_breaches(entry, file) == []
-    cells = [w["name"] for w in listed["workloads"]]
-    assert cells[:2] == ["logbert-256x4.steady",
-                         "kanana2-30b-a3b-ep8.steady"]
-    assert cells[2] == CELL and len(set(cells)) == len(cells)
-
-
-def test_every_configuration_keeps_the_rule_on_reduced_and_has_a_cell(listed):
-    used = {w["config"] for w in listed["workloads"]}
-    for entry in listed["configs"]:
-        file = read_json(os.path.join(REPO, entry["file"]))
-        assert manifest.reduced_breaches(entry, file) == [], entry["name"]
-        assert entry["name"] in used
-    assert len({c["file"] for c in listed["configs"]}) == len(
-        listed["configs"])
-
-
-def test_the_generic_metrics_list_every_cell_and_a_familys_own_its_cells(
-        listed):
-    cells = [w["name"] for w in listed["workloads"]]
-    experts = [c for c in cells if not c.startswith("logbert")]
-    by_name = {m["name"]: m["workloads"] for m in listed["per_layer"]}
-    assert EXPERT_METRICS | OWN_METRICS <= set(by_name)
-    for name, where in by_name.items():
-        want = ([CELL] if name in OWN_METRICS
-                else experts if name in EXPERT_METRICS else cells)
-        assert where == want, name
-    generic = set(by_name) - EXPERT_METRICS - OWN_METRICS
-    assert len(generic) == 21
-    for cell in cells:
-        ours = {s["name"] for s in manifest.load_cell(REPO, cell)["per_layer"]}
-        assert generic <= ours
-        assert (EXPERT_METRICS <= ours) == (cell in experts)
-        assert (OWN_METRICS <= ours) == (cell == CELL)
-    assert all(m["layer"] == "kernels" and m["moves"] == "alert_p50_ms"
-               for m in listed["per_layer"]
-               if m["name"] in EXPERT_METRICS | OWN_METRICS)
-    # every cell reports a metric of every layer the manifest names
-    layers = {m["layer"] for m in listed["per_layer"]}
-    for cell in cells:
-        assert {s["layer"] for s in
-                manifest.load_cell(REPO, cell)["per_layer"]} == layers
-
-
-def test_a_further_configuration_still_follows_by_additions(tmp_path, listed):
-    """The room's rehearsal on top of three configurations: nothing that
-    was there is edited, entries are added, and the rehearsed ``.steady``
-    cell is appended to every list that holds a ``.steady`` cell — and to
-    no list of a metric that reads another traffic mix's cell alone."""
-    root, cell = room_root(tmp_path)
-    after = read_json(os.path.join(root, "BENCHMARK.json"))
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert after[key] == listed[key]
-    assert after["configs"][:-1] == listed["configs"]
-    assert after["workloads"][:-1] == listed["workloads"]
-    assert len(after["per_layer"]) == len(listed["per_layer"]) + 1
-    for old, new in zip(listed["per_layer"], after["per_layer"]):
-        follows = any(w.endswith(".steady") for w in old["workloads"])
-        assert new == dict(old, workloads=old["workloads"]
-                           + ([cell] if follows else [])), old["name"]
-        assert follows == (old["name"] not in OWN_METRICS)
-    ours = {s["name"] for s in manifest.load_cell(root, cell)["per_layer"]}
-    assert ours == {s["name"] for s in manifest.load_cell(
-        REPO, "kanana2-30b-a3b-ep8.steady")["per_layer"]} | {
-            "ffn_share_of_call"}
-    for sub in ("configs", "traffic", "cells", "layer_metrics"):
-        for name in os.listdir(os.path.join(REPO, "benchmark", sub)):
-            if name.endswith(".json"):
-                assert (read_json(os.path.join(root, "benchmark", sub, name))
-                        == read_json(os.path.join(REPO, "benchmark", sub,
-                                                  name))), name
-
+# -- the manifest's entries for this configuration and its cell -----------------
 
 def test_the_manifest_entries_keep_the_contracts_lengths(listed):
-    (entry,) = [c for c in listed["configs"] if c["name"] == CONFIG]
-    (cell,) = [w for w in listed["workloads"] if w["name"] == CELL]
+    entry = entry_of(listed, "configs", CONFIG)
+    cell = entry_of(listed, "workloads", CELL)
     for text in (entry["source"], entry["why"], cell["why"]):
         assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
     assert cell == {"name": CELL, "config": CONFIG, "traffic": "steady64",
@@ -379,9 +292,9 @@ def test_a_traced_run_of_the_tiny_cell_is_correct_and_reads_the_counters(
     from benchmark import run
 
     root, cell = temp_root(tmp_path, config_name=CONFIG, model="moe_conv",
-                           traffic="steady64", rate=1500, reduced={
-                               key: {"published": 1, "here": 1, "why": "tiny"}
-                               for key in CUT})
+                           traffic="steady64", rate=1500, like=CELL,
+                           reduced={key: {"published": 1, "here": 1,
+                                          "why": "tiny"} for key in CUT})
     assert cell == "tiny-moe_conv.steady64"
     path = os.path.join(root, "benchmark", "configs", "tiny-moe_conv.json")
     tiny = read_json(path)
@@ -390,18 +303,22 @@ def test_a_traced_run_of_the_tiny_cell_is_correct_and_reads_the_counters(
     write_json(path, tiny)
     loaded = manifest.load_cell(root, cell)
     assert loaded["traffic"]["frame_lines"] == 64
-    assert OWN_METRICS | EXPERT_METRICS <= {
-        s["name"] for s in loaded["per_layer"]}
+    # the tiny cell is asked for what its family's cell reports
+    assert OWN_METRICS < {s["name"] for s in loaded[
+        "per_layer"]} == metrics_due(REPO, read_json(os.path.join(
+            REPO, "BENCHMARK.json")), CELL)
     result = run.run_cell(root, cell, 2147483647 + 11, 3.0, True,
                           platform="cpu", t_start=time.monotonic())
     printed = capsys.readouterr().out
     assert result["correct"] is True and result["failed"] == 0, printed
     assert "num_hidden_layers 1 -> 1" in printed
     metrics = result["metrics"]
-    assert {"expert_held_share", "expert_skew", "batch_occupancy",
+    assert {"expert_held_share", "expert_busiest_share", "batch_occupancy",
             "dispatch_ready_ms.lat", "row_hold_mean_ms"} <= set(metrics)
     # 2 of 8 experts held: a quarter of the assignments under even routing
     assert 5.0 < metrics["expert_held_share"]["value"] < 60.0
+    # the busier of the two held experts: half when balanced, all at most
+    assert 50.0 <= metrics["expert_busiest_share"]["value"] <= 100.0
     assert result["compared"]["compiles_after_warmup"]["value"] == 0
     assert result["compared"]["dropped_lines"]["value"] == 0
     # the kernel's roofline reads nothing where no kernel ran (the CPU's

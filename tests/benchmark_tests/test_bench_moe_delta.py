@@ -1,23 +1,15 @@
 """The fourth configuration, ``qwen3-next-80b-a3b-ep16`` (a gated-delta-rule,
 gated-attention, sparse-expert scorer cut to one of sixteen chips' share),
-and its cell ``qwen3-next-80b-a3b-ep16.steady64``: the manifest with any
-number of configurations and of metrics that list their own cells alone, the
+and its cell ``qwen3-next-80b-a3b-ep16.steady64``: its manifest entries and
+its own metrics' files (the kernel's roofline among them since PR 40), the
 configuration's file against the source's published ``config.json``,
 ``flops/moe_delta.py`` against a hand count and against the built scorer's
 leaves, the reference's control and its recurrence, and the cell's path end
-to end on the CPU at a tiny size (``backend: cpu`` set by the test).
-
-Two more pins written for fewer configurations hold no longer by
-construction and are the benchmark's to edit, not a ``model_config`` PR's:
-``test_bench_moe_conv.py::test_the_generic_metrics_list_every_cell_and_a_
-familys_own_its_cells`` counts the generic lists as exactly 21 of the
-entries and wants ``expert_skew`` on every sparse-expert cell (its file
-scales by 16 held experts; this cell holds 32 and reports the scale-free
-``expert_busiest_share`` in its place), and its
-``test_a_further_configuration_still_follows_by_additions`` knows two
-metrics that list one traffic mix's cell alone and now meets four. What the
-two guard is restated here for any number (PERF.md section 7 names them,
-with the older five, for a ``benchmark`` PR)."""
+to end on the CPU at a tiny size (``backend: cpu`` set by the test). What
+holds of the manifest for any number of configurations and of metrics that
+list their own cells alone is in ``test_bench_room.py``, read from the
+cells' own files (``family_metrics``) — the table this file kept until
+PR 40 is data now, a file a cell."""
 import bench_helpers  # noqa: F401  (puts the repo root on sys.path)
 import importlib
 import json
@@ -27,26 +19,14 @@ import time
 import numpy as np
 import pytest
 
-from bench_helpers import REPO, read_json, room_root, temp_root, write_json
+from bench_helpers import (REPO, entry_of, metrics_due, read_json, temp_root,
+                           write_json)
 from benchmark.flops import moe_delta as flops
 from benchmark.lib import manifest
 
 CONFIG, CELL = "qwen3-next-80b-a3b-ep16", "qwen3-next-80b-a3b-ep16.steady64"
-# per-layer metrics that read one family's scopes, kernels or counters, and
-# the cells each is due in
-FAMILY_METRICS = {
-    "moe_share_of_call": ["kanana2-30b-a3b-ep8.steady",
-                          "lfm2-24b-a2b-ep8.steady64", CELL],
-    "expert_held_share": ["kanana2-30b-a3b-ep8.steady",
-                          "lfm2-24b-a2b-ep8.steady64", CELL],
-    "expert_skew": ["kanana2-30b-a3b-ep8.steady",
-                    "lfm2-24b-a2b-ep8.steady64"],
-    "conv_share_of_call": ["lfm2-24b-a2b-ep8.steady64"],
-    "gated_conv_roofline": ["lfm2-24b-a2b-ep8.steady64"],
-    "delta_share_of_call": [CELL],
-    "expert_busiest_share": [CELL],
-}
-OWN_METRICS = {"delta_share_of_call", "expert_busiest_share"}
+# the per-layer metrics this family alone reports
+OWN_METRICS = {"delta_share_of_call", "gated_delta_roofline"}
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 # the source's config.json as the model-configs catalog gives it
 PUBLISHED = {
@@ -91,14 +71,9 @@ def scorer_of(config):
     return block
 
 
-# -- the manifest, for any number of configurations ---------------------------
+# -- the manifest's entries for this configuration and its cell -----------------
 
-def test_the_cell_loads_and_the_earlier_ones_stand_where_they_stood(listed):
-    cells = [w["name"] for w in listed["workloads"]]
-    assert cells[:3] == ["logbert-256x4.steady", "kanana2-30b-a3b-ep8.steady",
-                         "lfm2-24b-a2b-ep8.steady64"]
-    assert cells[3] == CELL and len(set(cells)) == len(cells)
-    assert [c["name"] for c in listed["configs"]][3] == CONFIG
+def test_the_cell_loads_with_its_traffic_and_both_end_to_end_metrics(listed):
     loaded = manifest.load_cell(REPO, CELL)
     assert loaded["entry"]["chips"] == 1
     assert loaded["traffic"]["name"] == "steady64"
@@ -106,70 +81,14 @@ def test_the_cell_loads_and_the_earlier_ones_stand_where_they_stood(listed):
     assert loaded["cell"]["name"] == CELL
     assert [m["name"] for m in loaded["end_to_end"]] == ["setup_s",
                                                          "alert_p50_ms"]
-    used = {w["config"] for w in listed["workloads"]}
-    for entry in listed["configs"]:
-        file = read_json(os.path.join(REPO, entry["file"]))
-        assert manifest.reduced_breaches(entry, file) == [], entry["name"]
-        assert entry["name"] in used
-
-
-def test_every_generic_list_has_every_cell_and_a_familys_metric_its_own(
-        listed):
-    """For any number of cells: a metric that reads one family's scopes,
-    kernels or counters lists the cells named for it, every other lists
-    every cell in the manifest's order, and each cell reports a metric of
-    every layer."""
-    cells = [w["name"] for w in listed["workloads"]]
-    by_name = {m["name"]: m["workloads"] for m in listed["per_layer"]}
-    assert set(FAMILY_METRICS) <= set(by_name)
-    for name, where in by_name.items():
-        assert where == FAMILY_METRICS.get(name, cells), name
-    generic = set(by_name) - set(FAMILY_METRICS)
-    assert len(generic) == 21
-    layers = {m["layer"] for m in listed["per_layer"]}
-    for cell in cells:
-        ours = manifest.load_cell(REPO, cell)["per_layer"]
-        assert generic <= {s["name"] for s in ours}
-        assert {s["layer"] for s in ours} == layers
-    ours = {s["name"] for s in manifest.load_cell(REPO, CELL)["per_layer"]}
-    assert ours - generic == {"moe_share_of_call", "expert_held_share",
-                              "delta_share_of_call", "expert_busiest_share"}
-    assert all(m["layer"] == "kernels" and m["moves"] == "alert_p50_ms"
-               and m["unit"] == "%" and m["better"] == "lower"
-               for m in listed["per_layer"] if m["name"] in OWN_METRICS)
-    # the core has no kernel yet, so no roofline entry stands for one
-    assert "gated_delta_roofline" not in by_name
-
-
-def test_a_further_configuration_still_follows_by_additions(tmp_path, listed):
-    """The room's rehearsal on top of four configurations: nothing that was
-    there is edited, entries are added, and the rehearsed ``.steady`` cell
-    is appended to every list that holds a ``.steady`` cell — and to no
-    list of a metric that reads another traffic mix's cells alone."""
-    root, cell = room_root(tmp_path)
-    after = read_json(os.path.join(root, "BENCHMARK.json"))
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert after[key] == listed[key]
-    assert after["configs"][:-1] == listed["configs"]
-    assert after["workloads"][:-1] == listed["workloads"]
-    assert len(after["per_layer"]) == len(listed["per_layer"]) + 1
-    for old, new in zip(listed["per_layer"], after["per_layer"]):
-        follows = any(w.endswith(".steady") for w in old["workloads"])
-        assert new == dict(old, workloads=old["workloads"]
-                           + ([cell] if follows else [])), old["name"]
-        assert follows == (old["name"] not in OWN_METRICS | {
-            "conv_share_of_call", "gated_conv_roofline"})
-    for sub in ("configs", "traffic", "cells", "layer_metrics"):
-        for name in os.listdir(os.path.join(REPO, "benchmark", sub)):
-            if name.endswith(".json"):
-                assert (read_json(os.path.join(root, "benchmark", sub, name))
-                        == read_json(os.path.join(REPO, "benchmark", sub,
-                                                  name))), name
+    assert OWN_METRICS < {s["name"] for s in loaded["per_layer"]}
+    assert {s["name"] for s in loaded["per_layer"]} == metrics_due(
+        REPO, listed, CELL)
 
 
 def test_the_manifest_entries_keep_the_contracts_lengths(listed):
-    (entry,) = [c for c in listed["configs"] if c["name"] == CONFIG]
-    (cell,) = [w for w in listed["workloads"] if w["name"] == CELL]
+    entry = entry_of(listed, "configs", CONFIG)
+    cell = entry_of(listed, "workloads", CELL)
     for text in (entry["source"], entry["why"], cell["why"]):
         assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
     assert cell == {"name": CELL, "config": CONFIG, "traffic": "steady64",
@@ -180,7 +99,7 @@ def test_the_manifest_entries_keep_the_contracts_lengths(listed):
 
 
 def test_the_own_metrics_are_data_for_readers_that_are_there():
-    for name in OWN_METRICS:
+    for name in OWN_METRICS | {"expert_busiest_share"}:
         spec = read_json(os.path.join(REPO, "benchmark", "layer_metrics",
                                       name + ".json"))
         assert spec["name"] == name and spec["layer"] == "kernels"
@@ -195,6 +114,43 @@ def test_the_own_metrics_are_data_for_readers_that_are_there():
         "detector_moe_busiest_expert_assignments_total")
     assert busiest["denominator"]["series"] == (
         "detector_moe_held_assignments_total")
+
+
+def test_the_kernels_roofline_is_its_bytes_over_its_device_time(config):
+    """``gated_delta_roofline``: a data file for ``kernel_roofline_share``
+    over ``delta_ops_and_bytes`` — one kernel call is one layer's core,
+    bound by what it moves. A hand-made reduced trace: two whole 1024-row
+    calls of three delta layers, 4 ms a kernel call."""
+    from benchmark.lib import layers
+
+    spec = read_json(os.path.join(REPO, "benchmark", "layer_metrics",
+                                  "gated_delta_roofline.json"))
+    assert (spec["kind"], spec["reducer"], spec["kernel"], spec["least"]) == (
+        "trace", "kernel_roofline_share", "gated_delta",
+        "delta_ops_and_bytes")
+    scorer = scorer_of(config)
+    ops, nbytes = flops.delta_ops_and_bytes(scorer, 1024)
+    # q | k | v in and o out in bfloat16, two gates a value head in float32
+    tokens = 1024 * 32
+    assert nbytes == tokens * (2 * (8192 + 4096) + 4 * 2 * 32) == 813694976
+    assert ops / 197e12 < nbytes / 819e9 == pytest.approx(0.99352e-3,
+                                                          rel=1e-4)
+    module = "jit__score_impl(5)"
+    trace = {"modules": {module: {"count": 2, "total_s": 0.39,
+                                  "median_s": 0.195, "whole_count": 2,
+                                  "whole_total_s": 0.39}},
+             "kernels": {"gated_delta": {module: {"seconds": 6 * 4e-3,
+                                                  "count": 6}},
+                         "lse_pallas": {module: {"seconds": 0.0278,
+                                                 "count": 2}}}}
+    ctx = {"trace": trace, "capture_buckets": [1024], "scorer": scorer,
+           "peak": {"flops_per_s": 197e12, "bytes_per_s": 819e9}}
+    assert layers.evaluate(spec, ctx) == pytest.approx(24.838, rel=1e-4)
+    # a trace in which the kernel did not run (the CPU's and a mesh's route
+    # is the chunked form; another family's cell) reports nothing, never 0
+    del trace["kernels"]["gated_delta"]
+    assert layers.evaluate(spec, ctx) is None
+    assert layers.evaluate(spec, dict(ctx, trace={})) is None
 
 
 # -- the configuration's file ------------------------------------------------
@@ -466,9 +422,9 @@ def test_a_traced_run_of_the_tiny_cell_is_correct_and_reads_the_counters(
     from benchmark import run
 
     root, cell = temp_root(tmp_path, config_name=CONFIG, model="moe_delta",
-                           traffic="steady64", rate=1500, reduced={
-                               key: {"published": 1, "here": 1, "why": "tiny"}
-                               for key in CUT})
+                           traffic="steady64", rate=1500, like=CELL,
+                           reduced={key: {"published": 1, "here": 1,
+                                          "why": "tiny"} for key in CUT})
     assert cell == "tiny-moe_delta.steady64"
     path = os.path.join(root, "benchmark", "configs", "tiny-moe_delta.json")
     tiny = read_json(path)
@@ -477,7 +433,8 @@ def test_a_traced_run_of_the_tiny_cell_is_correct_and_reads_the_counters(
     write_json(path, tiny)
     loaded = manifest.load_cell(root, cell)
     assert loaded["traffic"]["frame_lines"] == 64
-    assert OWN_METRICS | {"moe_share_of_call", "expert_held_share"} <= {
+    assert OWN_METRICS | {"moe_share_of_call", "expert_held_share",
+                          "expert_busiest_share"} <= {
         s["name"] for s in loaded["per_layer"]}
     result = run.run_cell(root, cell, 2147483647 + 13, 3.0, True,
                           platform="cpu", t_start=time.monotonic())
@@ -492,3 +449,6 @@ def test_a_traced_run_of_the_tiny_cell_is_correct_and_reads_the_counters(
     assert 50.0 <= metrics["expert_busiest_share"]["value"] <= 100.0
     assert result["compared"]["compiles_after_warmup"]["value"] == 0
     assert result["compared"]["dropped_lines"]["value"] == 0
+    # the kernel's roofline reads nothing where no kernel ran (the CPU's
+    # route is the chunked form): left out, never 0
+    assert "gated_delta_roofline" not in metrics

@@ -1,17 +1,14 @@
 """The second configuration, ``kanana2-30b-a3b-ep8`` (a sparse-expert,
-latent-attention scorer cut to one of eight chips' share), and its cell:
-the manifest with two configurations, the configuration's file against the
-source's published ``config.json``, ``flops/moe_mla.py`` against a hand
-count, the reference's control, and the cell's path end to end on the CPU at
-a tiny size (``backend: cpu`` set by the test).
-
-``test_bench_room.py`` was written when the manifest had one configuration:
-its ``test_logbert_256x4_runs_as_published`` unpacks ``configs`` into one
-entry and its ``test_the_generic_per_layer_metrics_follow_the_cell`` expects
-every metric but the room's own to list ``logbert-256x4.steady``. Both hold
-no longer by construction; what they guard is restated here for any number
-of configurations (PERF.md section 7 names the two edits for a ``benchmark``
-PR)."""
+latent-attention scorer cut to one of eight chips' share), and its cell
+``kanana2-30b-a3b-ep8.steady64`` (64-line frames since PR 40; until then
+``.steady``, whose four 256-line frames a batch spread its median by 16%):
+the configuration's file against the source's published ``config.json``,
+``flops/moe_mla.py`` against a hand count, the reference's control, and the
+cell's path end to end on the CPU at a tiny size (``backend: cpu`` set by
+the test). What holds of the manifest for any number of configurations —
+every generic list has every cell, a family's metric the cells whose
+files name it, a further configuration follows by additions — is in
+``test_bench_room.py``, read from the cells' files (``family_metrics``)."""
 import bench_helpers  # noqa: F401  (puts the repo root on sys.path)
 import importlib
 import os
@@ -20,12 +17,12 @@ import time
 import numpy as np
 import pytest
 
-from bench_helpers import REPO, read_json, room_root, temp_root, write_json
+from bench_helpers import (REPO, entry_of, metrics_due, read_json, temp_root,
+                           write_json)
 from benchmark.flops import moe_mla as flops
 from benchmark.lib import manifest
 
-CONFIG, CELL = "kanana2-30b-a3b-ep8", "kanana2-30b-a3b-ep8.steady"
-OWN_METRICS = {"moe_share_of_call", "expert_held_share", "expert_skew"}
+CONFIG, CELL = "kanana2-30b-a3b-ep8", "kanana2-30b-a3b-ep8.steady64"
 # the source's config.json as the model-configs catalog gives it
 PUBLISHED = {
     "attention_bias": False, "first_k_dense_replace": 1, "head_dim": 64,
@@ -60,43 +57,6 @@ def config():
 def scorer_of(config):
     (block,) = config["stages"]["detector"]["component"]["detectors"].values()
     return block
-
-
-# -- the manifest with two configurations -----------------------------------
-
-def test_logbert_256x4_still_runs_as_published():
-    listed = read_json(os.path.join(REPO, "BENCHMARK.json"))
-    entry = listed["configs"][0]
-    assert entry["name"] == "logbert-256x4"
-    file = read_json(os.path.join(REPO, entry["file"]))
-    assert entry["reduced"] == file["reduced"] == []
-    assert manifest.reduced_breaches(entry, file) == []
-    assert [w["name"] for w in listed["workloads"]] == [
-        "logbert-256x4.steady", CELL]
-
-
-def test_the_generic_metrics_list_both_cells_and_the_own_ones_one():
-    listed = read_json(os.path.join(REPO, "BENCHMARK.json"))["per_layer"]
-    by_name = {m["name"]: m["workloads"] for m in listed}
-    assert OWN_METRICS <= set(by_name)
-    for name, cells in by_name.items():
-        assert cells == ([CELL] if name in OWN_METRICS
-                         else ["logbert-256x4.steady", CELL]), name
-    ours = {s["name"] for s in manifest.load_cell(REPO, CELL)["per_layer"]}
-    theirs = {s["name"] for s in
-              manifest.load_cell(REPO, "logbert-256x4.steady")["per_layer"]}
-    assert ours == theirs | OWN_METRICS and len(theirs) == 21
-    assert all(m["layer"] == "kernels" and m["moves"] == "alert_p50_ms"
-               for m in listed if m["name"] in OWN_METRICS)
-
-
-def test_a_third_configuration_still_follows_by_additions(tmp_path):
-    """The room rehearsal on top of two configurations: the rehearsed cell
-    follows every list that holds a ``.steady`` cell."""
-    root, cell = room_root(tmp_path)
-    ours = {s["name"] for s in manifest.load_cell(root, cell)["per_layer"]}
-    assert ours == {s["name"] for s in manifest.load_cell(
-        REPO, CELL)["per_layer"]} | {"ffn_share_of_call"}
 
 
 # -- the configuration's file ------------------------------------------------
@@ -145,10 +105,21 @@ def test_the_scorers_arch_is_the_published_widths_and_the_share(config):
 def test_the_cell_states_its_rate_and_where_it_comes_from():
     cell = read_json(os.path.join(REPO, "benchmark", "cells",
                                   CELL + ".json"))
-    (entry,) = [w for w in read_json(os.path.join(
-        REPO, "BENCHMARK.json"))["workloads"] if w["name"] == CELL]
-    assert cell["why"] == entry["why"] and entry["chips"] == 1
+    listed = read_json(os.path.join(REPO, "BENCHMARK.json"))
+    entry = entry_of(listed, "workloads", CELL)
+    assert entry == {"name": CELL, "config": CONFIG, "traffic": "steady64",
+                     "chips": 1, "why": cell["why"]}
+    assert 1 <= len(entry["why"]) <= 200
     assert cell["rate_lines_per_s"] > 0 and "knee" in cell["rate_from"]
+    assert f"{cell['rate_lines_per_s']:,}" in cell["why"]
+    # the cell it replaced is gone, and the configuration keeps one cell
+    assert [w["name"] for w in listed["workloads"]
+            if w["config"] == CONFIG] == [CELL]
+    assert not os.path.exists(os.path.join(
+        REPO, "benchmark", "cells", CONFIG + ".steady.json"))
+    traffic = read_json(os.path.join(REPO, "benchmark", "traffic",
+                                     "steady64.json"))
+    assert (traffic["frame_lines"], traffic["arrival"]) == (64, "exponential")
 
 
 # -- flops/moe_mla.py against a hand count ------------------------------------
@@ -253,7 +224,7 @@ def test_a_traced_run_of_the_tiny_cell_is_correct_and_reads_the_counters(
     from benchmark import run
 
     root, cell = temp_root(tmp_path, config_name=CONFIG, model="moe_mla",
-                           traffic="steady", rate=1500, reduced={
+                           traffic="steady64", rate=1500, like=CELL, reduced={
                                key: {"published": 1, "here": 1, "why": "tiny"}
                                for key in ("num_hidden_layers",
                                            "n_routed_experts", "vocab_size")})
@@ -268,10 +239,15 @@ def test_a_traced_run_of_the_tiny_cell_is_correct_and_reads_the_counters(
     assert result["correct"] is True and result["failed"] == 0, printed
     assert "num_hidden_layers 1 -> 1" in printed
     metrics = result["metrics"]
-    assert {"expert_held_share", "expert_skew", "batch_occupancy",
+    assert {"expert_held_share", "expert_busiest_share", "batch_occupancy",
             "dispatch_ready_ms.lat"} <= set(metrics)
+    # the tiny cell is asked for what its family's cell reports
+    assert {s["name"] for s in manifest.load_cell(root, cell)[
+        "per_layer"]} == metrics_due(
+            REPO, read_json(os.path.join(REPO, "BENCHMARK.json")), CELL)
     # 2 of 8 experts held: a quarter of the assignments under even routing
     # (a share's router is not trained, so the fit leaves it there)
     assert 10.0 < metrics["expert_held_share"]["value"] < 45.0
-    assert 1.0 <= metrics["expert_skew"]["value"] <= 16.0
+    # the busier of the two held experts: half when balanced, all at most
+    assert 50.0 <= metrics["expert_busiest_share"]["value"] <= 100.0
     assert result["compared"]["compiles_after_warmup"]["value"] == 0

@@ -38,6 +38,70 @@ def test_nested_ops_are_not_counted_twice():
         "whole_total_s": 2.0}
 
 
+def _one_device(ops: list, modules: list, host: list = ()) -> dict:
+    return {"planes": [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Ops", "events": ops},
+            {"name": "XLA Modules", "events": modules}]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "python", "events": list(host)}]}]}
+
+
+def test_control_flow_wrappers_leave_the_list_of_operations():
+    """A ``while`` or a ``conditional`` holds its body's operations, which
+    have events of their own: listed, the wrapper would stand above every
+    kernel and count their time a second time. Busy time and scopes' self
+    time are what they were."""
+    ops = [
+        ["%while.3 = (s32[], f32[8]) while((s32[], f32[8]) %tuple.1), "
+         "condition=%cond, body=%body", 0.0, 6e6, "m/moe", "while"],
+        ["%ragged-dot.1 = f32[8] custom-call(f32[8] %x)", 1e6, 4e6,
+         "m/moe", "custom-call"],
+        ["%conditional.2 = f32[8] conditional(pred[] %p, f32[8] %a, "
+         "f32[8] %b), true_computation=%t, false_computation=%f", 6e6, 3e6,
+         "m/moe", "conditional"],
+        ["%fusion.9 = f32[8] fusion(f32[8] %a)", 7e6, 1e6, "m/moe",
+         "loop fusion"],
+        # told by its text where the events carry no category
+        ["%while.4 = (s32[]) while((s32[]) %t), condition=%c, body=%b",
+         9e6, 1e6],
+    ]
+    reduced = xplane.reduce(_one_device(ops, [["jit_f(1)", 0.0, 10e6]]))
+    names = [name for name, _ in reduced["device_ops"]]
+    assert [name.split(" = ")[0] for name in names] == [
+        "m/moe: %ragged-dot.1", "m/moe: %fusion.9"]
+    assert reduced["busy_s"] == pytest.approx(10e-3)
+    assert reduced["scopes"]["m/moe"]["self_s"] == pytest.approx(9e-3)
+    assert xplane.wraps_others(["%fusion.1 = f32[8] fusion(f32[8] %while.3)",
+                                0.0, 1.0]) is False
+
+
+def test_gaps_under_a_millisecond_leave_the_list_of_idle_gaps():
+    """What lies between two operations of one call is no gap anyone waits
+    out; a gap that stays is still named by the annotation that covers at
+    least half of it, and by none where none does."""
+    ops = [["%a = f32[8] fusion(f32[8] %p)", 0.0, 1e6],
+           ["%b = f32[8] fusion(f32[8] %p)", 1.0e6 + 9e3, 1e6],    # 9 us on
+           ["%c = f32[8] fusion(f32[8] %p)", 4.009e6, 1e6],        # 2 ms on
+           ["%d = f32[8] fusion(f32[8] %p)", 5.009e6 + 0.99e6, 1e6],
+           ["%e = f32[8] fusion(f32[8] %p)", 10.999e6, 1e6]]       # 4 ms on
+    host = [["dm.featurize#batch=3,rows=256#", 2.1e6, 0.75e6],
+            ["dm.recv_wait", 7.0e6, 3.9e6]]
+    reduced = xplane.reduce(_one_device(ops, [["jit_f(1)", 0.0, 12e6]],
+                                        host))
+    assert reduced["idle_gaps"] == [["dm.recv_wait", pytest.approx(4e-3)],
+                                    ["unattributed", pytest.approx(2e-3)]]
+    # an annotation's name ends where its arguments begin; one that covers
+    # under half of a gap does not name it
+    assert reduced["idle_gap_cover"][1] == {
+        "dm.featurize": pytest.approx(0.375)}
+    assert all(seconds >= xplane.MIN_GAP_S
+               for _, seconds in reduced["idle_gaps"])
+    # the idle share counts every gap, listed or not
+    assert reduced["window_s"] - reduced["busy_s"] == pytest.approx(
+        6.999e-3)
+
+
 def test_a_trace_without_a_device_plane_reads_nothing():
     trace = {"planes": [{"name": "/host:CPU", "lines": [
         {"name": "python3", "events": [["f", 0.0, 1e6]]}]}]}
@@ -76,10 +140,10 @@ class TestRecordedTrace:
                                                     abs=1e-4)
 
     def test_back_to_back_calls_leave_only_microsecond_gaps(self, reduced):
-        gaps = [seconds for _, seconds in reduced["idle_gaps"]]
-        assert gaps and max(gaps) < 1e-4
-        assert all(name == "unattributed" for name, _ in
-                   reduced["idle_gaps"])
+        # … which are under ``MIN_GAP_S`` and so not listed; the idle share
+        # still counts them (busy time against the window)
+        assert reduced["idle_gaps"] == [] == reduced["idle_gap_cover"]
+        assert 0 < reduced["window_s"] - reduced["busy_s"] < 1e-4
 
     def test_the_head_dominates(self, reduced):
         top = [name for name, _ in reduced["device_ops"][:3]]
@@ -224,14 +288,11 @@ class TestBuiltXSpace:
 
     def test_idle_gaps_are_named_by_the_annotation_that_covers_them(
             self, reduced):
+        # the second gap, 0.8 ms, is under ``MIN_GAP_S`` and not listed
         assert reduced["idle_gaps"] == [
-            ["dm.recv_wait", pytest.approx(1.0e-3)],
-            ["unattributed", pytest.approx(0.8e-3)]]
-        first, second = reduced["idle_gap_cover"]
+            ["dm.recv_wait", pytest.approx(1.0e-3)]]
+        (first,) = reduced["idle_gap_cover"]
         assert first == {"dm.recv_wait": pytest.approx(0.9)}
-        # an annotation's name ends where its arguments begin; one that
-        # covers under half of a gap does not name it
-        assert second == {"dm.featurize": pytest.approx(0.375)}
 
     def test_the_trace_metrics_read_it(self, reduced):
         ctx = {"trace": reduced}
@@ -344,3 +405,52 @@ def test_a_call_the_capture_cut_is_left_out_of_scopes_and_kernels():
         "m/a": pytest.approx(120e-9), "m/b": pytest.approx(80e-9)}}
     assert reduced["kernels"] == {"k": {"jit__score_impl(1)": {
         "seconds": pytest.approx(80e-9), "count": 2}}}
+
+
+# -- a failed capture prints no result (run.py) -------------------------------
+
+def test_a_capture_that_ended_in_error_ends_the_run_with_its_error():
+    from benchmark import run
+    from benchmark.lib.stages import HarnessFailure
+
+    run.refuse_failed_capture({"running": False, "last": None})
+    run.refuse_failed_capture({"running": False,
+                               "last": {"state": "done", "seconds": 4.0}})
+    run.refuse_failed_capture({"running": False, "last": {"seconds": 4.0}})
+    with pytest.raises(HarnessFailure, match="left no .xplane.pb"):
+        run.refuse_failed_capture({"running": False, "last": {
+            "state": "error",
+            "error": "stop_trace returned and left no .xplane.pb"}})
+
+
+def test_a_capture_without_a_device_event_gives_no_line(tmp_path,
+                                                        monkeypatch):
+    """On the chip a traced run whose capture holds host planes alone ends
+    before the reference and the verdict, with the planes it did find; the
+    CPU, which has no device plane, goes on (``test_bench_rehearsal.py``)."""
+    import jax
+
+    from benchmark import run
+    from benchmark.lib.stages import HarnessFailure
+
+    host_only = """
+planes {
+  name: "/host:CPU"
+  lines { name: "python" events { metadata_id: 1 offset_ps: 0
+                                  duration_ps: 5000000000 } }
+  event_metadata { key: 1 value { id: 1 name: "dm.recv_wait" } }
+}
+"""
+    folder = tmp_path / "profile" / "plugins" / "profile" / "t"
+    folder.mkdir(parents=True)
+    (folder / "host.xplane.pb").write_bytes(
+        jax.profiler.ProfileData.text_proto_to_serialized_xspace(host_only))
+    monkeypatch.setattr(run, "reference_scores", lambda *a, **k: pytest.fail(
+        "the reference ran for a run that has nothing to report"))
+    measured = dict.fromkeys(("pool", "records", "gen", "offsets", "seed",
+                              "t", "obs", "device", "scorer"))
+    measured.update(cell={"config": {}, "traffic": {}}, work=str(tmp_path),
+                    trace=True, platform="tpu")
+    with pytest.raises(HarnessFailure, match="holds no event") as failure:
+        run.conclude(measured)
+    assert "/host:CPU" in str(failure.value)
