@@ -21,8 +21,9 @@ class ScorerFamily(NamedTuple):
 
 def _no_arch(cfg) -> Optional[str]:
     if cfg.arch is not None:
-        return ("'arch' is the moe_mla, moe_conv and moe_delta families' "
-                f"shape key; model {cfg.model!r} takes dim/depth/heads")
+        return ("'arch' is the moe_mla, moe_conv, moe_delta and moe_ssm "
+                f"families' shape key; model {cfg.model!r} takes "
+                "dim/depth/heads")
     return None
 
 
@@ -77,6 +78,15 @@ def _build_moe_delta(cfg, model_kw):
 
     return MoEDeltaScorer(MoEDeltaConfig(
         arch=MoEDeltaArch.from_mapping(cfg.arch), vocab_size=cfg.vocab_size,
+        seq_len=cfg.seq_len, score_topk=cfg.score_topk,
+        attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
+
+
+def _build_moe_ssm(cfg, model_kw):
+    from ...models.moe_ssm import MoESSMArch, MoESSMConfig, MoESSMScorer
+
+    return MoESSMScorer(MoESSMConfig(
+        arch=MoESSMArch.from_mapping(cfg.arch), vocab_size=cfg.vocab_size,
         seq_len=cfg.seq_len, score_topk=cfg.score_topk,
         attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
 
@@ -140,5 +150,13 @@ FAMILIES: Dict[str, ScorerFamily] = {
             "gated grouped-query attention (fewer key/value heads than "
             "query heads, causal, rotary on a head's first lanes) is "
             "computed by the grouped einsum"),
+        lambda cfg: False),
+    "moe_ssm": ScorerFamily(
+        _build_moe_ssm,
+        _expert_family_refuses(
+            ("auto", "einsum"),
+            "grouped-query attention (fewer key/value heads than query "
+            "heads, causal, no rotary positions) is computed by the "
+            "grouped einsum"),
         lambda cfg: False),
 }

@@ -43,6 +43,17 @@ class ExpertSpec(NamedTuple):
     norm_eps: float = 1e-20  # the weights' normalisation: w / (sum + eps)
     # the shared unit times sigmoid(y · w_s), a per-token gate ([D, 1])
     shared_gate: bool = False
+    # False: every unit, routed and shared, is the non-gated
+    # down(relu(up·y)²), with no gate projection
+    gated: bool = True
+    # the routed experts live in a latent this wide, between two projections
+    # the layer owns (D -> latent before them, latent -> D behind their
+    # weighted sum); 0: at the residual's width. The router and the shared
+    # unit read the residual either way
+    latent: int = 0
+    # the shared unit's width where it is none of the routed experts';
+    # 0: shared x width
+    shared_width: int = 0
 
 
 def arch_keys(cls: type, arch: Mapping[str, Any], one_value: Mapping[str, Any],
@@ -108,6 +119,15 @@ def gated_unit(y: jax.Array, width: int, out_features: int, cfg: Any,
     return dense(out_features, cfg, prefix + "down_proj")(nn.silu(gate) * up)
 
 
+def relu2_unit(y: jax.Array, width: int, out_features: int, cfg: Any,
+               prefix: str = "") -> jax.Array:
+    """``W_down(relu(W_up·y)²)`` at ``width``, the non-gated unit: children
+    ``<prefix>up_proj`` / ``down_proj`` of the calling module."""
+    up = dense(width, cfg, prefix + "up_proj")(y)
+    return dense(out_features, cfg, prefix + "down_proj")(
+        jnp.square(nn.relu(up)))
+
+
 # jitted, so that a stack's expert layers share one trace and one lowering
 # of the routed part (a scoring program's text is a third shorter, and the
 # warm-up traces it once a bucket, not once a layer); what the trace reads
@@ -120,11 +140,13 @@ _routed_experts = jax.jit(
 def expert_walk(tokens: int, width: int, spec: ExpertSpec, platform: str,
                 mesh_devices: int) -> Tuple[int, str]:
     """``(rows a chunk of the sorted list, the way back to the tokens)``
-    of one traced call of ``tokens`` tokens by ``width`` columns: what an
-    expert layer runs and what ``expert_routes`` records."""
+    of one traced call of ``tokens`` tokens by ``width`` columns of
+    residual (the routed experts' own width is the spec's latent where it
+    has one): what an expert layer runs and what ``expert_routes``
+    records."""
     chunk = expert_ops.chunk_rows_for(tokens, spec.top_k)
-    return chunk, expert_ops.combine_route(platform, tokens, chunk, width,
-                                           mesh_devices)
+    return chunk, expert_ops.combine_route(
+        platform, tokens, chunk, spec.latent or width, mesh_devices)
 
 
 def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
@@ -138,10 +160,15 @@ def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
     gradient), ``experts_gate`` / ``experts_up`` [held, D, M],
     ``experts_down`` [held, M, D] and, with shared experts,
     ``shared_{gate,up,down}_proj`` (and ``shared_gate`` [D, 1] where the
-    spec sets it: float32, the router's precision)."""
+    spec sets it: float32, the router's precision). A non-gated spec has
+    no ``experts_gate`` and no ``shared_gate_proj``; with a latent the
+    experts' D is the latent's width and ``latent_in`` / ``latent_out``
+    (scopes of the same names) lie before and behind them."""
     d = y.shape[-1]
     init = nn.initializers.normal(cfg.initializer_range)
     m, held = spec.width, spec.held
+    # the width the routed experts live at
+    lat = spec.latent or d
     valid = valid.reshape(-1)
     router = mod.param("router", init, (d, spec.router_experts))
     if held < spec.router_experts:
@@ -160,17 +187,27 @@ def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
             norm_eps=spec.norm_eps)
     chunk, combine = expert_walk(y.shape[0], d, spec, cfg.platform,
                                  current_placement().mesh_devices)
+    routed_in = y.astype(cfg.dtype)
+    if spec.latent:
+        with jax.named_scope("latent_in"):
+            routed_in = dense(lat, cfg, "latent_in")(routed_in)
     out, per_expert = _routed_experts(
-        y.astype(cfg.dtype), routing,
-        mod.param("experts_gate", init, (held, d, m)),
-        mod.param("experts_up", init, (held, d, m)),
-        mod.param("experts_down", init, (held, m, d)),
+        routed_in, routing,
+        (mod.param("experts_gate", init, (held, lat, m)) if spec.gated
+         else None),
+        mod.param("experts_up", init, (held, lat, m)),
+        mod.param("experts_down", init, (held, m, lat)),
         offset=spec.offset, chunk_rows=chunk, combine=combine,
         platform=cfg.platform)
+    if spec.latent:
+        with jax.named_scope("latent_out"):
+            out = dense(d, cfg, "latent_out")(out.astype(cfg.dtype)).astype(
+                jnp.float32)
     if spec.shared:
         with jax.named_scope("shared"):
-            shared = gated_unit(y.astype(cfg.dtype), spec.shared * m, d, cfg,
-                                "shared_")
+            shared = (gated_unit if spec.gated else relu2_unit)(
+                y.astype(cfg.dtype), spec.shared_width or spec.shared * m, d,
+                cfg, "shared_")
         if spec.shared_gate:
             with jax.named_scope("shared_gate"):
                 shared = shared.astype(jnp.float32) * jax.nn.sigmoid(jnp.dot(

@@ -386,7 +386,8 @@ def grouped_query_attention(
     token-major layout → ``[B * S, H * D]``, ready for the output
     projection: key/value head ``g`` serves the query heads ``g·H/G ..
     (g+1)·H/G − 1``; rotary positions (base ``theta``) over the whole head
-    — or its first ``rotary_dim`` lanes, a partial rotary factor — in the
+    — or its first ``rotary_dim`` lanes, a partial rotary factor; none where
+    that is 0 (a stack whose other mixers carry the positions) — in the
     rotate-half form on q and k, turned in float32 and cast back;
     ``softmax(q·kᵀ/√D + causal and PAD mask)·v``.
 
@@ -410,9 +411,10 @@ def grouped_query_attention(
         q = q.reshape(b, s, heads, d)
         k = k.reshape(b, s, kv_heads, d)
         v = v.reshape(b, s, kv_heads, d)
-        with jax.named_scope("rope"):
-            q = rotary(q, theta, False, True, rotary_dim).astype(q.dtype)
-            k = rotary(k, theta, False, True, rotary_dim).astype(k.dtype)
+        if rotary_dim != 0:
+            with jax.named_scope("rope"):
+                q = rotary(q, theta, False, True, rotary_dim).astype(q.dtype)
+                k = rotary(k, theta, False, True, rotary_dim).astype(k.dtype)
         q = q.reshape(b, s, kv_heads, group, d)
         logits = jnp.einsum("bsgrd,btgd->bgrst", q, k,
                             preferred_element_type=jnp.float32) * d ** -0.5

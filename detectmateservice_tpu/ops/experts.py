@@ -21,7 +21,8 @@ Contract:
   chunk makes the accumulator, a ``scan`` over the rest skips the dead
   ones under ``lax.cond`` (a loop whose length follows the routing would
   have no reverse pass, and the fit takes the same walk). Inside a live
-  chunk the three matmuls of the gated unit are grouped matmuls
+  chunk the three matmuls of the gated unit (the two of a non-gated
+  one) are grouped matmuls
   (``jax.lax.ragged_dot``: on the TPU a native grouped-matmul call whose
   tiles follow the group sizes; on the CPU XLA's reference lowering).
   Under even routing 16 of 128 experts see 0.75 N assignments: two of
@@ -401,7 +402,7 @@ def way_back(way: str, acc: Optional[jax.Array], y: jax.Array,
                rows(back.weight), live), tokens, interpret)
 
 
-def routed_experts(x: jax.Array, routing: Routing, gate: jax.Array,
+def routed_experts(x: jax.Array, routing: Routing, gate: Optional[jax.Array],
                    up: jax.Array, down: jax.Array, *, offset: int = 0,
                    chunk_rows: Optional[int] = None,
                    combine: str = "scatter_add",
@@ -411,13 +412,15 @@ def routed_experts(x: jax.Array, routing: Routing, gate: jax.Array,
 
     ``gate``/``up`` [held, D, M] and ``down`` [held, M, D] are the held
     experts ``offset .. offset + held - 1`` of the gated unit
-    ``down(silu(gate·x) ⊙ up·x)``; ``x`` [N, D] is in the compute dtype.
+    ``down(silu(gate·x) ⊙ up·x)`` or, with no ``gate``, of the non-gated
+    ``down(relu(up·x)²)``; ``x`` [N, D] is in the compute dtype (D the
+    width the experts live at: the residual's, or a latent's).
     ``combine`` is the way back, one of ``WAYS_BACK`` (the caller asks
     :func:`combine_route`), ``platform`` where the call runs (the process
     default where empty)."""
     n, d = x.shape
     k = routing.experts.shape[1]
-    held = gate.shape[0]
+    held = up.shape[0]
     slots = n * k
     chunk = chunk_rows or chunk_rows_for(n, k)
     if slots % chunk:
@@ -437,7 +440,8 @@ def routed_experts(x: jax.Array, routing: Routing, gate: jax.Array,
                 else None)
         n_held = plan.ends[-1]
     # cast once, not once a chunk
-    gate, up, down = (w.astype(x.dtype) for w in (gate, up, down))
+    gate, up, down = (None if w is None else w.astype(x.dtype)
+                      for w in (gate, up, down))
 
     def one_chunk(acc: Optional[jax.Array], lo: jax.Array) -> jax.Array:
         with jax.named_scope("dispatch"):
@@ -452,11 +456,13 @@ def routed_experts(x: jax.Array, routing: Routing, gate: jax.Array,
             sizes = sizes.at[-1].add(chunk - sizes.sum())
             xs = x[tok]
         with jax.named_scope("experts"):
-            g = jax.lax.ragged_dot(xs, gate, sizes,
-                                   preferred_element_type=jnp.float32)
+            if gate is not None:
+                g = jax.lax.ragged_dot(xs, gate, sizes,
+                                       preferred_element_type=jnp.float32)
             u = jax.lax.ragged_dot(xs, up, sizes,
                                    preferred_element_type=jnp.float32)
-            h = (jax.nn.silu(g) * u).astype(x.dtype)
+            h = (jnp.square(jax.nn.relu(u)) if gate is None
+                 else jax.nn.silu(g) * u).astype(x.dtype)
             y = jax.lax.ragged_dot(h, down, sizes,
                                    preferred_element_type=jnp.float32)
         with jax.named_scope("combine"):

@@ -33,11 +33,13 @@ Two forms, told apart by :func:`conv_route`:
 
 :func:`causal_conv_silu` is the same tap walk with no gate on either side
 and SiLU behind it (a linear-attention layer's short convolution,
-models/moe_delta.py), in the plain form only.
+models/moe_delta.py, and with a bias a state-space mixer's,
+models/moe_ssm.py), in the plain form only.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -134,17 +136,23 @@ def gated_conv_xla(bcx: jax.Array, weight: jax.Array, seq: int) -> jax.Array:
     return (c * causal_taps(b * x, weight, seq)).astype(bcx.dtype)
 
 
-def causal_conv_silu(x: jax.Array, weight: jax.Array, seq: int) -> jax.Array:
-    """``silu(conv_K(x))``: the depthwise causal convolution of ``x``
+def causal_conv_silu(x: jax.Array, weight: jax.Array, seq: int,
+                     bias: Optional[jax.Array] = None) -> jax.Array:
+    """``silu(conv_K(x) + bias)``: the depthwise causal convolution of ``x``
     [tokens, D] with ``weight`` [D, K] over each line's positions (zeros
-    left of the line, no bias), then SiLU — a linear-attention layer's
-    short convolution (models/moe_delta.py), no gate on either side.
+    left of the line), plus ``bias`` [D] where the layer has one, then SiLU
+    — a linear-attention layer's short convolution (models/moe_delta.py,
+    no bias) and a state-space mixer's (models/moe_ssm.py, with one), no
+    gate on either side.
     Products, sums and the activation in float32, the result in ``x``'s
     dtype. XLA's fusion: at 1024 rows of 32 tokens and 8,192 channels it
     has to read and write 537 MB each in bfloat16, 1.3 ms at the v5e's
     819 GB/s, behind a projection of 8.4 ms."""
     with jax.named_scope("conv_xla"):
-        return jax.nn.silu(causal_taps(x, weight, seq)).astype(x.dtype)
+        v = causal_taps(x, weight, seq)
+        if bias is not None:
+            v = v + bias.astype(jnp.float32)
+        return jax.nn.silu(v).astype(x.dtype)
 
 
 def _kernel(b_ref, c_ref, x_ref, w_ref, o_ref, *, seq: int, taps: int):

@@ -453,3 +453,105 @@ def test_moe_deltas_donated_train_step_fits_the_chip(
             - stats.alias_size_in_bytes + stats.temp_size_in_bytes)
     assert held < 12_500_000_000
     assert held == pytest.approx(10_350_473_728, rel=0.05)
+
+
+# -- the state-space, latent-expert family (moe_ssm) ------------------------
+
+@pytest.fixture(scope="module")
+def moe_ssm_scorer():
+    from benchmark.lib.manifest import read_json
+    from detectmateservice_tpu.models.moe_ssm import (
+        MoESSMArch, MoESSMConfig, MoESSMScorer)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    (block,) = read_json(os.path.join(
+        repo, "benchmark", "configs", "nemotron3-super-120b-a12b-tp8.json"))[
+        "stages"]["detector"]["component"]["detectors"].values()
+    return MoESSMScorer(MoESSMConfig(
+        arch=MoESSMArch.from_mapping(block["arch"]),
+        vocab_size=block["vocab_size"], seq_len=block["seq_len"],
+        platform="tpu"))
+
+
+def held_buffers(text, shape_text):
+    """The instructions of a compiled program whose RESULT is an array of
+    ``shape_text`` outside every fused computation: what the program holds
+    in memory (inside a fusion a shape is a value in flight, no buffer)."""
+    found, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            fused = "fused_computation" in line
+        elif not fused and f"= {shape_text}" in line:
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_moe_ssms_widest_scoring_program_and_its_bytes(
+        moe_ssm_scorer, one_chip, no_compile_cache):
+    """``nemotron3-super-120b-a12b-tp8``'s 1024-row bucket as ``auto``
+    routes it on one TPU, at 8 held experts and the shared unit whole: the
+    grouped einsum for the one attention layer, the fused head at D 4096
+    and V 16,384, the segment
+    sum back from the latent experts (five walks over 44 chunks of 16,384
+    of the 720,896 assignment slots, one of them live under even routing);
+    the state-space core with no loop over positions or chunks (a line is
+    one chunk); and the router's choice at 22 of 512 held nowhere as a
+    ``[32768, 22, 512]`` float32 array (1.48 GB: it lives inside one
+    fusion). Scratch 1,779,362,816 bytes when this was written, beside 2.80
+    GB of float32 parameters — with the 8.41 GB the fitted detector holds
+    (parameters and both moments) 10.2 GB of the chip's 16. About 50 s."""
+    scorer = moe_ssm_scorer
+    params = _described(jax.eval_shape(
+        lambda: scorer.init(jax.random.PRNGKey(0))[0]), one_chip)
+    compiled = jax.jit(scorer._score_impl).lower(
+        params, shape((1024, 32), jnp.uint16, one_chip)).compile()
+    assert scorer.attn_routes == {1024: "einsum"}
+    assert scorer.head_routes == {1024: "pallas"}
+    assert "8 of 512 experts from 0" in scorer.expert_routes[1024]
+    assert "chunks of 16384 of 720896 slots" in scorer.expert_routes[1024]
+    assert "combine segment_sum" in scorer.expert_routes[1024]
+    text = compiled.as_text()
+    assert "lse_pallas" in text
+    assert text.count("segment_sum_add") and not colliding_scatters(text)
+    assert not held_buffers(text, "f32[32768,22,512]")
+    assert not held_buffers(text, "pred[32768,22,512]")
+    # the only loops are the five expert layers' walks
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 5 and not [
+        line for line in loops if "/ssm/" in line], loops[:2]
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 2_300_000_000
+    assert stats.argument_size_in_bytes == pytest.approx(
+        4 * 700_865_520, rel=1e-3)
+    # beside what the fitted detector holds: under 14.5 GB
+    assert 12 * 700_865_520 + stats.temp_size_in_bytes < 14_500_000_000
+
+
+def test_moe_ssms_donated_train_step_fits_the_chip(
+        moe_ssm_scorer, one_chip, no_compile_cache):
+    """The boundary fit's 32-row donated train step at the published widths
+    and the cut's eleven layers with 8 experts held and the shared unit
+    whole: 16 bytes a parameter while a gradient lives. XLA's buffer
+    assignment for a described v5e read 8,410,452,992 bytes of arguments
+    (parameters and both moments, aliased to the outputs) and 3,851,598,336
+    of temporaries = 12.26 GB when this was written. The check that decides
+    how many experts are held: 16 would be 921,066,480 parameters, 14.74 GB
+    at 16 bytes before any temporary, over the 14.5 GB this holds the step
+    to. About 70 s."""
+    scorer = moe_ssm_scorer
+    params, opt_state = _described(jax.eval_shape(
+        lambda: scorer.init(jax.random.PRNGKey(0))), one_chip)
+    compiled = jax.jit(scorer._train_impl, donate_argnums=(0, 1)).lower(
+        params, opt_state, shape((2,), jnp.uint32, one_chip),
+        shape((32, 32), jnp.int32, one_chip)).compile()
+    # one chunk a layer (22,528 slots): the segment sum, every block written
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and "segment_sum_add" in line]
+    assert len(kernels) == 5, len(kernels)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == pytest.approx(
+        12 * 700_865_520, rel=1e-3)
+    held = (stats.argument_size_in_bytes + stats.output_size_in_bytes
+            - stats.alias_size_in_bytes + stats.temp_size_in_bytes)
+    assert held < 14_500_000_000
+    assert held == pytest.approx(12_262_069_760, rel=0.05)
