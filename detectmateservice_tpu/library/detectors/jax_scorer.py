@@ -36,28 +36,28 @@ from .scorer_families import FAMILIES
 class JaxScorerDetectorConfig(CoreDetectorConfig):
     method_type: str = "jax_scorer"
     # "mlp" | "gru" | "logbert" | "moe_mla" | "moe_conv" | "moe_delta" |
-    # "moe_ssm" (scorer_families.FAMILIES)
+    # "moe_ssm" | "moe_kda" (scorer_families.FAMILIES)
     model: str = "mlp"
     vocab_size: int = 32768
     seq_len: int = 32
     dim: int = 128
     depth: int = 2                    # logbert/gru layers
     heads: int = 4                    # logbert only
-    # moe_mla, moe_conv, moe_delta and moe_ssm only: the model's shape as the
+    # the five sparse-expert families only: the model's shape as the
     # published config.json keys under their published names (hidden_size,
-    # num_attention_heads, kv_lora_rank, n_routed_experts ...:
-    # models/moe_mla.py MoEMLAArch; layer_types, conv_L_cache,
-    # num_key_value_heads, num_experts ...: models/moe_conv.py MoEConvArch;
-    # full_attention_interval, linear_num_value_heads, partial_rotary_factor
-    # ...: models/moe_delta.py MoEDeltaArch; hybrid_override_pattern,
-    # mamba_num_heads, ssm_state_size, moe_latent_size ...:
-    # models/moe_ssm.py MoESSMArch),
+    # kv_lora_rank, n_routed_experts ...: models/moe_mla.py MoEMLAArch;
+    # layer_types, conv_L_cache, num_experts ...: models/moe_conv.py
+    # MoEConvArch; full_attention_interval, linear_num_value_heads ...:
+    # models/moe_delta.py MoEDeltaArch; hybrid_override_pattern,
+    # mamba_num_heads, moe_latent_size ...: models/moe_ssm.py MoESSMArch;
+    # layer_group_size, kda_lower_bound, n_group, topk_group ...:
+    # models/moe_kda.py MoEKDAArch),
     # plus the chip's share of an expert-parallel group: router_experts
     # (the published count the router scores over; the published expert
     # count's key is then the count HELD here) and expert_offset (the
-    # first one held); moe_ssm also takes a tensor share: tensor_parallel
-    # (chips that share each mixer; the keys that count heads, groups and
-    # the shared unit's columns are then this chip's part) and tensor_rank
+    # first one held); moe_ssm and moe_kda also take a tensor share:
+    # tensor_parallel (chips that share each mixer; the keys that count
+    # heads and groups are then this chip's part) and tensor_rank
     arch: Optional[Dict[str, Any]] = None
     score_topk: int = 0               # logbert/gru: 0=mean NLL, k>0=top-k mean
     # logbert/gru: candidate-vocab approximate scoring NLL. 0 = exact

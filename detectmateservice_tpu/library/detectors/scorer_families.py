@@ -21,8 +21,8 @@ class ScorerFamily(NamedTuple):
 
 def _no_arch(cfg) -> Optional[str]:
     if cfg.arch is not None:
-        return ("'arch' is the moe_mla, moe_conv, moe_delta and moe_ssm "
-                f"families' shape key; model {cfg.model!r} takes "
+        return ("'arch' is the moe_mla, moe_conv, moe_delta, moe_ssm and "
+                f"moe_kda families' shape key; model {cfg.model!r} takes "
                 "dim/depth/heads")
     return None
 
@@ -87,6 +87,15 @@ def _build_moe_ssm(cfg, model_kw):
 
     return MoESSMScorer(MoESSMConfig(
         arch=MoESSMArch.from_mapping(cfg.arch), vocab_size=cfg.vocab_size,
+        seq_len=cfg.seq_len, score_topk=cfg.score_topk,
+        attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
+
+
+def _build_moe_kda(cfg, model_kw):
+    from ...models.moe_kda import MoEKDAArch, MoEKDAConfig, MoEKDAScorer
+
+    return MoEKDAScorer(MoEKDAConfig(
+        arch=MoEKDAArch.from_mapping(cfg.arch), vocab_size=cfg.vocab_size,
         seq_len=cfg.seq_len, score_topk=cfg.score_topk,
         attn_impl=cfg.attn_impl, head_impl=cfg.head_impl, **model_kw))
 
@@ -158,5 +167,13 @@ FAMILIES: Dict[str, ScorerFamily] = {
             "grouped-query attention (fewer key/value heads than query "
             "heads, causal, no rotary positions) is computed by the "
             "grouped einsum"),
+        lambda cfg: False),
+    "moe_kda": ScorerFamily(
+        _build_moe_kda,
+        _expert_family_refuses(
+            ("auto", "einsum"),
+            "latent attention behind per-head query and key norms (every "
+            "head's rope part its own, causal) is computed by the einsum "
+            "over whole heads"),
         lambda cfg: False),
 }

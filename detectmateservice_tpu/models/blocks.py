@@ -54,6 +54,11 @@ class ExpertSpec(NamedTuple):
     # the shared unit's width where it is none of the routed experts';
     # 0: shared x width
     shared_width: int = 0
+    # group-limited routing: the router's experts in n_group groups, of
+    # which a token's topk_group best stand for its choice (ops/experts.py
+    # keep_groups); 1 / 1: none
+    n_group: int = 1
+    topk_group: int = 1
 
 
 def arch_keys(cls: type, arch: Mapping[str, Any], one_value: Mapping[str, Any],
@@ -94,6 +99,17 @@ def check_share(spec: ExpertSpec) -> None:
             f"{spec.router_experts}")
     if spec.top_k > spec.router_experts:
         raise ValueError("arch.num_experts_per_tok exceeds router_experts")
+    if (spec.n_group < 1 or spec.router_experts % spec.n_group
+            or not 0 < spec.topk_group <= spec.n_group):
+        raise ValueError(
+            f"arch.n_group {spec.n_group} must divide the router's "
+            f"{spec.router_experts} experts and topk_group "
+            f"{spec.topk_group} lie in 1..n_group")
+    if (spec.n_group > 1 and spec.top_k
+            > spec.topk_group * (spec.router_experts // spec.n_group)):
+        raise ValueError(
+            f"arch.num_experts_per_tok {spec.top_k} exceeds the experts of "
+            f"topk_group {spec.topk_group} of n_group {spec.n_group} groups")
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -184,7 +200,8 @@ def expert_layer(mod: nn.Module, y: jax.Array, valid: jax.Array,
                       (spec.router_experts,)),
             valid, top_k=spec.top_k, norm_topk_prob=spec.norm_topk_prob,
             scaling=spec.scaling, scoring_func=spec.scoring_func,
-            norm_eps=spec.norm_eps)
+            norm_eps=spec.norm_eps, n_group=spec.n_group,
+            topk_group=spec.topk_group)
     chunk, combine = expert_walk(y.shape[0], d, spec, cfg.platform,
                                  current_placement().mesh_devices)
     routed_in = y.astype(cfg.dtype)

@@ -35,7 +35,9 @@ residual after, the residual stream in float32):
 * dense layers (the first ``first_k_dense_replace``):
   ``W_down(silu(W_gate·x) ⊙ W_up·x)`` at ``intermediate_size``.
 * expert layers: ops/experts.py — router over all ``router_experts`` in
-  float32, ``num_experts_per_tok`` chosen by score + correction bias,
+  float32, ``num_experts_per_tok`` chosen by score + correction bias
+  (among the experts of the ``topk_group`` best of ``n_group`` groups
+  where the published router limits the choice so: 1 / 1 is no limit),
   weights normalised over the chosen and scaled; the held experts' part of
   ``Σ w_i·E_i(x)`` plus the shared experts (one gated unit at
   ``n_shared_experts × moe_intermediate_size``).
@@ -79,8 +81,8 @@ from .blocks import (ExpertLMScorer, ExpertSpec, arch_keys, causal_stack,
 # that says otherwise is refused by name instead of being run as something
 # else
 _ONE_VALUE = {
-    "q_lora_rank": None, "rope_scaling": None, "n_group": 1,
-    "topk_group": 1, "moe_layer_freq": 1, "attention_bias": False,
+    "q_lora_rank": None, "rope_scaling": None,
+    "moe_layer_freq": 1, "attention_bias": False,
     "tie_word_embeddings": False, "hidden_act": "silu",
     "rope_interleave": True,
 }
@@ -114,6 +116,9 @@ class MoEMLAArch:
     n_routed_experts: int          # experts HELD here
     router_experts: int            # experts the router scores over
     expert_offset: int = 0         # first held expert
+    # group-limited routing (ops/experts.py keep_groups); 1 / 1: none
+    n_group: int = 1
+    topk_group: int = 1
 
     @classmethod
     def from_mapping(cls, arch: Mapping[str, Any]) -> "MoEMLAArch":
@@ -146,7 +151,8 @@ class MoEMLAArch:
             top_k=self.num_experts_per_tok,
             norm_topk_prob=self.norm_topk_prob,
             scaling=self.routed_scaling_factor,
-            scoring_func=self.scoring_func, shared=self.n_shared_experts)
+            scoring_func=self.scoring_func, shared=self.n_shared_experts,
+            n_group=self.n_group, topk_group=self.topk_group)
 
 
 @dataclasses.dataclass(frozen=True)

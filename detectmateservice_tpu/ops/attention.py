@@ -429,9 +429,94 @@ def grouped_query_attention(
 
 def sigmoid_gate(out: jax.Array, gate: jax.Array) -> jax.Array:
     """An attention core's output gate: ``out ⊙ sigmoid(gate)``, the
-    product in float32, in ``out``'s dtype."""
+    product in float32, in ``out``'s dtype. ``gate`` [N, H] beside ``out``
+    [N, H · Dv] is head-wise: one value a head, over all its lanes."""
+    if gate.shape != out.shape:
+        heads = gate.shape[-1]
+        return (out.astype(jnp.float32).reshape(-1, heads,
+                                                out.shape[-1] // heads)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+                ).astype(out.dtype).reshape(out.shape)
     return (out.astype(jnp.float32)
             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
+
+
+def latent_head_norms(
+    q: jax.Array,        # [N, H * (nope + rope)]: head by head, a head's nope part then its rope part
+    kv: jax.Array,       # [N, H * (nope + Dv)]: head by head, a head's k_nope then its values
+    k_rope: jax.Array,   # [N, rope]: one for all heads
+    q_scale: jax.Array,  # [nope + rope]: the query norm's weight
+    k_scale: jax.Array,  # [nope + rope]: the key norm's
+    eps: float,
+    heads: int,
+    nope: int,
+) -> tuple:
+    """RMSNorm over each head's whole ``nope + rope``-wide query and key,
+    before any rotation → ``(q, k)`` [N, H, nope + rope] in ``q``'s dtype
+    (statistics and scaling in float32). The key's norm reads the shared
+    ``k_rope`` beside the head's own ``k_nope``, so behind it every head
+    has a rope part of its own: what :func:`per_head_latent_attention`
+    takes and :func:`latent_attention` (one ``k_rope`` for all heads) does
+    not."""
+    n, rope = q.shape[0], k_rope.shape[-1]
+
+    def normed(x: jax.Array, scale: jax.Array) -> jax.Array:
+        x = x.astype(jnp.float32)
+        return (x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps)
+                * scale.astype(jnp.float32)).astype(q.dtype)
+
+    k = jnp.concatenate(
+        [kv.reshape(n, heads, -1)[..., :nope],
+         jnp.broadcast_to(k_rope[:, None, :], (n, heads, rope))], axis=-1)
+    return normed(q.reshape(n, heads, nope + rope), q_scale), normed(k, k_scale)
+
+
+def per_head_latent_attention(
+    q: jax.Array,        # [B * S, H, nope + rope]
+    k: jax.Array,        # [B * S, H, nope + rope]: every head's rope part its own
+    v: jax.Array,        # [B * S, H, Dv]
+    key_mask: jax.Array,  # [B, S] bool; True = attend
+    nope: int,
+    theta: float,
+    impl: str = "auto",
+    platform: Optional[str] = None,
+    causal: bool = True,
+) -> jax.Array:
+    """Latent attention's core where the keys' rope parts differ by head
+    (:func:`latent_head_norms`) → ``[B * S, H * Dv]``: rotary positions
+    (interleaved, base ``theta``; angles and products in float32) on the
+    lanes from ``nope`` up of q and k, ``softmax(q·kᵀ/√(nope + rope) +
+    causal and PAD mask)·v``. The one route is the einsum over whole heads
+    (:func:`dot_product_attention`): :func:`latent_attention`'s kernel
+    reads one ``k_rope`` for all heads. A forced kernel is refused by
+    name."""
+    b, s = key_mask.shape
+    heads = q.shape[1]
+    impl, _ = _resolve(impl, platform, (b, heads, s, q.shape[-1]), s,
+                       v.shape[-1], causal)
+    if impl != "einsum":
+        raise ValueError(
+            f"attention impl={impl!r} does not compute latent attention "
+            "behind per-head key norms (every head's rope part is its "
+            "own): 'einsum' does")
+
+    def head_major(x: jax.Array) -> jax.Array:
+        return x.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
+
+    def turned(x: jax.Array) -> jax.Array:
+        x = x.reshape(b, s, heads, -1)
+        with jax.named_scope("rope"):
+            rope = rotary(x[..., nope:], theta, heads_inside=True)
+        return head_major(jnp.concatenate(
+            [x[..., :nope], rope.astype(x.dtype)], axis=-1))
+
+    with jax.named_scope("attn_einsum"):
+        mask = key_mask[:, None, None, :]
+        if causal:
+            mask = mask & jnp.tril(jnp.ones((s, s), bool))[None, None]
+        out = dot_product_attention(turned(q), turned(k), head_major(v),
+                                    mask)
+        return merge_heads(out).reshape(b * s, -1)
 
 
 def latent_einsum(q: jax.Array, kv: jax.Array, k_rope: jax.Array,

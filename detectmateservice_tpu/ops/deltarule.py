@@ -623,3 +623,230 @@ def _fused_bwd(heads, seq, dtype, interpret, saved, grad):
 
 
 _fused.defvjp(_fused_fwd, _fused_bwd)
+
+
+# -- the delta rule whose decay is a vector a head ----------------------------
+#
+# Kimi Delta Attention (models/moe_kda.py): the state's rows decay each at
+# a rate of their own,
+#
+#     S' = Diag(exp(g_t)) · S_{t-1}           (g_t in R^Dk, lower_bound < g < 0)
+#     u_t = β_t · (v_t − S'ᵀ k_t);   S_t = S' + k_t u_tᵀ;   o_t = S_tᵀ q_t
+#
+# so the closed form has no scalar ``exp(γ_t − γ_s)`` to mask ``k kᵀ`` with:
+# the decay sits inside the contraction, ``M[t, s] = Σ_c k_tc k_sc
+# exp(γ_tc − γ_sc)``, with ``γ`` the gates' running sum over the chunk. As a
+# matmul of ``k ⊙ e^γ`` with ``k ⊙ e^−γ`` it overflows inside one served
+# line (32 positions at a gate of −5 are e^160; float32 ends at e^88.7).
+# The gate's bound is what makes it computable: the chunk's rows are taken
+# in sub-blocks of ``KDA_BLOCK`` positions, sub-block j about a reference
+# point of its own, ``r_j = γ`` just before its first position,
+#
+#     M[t, s] = (k_t ⊙ e^{γ_t − r_j}) · (k_s ⊙ e^{r_j − γ_s})     t in j, s <= t
+#
+# where the left exponent lies in ``[−KDA_BLOCK · |lower_bound|, 0]`` and
+# the right one in ``[0, KDA_BLOCK · |lower_bound|]`` for the positions of
+# j itself — 40 at the published −5 and 8 positions, e^±40 = 2.4e17 and
+# its inverse — and is <= 0 for those of earlier sub-blocks (γ falls).
+# Eight positions and not sixteen: 16 keep the exponents under 80 and
+# overflow nothing, but at the bound the left factor of a sub-block's last
+# row is then e^−80 = 1.8e-35, and a lane of ``k`` under 6e-4 leaves
+# float32's normal range with it (2e-4 of error on one position in five
+# seeded cases); within ±40 both factors keep twenty decades of room. A
+# reference point at a sub-block's middle would do the same for 16
+# positions, but it lies in the future of the sub-block's first rows: their
+# results would follow a later token's gate in the last digit, and a line's
+# positions are causal to the bit here as in the scan.
+# The rest is the scalar form's: ``A = β_t M[t, s]`` below the diagonal, ``T =
+# (I + A)^-1`` (:func:`unit_lower_inverse`), ``u = T(β ⊙ v) − T(β ⊙ e^γ ⊙ k)
+# S``, ``o = (e^γ ⊙ q) S + (M_qk, s <= t) u``, ``S ← e^{γ_C} ⊙ S + (e^{γ_C −
+# γ} ⊙ k)ᵀ u`` with every exponent of the entering state's terms <= 0.
+# Precision as the scalar form's: gates, decays, their running sums, the
+# inverse, T's products and the state in float32, the two ``M`` products
+# and the product with ``u`` on operands in ``dtype`` with float32
+# accumulation. No kernel: PERF.md sets the scope ``layer<i>/kda/core``
+# against ``benchmark/flops/moe_kda.py::kda_core_ops_and_bytes``.
+
+KDA_IMPLS = ("auto", "chunked", "scan")
+# positions a sub-block of the closed form
+KDA_BLOCK = 8
+# the largest exponent either factor of a sub-block's products may reach,
+# a sub-block at the bound: e^40 = 2.4e17, twenty decades inside float32's
+# range on either side
+_KDA_MAX_EXPONENT = 40.0
+
+
+def kda_route(impl: str, seq: int, chunk: int,
+              lower_bound: float = -5.0) -> str:
+    """``"kda chunked <C>/<B>"`` (chunks of C positions in sub-blocks of B)
+    or ``"kda scan"`` for one traced call; ``auto`` is the chunked form
+    everywhere (there is no kernel), the chunk cut to the line where the
+    line is shorter. A bound under which a sub-block's exponent could pass
+    ``_KDA_MAX_EXPONENT`` is refused by name."""
+    if impl not in KDA_IMPLS:
+        raise ValueError(f"kda impl {impl!r}: expected one of "
+                         f"{list(KDA_IMPLS)}")
+    if impl == "scan":
+        return "kda scan"
+    chunk, block = _kda_blocks(seq, chunk)
+    if seq % chunk or chunk % block:
+        raise ValueError(f"kda: chunks of {chunk} positions in sub-blocks "
+                         f"of {block} do not divide a line's {seq}")
+    if not -_KDA_MAX_EXPONENT <= lower_bound * block <= 0:
+        raise ValueError(
+            f"kda lower_bound {lower_bound}: a sub-block's {block} "
+            f"positions at that gate pass e^{_KDA_MAX_EXPONENT:g}, which "
+            "the closed form's float32 products do not hold")
+    return f"kda chunked {chunk}/{block}"
+
+
+def _kda_blocks(seq: int, chunk: int) -> Tuple[int, int]:
+    """(positions a chunk, positions a sub-block): the chunk cut to the
+    line, the sub-block to the chunk."""
+    chunk = min(chunk, seq)
+    return chunk, min(KDA_BLOCK, chunk)
+
+
+def kda_gates(f: jax.Array, b: jax.Array, a_log: jax.Array,
+              dt_bias: jax.Array, lower_bound: float
+              ) -> Tuple[jax.Array, jax.Array]:
+    """``(g [N, H, Dk], β [N, H])`` float32 from the decay projection ``f``
+    [N, H, Dk] and ``b`` [N, H]: the lower-bound gate ``g = lower_bound ·
+    sigmoid(exp(A_log_h) · (f + dt_bias))`` in ``(lower_bound, 0)``
+    (``A_log`` [H], ``dt_bias`` [H, Dk]), ``β = sigmoid(b)``."""
+    rate = jnp.exp(a_log.astype(jnp.float32))[:, None]
+    g = lower_bound * jax.nn.sigmoid(
+        rate * (f.astype(jnp.float32) + dt_bias.astype(jnp.float32)))
+    return g, jax.nn.sigmoid(b.astype(jnp.float32))
+
+
+def kda_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                   beta: jax.Array, seq: int, chunk: int = 32,
+                   impl: str = "auto", dtype: Any = jnp.bfloat16,
+                   lower_bound: float = -5.0) -> jax.Array:
+    """``q``, ``k`` and the log decay ``g`` (``lower_bound <= g <= 0``) [N,
+    H, Dk], ``v`` [N, H, Dv] and ``beta`` [N, H] over ``N = B·seq`` tokens
+    in lines of ``seq`` → ``o`` [N, H, Dv] float32. ``q`` and ``k`` arrive
+    unnormalised (:func:`l2_normalise`, ``q`` also times ``Dk^-0.5``); every
+    head has its own keys. ``chunk`` and ``lower_bound`` are static: the
+    bound is the published ``kda_lower_bound`` the gates were made with."""
+    placed = current_placement()
+    route = kda_route(impl, seq, chunk, lower_bound)
+    n, h, dk = q.shape
+    lines = n // seq
+    if placed.delta_routes is not None:
+        placed.delta_routes[lines] = route
+    q = l2_normalise(q) * dk ** -0.5
+    k = l2_normalise(k)
+
+    def by_line(x: jax.Array) -> jax.Array:
+        return x.astype(jnp.float32).reshape(lines, seq, *x.shape[1:])
+
+    operands = tuple(by_line(x) for x in (q, k, v, g, beta))
+    if route == "kda scan":
+        with jax.named_scope("kda_scan"):
+            out = _kda_scan(*operands)
+    else:
+        with jax.named_scope("kda_chunked"):
+            out = _kda_chunked(*operands, *_kda_blocks(seq, chunk), dtype)
+    return out.reshape(n, h, v.shape[2])
+
+
+def _kda_scan(q, k, v, g, beta) -> jax.Array:
+    """The recurrence, position by position: ``q``, ``k`` (normalised) and
+    ``g`` [B, S, H, Dk], ``v`` [B, S, H, Dv], ``beta`` [B, S, H]."""
+    b, _, h, dk = q.shape
+
+    def step(state, xs):
+        q_t, k_t, v_t, g_t, b_t = xs
+        state = state * jnp.exp(g_t)[..., None]
+        seen = jnp.einsum("bhkv,bhk->bhv", state, k_t, precision=_HIGHEST)
+        u_t = b_t[..., None] * (v_t - seen)
+        state = state + k_t[..., :, None] * u_t[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t,
+                                 precision=_HIGHEST)
+
+    positions_first = tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta))
+    _, out = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[3]),
+                                          jnp.float32), positions_first)
+    return jnp.moveaxis(out, 0, 1)
+
+
+def _kda_chunked(q, k, v, g, beta, chunk: int, block: int, dtype
+                 ) -> jax.Array:
+    """The closed form a chunk in sub-blocks of ``block`` positions, the
+    state carried between chunks (the section's comment). Shapes as
+    :func:`_kda_scan`."""
+    b, s, h, dk = q.shape
+    nc, c, nb = s // chunk, chunk, chunk // block
+    exact = _HIGHEST if jnp.dtype(dtype) == jnp.float32 else None
+
+    def chunks(x: jax.Array) -> jax.Array:
+        return x.reshape(b, nc, c, *x.shape[2:])
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    gamma = jnp.cumsum(g, axis=2)                         # [B, nc, C, H, Dk]
+    # r_j: γ just before sub-block j's first position (0 before the chunk)
+    ref = jnp.pad(gamma, ((0, 0), (0, 0), (1, 0), (0, 0), (0, 0)))[
+        :, :, :c:block, None]                          # [B, nc, nb, 1, H, Dk]
+
+    def by_block(x: jax.Array) -> jax.Array:
+        return x.reshape(b, nc, nb, block, h, dk)
+
+    # a chunk's positions as rows of their sub-block, about its reference
+    # point: exponents <= 0
+    shrink = jnp.exp(by_block(gamma) - ref)
+
+    def rows(x: jax.Array) -> jax.Array:
+        return (by_block(x) * shrink).astype(dtype)
+
+    # and as columns against sub-block j's rows: r_j − γ_s is <= 0 in the
+    # sub-blocks before j and within a sub-block at the bound in j; the
+    # sub-blocks behind j lie above the diagonal and read exponent 0 — set
+    # before exp, so that nothing masked is infinite on the way back either
+    seen = (jnp.arange(c) // block)[None, :] <= jnp.arange(nb)[:, None]
+    cols = (k[:, :, None] * jnp.exp(jnp.where(
+        seen[:, :, None, None], ref - gamma[:, :, None], 0.0))).astype(dtype)
+    kk, qk = (jnp.einsum("bnjthd,bnjshd->jtsbnh", rows(x), cols,
+                         precision=exact, preferred_element_type=jnp.float32
+                         ).reshape(c, c, b, nc, h) for x in (k, q))
+    lower = jnp.tril(jnp.ones((c, c), bool))[:, :, None, None, None]
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)[:, :, None, None, None]
+    a = jnp.where(strict, jnp.moveaxis(beta, 2, 0)[:, None] * kk, 0.0)
+    with jax.named_scope("solve"):
+        t_inv = unit_lower_inverse(a.reshape(c, c, -1)).reshape(a.shape)
+    t_inv = jnp.moveaxis(t_inv, (0, 1), (3, 4))            # [B, nc, H, C, C]
+    attn = jnp.moveaxis(jnp.where(lower, qk, 0.0), (0, 1), (3, 4))
+    u_v = jnp.einsum("bnhts,bnshd->bnthd", t_inv, beta[..., None] * v,
+                     precision=_HIGHEST)
+
+    def read(scores: jax.Array, u: jax.Array, index: str) -> jax.Array:
+        return jnp.einsum(index, scores.astype(dtype), u.astype(dtype),
+                          precision=exact, preferred_element_type=jnp.float32)
+
+    if nc == 1:
+        return read(attn, u_v, "bnhts,bnshd->bnthd").reshape(b, s, h, -1)
+
+    # what the entering state adds: its products stay in float32, and every
+    # exponent here is <= 0
+    grow = jnp.exp(gamma)
+    w = jnp.einsum("bnhts,bnshk->bnthk", t_inv, beta[..., None] * grow * k,
+                   precision=_HIGHEST)
+    to_end = jnp.exp(gamma[:, :, -1:] - gamma) * k
+    end = jnp.exp(gamma[:, :, -1])                            # [B, nc, H, Dk]
+
+    def step(state, xs):
+        u_c, w_c, q_c, attn_c, k_c, end_c = xs
+        u = u_c - jnp.einsum("bthk,bhkv->bthv", w_c, state,
+                             precision=_HIGHEST)
+        out = (jnp.einsum("bthk,bhkv->bthv", q_c, state, precision=_HIGHEST)
+               + read(attn_c, u, "bhts,bshd->bthd"))
+        state = (state * end_c[..., None]
+                 + jnp.einsum("bthk,bthv->bhkv", k_c, u, precision=_HIGHEST))
+        return state, out
+
+    chunks_first = tuple(jnp.moveaxis(x, 1, 0)
+                         for x in (u_v, w, q * grow, attn, to_end, end))
+    _, out = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]),
+                                          jnp.float32), chunks_first)
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, -1)

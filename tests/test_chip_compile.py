@@ -555,3 +555,108 @@ def test_moe_ssms_donated_train_step_fits_the_chip(
             - stats.alias_size_in_bytes + stats.temp_size_in_bytes)
     assert held < 14_500_000_000
     assert held == pytest.approx(12_262_069_760, rel=0.05)
+
+
+# -- the vector-decay delta rule, gated latent attention family (moe_kda) ----
+
+@pytest.fixture(scope="module")
+def moe_kda_scorer():
+    from benchmark.lib.manifest import read_json
+    from detectmateservice_tpu.models.moe_kda import (
+        MoEKDAArch, MoEKDAConfig, MoEKDAScorer)
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    (block,) = read_json(os.path.join(
+        repo, "benchmark", "configs", "ling3-flash-125b-a5b-tp4.json"))[
+        "stages"]["detector"]["component"]["detectors"].values()
+    return MoEKDAScorer(MoEKDAConfig(
+        arch=MoEKDAArch.from_mapping(block["arch"]),
+        vocab_size=block["vocab_size"], seq_len=block["seq_len"],
+        platform="tpu"))
+
+
+def test_moe_kdas_widest_scoring_program_and_its_bytes(
+        moe_kda_scorer, one_chip, no_compile_cache):
+    """``ling3-flash-125b-a5b-tp4``'s 1024-row bucket as ``auto`` routes it
+    on one TPU, at 8 heads and 8 experts held: the delta rule's chunked
+    closed form in four sub-blocks of 8 positions with no loop over
+    positions or chunks (a line is one chunk), the einsum over whole heads
+    for the one latent-attention layer (behind the key's norm every head's
+    rope part is its own), the fused head at D 2560 and V 19,648, the
+    segment sum back from the experts (walks over 16 chunks of 16,384 of
+    the 262,144 assignment slots, one of them live under even routing),
+    the grouped router's choice at 8 of 512 held nowhere as a ``[32768,
+    8, 512]`` array (it lives inside one fusion), and **layers 1-4, one
+    kind, as one scan of a block over their stacked leaves**
+    (``MoEKDALM._walk``: the program holds their body once — 21.6 MB of
+    compile-cache entry where the unrolled stack made 41.0, which is what
+    lets the configuration's seven programs stay in the 192 MiB the chip
+    tool's machine keeps). Scratch 3,010,892,800 bytes when this was
+    written (2,296,401,408 unrolled: the stack is a copy of the four
+    layers' leaves), beside 2.31 GB of float32 parameters — with the 6.93
+    GB the fitted detector holds (parameters and both moments) 9.9 GB of
+    the chip's 16. About 35 s."""
+    scorer = moe_kda_scorer
+    params = _described(jax.eval_shape(
+        lambda: scorer.init(jax.random.PRNGKey(0))[0]), one_chip)
+    compiled = jax.jit(scorer._score_impl).lower(
+        params, shape((1024, 32), jnp.uint16, one_chip)).compile()
+    assert scorer.attn_routes == {1024: "einsum"}
+    assert scorer.head_routes == {1024: "pallas"}
+    assert scorer.delta_routes == {1024: "kda chunked 32/8"}
+    assert "8 of 512 experts from 0" in scorer.expert_routes[1024]
+    assert "chunks of 16384 of 262144 slots" in scorer.expert_routes[1024]
+    assert "combine segment_sum" in scorer.expert_routes[1024]
+    text = compiled.as_text()
+    assert "lse_pallas" in text
+    assert text.count("segment_sum_add") and not colliding_scatters(text)
+    assert not held_buffers(text, "f32[32768,8,512]")
+    assert not held_buffers(text, "pred[32768,8,512]")
+    # the only loops are the run's scan with its layers' expert walk
+    # inside, and the walks of layers 5 and 6
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 4 and not [
+        line for line in loops if "/kda/" in line], loops[:2]
+    # the three scopes of the grouped router reach the compiled program
+    for step in ("scores", "groups", "top_k"):
+        assert f"/moe/router/{step}/" in text, step
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 3_500_000_000
+    assert stats.argument_size_in_bytes == pytest.approx(
+        4 * 577_867_952, rel=1e-3)
+    # beside what the fitted detector holds: under 14.5 GB
+    assert 12 * 577_867_952 + stats.temp_size_in_bytes < 14_500_000_000
+
+
+def test_moe_kdas_donated_train_step_fits_the_chip(
+        moe_kda_scorer, one_chip, no_compile_cache):
+    """The boundary fit's 32-row donated train step at the published widths
+    and the cut's seven layers with 8 heads and 8 experts held: 16 bytes a
+    parameter while a gradient lives. XLA's buffer assignment for a
+    described v5e read 6,934,534,656 bytes of arguments (parameters and
+    both moments, aliased to the outputs) and 2,950,903,808 of temporaries
+    = 9.89 GB when this was written. Sixteen experts held would be
+    860,983,472 parameters, 13.78 GB at 16 bytes before any temporary. The
+    delta rule's reverse pass is the chunked form's own (no scan over
+    positions is differentiated), and the step walks the layers one by one
+    (scanned like the scoring programs it read 6,279,259,648 bytes of
+    temporaries: the stacked leaves and their gradient). About 45 s."""
+    scorer = moe_kda_scorer
+    params, opt_state = _described(jax.eval_shape(
+        lambda: scorer.init(jax.random.PRNGKey(0))), one_chip)
+    compiled = jax.jit(scorer._train_impl, donate_argnums=(0, 1)).lower(
+        params, opt_state, shape((2,), jnp.uint32, one_chip),
+        shape((32, 32), jnp.int32, one_chip)).compile()
+    assert scorer.delta_routes[32] == "kda chunked 32/8"
+    assert " while(" not in compiled.as_text()
+    # one chunk a layer (8,192 slots): the segment sum, every block written
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and "segment_sum_add" in line]
+    assert len(kernels) == 6, len(kernels)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == pytest.approx(
+        12 * 577_867_952, rel=1e-3)
+    held = (stats.argument_size_in_bytes + stats.output_size_in_bytes
+            - stats.alias_size_in_bytes + stats.temp_size_in_bytes)
+    assert held < 14_500_000_000
+    assert held == pytest.approx(9_885_440_512, rel=0.05)

@@ -353,7 +353,12 @@ def test_the_fit_donates_and_a_shares_router_is_not_trained():
 
 @pytest.mark.parametrize("change,named", [
     ({"q_lora_rank": 64}, "q_lora_rank"),
-    ({"n_group": 2}, "n_group"),
+    # group-limited routing runs since PR 44 (tests/test_kda.py holds it to
+    # a sort); what is refused is a grouping that does not divide
+    ({"n_group": 3}, "n_group"),
+    ({"n_group": 2, "topk_group": 3}, "topk_group"),
+    ({"n_group": 4, "topk_group": 1, "num_experts_per_tok": 3},
+     "num_experts_per_tok"),
     ({"bogus": 1}, "bogus"),
     ({"expert_offset": 6}, "held experts"),
     ({"kv_lora_rank": None}, "kv_lora_rank"),
